@@ -288,9 +288,9 @@ def main() -> int:
     ap.add_argument("--persist", action="store_true")
     args = ap.parse_args()
 
-    from deepfm_tpu.core.platform import sanitize_backend
+    from deepfm_tpu.core.platform import configure_runtime
 
-    sanitize_backend()
+    configure_runtime()
     from deepfm_tpu.data.object_store import HttpObjectStore, set_store
     from deepfm_tpu.train import create_train_state
     from deepfm_tpu.utils.dev_object_store import serve

@@ -32,13 +32,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from deepfm_tpu.core.platform import (  # noqa: E402
-    relax_cpu_collective_timeouts,
-    sanitize_backend,
-)
+from deepfm_tpu.core.platform import configure_runtime  # noqa: E402
 
-sanitize_backend()
-relax_cpu_collective_timeouts()
+configure_runtime()
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -977,9 +973,7 @@ def write_md(out_dir: str) -> None:
                 "Earlier runs (2M-scale ramp, a "
                 "3-seed matched set with early-training spread 0.0097 — "
                 "the seed yardstick at that scale; §1's converged "
-                "yardstick is 0.0007) live in the `runs` history.  A "
-                "real-TPU `latest` is never demoted by CPU fallback runs; "
-                "TPU rows land via `benchmarks/tpu_session.sh`.",
+                "yardstick is 0.0007) live in the `runs` history.",
             ]
     with open(os.path.join(out_dir, "CONVERGENCE.md"), "w") as f:
         f.write("\n".join(lines) + "\n")

@@ -24,9 +24,9 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from deepfm_tpu.core.platform import sanitize_backend  # noqa: E402
+from deepfm_tpu.core.platform import configure_runtime  # noqa: E402
 
-sanitize_backend()
+configure_runtime()
 
 import _bench_util as bu  # noqa: E402
 import convergence as cv  # noqa: E402

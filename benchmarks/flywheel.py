@@ -324,9 +324,9 @@ def main() -> int:
     ap.add_argument("--persist", action="store_true")
     args = ap.parse_args()
 
-    from deepfm_tpu.core.platform import sanitize_backend
+    from deepfm_tpu.core.platform import configure_runtime
 
-    sanitize_backend()
+    configure_runtime()
     platform, device = bu.backend_platform()
     out = run_flywheel_drill(
         n_requests=args.requests, rows=args.rows, n_eval=args.eval,

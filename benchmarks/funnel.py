@@ -461,9 +461,9 @@ def bench_retrieval_modes(rank_cfg, query_cfg, qparams, index, *,
             **_percentiles_ms(lat),
         }
         if mode_label == "int8+pallas" and not row["kernel_engaged"]:
-            row["note"] = ("fused kernel not engaged on this backend "
-                           "(compile probe / non-TPU) — measured the "
-                           "lax-scan fallback")
+            row["note"] = ("fused kernel not engaged (funnel_pallas="
+                           "'auto' resolves to the lax scan) — measured "
+                           "the lax scan")
         section["modes"].append(row)
         print(json.dumps({"retrieval_bench": label, **row}),
               file=sys.stderr, flush=True)

@@ -10,8 +10,8 @@ mesh splits [2,4] / [4,2] / [8,1] and variants dense / lazy / scan8.
 
 The numbers are a SHARDING-CORRECTNESS + relative-cost signal (CPU executes
 the same GSPMD program a pod would, minus real ICI): absolute ex/s on a
-1-core host is not a perf claim, and the artifact says so.  Real-chip rates
-live in BENCH_TPU.json / docs/BENCH_SPMD_SWEEP.json.
+1-core host is not a perf claim, and the artifact says so.  Chip rates are
+not measured yet (ROADMAP S0/S7).
 
 Persists docs/MULTICHIP_FLAGSHIP.json.
 
@@ -123,12 +123,9 @@ def measure(dp: int, mp: int, variant: str, dispatches: int) -> dict:
 
 
 def run_point(args) -> None:
-    from deepfm_tpu.core.platform import (
-        relax_cpu_collective_timeouts, sanitize_backend,
-    )
+    from deepfm_tpu.core.platform import configure_runtime
 
-    sanitize_backend()
-    relax_cpu_collective_timeouts()
+    configure_runtime()
     dp, mp, variant = args.point.split(",")
     r = measure(int(dp), int(mp), variant, args.dispatches)
     print(json.dumps(r))
@@ -193,7 +190,7 @@ def main() -> None:
             "8 virtual CPU devices on one host: validates the full GSPMD "
             "program (row-sharded tables + batch sharding + collectives) at "
             "flagship vocab and shows RELATIVE mesh/variant costs; absolute "
-            "rates are not a hardware perf claim (see BENCH_TPU.json). "
+            "rates are not a hardware perf claim. "
             "shard_exchange pairs share the mesh/model/data config: on this "
             "shared-memory mesh the DENSE pair favors psum (its assembly is "
             "a memcpy; alltoall's wire win needs a wire) while the LAZY "

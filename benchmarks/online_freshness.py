@@ -237,9 +237,9 @@ def main() -> int:
     ap.add_argument("--persist", action="store_true")
     args = ap.parse_args()
 
-    from deepfm_tpu.core.platform import sanitize_backend
+    from deepfm_tpu.core.platform import configure_runtime
 
-    sanitize_backend()
+    configure_runtime()
     from deepfm_tpu.serve.export import export_servable
     from deepfm_tpu.train import create_train_state
 

@@ -44,13 +44,9 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from deepfm_tpu.core.platform import (  # noqa: E402
-    relax_cpu_collective_timeouts,
-    sanitize_backend,
-)
+from deepfm_tpu.core.platform import configure_runtime  # noqa: E402
 
-sanitize_backend()
-relax_cpu_collective_timeouts()
+configure_runtime()
 
 import numpy as np  # noqa: E402
 

@@ -198,9 +198,9 @@ def main() -> None:
     p.add_argument("--persist", action="store_true")
     args = p.parse_args()
 
-    from deepfm_tpu.core.platform import host_cpu_count, sanitize_backend
+    from deepfm_tpu.core.platform import configure_runtime, host_cpu_count
 
-    sanitize_backend()
+    configure_runtime()
     platform, device_kind = bu.backend_platform()
     buckets = tuple(int(x) for x in args.buckets.split(","))
     concs = [int(x) for x in args.concurrency.split(",")]

@@ -37,62 +37,6 @@ import numpy as np
 from ..train.step import TrainState
 from .ckpt import Checkpointer
 
-# sharding -> verdict of _reshape_under_sharding_ok (one tiny probe compile
-# per distinct (mesh, spec) pair per process)
-_RESHAPE_PROBE_CACHE: dict = {}
-
-
-def _reshape_under_sharding_ok(sharding) -> bool:
-    """Probe whether jitted row-reshapes with ``out_shardings=sharding``
-    are value-correct on this backend.
-
-    Some XLA:CPU builds (observed on jaxlib 0.4.36's 8-virtual-device
-    mesh) MISCOMPILE ``concatenate``/slice under an ``out_shardings`` whose
-    mesh has a replicated axis: the replicated output is assembled by
-    SUMMING partial shards, silently doubling every value.  Restoring a
-    checkpoint across topologies would corrupt the tables, so the jitted
-    streaming reshape is only used after this tiny probe proves it honest;
-    otherwise the adapt falls back to a host-staged pad/slice (correct
-    everywhere, O(leaf) host memory — acceptable on the small backends
-    that exhibit the bug)."""
-    key = (sharding.mesh, sharding.spec)
-    hit = _RESHAPE_PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    # dim0 divisible by any axis product; dim1 broadcastable for 1-D specs
-    rows = 8
-    for name in sharding.mesh.axis_names:
-        rows *= sharding.mesh.shape[name]
-    probe = np.arange(1, rows + 1, dtype=np.float32)
-    try:
-        cat = jax.jit(
-            lambda a: jnp.concatenate([a[: rows // 2], a[: rows // 2]]),
-            out_shardings=jax.sharding.NamedSharding(
-                sharding.mesh, jax.sharding.PartitionSpec(
-                    *(sharding.spec[:1] or [None])
-                )
-            ),
-        )(jnp.asarray(probe))
-        want = np.concatenate([probe[: rows // 2], probe[: rows // 2]])
-        # verify per ADDRESSABLE shard, not via a full device_get: on a
-        # multi-host mesh fetching the whole output raises for
-        # addressability, which says nothing about value-correctness —
-        # a blanket fetch would route every multi-host restore onto the
-        # O(full-leaf) host-staged fallback exactly where it can't afford
-        # to.  The summed-shard miscompile corrupts local shards too, so
-        # the local view is a sufficient witness.
-        ok = all(
-            np.array_equal(np.asarray(s.data), want[s.index])
-            for s in cat.addressable_shards
-        )
-    # da:allow[swallowed-exception] probe: a compile/execute failure fails the jitted path identically — fall back
-    except Exception:
-        ok = False
-    _RESHAPE_PROBE_CACHE[key] = ok
-    return ok
-
-
-
 class ReshardDataLossError(ValueError):
     """Deliberate refusal: the target vocabulary is smaller than the
     checkpoint's true data.  Semantic — NOT a torn checkpoint, so the
@@ -193,8 +137,7 @@ def relayout_state(state, target_shapes, target_shardings):
     pad or slice — slicing verifies the dropped tail is all-zero padding
     (anything else is real data and raises
     :class:`ReshardDataLossError`).  Everything stays on-device through
-    jitted reshapes (probe-guarded like the row adapt; the host fallback
-    only engages on backends whose sharded reshape miscompiles)."""
+    jitted reshapes."""
     src_leaves = jax.tree_util.tree_leaves(state)
     tgt_paths = jax.tree_util.tree_flatten_with_path(target_shapes)[0]
     tgt_def = jax.tree_util.tree_structure(target_shapes)
@@ -234,30 +177,17 @@ def relayout_state(state, target_shapes, target_shardings):
                 )
             return flat[:n].reshape(shape)
 
-        if _reshape_under_sharding_ok(sh):
-            # one jitted executable cannot span two device sets: when the
-            # source lives on a different mesh (the live reshard path),
-            # stage it onto the target mesh first
-            src_devs = getattr(getattr(s, "sharding", None),
-                               "device_set", None)
-            if src_devs is not None and src_devs != sh.device_set:
-                from jax.sharding import (
-                    NamedSharding, PartitionSpec as P2,
-                )
+        # one jitted executable cannot span two device sets: when the
+        # source lives on a different mesh (the live reshard path), stage
+        # it onto the target mesh first
+        src_devs = getattr(getattr(s, "sharding", None), "device_set", None)
+        if src_devs is not None and src_devs != sh.device_set:
+            from jax.sharding import NamedSharding, PartitionSpec as P2
 
-                s = jax.device_put(
-                    s, NamedSharding(sh.mesh, P2(*([None] * s.ndim)))
-                )
-            out.append(jax.jit(_reform, out_shardings=sh)(s))
-        else:
-            host = np.asarray(jax.device_get(s)).reshape(-1)
-            if host.size < n_t:
-                host = np.concatenate(
-                    [host, np.zeros((n_t - host.size,), host.dtype)]
-                )
-            out.append(jax.device_put(
-                host[:n_t].reshape(tuple(t.shape)), sh
-            ))
+            s = jax.device_put(
+                s, NamedSharding(sh.mesh, P2(*([None] * s.ndim)))
+            )
+        out.append(jax.jit(_reform, out_shardings=sh)(s))
     return jax.tree_util.tree_unflatten(tgt_def, out)
 
 
@@ -592,19 +522,7 @@ def _restore_tree_at(
                     f"data — the target feature_size is smaller than the "
                     f"checkpoint's true vocabulary"
                 )
-            if _reshape_under_sharding_ok(sharding):
-                return jit_row_adapter(sharding, rows_t)(saved)
-            return jax.device_put(
-                np.asarray(jax.device_get(saved))[:rows_t], sharding
-            )
-        if _reshape_under_sharding_ok(sharding):
-            return jit_row_adapter(sharding, rows_t)(saved)
-        pad = rows_t - rows_s
-        host = np.asarray(jax.device_get(saved))
-        host = np.concatenate(
-            [host, np.zeros((pad, *host.shape[1:]), host.dtype)]
-        )
-        return jax.device_put(host, sharding)
+        return jit_row_adapter(sharding, rows_t)(saved)
 
     adapted = jax.tree_util.tree_map_with_path(
         adapt, raw, target_dict, shard_dict

@@ -202,11 +202,11 @@ class ModelConfig:
     # VJP (one scatter-add update per lookup; XLA:TPU serializes colliding
     # rows) | "segsum" = sort + segment-sum + one sorted-unique write per
     # distinct row (ops/embedding.py segsum_lookup).  Default stays
-    # "scatter" until the TPU attribution bench decides
-    # (benchmarks/attribution.py; round-5 finding in docs/TPU_REPORT.md)
+    # "scatter" until a chip measurement decides (ROADMAP S1)
     table_grad: str = "scatter"
     # Pallas fused gather+FM kernel (ops/pallas_ctr.py): "off" | "auto" | "on".
-    # "auto" uses it on TPU backends; "on" forces it (interpret mode on CPU).
+    # "auto" uses it on TPU backends; "on" means the compiled kernel and
+    # raises where it cannot compile (never interpret mode).
     fused_kernel: str = "off"
     # row-sharded lookup collective strategy (parallel/embedding.py):
     # "psum" = every shard contributes a mostly-zeros [B, F, K] dense tensor,
@@ -973,8 +973,9 @@ class RunConfig:
     # measured recall@top_k falls under this is refused
     funnel_min_recall: float = 0.95
     # the fused Pallas score/top-k kernel (ops/pallas_retrieval.py):
-    # on | off | auto (auto = TPU backends only, with a compile-probe
-    # fallback to the lax composition)
+    # on | off | auto.  The TPU compiler refuses the kernel today (no
+    # Mosaic lowering for top_k), so auto = the lax composition and "on"
+    # raises with the compiler's message
     funnel_pallas: str = "auto"
     # online continuous training (task_type=online-train, online/trainer.py):
     # publish a servable version every N optimizer steps (0 = only at

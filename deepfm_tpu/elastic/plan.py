@@ -229,7 +229,6 @@ def reshard_state(state, new_ctx):
 
     from ..checkpoint.reshard import (
         _is_zero_leaf,
-        _reshape_under_sharding_ok,
         jit_row_adapter,
         relayout_state,
     )
@@ -286,10 +285,7 @@ def reshard_state(state, new_ctx):
             # odd paddings (e.g. 117,582 rows onto mp=4) take the
             # host-staged fallback — the same condition
             # _restore_resharded_tree guards with make_abstract
-            if (
-                _reshape_under_sharding_ok(sharding)
-                and leaf.shape[0] % _dim0_partitions(sharding) == 0
-            ):
+            if leaf.shape[0] % _dim0_partitions(sharding) == 0:
                 # stage the saved-shape rows onto the NEW mesh first
                 # (device_put moves shards directly; one jitted
                 # executable cannot span two device sets), then

@@ -5,7 +5,7 @@ version-consistent system.
   row-sharded over the serve mesh; per-shard matmul + ``lax.top_k``,
   candidate-pack ``all_gather``, lexicographic global merge inside one
   precompiled executable; index arrays ride as ARGUMENTS) plus the
-  brute-force bit-parity reference.
+  brute-force reference.
 * ``publish.py`` — funnel versions: ranking weights + query tower + index
   under ONE marker-last manifest (``index`` section), so retrieval and
   ranking can never skew versions.

@@ -22,8 +22,12 @@ full per-shard score tensor stays shard-local (the trace contract
 corpus-sized operand).  Ties break toward the smaller GLOBAL corpus row
 (within a shard ``lax.top_k`` already keeps the earliest row; rows are
 corpus-contiguous per shard, so the cross-shard merge key extends the
-same order), which is exactly what :func:`brute_force_topk` — the
-bit-parity reference — implements with ``np.lexsort``.
+same order), which is exactly what :func:`brute_force_topk` — the dense
+reference — implements with ``np.lexsort``.  The two agree on ids and
+order wherever two candidates' scores differ by more than the
+summation-order error of a D-term f32 dot product, and on scores within
+that error (XLA and the reference's BLAS sum the products in different
+orders, so the last bit may differ).
 
 The index arrays ride the jitted functions as ARGUMENTS (the
 serve/reload.py discipline, state-sharding per arxiv 2004.13336): a
@@ -299,21 +303,20 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
     index) rides as arguments, so an index refresh is a jit cache hit.
 
     ``ctx.retrieval_mode`` picks the per-shard scorer.  ``"exact"`` is
-    the original full-precision matmul, unchanged (bit-parity with
-    :func:`brute_force_topk`).  ``"int8"`` streams the quantized code
-    tiles through a running top-(K·oversample) (ops/pallas_retrieval.py
-    — the lax scan, or the fused Pallas kernel when ``ctx.pallas``
-    resolves on and the compile probe passes), then re-scores ONLY the
-    shortlist rows against the exact f32 embeddings (a shortlist-sized
-    gather — never the corpus) before the unchanged candidate-pack merge:
-    the output ABI, tie order, and collective footprint are identical
-    across modes."""
+    the original full-precision matmul, unchanged (the same ids and order
+    as :func:`brute_force_topk` up to f32 summation order).  ``"int8"``
+    streams the quantized code tiles through a running top-(K·oversample)
+    (ops/pallas_retrieval.py — the lax scan, or the fused Pallas kernel
+    when ``ctx.pallas`` resolves on), then re-scores ONLY the shortlist
+    rows against the exact f32 embeddings (a shortlist-sized gather —
+    never the corpus) before the unchanged candidate-pack merge: the
+    output ABI, tie order, and collective footprint are identical across
+    modes."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..core.compat import shard_map
     from ..models.two_tower import encode_tower
     from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
 
@@ -354,8 +357,6 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
         from ..ops.pallas_retrieval import (
             DEFAULT_SCAN_TILE,
             resolve_retrieval_kernel,
-            retrieval_kernel_available,
-            retrieval_kernel_lowers,
             retrieval_topk_kernel,
             score_topk_tiles,
         )
@@ -363,17 +364,6 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
         kos = k * ctx.oversample
         tile = ctx.retrieval_tile or DEFAULT_SCAN_TILE
         use_kernel = resolve_retrieval_kernel(ctx.pallas)
-        if use_kernel:
-            from ..parallel.mesh import mesh_shape
-
-            dp, mp = mesh_shape(ctx.mesh)
-            d = ctx.query_cfg.model.tower_dim
-            # probe at the largest per-shard dispatch shape; a Mosaic
-            # gap falls back to the lax scan instead of failing the boot
-            use_kernel = retrieval_kernel_lowers(
-                1, d, ctx.capacity // mp, kos, min(tile, ctx.capacity // mp)
-            )
-        interpret = use_kernel and not retrieval_kernel_available()
 
         def local_retrieve_int8(payload, user_ids, user_vals):
             u = encode_tower(
@@ -384,9 +374,7 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
             codes = payload["index"]["item_codes"]  # [rows_local, D] i8
             scl = payload["index"]["item_scales"]   # [rows_local]
             if use_kernel:
-                s_a, li = retrieval_topk_kernel(
-                    u, codes, scl, iid, kos=kos, interpret=interpret
-                )
+                s_a, li = retrieval_topk_kernel(u, codes, scl, iid, kos=kos)
             else:
                 s_a, li = score_topk_tiles(
                     u, codes, scl, iid, kos=kos, tile=tile
@@ -428,8 +416,8 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
     def retrieve_with(payload, user_ids, user_vals):
         return mapped(payload, user_ids, user_vals)
 
-    # observability: did the Pallas kernel actually engage (vs the lax
-    # scan fallback)?  funnel_snapshot and the bench read this.
+    # observability: which int8 scorer this executable runs (the Pallas
+    # kernel or the lax scan).  funnel_snapshot and the bench read this.
     retrieve_with.kernel_engaged = (
         ctx.retrieval_mode == "int8" and use_kernel
     )
@@ -449,10 +437,9 @@ def build_rank_topn_with(ctx: FunnelContext) -> Callable:
     probabilities, ``[:, 2, :]`` retrieval scores."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..core.compat import shard_map
     from ..models.base import get_model
     from ..parallel.mesh import DATA_AXIS
 
@@ -625,7 +612,9 @@ def brute_force_topk(
     user_emb: np.ndarray,
     k: int,
 ) -> tuple[np.ndarray, np.ndarray]:
-    """The dense reference the sharded index must bit-match: full
+    """The dense reference the sharded index is tested against (same ids
+    and order wherever scores differ by more than the f32 summation-order
+    bound; scores equal within it — tests/test_funnel.py): full
     ``[B, N]`` score matrix, per-row ``np.lexsort`` by (-score, corpus
     row) — descending score, ties toward the earlier corpus row, pad rows
     (id < 0) forced to ``-inf``.  Returns ``(scores [B, k], ids [B, k])``."""

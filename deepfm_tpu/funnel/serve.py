@@ -768,10 +768,13 @@ def serve_funnel(
         swapper.poll_once()   # adopt an already-published version pre-socket
         swapper.start()
     reload_status = swapper.status if swapper else None
+    from ..core.platform import runtime_report
+
+    runtime = runtime_report(mesh)
 
     def readiness():
         doc = {"ready": True, "engine_compiled": True,
-               "weights_loaded": True,
+               "weights_loaded": True, "runtime": runtime,
                "retrieval_mode": scorer.ctx.retrieval_mode}
         mv, iv = scorer.versions()
         doc["model_version"], doc["index_version"] = mv, iv

@@ -23,7 +23,7 @@ import json
 import sys
 
 from ..core.config import Config
-from ..core.platform import relax_cpu_collective_timeouts, sanitize_backend
+from ..core.platform import configure_runtime
 
 
 def _coerce(value: str):
@@ -140,8 +140,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--funnel_pallas", choices=("on", "off", "auto"),
         help="the fused Pallas score/top-k retrieval kernel "
-             "(ops/pallas_retrieval.py): on | off | auto (TPU backends, "
-             "compile-probe fallback to the lax composition)",
+             "(ops/pallas_retrieval.py): on (the compiled kernel; raises "
+             "where the compiler refuses it) | off | auto (the lax "
+             "composition today)",
     )
     p.add_argument(
         "--coordinator_url",
@@ -251,8 +252,7 @@ def main(argv: list[str] | None = None) -> int:
         from .preemption import install_early_handler
 
         install_early_handler()
-    sanitize_backend()
-    relax_cpu_collective_timeouts()
+    configure_runtime()
     from ..checkpoint import maybe_clear
     from ..train.loop import run_task
     from ..utils import MetricLogger

@@ -149,21 +149,13 @@ def apply_deepfm(
             "their own row lookup, which cannot be fused — use "
             "fused_kernel='auto' (or 'off') with those configs"
         )
-    use_fused = lookup_fn is dense_lookup and resolve_fused(cfg.fused_kernel)
-    if use_fused and 128 % cfg.embedding_size != 0:
-        if cfg.fused_kernel == "on":
-            raise ValueError(
-                f"fused_kernel='on' needs embedding_size dividing 128, "
-                f"got {cfg.embedding_size}"
-            )
-        use_fused = False  # "auto": quietly keep the XLA gather path
+    use_fused = lookup_fn is dense_lookup and resolve_fused(
+        cfg.fused_kernel, cfg.embedding_size
+    )
     if use_fused:
-        from ..core.platform import is_tpu_backend
-
         # one HBM pass: both gathers + scaling + FM sums (ops/pallas_ctr.py)
         emb, y_w, y_v = fused_ctr_interaction(
-            params["fm_w"], params["fm_v"], feat_ids, feat_vals,
-            not is_tpu_backend(),  # interpret on CPU (tests)
+            params["fm_w"], params["fm_v"], feat_ids, feat_vals
         )
     else:
         if lookup_fn is dense_lookup and cfg.table_grad == "segsum":

@@ -17,7 +17,10 @@ interpreter per record.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Iterable, Iterator, Sequence
@@ -30,47 +33,53 @@ _SRCS = [
     os.path.join(_HERE, "src", "criteo_encoder.cc"),
 ]
 _LIB_DIR = os.path.join(_HERE, "_build")
-_LIB = os.path.join(_LIB_DIR, "libdeepfm_native.so")
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-fno-exceptions",
+        "-Wall"]
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_error: str | None = None
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB):
-        return True
-    lib_mtime = os.path.getmtime(_LIB)
-    return any(lib_mtime < os.path.getmtime(s) for s in _SRCS)
+def _lib_path() -> str:
+    """The library is named by the hash of what it is built from (sources,
+    compiler command, machine), so a tree copied from elsewhere — stale
+    ``.so`` and fresh mtimes included — rebuilds exactly when its sources
+    differ from the ones the library was compiled from."""
+    h = hashlib.sha256(" ".join(_CXX + [platform.machine()]).encode())
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_LIB_DIR, f"libdeepfm_native.{h.hexdigest()[:16]}.so")
 
 
-def _build() -> None:
+def _build(lib: str) -> None:
     os.makedirs(_LIB_DIR, exist_ok=True)
-    tmp = f"{_LIB}.{os.getpid()}.tmp"  # unique per builder: concurrent
+    tmp = f"{lib}.{os.getpid()}.tmp"  # unique per builder: concurrent
     # processes each compile their own file; os.replace publishes whichever
     # finishes last, atomically
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-        "-fno-exceptions", "-Wall", *_SRCS, "-o", tmp,
-    ]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
+    proc = subprocess.run([*_CXX, *_SRCS, "-o", tmp],
+                          capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(f"native build failed:\n{proc.stderr}")
-    os.replace(tmp, _LIB)
+    os.replace(tmp, lib)
 
 
 def _load() -> ctypes.CDLL:
     global _lib, _build_error
+    if _lib is not None:       # loaded: no lock, no source hashing
+        return _lib
+    path = _lib_path()         # reads the sources — outside the lock
     with _lock:
         if _lib is not None:
             return _lib
         if _build_error is not None:
             raise RuntimeError(_build_error)
         try:
-            if _needs_build():
+            if not os.path.exists(path):
                 # da:allow[blocking-under-lock] build-once lazy init: the lock exists to make the slow compile happen exactly once; callers blocking behind it is the design
-                _build()
-            lib = ctypes.CDLL(_LIB)
+                _build(path)
+            lib = ctypes.CDLL(path)
         except Exception as e:  # remember failure; don't retry per call
             _build_error = f"{type(e).__name__}: {e}"
             raise
@@ -106,14 +115,22 @@ def _load() -> ctypes.CDLL:
 
 
 def available() -> bool:
-    """True when the native library is usable (builds it on first call)."""
+    """True when the native library is usable (builds it on first call).
+    A failed build or load is logged ONCE with the compiler's stderr — the
+    pure-Python reader then serves a host without a compiler, but never
+    silently."""
     if os.environ.get("DEEPFM_NO_NATIVE"):
         return False
+    first_failure = _build_error is None
     try:
         _load()
         return True
-    # da:allow[swallowed-exception] availability probe: build/load failure means "use the python path"
-    except Exception:
+    except (RuntimeError, OSError) as e:
+        if first_failure:
+            logging.getLogger(__name__).warning(
+                "native reader unavailable, using the pure-Python reader: %s",
+                e,
+            )
         return False
 
 

@@ -194,9 +194,9 @@ def segsum_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     """``dense_lookup`` with a sort+segment-sum backward.
 
     The gather's default VJP is a scatter-add with one update per LOOKUP
-    (B·F of them, duplicate rows colliding) — the pattern XLA:TPU
-    serializes, measured at ~9-16 ms/step for the flagship shape (round-5
-    finding, docs/TPU_REPORT.md).  This variant's backward sorts the ids
+    (B·F of them, duplicate rows colliding) — the pattern XLA:TPU is
+    suspected to serialize (ROADMAP S1; not yet measured on the chip).
+    This variant's backward sorts the ids
     once, segment-sums duplicate rows' cotangents, and issues ONE
     sorted-unique write per distinct row — the same dedup structure the
     lazy-Adam update uses (train/lazy.py).  Forward is identical

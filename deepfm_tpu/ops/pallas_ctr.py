@@ -36,18 +36,14 @@ backward: the custom VJP segment-sums row gradients by the same inverse
 map and scatter-adds each unique row once — no duplicate-index scatter
 serialization.
 
-**Measured on a real v5e chip (round 3, docs/BENCH_TPU_TUNE.json)**: v2
-compiles and is bit-correct on hardware (tests/test_pallas_ctr.py compiled)
-and the whole-step rate at the flagship shape (V=117,581, F=39, K=32) is
-within a few percent of the XLA-gather path across batch sizes — e.g.
-~170 µs vs ~135 µs at batch 1024, and at batch 4096 the fused kernel edges
-XLA out (25.0M vs 23.4M ex/s).  At this vocab the 15 MB table is
-VMEM-resident, so XLA's plain gather is already near-optimal and the step
-is bounded by the fixed dense-Adam state update; the dedup design's real
-payoff is the regime where the table does NOT fit fast memory (the
-100M-row north star served by the lazy path, docs/BENCH_LARGE_VOCAB.json).
-The default stays "off": XLA wins or ties at reference shapes, with
-hardware evidence either way.
+**On the v5e (jax 0.9.0, libtpu 0.0.34; CHANGES.md PR 21)** the kernel
+compiles and matches the lax reference at the reference widths (V=117,581,
+F=39, K=32, batch 1024): emb / y_w / y_v bit-equal, gradients within 1.4e-7
+relative — ``chip_smoke.py`` keeps that compile-and-compare step.  Its
+speed is not measured on today's code.  At this vocab the 15 MB table fits
+fast memory, so XLA's plain gather has nothing to lose; the dedup design's
+payoff, if any, is the regime where the table does NOT fit (ROADMAP S6
+decides whether the kernel stays).  The default stays "off".
 
 Only the gathered working set sits in VMEM, so the kernel scales to
 vocabularies far beyond VMEM (the 100M-row north star) — the table stays in
@@ -55,11 +51,9 @@ HBM and is touched only near the gathered rows, exactly like the
 parameter-server pull the reference does over grpc (README.md:15,63), but at
 HBM-DMA latency instead of network latency.
 
-Use ``fused_ctr_interaction`` (the custom-vjp wrapper).  On CPU the kernel
-runs in Pallas interpret mode — the same code path CI exercises
-deterministically (tests/test_pallas_ctr.py).  The default stays
-``fused_kernel="off"`` per the recorded round-3 hardware evidence above
-(bench.py measures both paths and reports the faster).
+Use ``fused_ctr_interaction`` (the custom-vjp wrapper).  It compiles for
+the chip; a CPU test asks for Pallas interpret mode by name
+(``interpret=True``, tests/test_pallas_ctr.py) — the model path never does.
 """
 
 from __future__ import annotations
@@ -70,10 +64,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 spells the unconstrained/off-chip memory space ANY; newer jax
-# added the explicit HBM alias this kernel targets
-_HBM = getattr(pltpu, "HBM", pltpu.ANY)
 
 _LANES = 128
 _N_TILE = 1024          # gathered rows per grid step
@@ -220,7 +210,7 @@ def _gather_unique(fm_v, win, sel, first, dist, dma_rows, *, interpret: bool):
         in_specs=[
             pl.BlockSpec((_N_TILE, 1), lambda i, *_: (i, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((_N_TILE, 1), lambda i, *_: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=pl.BlockSpec(
             (_N_TILE, k), lambda i, *_: (i, 0), memory_space=pltpu.VMEM
@@ -346,21 +336,26 @@ def _fused_bwd(interpret, res, cotangents):
 _fused_chunk.defvjp(_fused_fwd, _fused_bwd)
 
 
-def fused_kernel_available() -> bool:
-    """True when the default backend can run the kernel compiled (TPU)."""
-    from ..core.platform import is_tpu_backend
-
-    return is_tpu_backend()
-
-
-def resolve_fused(setting: str) -> bool:
+def resolve_fused(setting: str, embedding_size: int) -> bool:
     """Resolve ModelConfig.fused_kernel: "on" | "off" | "auto".
 
-    "auto" enables the kernel on TPU backends only; "on" forces it (interpret
-    mode on CPU — used by tests); "off" keeps the XLA gather path.
-    """
+    "on" always means the COMPILED kernel: off a TPU, or for a shape or
+    kernel the compiler refuses, the call raises with the compiler's
+    message.  "auto" takes the kernel exactly where it applies — a TPU
+    backend and an embedding_size that divides the 128-lane window — and
+    the XLA gather path elsewhere.  "off" keeps the XLA gather path.
+    Interpret mode is never a resolution: a CPU test asks for it by name
+    (``fused_ctr_interaction(..., interpret=True)`` or
+    ``pltpu.force_tpu_interpret_mode``)."""
     if setting == "on":
+        if _LANES % embedding_size:
+            raise ValueError(
+                f"fused_kernel='on' needs embedding_size dividing {_LANES}, "
+                f"got {embedding_size}"
+            )
         return True
     if setting == "auto":
-        return fused_kernel_available()
+        from ..core.platform import is_tpu_backend
+
+        return _LANES % embedding_size == 0 and is_tpu_backend()
     return False

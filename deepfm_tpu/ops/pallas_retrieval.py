@@ -31,11 +31,10 @@ Two implementations share that contract:
 * :func:`retrieval_topk_kernel` — the Pallas TPU kernel: same tiling,
   but the accumulator lives in VMEM scratch across grid steps and only
   the final ``[B, K·os]`` pair is written back — the score row never
-  round-trips HBM at all.  Gated exactly like ``fused_kernel``
-  (``resolve_retrieval_kernel``: on | off | auto) with a compile-probe
-  fallback (:func:`retrieval_kernel_lowers`) to the lax composition, so
-  a Mosaic lowering gap degrades to the portable path instead of failing
-  the boot.
+  round-trips HBM at all.  It runs in interpret mode under test and has
+  never compiled for a chip: Mosaic has no lowering for the in-kernel
+  ``lax.top_k`` (see :func:`resolve_retrieval_kernel`), so only
+  ``funnel_pallas="on"`` selects it, and that raises on today's compiler.
 
 Both return ``(scores [B, kos] f32, rows [B, kos] i32)`` sorted by
 (-score, row): ``lax.top_k`` keeps the earlier input index on ties, the
@@ -68,7 +67,7 @@ DEFAULT_KERNEL_TILE = 2048
 DEFAULT_SCREEN_GROUP = 128
 _MAX_UNROLL = 64
 
-_NEG_INF = jnp.float32(-jnp.inf)
+_NEG_INF = float("-inf")
 
 
 def _tiled(codes, scales, ids, tile: int):
@@ -248,46 +247,17 @@ def retrieval_topk_kernel(u, codes, scales, ids, *, kos: int,
 # ---------------------------------------------------------------------------
 # gating (the resolve_fused idiom, ops/pallas_ctr.py)
 
-def retrieval_kernel_available() -> bool:
-    """True when the default backend can run the kernel compiled (TPU)."""
-    from ..core.platform import is_tpu_backend
-
-    return is_tpu_backend()
-
-
 def resolve_retrieval_kernel(setting: str) -> bool:
     """Resolve the ``funnel_pallas`` knob: "on" | "off" | "auto".
 
-    "auto" engages the kernel on TPU backends only; "on" forces it
-    (interpret mode off-TPU — tests drive that path); "off" keeps the
-    lax composition."""
-    if setting == "on":
-        return True
-    if setting == "auto":
-        return retrieval_kernel_available()
-    return False
-
-
-@functools.lru_cache(maxsize=32)
-def retrieval_kernel_lowers(b: int, d: int, rows: int, kos: int,
-                            tile: int) -> bool:
-    """Compile-probe the kernel at one shard shape.  A Mosaic gap (an op
-    the TPU lowering lacks, a tiling it refuses) answers False and the
-    builder falls back to the lax composition — the knob degrades, the
-    boot never fails on it."""
-    try:
-        jax.jit(
-            lambda u, c, s, i: retrieval_topk_kernel(
-                u, c, s, i, kos=kos, tile=tile,
-                interpret=not retrieval_kernel_available(),
-            )
-        ).lower(
-            jax.ShapeDtypeStruct((b, d), jnp.float32),
-            jax.ShapeDtypeStruct((rows, d), jnp.int8),
-            jax.ShapeDtypeStruct((rows,), jnp.float32),
-            jax.ShapeDtypeStruct((rows,), jnp.int32),
-        ).compile()
-        return True
-    # da:allow[swallowed-exception] capability probe: an uncompilable kernel means "use the lax fallback", not an error
-    except Exception:
-        return False
+    The TPU compiler refuses this kernel today — on the v5e (jax 0.9.0,
+    libtpu 0.0.34; CHANGES.md PR 21) Mosaic answers ``NotImplementedError:
+    Unimplemented primitive in Pallas TPU lowering for KernelType.TC:
+    top_k`` for the in-kernel merge, and the merge is the kernel's design,
+    not a line to patch (ROADMAP S6/D5 owns the rewrite-or-delete).  So
+    "auto" resolves to the lax composition on every backend, and "on" —
+    which always means the COMPILED kernel — raises with the compiler's
+    message when the retrieve executable is built.  Nothing probes at run
+    time.  Interpret mode is something a CPU test asks for by name
+    (``retrieval_topk_kernel(..., interpret=True)``)."""
+    return setting == "on"

@@ -21,8 +21,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
-from ..core.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import Config

@@ -28,9 +28,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..core.compat import shard_map
 
 from ..core.config import Config
 from ..models.base import get_model

@@ -409,6 +409,16 @@ def main(argv: list[str] | None = None) -> int:
     if args.member_entry:
         return _run_member(args)
 
+    # one member process per host: every member opens all of its host's
+    # chips, so two members here (now, or spawned later by the autoscaler)
+    # cannot both have them
+    from ...core.platform import refuse_shared_chip
+
+    refuse_shared_chip(
+        max(args.groups, 2 if args.autoscale else 1),
+        "serve.pool --groups/--autoscale",
+    )
+
     # SIGTERM must tear the whole tree down: without a handler the
     # supervisor dies on the signal's default action and the member
     # processes ORPHAN onto init, still serving (observed live) — route

@@ -167,9 +167,9 @@ def build_sharded_predict_with(ctx: ServeGroupContext) -> Callable:
     [true, padded) can never be gathered."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ...core.compat import shard_map
     from ...models.base import get_model
     from ...ops.embedding import narrow_ids
     from ...parallel.embedding import make_sharded_lookup_fn

@@ -367,6 +367,10 @@ class GroupMember:
                     for name, ts in self._tenants.items()
                 }
                 self.compile_secs = self.tenant_compile_secs[self._default]
+        from ...core.platform import runtime_report
+
+        # after load + precompile: bytes_in_use shows the sharded payload
+        self.runtime = runtime_report(mesh)
 
     def _tenant_predict(self, holder) -> Callable:
         """Engine-facing closure for one tenant's holder over the SHARED
@@ -525,6 +529,7 @@ class GroupMember:
             "ready": True, "engine_compiled": True, "weights_loaded": True,
             "model_version": self._holder.version,
             "tenants": tenants,
+            "runtime": self.runtime,
         }
         if self.funnel:
             doc["retrieval_mode"] = self._scorer.ctx.retrieval_mode
@@ -1026,8 +1031,10 @@ def serve_member(
 
     import jax
 
+    from ...core.platform import configure_runtime
     from .sharded import build_serve_mesh
 
+    configure_runtime()
     if model_parallel <= 0:
         model_parallel = max(1, len(jax.devices()) // max(1, data_parallel))
     mesh = build_serve_mesh(data_parallel, model_parallel,

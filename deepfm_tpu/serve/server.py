@@ -734,6 +734,9 @@ def serve_pool(
     import socket
     import time
 
+    from ..core.platform import refuse_shared_chip
+
+    refuse_shared_chip(workers, "serve --workers")
     placeholder = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     placeholder.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
     placeholder.bind((host, port))
@@ -963,6 +966,9 @@ def serve_forever(
             registry=registry,
         )
         compiles = scorer.precompile()
+        from ..core.platform import runtime_report
+
+        runtime = runtime_report()
 
         def readiness():
             # the handler exists only after load + precompile, so those
@@ -970,7 +976,7 @@ def serve_forever(
             # reloader's circuit — open means the weight supply is broken
             # (store outage) and this worker may be serving stale scores
             doc = {"ready": True, "engine_compiled": True,
-                   "weights_loaded": True}
+                   "weights_loaded": True, "runtime": runtime}
             if reload_status is not None:
                 st = reload_status()
                 breaker = st.get("breaker") or {}
@@ -1060,9 +1066,9 @@ def score_stdin(
 
 
 def main(argv: list[str] | None = None) -> int:
-    from ..core.platform import sanitize_backend
+    from ..core.platform import configure_runtime
 
-    sanitize_backend()
+    configure_runtime()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--servable", required=True)
     ap.add_argument("--port", type=int, default=8501)
@@ -1145,8 +1151,9 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument(
         "--funnel-pallas", default="", choices=("", "on", "off", "auto"),
-        help="the fused Pallas score/top-k retrieval kernel: on | off | "
-             "auto (TPU backends, compile-probe fallback); '' = auto",
+        help="the fused Pallas score/top-k retrieval kernel: on (the "
+             "compiled kernel; raises where the compiler refuses it) | off "
+             "| auto (the lax scan today); '' = auto",
     )
     ap.add_argument(
         "--funnel-dp", type=int, default=1,

@@ -22,6 +22,7 @@ import numpy as np
 
 from ..checkpoint import Checkpointer, make_checkpointer, maybe_clear, restore_resharded
 from ..core.config import Config
+from ..core.platform import runtime_report
 from ..launch.preemption import PreemptedError, PreemptionGuard
 from ..data.pipeline import (
     DevicePrefetcher,
@@ -64,6 +65,19 @@ def setup(cfg: Config) -> SPMDContext:
     initialize_distributed(cfg.mesh)
     mesh = build_mesh(cfg.mesh)
     return make_context(cfg, mesh)
+
+
+def log_runtime(log: MetricLogger, mesh) -> None:
+    """The start-up report as one ``runtime`` event: which device this run
+    got, on what mesh, with which compile cache and record reader.  Emitted
+    AFTER state creation, so the per-device ``bytes_in_use`` shows where
+    the state landed."""
+    from .. import native
+
+    log.event(
+        "runtime", **runtime_report(mesh),
+        record_reader="native" if native.available() else "python",
+    )
 
 
 def _cpu_serialize_dispatch() -> bool:
@@ -201,14 +215,16 @@ def restore_latest(
     log: MetricLogger | None = None,
 ) -> TrainState:
     """Restore the latest checkpoint into the running mesh: exact-shape
-    restore first; on a table-shape mismatch (the checkpoint was written
-    under a different mesh topology — padded vocab differs) fall back to
-    the cross-topology resharding restore."""
+    restore first; on a mismatch that another mesh topology explains — the
+    padded vocab differs (table shapes), or the optimizer state is in the
+    other zero-sharding layout because the data-parallel degree crossed 1
+    (tree structure) — fall back to the cross-topology resharding restore."""
     try:
         return ckpt.restore(state)
     except Exception as e:
         msg = str(e)
-        if not any(k in msg for k in ("shape", "Sizes", "fm_v", "embedding")):
+        if not any(k in msg for k in ("shape", "Sizes", "fm_v", "embedding",
+                                      "structure")):
             raise
         if log is not None:
             log.event("resume_reshard", reason=msg[:200])
@@ -383,6 +399,7 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
     if ckpt.latest_step() is not None:
         state = restore_latest(ckpt, ctx, state, log)
         log.event("resume", step=int(state.step))
+    log_runtime(log, ctx.mesh)
     train_step = make_spmd_train_step(ctx)
     steps_per_loop = max(1, cfg.run.steps_per_loop)
     loop_step = (
@@ -522,6 +539,7 @@ def run_infer(cfg: Config, *, output_path: str | None = None) -> str:
         )
     ckpt = make_checkpointer(cfg.run.model_dir)
     state = restore_latest(ckpt, ctx, create_spmd_state(ctx))
+    log_runtime(MetricLogger(), ctx.mesh)
     predict_step = make_spmd_predict_step(ctx)
     # fallback chain, not a union: te*/test* first (the reference's infer
     # globs te* only, ps:526-533); va*/val* only when no test files exist
@@ -559,6 +577,7 @@ def run_export(cfg: Config) -> str:
     ctx = setup(cfg)
     ckpt = make_checkpointer(cfg.run.model_dir)
     state = restore_latest(ckpt, ctx, create_spmd_state(ctx))
+    log_runtime(MetricLogger(), ctx.mesh)
     path = export_servable(ctx.cfg, state, cfg.run.servable_model_dir)
     ckpt.close()
     MetricLogger().event("export", path=path)
@@ -626,6 +645,7 @@ def _run_retrieval_train_guarded(
     if ckpt.latest_step() is not None:
         state = ckpt.restore(state)
         log.event("resume", step=int(state.step))
+    log_runtime(log, ctx.mesh)
     train_step = make_retrieval_spmd_train_step(ctx)
 
     step = int(state.step)
@@ -911,6 +931,7 @@ def run_task(cfg: Config):
         ctx = setup(cfg)
         ckpt = make_checkpointer(cfg.run.model_dir)
         state = restore_latest(ckpt, ctx, create_spmd_state(ctx))
+        log_runtime(MetricLogger(), ctx.mesh)
         result = run_eval(cfg, ctx, state, MetricLogger())
         ckpt.close()
         return result

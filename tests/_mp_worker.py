@@ -27,9 +27,9 @@ def main() -> None:
     ).strip()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
-    from deepfm_tpu.core.platform import sanitize_backend
+    from deepfm_tpu.core.platform import configure_runtime
 
-    sanitize_backend()
+    configure_runtime()
     from deepfm_tpu.core.config import Config
 
     lazy = bool(int(os.environ.get("MP_TEST_LAZY", "0")))

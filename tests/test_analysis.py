@@ -1993,11 +1993,10 @@ class TestFunnelContract:
         globally — corpus-proportional wire bytes per query batch."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from jax import lax, shard_map
         from jax.sharding import PartitionSpec as P
 
         from deepfm_tpu.analysis.trace_audit import audit_funnel
-        from deepfm_tpu.core.compat import shard_map
         from deepfm_tpu.models.two_tower import encode_tower
         from deepfm_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
@@ -2087,11 +2086,10 @@ class TestFunnelContract:
         exact copy the quantized scorer exists to never hold."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from jax import lax, shard_map
         from jax.sharding import PartitionSpec as P
 
         from deepfm_tpu.analysis.trace_audit import audit_funnel
-        from deepfm_tpu.core.compat import shard_map
         from deepfm_tpu.models.two_tower import encode_tower
         from deepfm_tpu.parallel.mesh import DATA_AXIS
 
@@ -2134,11 +2132,10 @@ class TestFunnelContract:
         convict it even though no corpus-sized f32 exists."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from jax import lax, shard_map
         from jax.sharding import PartitionSpec as P
 
         from deepfm_tpu.analysis.trace_audit import audit_funnel
-        from deepfm_tpu.core.compat import shard_map
         from deepfm_tpu.models.two_tower import encode_tower
         from deepfm_tpu.ops.pallas_retrieval import score_topk_tiles
         from deepfm_tpu.parallel.mesh import DATA_AXIS

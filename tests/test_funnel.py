@@ -1,5 +1,5 @@
-"""Recommendation funnel (deepfm_tpu/funnel): sharded top-K bit-parity
-with brute force on both mesh orientations (ties + padded-vocab rows),
+"""Recommendation funnel (deepfm_tpu/funnel): sharded top-K parity with
+brute force on both mesh orientations (ties + padded-vocab rows),
 the /v1/recommend end-to-end path vs the naive two-stage loop, atomic
 index+weights publishing, the mid-load version-skew drill, and the pool
 member/router integration."""
@@ -136,10 +136,14 @@ def _instances(rng, b):
 
 @pytest.mark.parametrize("dp,mp", [(2, 4), (4, 2)])
 def test_ann_topk_bit_parity(funnel_env, dp, mp):
-    """Sharded retrieve == brute force on both mesh orientations: same
-    ids (including across the engineered exact ties — the (-score,
-    corpus row) merge key is total), same scores, and padded-vocab rows
-    never returned."""
+    """Sharded retrieve vs brute force on both mesh orientations — what
+    the exact tier guarantees: the same ids in the same order (including
+    across the engineered exact ties — the (-score, corpus row) merge key
+    is total) wherever two candidates' scores differ by more than the
+    summation-order bound of a D-term f32 dot product, scores equal to the
+    reference within that bound, and padded-vocab rows never returned.
+    (The reference is numpy's BLAS matmul and the index is XLA's: the two
+    sum the D products in different orders, so the last bit may differ.)"""
     from deepfm_tpu.funnel import (
         brute_force_topk, build_retrieve_with, make_funnel_context,
         stage_funnel_payload,
@@ -173,8 +177,24 @@ def test_ann_topk_bit_parity(funnel_env, dp, mp):
     pad_emb[:N_ITEMS] = env["index"].item_emb
     ref_s, ref_i = brute_force_topk(pad_emb, pad_ids, u, TOP_K)
 
-    np.testing.assert_array_equal(c, ref_i)
-    np.testing.assert_array_equal(s, ref_s)
+    # two f32 evaluations of one D-term dot product each err by at most
+    # gamma_D * sum|u_d e_d|, so they differ by at most D * eps * sum|u_d e_d|
+    full = u @ pad_emb.T
+    bound = (np.finfo(np.float32).eps * u.shape[1]
+             * (np.abs(u) @ np.abs(pad_emb).T))
+    row_of = {int(i): r for r, i in enumerate(pad_ids) if i >= 0}
+    rows = np.vectorize(row_of.__getitem__)(c)
+    ref_rows = np.vectorize(row_of.__getitem__)(ref_i)
+    b_idx = np.arange(c.shape[0])[:, None]
+    assert (np.abs(s - full[b_idx, rows]) <= bound[b_idx, rows]).all()
+    # an id may differ from the reference's only between near-ties
+    swapped = c != ref_i
+    assert (np.abs(full[b_idx, rows] - full[b_idx, ref_rows])[swapped]
+            <= (bound[b_idx, rows] + bound[b_idx, ref_rows])[swapped]).all()
+    # the bound is far below the spread of the scores, so it excuses
+    # nothing but last-bit differences (exact ties are ordered by the
+    # tie-break alone: test_tie_break_prefers_earlier_corpus_row)
+    assert (ref_s.max() - ref_s.min()) > 100 * bound.max()
     # padded rows are unreturnable and every id is a real corpus id
     assert (c >= 0).all()
     assert set(c.ravel().tolist()) <= set(env["index"].item_ids.tolist())

@@ -1,4 +1,5 @@
-"""Pallas fused CTR kernel vs the XLA oracle (interpret mode on CPU).
+"""Pallas fused CTR kernel vs the XLA oracle (interpret mode, asked for by
+name, on the CPU; compiled under DEEPFM_TEST_TPU=1).
 
 Validates the hand-scheduled gather+FM kernel (ops/pallas_ctr.py) against
 the plain-JAX path that reproduces the reference math (ps:206-217), both
@@ -17,7 +18,8 @@ from deepfm_tpu.ops.fm import fm_first_order, fm_second_order
 from deepfm_tpu.core.platform import is_tpu_backend
 from deepfm_tpu.ops.pallas_ctr import fused_ctr_interaction
 
-# compiled on real TPU (DEEPFM_TEST_TPU=1), interpret mode on CPU CI
+# compiled on the chip (DEEPFM_TEST_TPU=1); on the CPU the tests ask for
+# interpret mode by name
 INTERPRET = not is_tpu_backend()
 from deepfm_tpu.train import create_train_state
 
@@ -80,7 +82,18 @@ def test_gradients_match_oracle():
         np.testing.assert_allclose(g, w_, rtol=1e-4, atol=1e-4, err_msg=name)
 
 
-def test_deepfm_forward_identical_with_fused_kernel():
+def test_deepfm_forward_identical_with_fused_kernel(monkeypatch):
+    if INTERPRET:
+        # fused_kernel="on" means the COMPILED kernel; off a TPU the test
+        # asks for interpret mode by name at the model's call site
+        import functools
+
+        import deepfm_tpu.models.deepfm as deepfm_mod
+
+        monkeypatch.setattr(
+            deepfm_mod, "fused_ctr_interaction",
+            functools.partial(fused_ctr_interaction, interpret=True),
+        )
     base = Config.from_dict(
         {
             "model": {
@@ -106,6 +119,16 @@ def test_deepfm_forward_identical_with_fused_kernel():
         state.params, state.model_state, ids, vals, cfg=fused_cfg.model, train=False
     )
     np.testing.assert_allclose(logits_on, logits_off, rtol=2e-3, atol=2e-3)
+
+
+def test_on_means_compiled_never_interpreted():
+    """``fused_kernel="on"`` off a TPU raises the compiler's refusal; it
+    does not turn into interpret mode."""
+    if not INTERPRET:
+        pytest.skip("on a TPU 'on' compiles (covered by the tests above)")
+    fm_w, fm_v, ids, vals = _random_problem(batch=8)
+    with pytest.raises(ValueError, match="interpret mode"):
+        fused_ctr_interaction(fm_w, fm_v, ids, vals)
 
 
 def test_forward_and_grads_with_heavy_duplicates():

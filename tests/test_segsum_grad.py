@@ -1,7 +1,7 @@
 """segsum embedding-gradient path (ops/embedding.py segsum_lookup).
 
-The gather's default VJP scatter-adds one update per lookup; XLA:TPU
-serializes colliding rows (round-5 finding, docs/TPU_REPORT.md).  The
+The gather's default VJP scatter-adds one update per lookup, which XLA:TPU
+is suspected to serialize on colliding rows (ROADMAP S1).  The
 segsum backward sorts ids, segment-sums duplicates, and writes once per
 distinct row.  These tests pin: exact forward equality, gradient equality
 vs the scatter backward (to f32 tolerance — duplicate contributions are

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from deepfm_tpu.core.compat import shard_map
+from jax import shard_map
 from deepfm_tpu.core.config import Config, MeshConfig
 from deepfm_tpu.ops import dense_lookup
 from deepfm_tpu.parallel import (

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from deepfm_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepfm_tpu.core.config import Config, MeshConfig
