@@ -1776,8 +1776,9 @@ def audit_observability(cfg=None, predict_builder=None,
     """The unified-observability contract: instrumentation NEVER enters
     lowered code.  The real serving predict
     (``serve.reload.build_predict_with`` — what the instrumented
-    MicroBatcher dispatches) and the canonical train step (what the
-    ``StepPhases``-timed loop dispatches) must still
+    MicroBatcher dispatches) and the canonical train step (what the loop
+    dispatches under the ``SpanRecorder``'s ``train.dispatch`` span, with
+    its ``jax.named_scope``s — metadata only) must still
 
     * lower under ``jax.transfer_guard("disallow")`` (a registry call on
       a traced value concretizes it or forces a transfer — either way
@@ -1850,10 +1851,10 @@ def audit_observability(cfg=None, predict_builder=None,
         out.append(_finding(
             "trace-observability",
             f"lowering the train step with the observability layer "
-            f"active raised {type(e).__name__}: {e} — step-phase timers "
+            f"active raised {type(e).__name__}: {e} — a recorder span "
             f"or a registry call ran under trace",
-            hint="StepPhases wraps the dispatch on the host "
-                 "(train/loop.py); nothing records inside the step",
+            hint="the span recorder (obs/trace.py) wraps the dispatch on "
+                 "the host (train/loop.py); nothing records inside the step",
             where=where, slug="obs-train-lower",
         ))
     else:

@@ -915,7 +915,7 @@ class RunConfig:
     checkpoint_every_steps: int = 1000
     keep_checkpoints: int = 3
     seed: int = 0
-    profile_dir: str = ""             # jax.profiler trace dir ("" = off)
+    profile_dir: str = ""             # jax.profiler trace of 20 steps after the first logged window ("" = off)
     serve_port: int = 8501            # task_type=serve bind port
     serve_host: str = "127.0.0.1"     # bind address (0.0.0.0 for remote clients)
     serve_item_corpus: str = ""       # two-tower: JSONL corpus for :retrieve

@@ -17,6 +17,7 @@ the TPU never waits on the host thanks to the prefetch depth.
 from __future__ import annotations
 
 import glob as globlib
+import itertools
 import os
 import queue
 import random
@@ -26,6 +27,7 @@ from typing import Callable, Iterable, Iterator
 import numpy as np
 
 from ..core.config import DataConfig
+from ..obs.trace import get_span_recorder
 from .example_proto import decode_ctr_batch
 from .object_store import get_store, is_url, open_source
 from .sharding import ShardDecision, WorkerTopology, shard_plan
@@ -484,6 +486,11 @@ class DevicePrefetcher:
     Observers must be fast and non-raising (an exception would kill the
     feed); the tiered observer just enqueues ids to a background worker.
 
+    Both threads record into the training path's span recorder
+    (``obs/trace.py``): the worker ``feed.source`` / ``feed.put`` /
+    ``feed.offer`` under the seq it mints per batch, the consumer
+    ``feed.take`` — its ``q.get()`` and nothing else — under the same seq.
+
     Abandoning iteration early?  Call ``close()`` (or use as a context
     manager) — otherwise the worker would sit blocked on a full queue holding
     ``depth`` device-resident batches alive.
@@ -502,6 +509,10 @@ class DevicePrefetcher:
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
         self._err: BaseException | None = None
         self._stop = threading.Event()
+        # the queue is FIFO with one consumer, so the n-th take gets the
+        # batch the worker minted seq n for
+        self._taken = 0
+        rec = self._rec = get_span_recorder()
 
         def offer(item) -> bool:
             while not self._stop.is_set():
@@ -514,11 +525,19 @@ class DevicePrefetcher:
 
         def worker():
             try:
-                for b in batches:
+                it = iter(batches)
+                for seq in itertools.count():
+                    with rec.span("feed.source", seq):
+                        b = next(it, self._DONE)
+                    if b is self._DONE:
+                        return
                     if observer is not None:
                         observer(b)
-                    if not offer(put(b)):
-                        return
+                    with rec.span("feed.put", seq):
+                        placed = put(b)
+                    with rec.span("feed.offer", seq):
+                        if not offer(placed):
+                            return
             except BaseException as e:  # surfaced on next __next__
                 self._err = e
             finally:
@@ -531,7 +550,8 @@ class DevicePrefetcher:
         return self
 
     def __next__(self):
-        item = self._q.get()
+        with self._rec.span("feed.take", self._taken):
+            item = self._q.get()
         if item is self._DONE:
             # keep the sentinel in the queue: next() after exhaustion must
             # re-raise StopIteration, not block on an empty queue forever
@@ -539,6 +559,7 @@ class DevicePrefetcher:
             if self._err is not None:
                 raise self._err
             raise StopIteration
+        self._taken += 1
         return item
 
     def close(self) -> None:

@@ -46,6 +46,7 @@ def init_cross(key: jax.Array, dim: int, num_layers: int) -> dict:
     return params
 
 
+@jax.named_scope("cross")
 def apply_cross(params: dict, x0: jnp.ndarray, *, cfg: ModelConfig) -> jnp.ndarray:
     """x0 [B, D] -> y_cross [B]."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -97,10 +98,11 @@ def apply_dcnv2(
     if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
         lookup_fn = segsum_lookup  # sorted-unique-write backward
 
-    if lookup_fn is dense_lookup:
-        emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
-    else:
-        emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
+    with jax.named_scope("lookup"):
+        if lookup_fn is dense_lookup:
+            emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
+        else:
+            emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
 
     x0 = emb.reshape(emb.shape[0], cfg.field_size * cfg.embedding_size)
     y_cross = apply_cross(params["cross"], x0, cfg=cfg)

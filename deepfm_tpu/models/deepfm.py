@@ -50,6 +50,7 @@ def init_mlp(key: jax.Array, in_dim: int, cfg: ModelConfig) -> dict:
     return params
 
 
+@jax.named_scope("mlp")
 def apply_mlp(
     params: dict,
     bn_params: dict | None,
@@ -154,22 +155,27 @@ def apply_deepfm(
     )
     if use_fused:
         # one HBM pass: both gathers + scaling + FM sums (ops/pallas_ctr.py)
-        emb, y_w, y_v = fused_ctr_interaction(
-            params["fm_w"], params["fm_v"], feat_ids, feat_vals
-        )
+        with jax.named_scope("lookup"):
+            emb, y_w, y_v = fused_ctr_interaction(
+                params["fm_w"], params["fm_v"], feat_ids, feat_vals
+            )
     else:
         if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
             lookup_fn = segsum_lookup  # sorted-unique-write backward
         # first order (ps:206-209)
-        feat_w = lookup_fn(params["fm_w"], feat_ids)        # [B, F]
-        y_w = fm_first_order(feat_w, feat_vals)
+        with jax.named_scope("lookup"):
+            feat_w = lookup_fn(params["fm_w"], feat_ids)        # [B, F]
+        with jax.named_scope("fm"):
+            y_w = fm_first_order(feat_w, feat_vals)
 
         # second order (ps:211-217): e = V[ids] * vals
-        if lookup_fn is dense_lookup:
-            emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
-        else:
-            emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
-        y_v = fm_second_order(emb)
+        with jax.named_scope("lookup"):
+            if lookup_fn is dense_lookup:
+                emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
+            else:
+                emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
+        with jax.named_scope("fm"):
+            y_v = fm_second_order(emb)
 
     # deep tower (ps:228-255)
     deep_in = emb.reshape(emb.shape[0], cfg.field_size * cfg.embedding_size)

@@ -67,6 +67,7 @@ def _init_tower(key: jax.Array, in_dim: int, cfg: ModelConfig) -> dict:
     return params
 
 
+@jax.named_scope("tower")
 def _apply_tower(params: dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     h = x.astype(compute_dtype)
@@ -120,7 +121,8 @@ def encode_tower(
     vals = vals.reshape(-1, field).astype(jnp.float32)
     if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
         lookup_fn = segsum_lookup  # sorted-unique-write backward
-    emb = lookup_fn(params[f"{side}_embedding"], ids) * vals[..., None]
+    with jax.named_scope("lookup"):
+        emb = lookup_fn(params[f"{side}_embedding"], ids) * vals[..., None]
     return _apply_tower(
         params[f"{side}_tower"],
         emb.reshape(emb.shape[0], field * cfg.embedding_size),

@@ -54,6 +54,7 @@ def init_cin(key: jax.Array, cfg: ModelConfig) -> dict:
     return params
 
 
+@jax.named_scope("cin")
 def apply_cin(params: dict, emb: jnp.ndarray, *, cfg: ModelConfig) -> jnp.ndarray:
     """emb [B, F, K] -> y_cin [B] via the compressed interaction stack."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -130,13 +131,16 @@ def apply_xdeepfm(
     if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
         lookup_fn = segsum_lookup  # sorted-unique-write backward
 
-    feat_w = lookup_fn(params["fm_w"], feat_ids)
-    y_w = fm_first_order(feat_w, feat_vals)
+    with jax.named_scope("lookup"):
+        feat_w = lookup_fn(params["fm_w"], feat_ids)
+    with jax.named_scope("fm"):
+        y_w = fm_first_order(feat_w, feat_vals)
 
-    if lookup_fn is dense_lookup:
-        emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
-    else:
-        emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
+    with jax.named_scope("lookup"):
+        if lookup_fn is dense_lookup:
+            emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
+        else:
+            emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
 
     y_cin = apply_cin(params["cin"], emb, cfg=cfg)
 

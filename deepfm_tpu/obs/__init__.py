@@ -11,16 +11,20 @@ One layer, three surfaces, shared by train→publish→serve:
   minted at the router (or accepted from the client), propagated through
   worker predict/recommend and the MicroBatcher so each request
   accumulates per-stage spans; bounded recent-traces buffer behind
-  ``GET /v1/trace/recent``; host-side step-phase timers for the train
-  loop.
+  ``GET /v1/trace/recent``; the training path's span recorder
+  (``SpanRecorder``: feed worker, consumer and loop spans in a ring, as
+  per-step means on the log line, and as ``TraceAnnotation``s in a
+  profile).
 * :mod:`.flight` — a bounded ring of structured events every subsystem
   appends to through one hook, dumped as JSONL on SIGTERM/crash (riding
   PreemptionGuard) and on demand via ``GET /v1/flight``.
 
-Everything here is host-side and dependency-light (numpy only, no jax):
-instrumentation must never enter lowered code — the ``audit_observability``
-trace contract (analysis/trace_audit.py) proves the jitted predict and
-train step stay free of host callbacks and baked timer values.
+Everything here is host-side and dependency-light (numpy only; the span
+recorder imports ``jax.profiler.TraceAnnotation`` on its first span and
+nothing of jax before): instrumentation must never enter lowered code —
+the ``audit_observability`` trace contract (analysis/trace_audit.py)
+proves the jitted predict and train step stay free of host callbacks and
+baked timer values.
 """
 
 from .flight import FlightRecorder, get_recorder, record
@@ -28,10 +32,11 @@ from .metrics import Counter, Gauge, Histogram, MetricsRegistry, SlidingWindow
 from .trace import (
     SPAN_HEADER,
     TRACE_HEADER,
-    StepPhases,
+    SpanRecorder,
     TraceContext,
     Tracer,
     current_trace,
+    get_span_recorder,
     span,
 )
 
@@ -43,7 +48,8 @@ __all__ = [
     "SlidingWindow",
     "Tracer",
     "TraceContext",
-    "StepPhases",
+    "SpanRecorder",
+    "get_span_recorder",
     "current_trace",
     "span",
     "TRACE_HEADER",

@@ -78,10 +78,6 @@ class FlightRecorder:
             out = [e for e in out if e["kind"] == kind]
         return out if limit is None else out[-int(limit):]
 
-    def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
-
     # -- dumps --------------------------------------------------------------
     def configure_dump(self, path: str) -> None:
         """Arm the termination dump: :meth:`dump` (and the signal/crash

@@ -1,4 +1,4 @@
-"""End-to-end request tracing + train-loop step-phase timers.
+"""End-to-end request tracing + the training path's span recorder.
 
 A trace is minted where the request enters the system (the pool router —
 or accepted from the client via ``X-Trace-Id``) and propagated over HTTP
@@ -19,10 +19,14 @@ Design constraints the audit (``audit_observability``) pins:
   ``GET /v1/trace/recent``; optional JSONL span export for offline
   correlation with the flight recorder.
 
-``StepPhases`` is the train-side sibling: per-step host phases (data
-wait vs host prep vs dispatch) accumulated between ``MetricLogger``
-emits, so a throughput regression is attributable to input starvation
-vs host work vs device time without a profiler run.
+``SpanRecorder`` is the train-side sibling: ONE process-wide recorder
+of the training path's host spans (the feed worker, the consumer, the
+loop's own boundaries), on ``time.perf_counter`` and — through
+``jax.profiler.TraceAnnotation`` — on the profiler's clock as well, so a
+throughput regression is attributable to the source, the host's work per
+batch, a full or an empty queue, the dispatch, a log line or a
+checkpoint, from the metrics line alone or next to the device ops of a
+profile.  The vocabulary of its spans is ``SPANS`` below.
 """
 
 from __future__ import annotations
@@ -110,9 +114,6 @@ class TraceContext:
         readings taken by the caller AROUND the stage (never inside
         traced code)."""
         self.spans.append((name, t0, t1, attrs or None))
-
-    def set_attrs(self, **kv) -> None:
-        self.attrs.update(kv)
 
     def headers(self) -> dict[str, str]:
         """The propagation pair a forwarding hop sends downstream."""
@@ -236,11 +237,6 @@ class Tracer:
             out = out[-int(limit):]
         return [c.to_dict() for c in out]
 
-    def find(self, trace_id: str) -> list[dict]:
-        with self._lock:
-            out = [c for c in self._recent if c.trace_id == trace_id]
-        return [c.to_dict() for c in out]
-
     def _export(self, doc: dict) -> None:
         line = json.dumps(doc, default=str) + "\n"
         with self._export_lock:
@@ -262,40 +258,198 @@ class Tracer:
                 self._export_file = None
 
 
-class StepPhases:
-    """Host-side per-step phase accumulator for the train loop.
+# -- the training path's span recorder ----------------------------------------
 
-    Phases (``data_wait`` — blocking on the input pipeline, ``host`` —
-    host-side prep/bookkeeping, ``dispatch`` — handing the step to the
-    device) accumulate between snapshots; :meth:`snapshot_ms` returns
-    per-step averages and resets, sized to feed ``MetricLogger.step``'s
-    ``extra`` hook (evaluated only on emitting boundaries).  Single
-    consumer thread (the train loop) — no locking."""
+# The one vocabulary of the training path's host spans: every name a call
+# site may pass to ``SpanRecorder.span`` (what each is: the span table of
+# docs/ARCHITECTURE.md "Observability").  ``feed.*`` is the input feed —
+# ``data/pipeline.DevicePrefetcher``: the worker's ``source`` (waiting for
+# the source iterator), ``put`` (placing one batch) and ``offer`` (blocked on
+# the full queue), the consumer's ``take`` (inside ``q.get()``); the placers
+# of ``parallel/spmd.py`` inside ``put``: ``validate``, ``narrow``,
+# ``device_put`` — and ``train.*`` the loop's own boundaries
+# (``train/loop._run_train_guarded``).
+SPANS = frozenset({
+    "feed.source", "feed.put", "feed.validate", "feed.narrow",
+    "feed.device_put", "feed.offer", "feed.take",
+    "train.dispatch", "train.log", "train.checkpoint", "train.eval",
+})
 
-    def __init__(self):
-        self._acc: dict[str, float] = {}
+# span name -> the key its per-step mean gets on a MetricLogger line
+LOG_KEYS = {
+    "feed.take": "data_wait_ms",
+    "train.dispatch": "dispatch_ms",
+    "train.log": "log_ms",
+    "train.checkpoint": "checkpoint_ms",
+}
+
+_ANNOTATION = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use: the rest of
+    this package stays importable without jax, and the training path has
+    it loaded long before its first span."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+class _Span:
+    """One open span (``SpanRecorder.span``)."""
+
+    __slots__ = ("_rec", "_name", "_ann", "_t0")
+
+    def __init__(self, rec: "SpanRecorder", name: str, seq):
+        self._rec = rec
+        self._name = name
+        # with no profiler session this is a flag test; with one the span
+        # lands on this thread's host line, on the device trace's clock
+        self._ann = (_annotation()(name) if seq is None
+                     else _annotation()(name, seq=seq))
+
+    def __enter__(self):
+        self._ann.__enter__()
+        # read last, and first on the way out: the interval is the body's,
+        # the recorder's own bookkeeping lies outside it
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        rec = self._rec
+        rec._ring.append((self._name, self._t0, t1, threading.get_ident()))
+        with rec._lock:
+            entry = rec._sums.get(self._name)
+            if entry is None:
+                entry = rec._sums[self._name] = [0, 0.0]
+            entry[0] += 1
+            entry[1] += t1 - self._t0
+        return False
+
+
+class SpanRecorder:
+    """The process-wide recorder of the training path's host spans.
+
+    ``with recorder.span(name, seq=...)`` times its body on
+    ``time.perf_counter`` and writes three surfaces:
+
+    * a bounded ring of finished spans ``(name, start, end, thread)``
+      (rendered only when read: :meth:`spans`) — what the benchmark's
+      readers and a post-mortem read;
+    * running ``[count, total_s]`` per name — what ``MetricLogger``'s
+      ``extra`` hook reads per logged window (:meth:`snapshot_ms`);
+    * a ``jax.profiler.TraceAnnotation(name, seq=seq)`` around the same
+      body, so a profile (``run.profile_dir``, the benchmark's
+      ``--trace 1``) shows the program's spans beside the device ops, on
+      one clock.  ``seq`` lives there only: the feed worker mints one per
+      batch for its ``source`` / ``put`` / ``offer`` and the consumer's
+      ``take`` of that batch carries the same number, so a batch is
+      followed across the two threads' lines.
+
+    Always on.  The ring is a ``deque`` (atomic appends).  The per-name
+    sums live in one dict under one lock: in the train loop the writers use
+    disjoint names (the worker owns ``feed.source/put/validate/narrow/
+    device_put/offer``, the consumer ``feed.take`` and ``train.*``), but an
+    in-training eval places its batches from the consumer's thread while
+    the train feed's worker places its own, and a read-modify-write of one
+    entry from two threads would lose updates.
+    """
+
+    def __init__(self, maxlen: int = 65536):
+        self._ring: deque[tuple] = deque(maxlen=max(1, int(maxlen)))
+        self._sums: dict[str, list] = {}
+        self._lock = threading.Lock()
+        # optimizer steps dispatched, and what the last snapshot saw: the
+        # loop's thread alone writes and reads these
         self._steps = 0
+        self._snap: dict[str, float] = {}
 
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._acc[name] = (
-                self._acc.get(name, 0.0) + time.perf_counter() - t0
-            )
+    # -- record path --------------------------------------------------------
+    def span(self, name: str, seq: int | None = None) -> _Span:
+        return _Span(self, name, seq)
 
     def step_done(self, n: int = 1) -> None:
+        """The loop dispatched ``n`` more optimizer steps: the divisor of
+        :meth:`snapshot_ms`."""
         self._steps += n
 
+    # -- read path ----------------------------------------------------------
+    def spans(self, t_min: float | None = None,
+              t_max: float | None = None) -> list[dict]:
+        """The ring's finished spans, in the order they finished, that lie
+        wholly inside ``[t_min, t_max]`` (perf_counter readings; None =
+        open), rendered: ``name, t_start, t_end, thread``."""
+        return [
+            {"name": name, "t_start": t0, "t_end": t1, "thread": thread}
+            for name, t0, t1, thread in list(self._ring)
+            if (t_min is None or t0 >= t_min)
+            and (t_max is None or t1 <= t_max)
+        ]
+
+    def covers(self, t: float) -> bool:
+        """Whether every span finished since ``t`` is still in the ring
+        (False once the ring has wrapped past it)."""
+        ring = self._ring
+        return len(ring) < ring.maxlen or ring[0][2] <= t
+
     def snapshot_ms(self) -> dict[str, float]:
-        """{"<phase>_ms": avg per optimizer step} since the last call."""
-        steps = max(1, self._steps)
-        out = {
-            f"{k}_ms": round(1e3 * v / steps, 3)
-            for k, v in sorted(self._acc.items())
-        }
-        self._acc.clear()
-        self._steps = 0
+        """{"<LOG_KEYS[name]>": mean ms per optimizer step} since the last
+        call, for the names recorded so far; steps are :meth:`step_done`'s
+        advance (at least 1).  One caller: the train loop's
+        ``MetricLogger`` hook."""
+        advance = max(1, self._steps - self._snap.get("steps", 0))
+        self._snap["steps"] = self._steps
+        with self._lock:
+            totals = {name: self._sums[name][1] for name in LOG_KEYS
+                      if name in self._sums}
+        out = {}
+        for name, total in totals.items():
+            out[LOG_KEYS[name]] = round(
+                1e3 * (total - self._snap.get(name, 0.0)) / advance, 3)
+            self._snap[name] = total
         return out
+
+
+_RECORDER = SpanRecorder()
+
+
+def get_span_recorder() -> SpanRecorder:
+    return _RECORDER
+
+
+def set_span_recorder(recorder: SpanRecorder) -> SpanRecorder:
+    """Swap the process recorder (tests); returns the previous one."""
+    global _RECORDER
+    prev, _RECORDER = _RECORDER, recorder
+    return prev
+
+
+# -- the jitted step's named scopes --------------------------------------------
+
+# The one vocabulary of ``jax.named_scope`` in the jitted steps (models/*,
+# parallel/spmd.py, train/step.py, train/optimizer.py): HLO metadata only —
+# the lowered module the compile cache keys on does not carry it.  JAX wraps
+# a scope in the transforms it ran under, so in a train step the forward of
+# ``lookup`` reads ``jvp(lookup)`` and its backward ``transpose(jvp(lookup))``:
+# the table gradient (the scatter) is ``transpose(jvp(lookup))`` and its L2
+# base ``transpose(jvp(l2_penalty))`` — there is no scope of their own.
+STEP_SCOPES = ("lookup", "fm", "cin", "cross", "mlp", "tower", "loss",
+               "l2_penalty", "grad_sync", "optimizer", "metrics")
+
+
+def scope_of(op_name: str) -> tuple[str | None, str | None]:
+    """The named scope an HLO ``op_name`` lies under, bare and as written:
+    ``jit(local_step)/transpose(jvp(lookup))/scatter-add`` ->
+    ``("lookup", "transpose(jvp(lookup))")``; ``(None, None)`` under none."""
+    for part in op_name.split("/"):
+        inner = part
+        while "(" in inner and inner.endswith(")"):
+            inner = inner[inner.index("(") + 1:-1]
+        if inner in STEP_SCOPES:
+            return inner, part
+    return None, None

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import Config
 from ..models.base import get_model
+from ..obs.trace import get_span_recorder
 from ..ops.auc import AUCState, auc_init, auc_update
 from ..train.optimizer import (
     build_optimizer,
@@ -244,6 +245,7 @@ def create_spmd_state(ctx: SPMDContext, key: jax.Array | None = None) -> TrainSt
         return jax.jit(init_fn, out_shardings=ctx.state_shardings)(key)
 
 
+@jax.named_scope("l2_penalty")
 def _sharded_penalty(params: dict, l2_reg: float) -> jnp.ndarray:
     """Reference loss regularizer (ps:275-279) over row-sharded tables:
     ½·psum_model(Σ local²) per table.  Mirrors ModelDef.l2_penalty's
@@ -271,6 +273,7 @@ def _sync_model_state(model_state):
     )
 
 
+@jax.named_scope("grad_sync")
 def _pmean_grads(grads: dict) -> dict:
     """Sync gradients: every leaf pmean-ed over the data axis (the Horovod
     DistributedOptimizer capability, hvd:296); replicated (non-table) leaves
@@ -300,8 +303,9 @@ def _local_loss(cfg: Config, model, params, model_state, batch, rng, train):
         rng=rng,
         lookup_fn=lookup,
     )
-    labels = batch["label"].reshape(-1).astype(jnp.float32)
-    ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
+    with jax.named_scope("loss"):
+        labels = batch["label"].reshape(-1).astype(jnp.float32)
+        ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
     loss = ce + _sharded_penalty(params, cfg.model.l2_reg)
     return loss, (ce, logits, new_state)
 
@@ -350,21 +354,24 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
             )
         else:
             grads = _pmean_grads(grads)
-            updates, new_opt_state = tx.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
-        metrics = {
-            "loss": lax.pmean(loss, DATA_AXIS),
-            "ce": lax.pmean(ce, DATA_AXIS),
-            "pred_mean": lax.pmean(jnp.mean(jax.nn.sigmoid(logits)), DATA_AXIS),
-            "label_mean": lax.pmean(
-                jnp.mean(batch["label"].astype(jnp.float32)), DATA_AXIS
-            ),
-            # per-data-shard local loss, [dp] — observability into shard skew
-            # (and the per-shard dropout-mask invariant, see tests)
-            "loss_per_shard": loss[None],
-        }
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = tx.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("metrics"):
+            metrics = {
+                "loss": lax.pmean(loss, DATA_AXIS),
+                "ce": lax.pmean(ce, DATA_AXIS),
+                "pred_mean": lax.pmean(
+                    jnp.mean(jax.nn.sigmoid(logits)), DATA_AXIS),
+                "label_mean": lax.pmean(
+                    jnp.mean(batch["label"].astype(jnp.float32)), DATA_AXIS
+                ),
+                # per-data-shard local loss, [dp] — observability into shard
+                # skew (and the per-shard dropout-mask invariant, see tests)
+                "loss_per_shard": loss[None],
+            }
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -507,11 +514,12 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
             total_rows,
         )
         ids_feed = flat_mapped.reshape(ids2d.shape)
-        rows = {
-            k: sharded_lookup(tables[k], ids_feed, exchange=fwd_exchange,
-                              capacity=cap_frac)
-            for k in keys
-        }
+        with jax.named_scope("lookup"):
+            rows = {
+                k: sharded_lookup(tables[k], ids_feed, exchange=fwd_exchange,
+                                  capacity=cap_frac)
+                for k in keys
+            }
 
         def loss_fn(rest, rows):
             def row_lookup(table, _ids):
@@ -527,8 +535,9 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
                 rng=step_rng,
                 lookup_fn=row_lookup,
             )
-            labels = batch["label"].reshape(-1).astype(jnp.float32)
-            ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
+            with jax.named_scope("loss"):
+                labels = batch["label"].reshape(-1).astype(jnp.float32)
+                ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
             return ce, (logits, new_state)
 
         (loss, (logits, new_model_state)), (g_rest, g_rows) = jax.value_and_grad(
@@ -543,8 +552,9 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
             )
         else:
             g_rest = _pmean_grads(g_rest)
-            updates, new_rest_opt = tx.update(g_rest, rest_opt, rest)
-            new_rest = optax.apply_updates(rest, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_rest_opt = tx.update(g_rest, rest_opt, rest)
+                new_rest = optax.apply_updates(rest, updates)
 
         # global id stream over the data axis (replicated over the model
         # axis).  Global loss = mean of shard means -> 1/dp scale.
@@ -591,63 +601,67 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
                 )
             return apply_updates(row_id, gsum_by_key, valid)
 
-        if dedup_gather:
-            # dedup BEFORE the exchange: one local sort shared by the
-            # tables folds duplicate rows into per-unique sums, and only a
-            # capacity-bounded unique pack rides the all_gather
-            # auto = N/2 unique slots per data shard (core/config.py); the
-            # fraction is explicit — num_shards plays no role here
-            cap = exchange_capacity(n_local, 1, cap_frac or 0.5)
-            order_l, seg_l, row_l, valid_l = shared_segments(
-                flat_mapped, total_rows + 1
-            )
-            n_unique = jnp.sum(valid_l.astype(jnp.int32))
-            # collective-shape consistency: every data shard in the gather
-            # group must take the same branch
-            overflow = lax.pmax(
-                (n_unique > cap).astype(jnp.int32), DATA_AXIS
-            ) > 0
-
-            def update_dedup(_):
-                ids_pack = jnp.where(valid_l[:cap], row_l[:cap], total_rows)
-                ids_g = lax.all_gather(ids_pack, DATA_AXIS, tiled=True)
-                order, seg, row_id, valid = shared_segments(
-                    ids_g, total_rows + 1
+        # the touched-row update, its grad-stream gather included
+        with jax.named_scope("optimizer"):
+            if dedup_gather:
+                # dedup BEFORE the exchange: one local sort shared by the
+                # tables folds duplicate rows into per-unique sums, and only a
+                # capacity-bounded unique pack rides the all_gather
+                # auto = N/2 unique slots per data shard (core/config.py); the
+                # fraction is explicit — num_shards plays no role here
+                cap = exchange_capacity(n_local, 1, cap_frac or 0.5)
+                order_l, seg_l, row_l, valid_l = shared_segments(
+                    flat_mapped, total_rows + 1
                 )
-                gsum_by_key = {}
-                for k in keys:
-                    g2 = g_rows[k].reshape(n_local, -1)
-                    gsum_l = jax.ops.segment_sum(
-                        g2[order_l], seg_l, num_segments=n_local,
-                        indices_are_sorted=True,
-                    )[:cap]
-                    g_g = lax.all_gather(gsum_l, DATA_AXIS, tiled=True) / dp
-                    gsum_by_key[k] = jax.ops.segment_sum(
-                        g_g[order], seg, num_segments=ids_g.shape[0],
-                        indices_are_sorted=True,
-                    )
-                return apply_updates(row_id, gsum_by_key, valid)
+                n_unique = jnp.sum(valid_l.astype(jnp.int32))
+                # collective-shape consistency: every data shard in the gather
+                # group must take the same branch
+                overflow = lax.pmax(
+                    (n_unique > cap).astype(jnp.int32), DATA_AXIS
+                ) > 0
 
-            if cap >= n_local:  # overflow statically impossible
-                updated = update_dedup(0)
+                def update_dedup(_):
+                    ids_pack = jnp.where(valid_l[:cap], row_l[:cap], total_rows)
+                    ids_g = lax.all_gather(ids_pack, DATA_AXIS, tiled=True)
+                    order, seg, row_id, valid = shared_segments(
+                        ids_g, total_rows + 1
+                    )
+                    gsum_by_key = {}
+                    for k in keys:
+                        g2 = g_rows[k].reshape(n_local, -1)
+                        gsum_l = jax.ops.segment_sum(
+                            g2[order_l], seg_l, num_segments=n_local,
+                            indices_are_sorted=True,
+                        )[:cap]
+                        g_g = lax.all_gather(gsum_l, DATA_AXIS, tiled=True) / dp
+                        gsum_by_key[k] = jax.ops.segment_sum(
+                            g_g[order], seg, num_segments=ids_g.shape[0],
+                            indices_are_sorted=True,
+                        )
+                    return apply_updates(row_id, gsum_by_key, valid)
+
+                if cap >= n_local:  # overflow statically impossible
+                    updated = update_dedup(0)
+                else:
+                    updated = lax.cond(overflow, update_full, update_dedup, 0)
             else:
-                updated = lax.cond(overflow, update_full, update_dedup, 0)
-        else:
-            updated = update_full(0)
+                updated = update_full(0)
         new_tables = {k: updated[k][0] for k in keys}
         new_m = {k: updated[k][1] for k in keys}
         new_v = {k: updated[k][2] for k in keys}
-        metrics = {
-            # CE only (table-L2 folds into the lazy update); 'ce' is the
-            # cross-path comparable quantity (docs/PARITY.md)
-            "loss": lax.pmean(loss, DATA_AXIS),
-            "ce": lax.pmean(loss, DATA_AXIS),
-            "pred_mean": lax.pmean(jnp.mean(jax.nn.sigmoid(logits)), DATA_AXIS),
-            "label_mean": lax.pmean(
-                jnp.mean(batch["label"].astype(jnp.float32)), DATA_AXIS
-            ),
-            "loss_per_shard": loss[None],
-        }
+        with jax.named_scope("metrics"):
+            metrics = {
+                # CE only (table-L2 folds into the lazy update); 'ce' is the
+                # cross-path comparable quantity (docs/PARITY.md)
+                "loss": lax.pmean(loss, DATA_AXIS),
+                "ce": lax.pmean(loss, DATA_AXIS),
+                "pred_mean": lax.pmean(
+                    jnp.mean(jax.nn.sigmoid(logits)), DATA_AXIS),
+                "label_mean": lax.pmean(
+                    jnp.mean(batch["label"].astype(jnp.float32)), DATA_AXIS
+                ),
+                "loss_per_shard": loss[None],
+            }
         new_state = TrainState(
             step=step1,
             params={**new_rest, **new_tables},
@@ -677,17 +691,19 @@ def make_spmd_eval_step(ctx: SPMDContext) -> Callable:
         _, (_, logits, _) = _local_loss(
             cfg, model, state.params, state.model_state, model_batch, None, False
         )
-        labels = batch["label"].reshape(-1).astype(jnp.float32)
-        w = jnp.ones_like(labels) if weight is None else weight.reshape(-1)
-        ce = sigmoid_cross_entropy(logits, labels)
-        loss_sum = lax.psum(jnp.sum(ce * w), DATA_AXIS)
-        w_sum = lax.psum(jnp.sum(w), DATA_AXIS)
+        with jax.named_scope("loss"):
+            labels = batch["label"].reshape(-1).astype(jnp.float32)
+            w = jnp.ones_like(labels) if weight is None else weight.reshape(-1)
+            ce = sigmoid_cross_entropy(logits, labels)
+            loss_sum = lax.psum(jnp.sum(ce * w), DATA_AXIS)
+            w_sum = lax.psum(jnp.sum(w), DATA_AXIS)
         penalty = _sharded_penalty(state.params, cfg.model.l2_reg)
-        preds = jax.nn.sigmoid(logits)
-        local_counts = auc_update(
-            auc_init(auc_state.num_thresholds), labels, preds, weights=w
-        ).counts
-        new_counts = auc_state.counts + lax.psum(local_counts, DATA_AXIS)
+        with jax.named_scope("metrics"):
+            preds = jax.nn.sigmoid(logits)
+            local_counts = auc_update(
+                auc_init(auc_state.num_thresholds), labels, preds, weights=w
+            ).counts
+            new_counts = auc_state.counts + lax.psum(local_counts, DATA_AXIS)
         return AUCState(new_counts), {
             "loss": loss_sum / jnp.maximum(w_sum, 1.0) + penalty,
             "count": w_sum,
@@ -809,23 +825,34 @@ def shard_batch(ctx: SPMDContext, batch: dict, *, validate_ids: bool = True) -> 
     instead.  Set ``validate_ids=False`` on a hot path that has already
     validated.
     """
-    nproc = _validate_local_batch(
-        ctx, batch["label"].shape[0],
-        batch.get("feat_ids") if validate_ids else None,
-    )
-    batch = _narrow_id_fields(ctx, batch)
-    if nproc > 1:
-        import numpy as np
+    return _place(ctx, batch, batch["label"].shape[0], ctx.batch_shardings,
+                  validate_ids)
 
-        return {
-            k: jax.make_array_from_process_local_data(
-                ctx.batch_shardings[k], np.asarray(batch[k])
-            )
-            for k in batch
-        }
-    return {
-        k: jax.device_put(batch[k], ctx.batch_shardings[k]) for k in batch
-    }
+
+def _place(ctx: SPMDContext, batch: dict, rows: int, shardings: dict,
+           validate_ids: bool) -> dict:
+    """Validate, narrow and place one host batch (or K stacked ones) — the
+    body both placers share, under the feed's spans (``obs/trace.py``
+    SPANS)."""
+    import numpy as np
+
+    rec = get_span_recorder()
+    ids = batch.get("feat_ids") if validate_ids else None
+    with rec.span("feed.validate"):
+        nproc = _validate_local_batch(ctx, rows, ids)
+    with rec.span("feed.narrow"):
+        batch = _narrow_id_fields(ctx, batch)
+    with rec.span("feed.device_put"):
+        if nproc > 1:
+            placed = {
+                k: jax.make_array_from_process_local_data(
+                    shardings[k], np.asarray(batch[k])
+                )
+                for k in batch
+            }
+        else:
+            placed = {k: jax.device_put(batch[k], shardings[k]) for k in batch}
+    return placed
 
 
 def shard_batch_stacked(
@@ -841,20 +868,11 @@ def shard_batch_stacked(
     stacked = {
         k: np.stack([np.asarray(b[k]) for b in batches]) for k in batches[0]
     }
-    nproc = _validate_local_batch(
-        ctx, stacked["label"].shape[1],
-        stacked.get("feat_ids") if validate_ids else None,
-    )
-    stacked = _narrow_id_fields(ctx, stacked)
     shardings = {
         k: NamedSharding(
             ctx.mesh, P(*((None,) + tuple(ctx.batch_specs[k])))
         )
         for k in stacked
     }
-    if nproc > 1:
-        return {
-            k: jax.make_array_from_process_local_data(shardings[k], stacked[k])
-            for k in stacked
-        }
-    return {k: jax.device_put(stacked[k], shardings[k]) for k in stacked}
+    return _place(ctx, stacked, stacked["label"].shape[1], shardings,
+                  validate_ids)

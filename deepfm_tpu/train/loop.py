@@ -3,9 +3,9 @@ SPMD machinery — the ``main()`` capability of the reference scripts
 (ps:389-556, hvd:331-493) without sessions, hooks, or Estimator.
 
 The ``train`` task runs the epoch loop with periodic structured logging
-(log_steps), periodic checkpointing, optional jax.profiler traces, resume-
-from-latest on startup (the spot-restart capability, SURVEY §5), end-of-
-training eval, and a final export — mirroring the reference's
+(log_steps), periodic checkpointing, an optional bounded jax.profiler
+trace, resume-from-latest on startup (the spot-restart capability, SURVEY
+§5), end-of-training eval, and a final export — mirroring the reference's
 train_and_evaluate + export flow (ps:501-521, 535-551).
 """
 
@@ -45,11 +45,51 @@ from ..parallel import (
     shard_batch,
     shard_batch_stacked,
 )
-from ..obs.trace import StepPhases
+from ..obs.trace import get_span_recorder
 from ..serve import export_servable, write_predictions
 from ..train.step import TrainState
 from ..utils import MetricLogger
 from .step import new_auc_state
+
+
+# steps a ``run.profile_dir`` trace covers: enough for a step-time reading,
+# small enough to open
+PROFILE_STEPS = 20
+
+
+class _ProfileWindow:
+    """``run.profile_dir``: trace ``PROFILE_STEPS`` steps once the first
+    logged window is behind (compilation and warm-up are out of the way),
+    then stop — a trace small enough to open.  It holds the device ops, the
+    recorder's host spans (obs/trace.py) and a ``train`` marker per step; it
+    does NOT hold the step's named scopes: a device op in it is the bare
+    HLO instruction, and its scope is joined from the compiled step's HLO
+    text (``scripts/step_scopes.py``) — of a cache-off compile, since a
+    warm compile cache serves an executable with the names it was built
+    with.  One ``profile`` event names the directory and the steps traced."""
+
+    def __init__(self, directory: str, first_step: int, log: MetricLogger):
+        self._dir, self._first, self._log = directory, first_step, log
+        self._on = False
+        self._step = first_step
+
+    def at(self, step: int) -> None:
+        """Before each iteration, with the optimizer steps done so far."""
+        self._step = step
+        if self._on and step >= self._first + PROFILE_STEPS:
+            self.close()
+        elif self._dir and not self._on and step >= self._first:
+            jax.profiler.start_trace(self._dir)
+            self._on = True
+
+    def close(self) -> None:
+        """End the trace if one is open (the window is full, or the run
+        ended inside it)."""
+        if self._on:
+            jax.profiler.stop_trace()
+            self._log.event("profile", dir=self._dir, first_step=self._first,
+                            steps=self._step - self._first)
+            self._on, self._dir = False, ""
 
 
 def worker_topology(cfg: Config) -> WorkerTopology:
@@ -406,11 +446,6 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
         make_spmd_train_loop(ctx, steps_per_loop) if steps_per_loop > 1 else None
     )
 
-    profile_cm = (
-        jax.profiler.trace(cfg.run.profile_dir)
-        if cfg.run.profile_dir
-        else contextlib.nullcontext()
-    )
     # host-side step counter: int(state.step) every iteration would block on
     # the just-dispatched step and defeat async-dispatch pipelining
     step = int(state.step)
@@ -427,15 +462,15 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
     lr_sched = build_lr_schedule(
         ctx.cfg.optimizer, data_parallel_size=ctx.cfg.mesh.data_parallel
     )
-    # step-phase spans (obs/trace.py): where each logged window's host
-    # time went — input-pipeline wait vs host bookkeeping vs device
-    # dispatch — attributable from the metrics line alone, no profiler.
-    # Evaluated only on emitting calls (MetricLogger.step `extra`), like
-    # the scheduled lr below.
-    phases = StepPhases()
+    # the training path's spans (obs/trace.py SPANS): where each logged
+    # window's host time went — waiting for the feed, dispatching, logging,
+    # checkpointing — as per-step means on the metrics line, and as
+    # annotations beside the device ops in a profile.  Evaluated only on
+    # emitting calls (MetricLogger.step `extra`), like the scheduled lr.
+    rec = get_span_recorder()
 
     def lr_extra():
-        out = phases.snapshot_ms()
+        out = rec.snapshot_ms()
         if callable(lr_sched):
             out["lr"] = float(schedule_value(lr_sched, max(0, step - 1)))
         return out
@@ -455,51 +490,58 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
         if not guard.should_stop
         else contextlib.nullcontext(())
     )
+    profile = _ProfileWindow(cfg.run.profile_dir,
+                             step + max(1, cfg.run.log_steps), log)
     _END = object()
-    with profile_cm, feed_cm as batches:
+    with feed_cm as batches, contextlib.closing(profile):
         it = iter(batches)
         while True:
-            # data_wait: time blocked on the input pipeline's next item
-            with phases.phase("data_wait"):
+            profile.at(step)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                # feed.take (inside DevicePrefetcher.__next__): time blocked
+                # on the input pipeline's next item
                 item = next(it, _END)
-            if item is _END:
-                break
-            if steps_per_loop > 1:
-                tag, batch = item
-            else:
-                tag, batch = "one", item
-            if tag == "stack":
-                # K fused optimizer steps; metrics come back stacked [K] —
-                # log the last sub-step's values (no extra device sync)
-                with phases.phase("dispatch"):
-                    state, stacked_metrics = loop_step(state, batch)
-                    if cpu_serial:
-                        jax.block_until_ready(stacked_metrics)
-                metrics = {k: v[-1] for k, v in stacked_metrics.items()}
-                inc = steps_per_loop
-                batch_size = int(batch["label"].shape[1]) * inc
-            else:
-                with phases.phase("dispatch"):
-                    state, metrics = train_step(state, batch)
-                    if cpu_serial:
-                        jax.block_until_ready(metrics)
-                inc = 1
-                batch_size = int(batch["label"].shape[0])
-            step += inc
-            phases.step_done(inc)
-            with phases.phase("host"):
-                log.step(step, batch_size,
-                         {k: v for k, v in metrics.items()
-                          if k != "loss_per_shard"},
-                         extra=lr_extra)
+                if item is _END:
+                    break
+                if steps_per_loop > 1:
+                    tag, batch = item
+                else:
+                    tag, batch = "one", item
+                if tag == "stack":
+                    # K fused optimizer steps; metrics come back stacked
+                    # [K] — log the last sub-step's values (no extra device
+                    # sync)
+                    with rec.span("train.dispatch"):
+                        state, stacked_metrics = loop_step(state, batch)
+                        if cpu_serial:
+                            jax.block_until_ready(stacked_metrics)
+                    metrics = {k: v[-1] for k, v in stacked_metrics.items()}
+                    inc = steps_per_loop
+                    batch_size = int(batch["label"].shape[1]) * inc
+                else:
+                    with rec.span("train.dispatch"):
+                        state, metrics = train_step(state, batch)
+                        if cpu_serial:
+                            jax.block_until_ready(metrics)
+                    inc = 1
+                    batch_size = int(batch["label"].shape[0])
+                step += inc
+                rec.step_done(inc)
+                with rec.span("train.log"):
+                    log.step(step, batch_size,
+                             {k: v for k, v in metrics.items()
+                              if k != "loss_per_shard"},
+                             extra=lr_extra)
                 # boundary-crossing test: a K-step dispatch may jump past
                 # the exact multiple (same as `step % N == 0` when inc == 1)
                 if (ckpt_every
                         and step // ckpt_every > (step - inc) // ckpt_every):
-                    ckpt.save(state)
-            if eval_enabled and time.time() >= next_eval:
-                run_eval(cfg, ctx, state, log)
-                next_eval = time.time() + cfg.run.eval_throttle_secs
+                    with rec.span("train.checkpoint"):
+                        ckpt.save(state)
+                if eval_enabled and time.time() >= next_eval:
+                    with rec.span("train.eval"):
+                        run_eval(cfg, ctx, state, log)
+                    next_eval = time.time() + cfg.run.eval_throttle_secs
             if guard.should_stop:
                 break
 

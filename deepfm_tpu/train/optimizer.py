@@ -387,14 +387,16 @@ def zero_sharded(
                 _pad_flat(p, plan[1]), (d * plan[1],), (plan[1],)
             )
 
-        g_win = tm(scatter, grads)
-        p_win = tm(window, params)
-        updates_win, new_inner = tx.update(g_win, state.zero_dp, p_win)
-        # apply on the WINDOW, then gather the fresh params: the p + u add
-        # stays adjacent to the update math (same fused pattern as the
-        # replicated path — bit parity), and what crosses the wire is the
-        # new 1/dp param windows, once
-        new_win = optax.apply_updates(p_win, updates_win)
+        with jax.named_scope("grad_sync"):
+            g_win = tm(scatter, grads)
+        with jax.named_scope("optimizer"):
+            p_win = tm(window, params)
+            updates_win, new_inner = tx.update(g_win, state.zero_dp, p_win)
+            # apply on the WINDOW, then gather the fresh params: the p + u
+            # add stays adjacent to the update math (same fused pattern as
+            # the replicated path — bit parity), and what crosses the wire
+            # is the new 1/dp param windows, once
+            new_win = optax.apply_updates(p_win, updates_win)
 
         def gather(path, w, p):
             plan = _plan(path, p.shape, local=True)
@@ -403,7 +405,8 @@ def zero_sharded(
             full = lax.all_gather(w, data_axis, tiled=True)
             return full[: _size(p.shape)].reshape(p.shape)
 
-        new_params = tm(gather, new_win, params)
+        with jax.named_scope("optimizer"):
+            new_params = tm(gather, new_win, params)
         return new_params, ZeroDpState(zero_dp=new_inner)
 
     return ZeroShardedOptimizer(init_fn, update_and_apply)

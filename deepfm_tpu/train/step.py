@@ -57,9 +57,11 @@ def make_loss_fn(cfg: Config, model, lookup_fn=None) -> Callable:
             rng=rng,
             **kwargs,
         )
-        labels = batch["label"].reshape(-1).astype(jnp.float32)
-        ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
-        loss = ce + l2_penalty(params, cfg.model.l2_reg)
+        with jax.named_scope("loss"):
+            labels = batch["label"].reshape(-1).astype(jnp.float32)
+            ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
+        with jax.named_scope("l2_penalty"):
+            loss = ce + l2_penalty(params, cfg.model.l2_reg)
         return loss, (ce, logits, new_state)
 
     return loss_fn
@@ -148,14 +150,17 @@ def make_train_step(cfg: Config, lookup_fn=None) -> Callable:
         (loss, (ce, logits, new_model_state)), grads = grad_fn(
             state.params, state.model_state, batch, step_rng, True
         )
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        metrics = {
-            "loss": loss,
-            "ce": ce,
-            "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
-            "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
-        }
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("metrics"):
+            metrics = {
+                "loss": loss,
+                "ce": ce,
+                "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
+                "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
+            }
         return (
             TrainState(
                 step=state.step + 1,
@@ -202,7 +207,8 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
         ids = narrow_ids(batch["feat_ids"], cfg.model.feature_size,
                          cfg.model.narrow_ids)
         ids = ids.reshape(-1, cfg.model.field_size)
-        rows = {k: dense_lookup(tables[k], ids) for k in keys}
+        with jax.named_scope("lookup"):
+            rows = {k: dense_lookup(tables[k], ids) for k in keys}
 
         def loss_fn(rest, rows):
             # row substitution: the CTR families gather fm_w (1-D) and fm_v
@@ -220,42 +226,44 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
                 rng=step_rng,
                 lookup_fn=row_lookup,
             )
-            labels = batch["label"].reshape(-1).astype(jnp.float32)
-            return jnp.mean(sigmoid_cross_entropy(logits, labels)), (
-                logits,
-                new_state,
-            )
+            with jax.named_scope("loss"):
+                labels = batch["label"].reshape(-1).astype(jnp.float32)
+                ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
+            return ce, (logits, new_state)
 
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
         (loss, (logits, new_model_state)), (g_rest, g_rows) = grad_fn(
             rest, rows
         )
         rest_opt, lazy_state = state.opt_state
-        updates, new_rest_opt = tx.update(g_rest, rest_opt, rest)
-        new_rest = optax.apply_updates(rest, updates)
-
-        # one sort shared by the tables (identical ids); clip to the smallest
-        # table (fm_v may carry aligned-window padding rows beyond fm_w)
-        min_rows = min(tables[k].shape[0] for k in keys)
-        flat_ids = jnp.clip(ids.reshape(-1), 0, min_rows - 1)
-        segs = shared_segments(flat_ids, min_rows)
         step1 = state.step + 1
         new_tables, new_m, new_v = {}, {}, {}
-        for key in keys:
-            new_tables[key], new_m[key], new_v[key] = lazy_adam_update(
-                tables[key], lazy_state.m[key], lazy_state.v[key],
-                flat_ids, g_rows[key], step1, cfg.optimizer,
-                learning_rate=lr, l2_reg=cfg.model.l2_reg, segmented=segs,
-            )
-        metrics = {
-            # CE only: the table-L2 gradient is folded into the lazy update,
-            # so no dense penalty term exists here; 'ce' is the cross-path
-            # comparable quantity (docs/PARITY.md)
-            "loss": loss,
-            "ce": loss,
-            "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
-            "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
-        }
+        with jax.named_scope("optimizer"):
+            updates, new_rest_opt = tx.update(g_rest, rest_opt, rest)
+            new_rest = optax.apply_updates(rest, updates)
+
+            # one sort shared by the tables (identical ids); clip to the
+            # smallest table (fm_v may carry aligned-window padding rows
+            # beyond fm_w)
+            min_rows = min(tables[k].shape[0] for k in keys)
+            flat_ids = jnp.clip(ids.reshape(-1), 0, min_rows - 1)
+            segs = shared_segments(flat_ids, min_rows)
+            for key in keys:
+                new_tables[key], new_m[key], new_v[key] = lazy_adam_update(
+                    tables[key], lazy_state.m[key], lazy_state.v[key],
+                    flat_ids, g_rows[key], step1, cfg.optimizer,
+                    learning_rate=lr, l2_reg=cfg.model.l2_reg, segmented=segs,
+                )
+        with jax.named_scope("metrics"):
+            metrics = {
+                # CE only: the table-L2 gradient is folded into the lazy
+                # update, so no dense penalty term exists here; 'ce' is the
+                # cross-path comparable quantity (docs/PARITY.md)
+                "loss": loss,
+                "ce": loss,
+                "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
+                "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
+            }
         return (
             TrainState(
                 step=step1,

@@ -23,7 +23,6 @@ from deepfm_tpu.obs.metrics import MetricsRegistry, SlidingWindow
 from deepfm_tpu.obs.trace import (
     SPAN_HEADER,
     TRACE_HEADER,
-    StepPhases,
     Tracer,
     current_trace,
     span,
@@ -255,7 +254,6 @@ class TestTracing:
         d = next(s for s in doc["spans"] if s["name"] == "predict.dispatch")
         assert d["bucket"] == 4 and d["rows_coalesced"] == 2
         assert doc["attrs"]["status"] == 200
-        assert t.find(doc["trace_id"]) == [doc]
 
     def test_span_helper_noop_without_active_trace(self):
         with span("anything", k=1) as ctx:
@@ -270,18 +268,6 @@ class TestTracing:
         t.close()
         rows = [json.loads(x) for x in open(path)]
         assert rows and rows[0]["trace_id"] == ctx.trace_id
-
-    def test_step_phases_feed_metric_logger(self):
-        ph = StepPhases()
-        with ph.phase("data_wait"):
-            time.sleep(0.01)
-        with ph.phase("dispatch"):
-            time.sleep(0.005)
-        ph.step_done(2)
-        snap = ph.snapshot_ms()
-        assert set(snap) == {"data_wait_ms", "dispatch_ms"}
-        assert snap["data_wait_ms"] >= 4.0          # /2 steps
-        assert ph.snapshot_ms() == {}               # reset
 
 
 # ----------------------------------------------------------- flight recorder

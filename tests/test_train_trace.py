@@ -1,0 +1,513 @@
+"""The training path's measurement (obs/trace.SpanRecorder): the recorder
+itself, the spans the feed and the loop emit, the benchmark's
+readers of them, the bounded ``run.profile_dir`` trace, and the named scopes
+of the jitted step."""
+
+import glob
+import json
+import re
+import sys
+import threading
+import time
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from deepfm_tpu.core.config import Config, MeshConfig
+from deepfm_tpu.data.pipeline import DevicePrefetcher
+from deepfm_tpu.obs import trace as obs_trace
+from deepfm_tpu.obs.trace import (LOG_KEYS, SPANS, STEP_SCOPES, SpanRecorder,
+                                  scope_of)
+from deepfm_tpu.parallel import (
+    build_mesh,
+    create_spmd_state,
+    make_context,
+    make_spmd_train_step,
+    shard_batch,
+    shard_batch_stacked,
+)
+
+ROOT = Path(__file__).resolve().parents[1]
+
+TINY = {
+    "model": {"feature_size": 200, "field_size": 6, "embedding_size": 4,
+              "deep_layers": (16, 8), "dropout_keep": (0.5, 0.5),
+              "l2_reg": 0.001},
+    "optimizer": {"learning_rate": 0.01},
+    "mesh": {"data_parallel": 1, "model_parallel": 1},
+}
+
+
+@pytest.fixture
+def rec():
+    """A fresh process recorder for one test."""
+    fresh = SpanRecorder()
+    prev = obs_trace.set_span_recorder(fresh)
+    yield fresh
+    obs_trace.set_span_recorder(prev)
+
+
+def _ctx(**model):
+    cfg = Config.from_dict({**TINY, "model": {**TINY["model"], **model}})
+    mesh = build_mesh(MeshConfig(data_parallel=1, model_parallel=1),
+                      devices=jax.devices()[:1])
+    return make_context(cfg, mesh)
+
+
+def _host_batch(cfg, b=8, seed=0):
+    rng = np.random.default_rng(seed)
+    f = cfg.model.field_size
+    return {
+        "feat_ids": rng.integers(0, cfg.model.feature_size, (b, f),
+                                 dtype=np.int64),
+        "feat_vals": rng.random((b, f), dtype=np.float32),
+        "label": (rng.random(b) < 0.3).astype(np.float32),
+    }
+
+
+# ------------------------------------------------------------- the recorder
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps what each span
+    handed the profiler, and whether it was closed."""
+
+    seen: list = []
+
+    def __init__(self, name, **kwargs):
+        self.row = {"name": name, "kwargs": kwargs, "open": None,
+                    "thread": threading.get_ident()}
+        _FakeAnnotation.seen.append(self.row)
+
+    def __enter__(self):
+        self.row["open"] = True
+
+    def __exit__(self, *exc):
+        self.row["open"] = False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(_FakeAnnotation, "seen", [])
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _FakeAnnotation)
+    return _FakeAnnotation.seen
+
+
+def test_vocabulary_is_defined_once():
+    assert set(LOG_KEYS) <= SPANS
+    assert all(n.split(".")[0] in ("feed", "train") for n in SPANS)
+
+
+def test_a_span_is_its_body_on_its_thread(rec):
+    with rec.span("feed.put", seq=7):
+        time.sleep(0.004)
+        with rec.span("feed.validate"):
+            time.sleep(0.004)
+        with rec.span("feed.device_put"):
+            time.sleep(0.004)
+    rows = {s["name"]: s for s in rec.spans()}
+    assert all(set(s) == {"name", "t_start", "t_end", "thread"}
+               for s in rows.values())
+    assert {s["thread"] for s in rows.values()} == {threading.get_ident()}
+    dur = lambda s: s["t_end"] - s["t_start"]
+    put = rows["feed.put"]
+    # the children lie inside the parent, one after the other, and what is
+    # left of the parent is its own 4 ms and the children's bookkeeping
+    assert (put["t_start"] < rows["feed.validate"]["t_start"]
+            < rows["feed.validate"]["t_end"]
+            <= rows["feed.device_put"]["t_start"]
+            < rows["feed.device_put"]["t_end"] < put["t_end"])
+    children = dur(rows["feed.validate"]) + dur(rows["feed.device_put"])
+    assert 0.003 < dur(put) - children < dur(put) - 0.007
+
+
+def test_seq_rides_on_the_annotation_across_both_threads(rec, annotations):
+    def worker():
+        with rec.span("feed.put", seq=3):
+            with rec.span("feed.narrow"):
+                pass
+
+    t = threading.Thread(target=worker)
+    t.start()
+    t.join(5)
+    with rec.span("feed.take", seq=3):
+        pass
+    with rec.span("train.dispatch"):
+        pass
+    by_name = {a["name"]: a for a in annotations}
+    assert by_name["feed.put"]["kwargs"] == {"seq": 3}
+    assert by_name["feed.take"]["kwargs"] == {"seq": 3}
+    assert by_name["feed.narrow"]["kwargs"] == {}
+    assert by_name["train.dispatch"]["kwargs"] == {}
+    assert all(a["open"] is False for a in annotations)
+    assert (by_name["feed.put"]["thread"] == by_name["feed.narrow"]["thread"]
+            != by_name["feed.take"]["thread"])
+    # the ring says the same of the threads
+    threads = {s["name"]: s["thread"] for s in rec.spans()}
+    assert threads["feed.put"] == threads["feed.narrow"] != threads["feed.take"]
+    assert threads["feed.take"] == threads["train.dispatch"]
+
+
+def test_ring_is_bounded_and_says_what_it_still_covers():
+    rec = SpanRecorder(maxlen=8)
+    t_before = time.perf_counter()
+    for _ in range(5):
+        with rec.span("feed.take"):
+            pass
+    assert rec.covers(t_before)
+    first = rec.spans()[0]
+    for _ in range(5, 20):
+        with rec.span("feed.take"):
+            pass
+    rows = rec.spans()
+    assert len(rows) == 8 and rows[0]["t_start"] > first["t_end"]
+    assert not rec.covers(t_before)
+    assert rec.covers(rows[0]["t_end"])
+    assert rec._sums["feed.take"][0] == 20          # the sums keep counting
+
+
+def test_window_filter_keeps_spans_wholly_inside(rec):
+    with rec.span("feed.take"):
+        pass
+    t0 = time.perf_counter()
+    with rec.span("feed.put"):
+        pass
+    t1 = time.perf_counter()
+    with rec.span("feed.offer"):
+        pass
+    assert [s["name"] for s in rec.spans(t0, t1)] == ["feed.put"]
+    assert [s["name"] for s in rec.spans(t0)] == ["feed.put", "feed.offer"]
+
+
+def test_snapshot_ms_gives_per_step_means_and_starts_over(rec):
+    with rec.span("feed.take"):
+        time.sleep(0.01)
+    with rec.span("train.dispatch"):
+        time.sleep(0.005)
+    rec.step_done(2)
+    snap = rec.snapshot_ms()
+    assert set(snap) == {"data_wait_ms", "dispatch_ms"}
+    assert 4.0 <= snap["data_wait_ms"] < 50.0       # 10 ms over 2 steps
+    assert 2.0 <= snap["dispatch_ms"] < 25.0
+    with rec.span("train.checkpoint"):
+        pass
+    rec.step_done()
+    snap = rec.snapshot_ms()
+    assert snap["data_wait_ms"] == 0.0 and snap["dispatch_ms"] == 0.0
+    assert set(snap) == {"data_wait_ms", "dispatch_ms", "checkpoint_ms"}
+
+
+def test_a_span_whose_body_raises_still_closes(rec, annotations):
+    with pytest.raises(KeyError):
+        with rec.span("train.dispatch", seq=1):
+            with rec.span("train.log"):
+                raise KeyError("boom")
+    assert [s["name"] for s in rec.spans()] == ["train.log", "train.dispatch"]
+    assert [a["open"] for a in annotations] == [False, False]
+    assert rec._sums["train.dispatch"][0] == rec._sums["train.log"][0] == 1
+
+
+def test_ten_thousand_spans_cost_well_under_a_tenth_of_a_second(rec):
+    with rec.span("feed.take"):      # first use imports the annotation
+        pass
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for i in range(10_000):
+            with rec.span("feed.take", i):
+                pass
+        best = min(best, time.perf_counter() - t0)
+    assert best < 0.1, best
+
+
+def test_sums_lose_no_update_under_many_writers(rec):
+    """An in-training eval places batches from the consumer's thread while
+    the train feed's worker places its own: same names, two writers."""
+    import os
+
+    threads, each = 2 * (os.cpu_count() or 4), 2000
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def writer():
+            for _ in range(each):
+                with rec.span("feed.validate"):
+                    pass
+
+        ts = [threading.Thread(target=writer) for _ in range(threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    assert rec._sums["feed.validate"][0] == threads * each
+    assert len(rec.spans()) == min(threads * each, 65536)
+
+
+# ------------------------------------------------------- the feed's spans
+
+def test_prefetcher_and_shard_batch_emit_the_feed_spans(rec, annotations):
+    ctx = _ctx()
+    n, b = 5, 8
+    pool = [_host_batch(ctx.cfg, b, seed=i) for i in range(n)]
+    with DevicePrefetcher(iter(pool), lambda hb: shard_batch(ctx, hb),
+                          depth=2) as feed:
+        got = list(feed)
+    assert len(got) == n and got[0]["feat_ids"].dtype == np.int32
+    by_name = {}
+    for s in rec.spans():
+        by_name.setdefault(s["name"], []).append(s)
+    assert set(by_name) == {
+        "feed.source", "feed.put", "feed.validate", "feed.narrow",
+        "feed.device_put", "feed.offer", "feed.take"} <= SPANS
+    # one span a batch; one more source and take: the end of the stream
+    for name in ("feed.put", "feed.validate", "feed.narrow",
+                 "feed.device_put", "feed.offer"):
+        assert len(by_name[name]) == n, name
+    assert len(by_name["feed.source"]) == len(by_name["feed.take"]) == n + 1
+    # the placers' spans lie inside the put of their batch
+    for put, *children in zip(by_name["feed.put"], by_name["feed.validate"],
+                              by_name["feed.narrow"],
+                              by_name["feed.device_put"]):
+        assert all(put["t_start"] <= c["t_start"] and c["t_end"] <= put["t_end"]
+                   for c in children)
+    worker = {s["thread"] for s in by_name["feed.put"]}
+    consumer = {s["thread"] for s in by_name["feed.take"]}
+    assert len(worker) == len(consumer) == 1 and worker != consumer
+    # the worker's seq of a batch is the seq of the take that hands it over
+    seqs = {}
+    for a in annotations:
+        seqs.setdefault(a["name"], []).append(a["kwargs"].get("seq"))
+    assert seqs["feed.put"] == seqs["feed.offer"] == list(range(n))
+    assert seqs["feed.source"] == seqs["feed.take"] == list(range(n + 1))
+    assert seqs["feed.device_put"] == [None] * n
+    for put, taken in zip(by_name["feed.put"], by_name["feed.take"]):
+        assert put["t_end"] <= taken["t_end"]
+
+
+def test_a_source_that_cannot_start_fails_the_take(rec):
+    class Broken:
+        def __iter__(self):
+            raise OSError("no such file")
+
+    with DevicePrefetcher(Broken(), lambda hb: hb) as feed:
+        with pytest.raises(OSError, match="no such file"):
+            next(feed)
+
+
+def test_stacked_placement_runs_under_the_same_spans(rec):
+    ctx = _ctx()
+    pool = [_host_batch(ctx.cfg, 8, seed=i) for i in range(3)]
+    placed = shard_batch_stacked(ctx, pool)
+    assert placed["feat_ids"].shape == (3, 8, 6)
+    assert [s["name"] for s in rec.spans()] == [
+        "feed.validate", "feed.narrow", "feed.device_put"]
+
+
+def test_out_of_range_ids_still_fail_inside_the_validate_span(rec):
+    ctx = _ctx()
+    hb = _host_batch(ctx.cfg)
+    hb["feat_ids"][0, 0] = ctx.cfg.model.feature_size
+    with pytest.raises(ValueError, match="out of range"):
+        shard_batch(ctx, hb)
+    assert [s["name"] for s in rec.spans()] == ["feed.validate"]
+
+
+# ------------------------------------------------- the benchmark's readers
+
+READERS = {
+    # name -> what the synthetic ring below must read as
+    "feed_worker_busy_share": 100.0 * (0.010 + 0.030 + 0.030) / 2.0,
+    "feed_put_ms": 30.0,
+    "feed_take_share": 100.0 * 0.020 / 2.0,
+}
+
+
+def _reader(name):
+    sys.path.insert(0, str(ROOT))
+    try:
+        import importlib
+
+        return importlib.import_module(f"perf.metrics.{name}")
+    finally:
+        sys.path.remove(str(ROOT))
+
+
+def _synthetic_ring(rec, t0):
+    """Spans written straight into the ring: two batches inside the window
+    [t0, t0 + 2], one put before it and one take that straddles its end."""
+    def add(name, start, dur):
+        rec._ring.append((name, t0 + start, t0 + start + dur, 1))
+
+    add("feed.put", -0.5, 0.2)                       # before the window
+    add("feed.source", 0.1, 0.010)
+    for start in (0.2, 0.6):
+        add("feed.validate", start, 0.010)
+        add("feed.narrow", start + 0.010, 0.005)
+        add("feed.device_put", start + 0.015, 0.012)
+        add("feed.put", start, 0.030)
+    add("feed.take", 1.0, 0.020)
+    add("feed.take", 1.9, 0.5)                       # straddles the end
+
+
+@pytest.mark.parametrize("name", sorted(READERS))
+def test_reader_keeps_the_window_and_reads_nothing_as_none(rec, name, capsys):
+    read = _reader(name).read
+    run = {"spans": {"t_start": 100.0, "window_s": 2.0}}
+    assert read(run) is None                         # empty ring: not 0
+    assert read({}) is None and read({"spans": {}}) is None
+    _synthetic_ring(rec, 100.0)
+    assert read(run) == pytest.approx(READERS[name])
+    if name == "feed_put_ms":
+        err = capsys.readouterr().err
+        assert "validate 10.000 + narrow 5.000 + device_put 12.000" in err
+        assert "self 3.000 ms a batch (2 batches" in err
+    # a window the ring no longer covers reads as nothing
+    small = SpanRecorder(maxlen=4)
+    obs_trace.set_span_recorder(small)
+    _synthetic_ring(small, 100.0)
+    assert read(run) is None
+
+
+def test_readers_read_the_live_feed(rec):
+    ctx = _ctx()
+    pool = [_host_batch(ctx.cfg, 8, seed=i) for i in range(4)]
+    t0 = time.perf_counter()
+    with DevicePrefetcher(iter(pool), lambda hb: shard_batch(ctx, hb)) as feed:
+        list(feed)
+    run = {"spans": {"t_start": t0, "window_s": time.perf_counter() - t0}}
+    for name in READERS:
+        value = _reader(name).read(run)
+        assert value is not None and value > 0
+        if name.endswith("_share"):
+            assert value <= 100.0
+
+
+# ---------------------------------- the loop: log line and bounded profile
+
+def test_run_train_logs_the_spans_and_traces_a_bounded_window(
+        tmp_path, capsys):
+    from deepfm_tpu.data.libsvm import generate_synthetic_ctr
+    from deepfm_tpu.train import loop
+
+    data = tmp_path / "data"
+    data.mkdir()
+    generate_synthetic_ctr(data / "tr-0.tfrecords", num_records=16 * 28,
+                           feature_size=200, field_size=6, seed=0)
+    prof = tmp_path / "prof"
+    cfg = Config.from_dict(TINY).with_overrides(
+        mesh={"data_parallel": 8, "model_parallel": 1},
+        data={"training_data_dir": str(data), "batch_size": 16,
+              "num_epochs": 1},
+        run={"model_dir": str(tmp_path / "model"), "servable_model_dir": "",
+             "log_steps": 2, "checkpoint_every_steps": 4,
+             "profile_dir": str(prof)},
+    )
+    state = loop.run_train(cfg)
+    assert int(state.step) == 28
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    train = [x for x in lines if x["kind"] == "train"]
+    assert len(train) == 14
+    assert all({"data_wait_ms", "dispatch_ms", "log_ms"} <= set(x)
+               and "host_ms" not in x for x in train)
+    # the save at step 4 shows in the window logged at step 6
+    assert "checkpoint_ms" in train[2]
+    assert train[2]["checkpoint_ms"] > 0 and train[3]["checkpoint_ms"] == 0
+    # one bounded trace: PROFILE_STEPS steps after the first logged window
+    events = [x for x in lines if x["kind"] == "profile"]
+    assert events == [{"kind": "profile", "dir": str(prof), "first_step": 2.0,
+                       "steps": float(loop.PROFILE_STEPS)}]
+    files = glob.glob(str(prof / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert len(files) == 1
+    # the program's spans lie in the trace, the worker's and the consumer's
+    # on host lines of their own
+    from jax.profiler import ProfileData
+
+    lines_of, seqs = {}, {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):   # one line per thread
+            for ev in line.events:
+                if ev.name in SPANS or ev.name == "train":
+                    lines_of.setdefault(ev.name, set()).add(i)
+                    seqs.setdefault(ev.name, set()).add(
+                        dict(ev.stats).get("seq"))
+    assert {"feed.put", "feed.device_put", "feed.take", "train.dispatch",
+            "train.log", "train.checkpoint", "train"} <= set(lines_of)
+    assert lines_of["feed.take"] == lines_of["train.dispatch"]
+    assert not lines_of["feed.put"] & lines_of["feed.take"]
+    # a batch is followed from the worker's line to the consumer's by seq
+    assert None not in seqs["feed.put"] | seqs["feed.take"]
+    assert len(seqs["feed.take"]) >= loop.PROFILE_STEPS - 1
+    assert seqs["feed.take"] & seqs["feed.put"]
+
+
+# ------------------------------------------------ named scopes in the step
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([a-z][a-z\-]*)\(")
+
+
+def test_scope_of_reads_through_the_transforms():
+    assert scope_of("jit(local_step)/transpose(jvp(lookup))/scatter-add") == (
+        "lookup", "transpose(jvp(lookup))")
+    assert scope_of("jit(local_step)/optimizer/sqrt") == (
+        "optimizer", "optimizer")
+    assert scope_of("jit(local_step)/jvp()/add") == (None, None)
+    assert scope_of("jit(lookup_table)/mul") == (None, None)   # no such scope
+    assert len(set(STEP_SCOPES)) == len(STEP_SCOPES)
+
+
+@pytest.mark.parametrize("model", [
+    {"model_name": "deepfm"},
+    {"model_name": "xdeepfm", "cin_layers": (5, 4)},
+], ids=["deepfm", "xdeepfm"])
+def test_step_instructions_carry_their_scope(model):
+    ctx = _ctx(**model)
+    state = create_spmd_state(ctx)
+    batch = shard_batch(ctx, _host_batch(ctx.cfg, 16))
+    hlo = make_spmd_train_step(ctx, donate=False).lower(
+        state, batch).compile().as_text()
+    found = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or m.group(2) not in (
+                "gather", "scatter", "dot", "convolution"):
+            continue
+        name = _OP_NAME.search(line)
+        assert name, line
+        scope, part = scope_of(name.group(1))
+        assert scope, f"{m.group(2)} {m.group(1)} lies under no scope: " \
+                      f"{name.group(1)}"
+        found.setdefault(m.group(2), set()).add(part)
+    # forward gathers and the table gradient's scatters
+    assert "jvp(lookup)" in found["gather"]
+    assert found["scatter"] == {"transpose(jvp(lookup))"}
+    dots = found.get("dot", set()) | found.get("convolution", set())
+    assert {"jvp(mlp)", "transpose(jvp(mlp))"} <= dots
+    if model["model_name"] == "xdeepfm":
+        assert {"jvp(cin)", "transpose(jvp(cin))"} <= dots
+    # every instruction of the optimizer's update names its scope, and the
+    # L2 base of the table gradient reads transpose(jvp(l2_penalty))
+    names = set(_OP_NAME.findall(hlo))
+    parts = {scope_of(n)[1] for n in names}
+    assert {"optimizer", "grad_sync", "metrics", "jvp(loss)", "jvp(fm)",
+            "transpose(jvp(l2_penalty))"} <= parts
+    adam = [n for n in names if n.endswith(("/sqrt", "/integer_pow"))]
+    assert adam and all(scope_of(n)[0] == "optimizer" for n in adam)
+
+
+def test_scopes_leave_the_lowered_step_as_it_was():
+    """Scopes are metadata: the lowered module (locations stripped, which is
+    what the compile cache keys on) does not name them."""
+    ctx = _ctx()
+    state = create_spmd_state(ctx)
+    batch = shard_batch(ctx, _host_batch(ctx.cfg, 16))
+    text = make_spmd_train_step(ctx, donate=False).lower(
+        state, batch).as_text()
+    assert "lookup" not in text and "optimizer" not in text
