@@ -198,11 +198,11 @@ class ModelConfig:
     # no native 64-bit integer datapath).  On by default; the switch exists
     # for the id-dtype cost ablation (benchmarks/attribution.py)
     narrow_ids: bool = True
-    # embedding-table gradient strategy: "scatter" = the gather's default
-    # VJP (one scatter-add update per lookup; XLA:TPU serializes colliding
-    # rows) | "segsum" = sort + segment-sum + one sorted-unique write per
-    # distinct row (ops/embedding.py segsum_lookup).  Default stays
-    # "scatter" until a chip measurement decides (ROADMAP S1)
+    # "scatter" | "segsum": selects nothing since PR 27.  The chip decided
+    # (PERF.md §6): the local row gather's backward combines duplicate ids
+    # and writes each distinct row once for either value
+    # (ops/embedding.py dense_lookup).  The field stays for the
+    # configuration files that name it; ROADMAP D3 removes it
     table_grad: str = "scatter"
     # Pallas fused gather+FM kernel (ops/pallas_ctr.py): "off" | "auto" | "on".
     # "auto" uses it on TPU backends; "on" means the compiled kernel and
@@ -273,28 +273,6 @@ class ModelConfig:
             raise ValueError(
                 f"shard_exchange_capacity must be in [0, 1] (a fraction of "
                 f"the local id stream), got {self.shard_exchange_capacity!r}"
-            )
-        # the fused Pallas kernel owns both gathers AND their backward, so
-        # table_grad='segsum' never takes effect on the fused path — reject
-        # the certain conflict, warn on the backend-dependent one
-        # (round-5 advisor finding: 'auto' resolving to fused on TPU
-        # silently dropped the segsum backward under test)
-        if self.table_grad == "segsum" and self.fused_kernel == "on":
-            raise ValueError(
-                "table_grad='segsum' has no effect with fused_kernel='on': "
-                "the fused kernel supplies its own dedup'd backward — use "
-                "fused_kernel='off' (or 'auto' on non-TPU) with segsum, or "
-                "table_grad='scatter' with the fused kernel"
-            )
-        if self.table_grad == "segsum" and self.fused_kernel == "auto":
-            import warnings
-
-            warnings.warn(
-                "table_grad='segsum' is ignored whenever "
-                "fused_kernel='auto' resolves to the fused path (TPU "
-                "backends): the fused kernel supplies its own backward. "
-                "Set fused_kernel='off' to guarantee the segsum backward.",
-                stacklevel=2,
             )
         if self.tiered_page_rows < 1:
             raise ValueError(
@@ -1074,8 +1052,9 @@ class Config:
             )
         # 2. packed-sort id bound: the dedup paths (exchange plan, lazy
         # pack) sort (id, position) packed into ONE uint32 key; a vocab
-        # too large for the local stream length falls back to the ~4x
-        # variadic argsort.  Correct, but the dominant sort cost — say so.
+        # too large for the local stream length sorts two operands
+        # instead.  On a v5e that is 1.16 against 1.06 ms at 319,488 ids
+        # (PERF.md §6, PR 27); on XLA:CPU about 4x.  Correct — say so.
         exchanges = mp > 1 or (o.lazy_embedding_updates and dp > 1)
         if exchanges and dp > 0:
             n_local = -(-d.batch_size // dp) * m.field_size
@@ -1085,8 +1064,8 @@ class Config:
                     f"feature_size={m.feature_size} exceeds the packed-"
                     f"sort id bound {packed_sort_id_bound(n_local)} for "
                     f"{n_local} local ids/shard: dedup sorts fall back to "
-                    f"the ~4x variadic argsort (ops/embedding.py "
-                    f"sort_segments).  Tiered embeddings "
+                    f"the two-operand sort (ops/embedding.py sort_segments; "
+                    f"about 4x on XLA:CPU, 1.1x on a v5e).  Tiered embeddings "
                     f"(model.tiered_embeddings) probe in SLOT space and "
                     f"keep the packed sort at any vocabulary.",
                     stacklevel=2,

@@ -24,8 +24,7 @@ import jax.numpy as jnp
 
 from ..core.config import ModelConfig
 from ..ops.batch_norm import bn_init
-from ..ops.embedding import (dense_lookup, narrow_ids, scaled_embedding,
-                             segsum_lookup)
+from ..ops.embedding import dense_lookup, narrow_ids, scaled_embedding
 from ..ops.initializers import glorot_normal, glorot_uniform
 from .base import register_model
 from .deepfm import apply_mlp, deepfm_l2_penalty, init_mlp
@@ -95,8 +94,6 @@ def apply_dcnv2(
     feat_ids = narrow_ids(feat_ids.reshape(-1, cfg.field_size),
                           cfg.feature_size, cfg.narrow_ids)
     feat_vals = feat_vals.reshape(-1, cfg.field_size).astype(jnp.float32)
-    if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
-        lookup_fn = segsum_lookup  # sorted-unique-write backward
 
     with jax.named_scope("lookup"):
         if lookup_fn is dense_lookup:

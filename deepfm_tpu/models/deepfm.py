@@ -25,8 +25,7 @@ import jax.numpy as jnp
 
 from ..core.config import ModelConfig
 from ..ops.batch_norm import batch_norm, bn_init
-from ..ops.embedding import (dense_lookup, narrow_ids, scaled_embedding,
-                             segsum_lookup)
+from ..ops.embedding import dense_lookup, narrow_ids, scaled_embedding
 from ..ops.fm import fm_first_order, fm_second_order
 from ..ops.initializers import glorot_normal, glorot_uniform
 from ..ops.pallas_ctr import fused_ctr_interaction, resolve_fused
@@ -160,8 +159,6 @@ def apply_deepfm(
                 params["fm_w"], params["fm_v"], feat_ids, feat_vals
             )
     else:
-        if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
-            lookup_fn = segsum_lookup  # sorted-unique-write backward
         # first order (ps:206-209)
         with jax.named_scope("lookup"):
             feat_w = lookup_fn(params["fm_w"], feat_ids)        # [B, F]

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.config import ModelConfig
-from ..ops.embedding import dense_lookup, narrow_ids, segsum_lookup
+from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.initializers import glorot_normal, glorot_uniform
 
 
@@ -119,8 +119,6 @@ def encode_tower(
                      user_vocab(cfg) if side == "user" else item_vocab(cfg),
                      cfg.narrow_ids)
     vals = vals.reshape(-1, field).astype(jnp.float32)
-    if lookup_fn is dense_lookup and cfg.table_grad == "segsum":
-        lookup_fn = segsum_lookup  # sorted-unique-write backward
     with jax.named_scope("lookup"):
         emb = lookup_fn(params[f"{side}_embedding"], ids) * vals[..., None]
     return _apply_tower(

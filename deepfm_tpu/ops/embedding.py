@@ -9,8 +9,13 @@ agnostic to the sharding strategy.
 
 from __future__ import annotations
 
+import functools
+import logging
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..core.config import packed_sort_id_bound
 
@@ -41,13 +46,103 @@ def narrow_ids(ids, vocab_size: int, enabled: bool = True):
     return ids
 
 
+# Chunk of distinct rows one pass of the table-gradient write carries.  The
+# write is priced by the index on the chip (≈ 0.13 µs a row of 32 floats into
+# a 12.5M-row table, PERF.md §6 PR 27), so the tail chunk's dropped sentinels
+# cost what live rows do: half a chunk a step on average.
+_WRITE_CHUNK = 2048
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gather_rows(meta, table, ids):
+    """The clip-mode gather; ``meta`` = (table shape, dtype), static, is all
+    the backward keeps of the table."""
+    return jnp.take(table, ids, axis=0, mode="clip")
+
+
+def _lookup_fwd(meta, table, ids):
+    return _gather_rows(meta, table, ids), ids
+
+
+def _lookup_bwd(meta, ids, g):
+    """Table gradient of the row gather: the cotangents of equal ids are
+    combined first, then every distinct row is written once.
+
+    XLA:TPU's scatter-add into a table-sized operand pays ≈ 0.13 µs an index
+    whatever the index vector promises (sorted, unique, dropped: all the
+    same; PERF.md §6 PR 27), while the same n updates into a buffer of a few
+    MB cost a seventh of that.  So: sort the ids (a two-operand sort, ≈ 1 ms
+    for 3·10⁵ ids), number the runs of equal ids, carry each position's run
+    number back to its place with a second sort, scatter-add ``g`` by run
+    number into a compact ``[n, K]`` buffer whose live prefix is the distinct
+    rows, and write that prefix into the table-shaped gradient chunk by
+    chunk: the trip count follows the batch's distinct rows, so there is no
+    capacity and no fallback.  Ids outside ``[0, rows)`` contribute nothing.
+
+    A table of scalars (FM_W) keeps XLA's own scatter-add: at one float a
+    row it is the compact buffer's price already (3.9 ms against 2.9 + 0.7
+    at n = 319,488, 2.3 against 1.9 + 0.6 at 159,744; same chip call)."""
+    shape, dtype = meta
+    rows, tail = shape[0], tuple(shape[1:])
+    flat_ids = ids.reshape(-1)
+    n = flat_ids.shape[0]
+    flat_g = g.reshape((n,) + tail).astype(dtype)
+    chunk = max(1, min(_WRITE_CHUNK, n))
+    n_pad = -(-n // chunk) * chunk
+    # the filler indices rows + position must stay representable, and a
+    # negative index would wrap python-style instead of being dropped
+    combine = bool(tail) and n > 0 and (
+        rows + n_pad <= jnp.iinfo(flat_ids.dtype).max)
+    logging.getLogger(__name__).info(
+        "table gradient: %s, n=%d rows=%d row=%s",
+        "combine-then-write" if combine else "xla scatter-add", n, rows, tail)
+    sentinel = jnp.asarray(rows, flat_ids.dtype)
+    flat_ids = jnp.where(
+        (flat_ids >= 0) & (flat_ids < rows), flat_ids, sentinel)
+    zero_ids = np.zeros(ids.shape, jax.dtypes.float0)
+    if not combine:
+        grad = jnp.zeros(shape, dtype).at[flat_ids].add(flat_g, mode="drop")
+        return grad, zero_ids
+
+    order, run, row_id, live = sort_segments(flat_ids, rows + 1)
+    distinct = jnp.sum(live, dtype=jnp.int32)
+    # each position's run number, back in the order of ``g``
+    _, run_of = lax.sort((order, run), num_keys=1)
+    combined = jnp.zeros((n_pad,) + tail, dtype).at[run_of].add(flat_g)
+    # the distinct ids ascending (the out-of-range run's is ``rows``), then
+    # fillers past the table's end that keep the vector sorted and unique
+    at_pad = jnp.arange(n_pad, dtype=row_id.dtype)
+    row_of = jnp.where(
+        at_pad < distinct, jnp.pad(row_id, (0, n_pad - n)), rows + at_pad)
+
+    def write(i, grad):
+        at = i * chunk
+        return grad.at[lax.dynamic_slice_in_dim(row_of, at, chunk)].add(
+            lax.dynamic_slice_in_dim(combined, at, chunk),
+            indices_are_sorted=True, unique_indices=True, mode="drop")
+
+    grad = lax.fori_loop(
+        0, (distinct + chunk - 1) // chunk, write, jnp.zeros(shape, dtype))
+    return grad, zero_ids
+
+
+_gather_rows.defvjp(_lookup_fwd, _lookup_bwd)
+
+
 def dense_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     """Gather rows: table [V] or [V, K], ids [B, F] -> [B, F] or [B, F, K].
 
     ``mode="clip"`` matches XLA:TPU's in-bounds guarantee while keeping the
     op fully vectorizable (no dynamic bounds checks in the hot path).
-    """
-    return jnp.take(table, ids, axis=0, mode="clip")
+
+    The backward combines duplicate ids before it touches the table-shaped
+    gradient (``_lookup_bwd``): float32 sums of the same addends as the
+    gather's default scatter-add VJP in another order, so the two agree to
+    float tolerance, not bit for bit (tests/test_segsum_grad.py).  It is the
+    one local row gather of every training path — the dense step, the SPMD
+    step's shard-local gather and the all-to-all exchange's owner side —
+    for either value of ``ModelConfig.table_grad``."""
+    return _gather_rows((tuple(table.shape), str(table.dtype)), table, ids)
 
 
 def scaled_embedding(
@@ -67,27 +162,29 @@ def sort_segments(flat_ids: jnp.ndarray, id_bound: int | None = None):
     ``seg[p]`` is the segment index of sorted position p, ``row_id[s]`` the
     id shared by segment s, ``valid[s]`` whether segment s exists (segments
     form a prefix).  One structure serves every table gathered with the
-    same ids (the lazy-Adam update, the segsum backward below, and the
-    all-to-all shard exchange's routing plan, parallel/embedding.py).
+    same ids (the lazy-Adam update, ``dense_lookup``'s backward above, and
+    the all-to-all shard exchange's routing plan, parallel/embedding.py).
 
     ``id_bound`` is the caller's STATIC promise that every id lies in
-    ``[0, id_bound)``.  It unlocks the packed single-key sort: XLA's
-    comparator sort pays ~4x for a variadic (key, payload) sort vs one
-    scalar key, and the sort is the dominant cost of every dedup path on
-    CPU/TPU.  When ``bits(id_bound) + ceil(log2 n)`` fits 32 bits, the
-    (id, position) pair packs losslessly into ONE uint32 key — the
-    position in the low bits tie-breaks ascending, i.e. exactly the
-    stable argsort permutation — so one single-key unsigned sort yields
-    both the sorted ids and the order.  (uint32 needs no jax x64 mode; an
-    int64 packing would silently TRUNCATE with x64 off.)  Without the
-    bound, or when it does not fit (e.g. huge-vocab streams), the general
-    variadic argsort runs instead — the flagship shape V=117,581 with
-    B_local*F ~= 20k packs exactly (17 + 15 bits).  The fit test is
+    ``[0, id_bound)``.  It unlocks the packed single-key sort: when
+    ``bits(id_bound) + ceil(log2 n)`` fits 32 bits, the (id, position) pair
+    packs losslessly into ONE uint32 key — the position in the low bits
+    tie-breaks ascending, i.e. exactly the stable argsort permutation — so
+    one single-key unsigned sort yields both the sorted ids and the order.
+    (uint32 needs no jax x64 mode; an int64 packing would silently TRUNCATE
+    with x64 off.)  Without the bound, or when it does not fit (e.g.
+    huge-vocab streams), one two-operand sort of (id, position) on the id
+    runs instead.  What the packing buys depends on the backend: XLA:CPU's
+    comparator sort pays ~4x for the second operand; on a v5e the
+    two-operand sort of 319,488 ids takes 1.16 ms against 1.06 for one key
+    (PERF.md §6, PR 27) — what costs there is ``argsort`` followed by
+    ``ids[order]``, a second n-index scalar gather (4.05 ms): sort the pair.
+    The fit test is
     ``core.config.packed_sort_id_bound`` — ONE definition shared with the
-    config-time validation that warns when a vocab/batch shape would
-    silently demote every dedup sort to the slow path.  Tiered-embedding
-    cache-probe streams (deepfm_tpu/tiered) always fit: their ids are
-    SLOTS bounded by the hot-cache capacity, not the vocabulary."""
+    config-time validation that names the shapes that leave the packed
+    path.  Tiered-embedding cache-probe streams (deepfm_tpu/tiered) always
+    fit: their ids are SLOTS bounded by the hot-cache capacity, not the
+    vocabulary."""
     n = flat_ids.shape[0]
     shift = max(1, int(n - 1).bit_length()) if n > 1 else 1
     if (
@@ -103,107 +200,16 @@ def sort_segments(flat_ids: jnp.ndarray, id_bound: int | None = None):
         order = (skey & ((1 << shift) - 1)).astype(jnp.int32)
         sid = (skey >> shift).astype(jnp.int32)  # logical shift: unsigned
     else:
-        order = jnp.argsort(flat_ids)
-        sid = flat_ids[order]
+        sid, order = lax.sort(
+            (flat_ids, jnp.arange(n, dtype=jnp.int32)), num_keys=1)
     first = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
     seg = jnp.cumsum(first) - 1
-    row_id = jnp.zeros((n,), sid.dtype).at[seg].set(
-        sid, indices_are_sorted=True
-    )
     valid = jnp.arange(n) < jnp.sum(first)
+    # the runs' ids, compacted by a single-key sort (every other position
+    # sorts last): on the chip a sort of n scalars is half the price of an
+    # n-index scalar scatter (1.0 against 2.1 ms at n = 319,488; PERF.md §6)
+    row_id = jnp.where(
+        valid,
+        jnp.sort(jnp.where(first, sid, jnp.iinfo(sid.dtype).max)),
+        jnp.zeros((), sid.dtype))
     return order, seg, row_id, valid
-
-
-def _segsum_meta(table) -> tuple:
-    return (tuple(table.shape), str(table.dtype))
-
-
-def _segsum_impl(meta, table, ids):
-    return jnp.take(table, ids, axis=0, mode="clip")
-
-
-def _segsum_fwd(meta, table, ids):
-    return _segsum_impl(meta, table, ids), ids
-
-
-def _segsum_bwd(meta, ids, g):
-    import jax
-
-    shape, dtype = meta
-    rows, tail = shape[0], tuple(shape[1:])
-    flat_ids = ids.reshape(-1)
-    n = flat_ids.shape[0]
-    flat_g = g.reshape((n,) + tail)
-    # collapse out-of-range ids onto the single sentinel ``rows`` BEFORE
-    # the sort: their cotangents were always dropped (the write below is
-    # mode="drop"), and the bounded non-negative stream unlocks the
-    # packed single-key sort
-    flat_ids = jnp.where(
-        (flat_ids >= 0) & (flat_ids < rows), flat_ids,
-        jnp.asarray(rows, flat_ids.dtype),
-    )
-    order, seg, row_id, valid = sort_segments(flat_ids, rows + 1)
-    summed = jax.ops.segment_sum(
-        flat_g[order], seg, num_segments=n, indices_are_sorted=True
-    )
-    # one write per UNIQUE row; empty segments target distinct out-of-range
-    # rows (rows + position) so the index vector stays sorted AND unique —
-    # XLA can emit a vectorized scatter instead of a serialized one
-    if rows + n - 1 <= jnp.iinfo(row_id.dtype).max:
-        write = jnp.where(
-            valid, row_id, rows + jnp.arange(n, dtype=row_id.dtype)
-        )
-        grad = jnp.zeros((rows,) + tail, dtype).at[write].add(
-            summed.astype(dtype), indices_are_sorted=True,
-            unique_indices=True, mode="drop",
-        )
-    else:
-        # the sentinel run rows..rows+n-1 would overflow the id dtype, and
-        # NO out-of-range sentinel is representable at all: .at[] wraps
-        # negative indices python-style (mode="drop" only drops >= rows,
-        # it does not drop negatives).  So route invalid segments at row 0
-        # and zero their contributions EXPLICITLY — segment_sum already
-        # leaves empty segments at 0, but masking here keeps correctness
-        # independent of that invariant.  Forfeits the sorted+unique
-        # scatter hint; only reachable when the table ends within B*F
-        # rows of the dtype max, so the slow scatter is a non-issue.
-        mask = valid.reshape((n,) + (1,) * len(tail))
-        write = jnp.where(valid, row_id, jnp.array(0, row_id.dtype))
-        grad = jnp.zeros((rows,) + tail, dtype).at[write].add(
-            jnp.where(mask, summed.astype(dtype), 0), mode="drop",
-        )
-    import numpy as _np
-
-    return grad, _np.zeros(ids.shape, jax.dtypes.float0)
-
-
-def _make_segsum_call():
-    import functools
-
-    import jax
-
-    call = jax.custom_vjp(_segsum_impl, nondiff_argnums=(0,))
-    call.defvjp(_segsum_fwd, _segsum_bwd)
-    return call
-
-
-_SEGSUM_CALL = _make_segsum_call()
-
-
-def segsum_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
-    """``dense_lookup`` with a sort+segment-sum backward.
-
-    The gather's default VJP is a scatter-add with one update per LOOKUP
-    (B·F of them, duplicate rows colliding) — the pattern XLA:TPU is
-    suspected to serialize (ROADMAP S1; not yet measured on the chip).
-    This variant's backward sorts the ids
-    once, segment-sums duplicate rows' cotangents, and issues ONE
-    sorted-unique write per distinct row — the same dedup structure the
-    lazy-Adam update uses (train/lazy.py).  Forward is identical
-    (clip-mode gather); select with ``ModelConfig.table_grad='segsum'``.
-
-    Numerical note: duplicate rows' contributions are summed in sorted-id
-    order instead of scatter order; f32 addition reorders, so gradients
-    match the scatter backward to float tolerance, not bit-exactly
-    (tests/test_segsum_grad.py)."""
-    return _SEGSUM_CALL(_segsum_meta(table), table, ids)

@@ -302,7 +302,6 @@ def _exchange_collect(
     capacity: int,
     num_shards: int,
     axis_name: str,
-    table_grad: str,
 ) -> jnp.ndarray:
     """The request/response all_to_all body (runs only when the plan did not
     overflow, so the request scatter's sorted/unique promises hold).
@@ -313,7 +312,7 @@ def _exchange_collect(
     [N, K] floats they do not), with a custom VJP that hand-writes the
     backward as sorted-segment-sum + one sorted-unique write into the
     response buffer — the same dedup structure train/lazy.py uses."""
-    from ..ops.embedding import segsum_lookup
+    from ..ops.embedding import dense_lookup
 
     rows = local_table.shape[0]
     n = plan.order.shape[0]
@@ -338,11 +337,8 @@ def _exchange_collect(
     recv = lax.all_to_all(reqbuf, axis_name, 0, 0, tiled=True)
     mask = recv < rows
     safe = jnp.clip(recv, 0, rows - 1)
-    if table_grad == "segsum":
-        # owner-side backward dedups the (peer-duplicated) scatter targets
-        got = segsum_lookup(local_table, safe)
-    else:
-        got = jnp.take(local_table, safe, axis=0)
+    # owner-side backward combines the (peer-duplicated) requests
+    got = dense_lookup(local_table, safe)
     got = jnp.where(mask if got.ndim == recv.ndim else mask[..., None], got, 0)
 
     # response leg: only the requested (owned) rows ride back
@@ -366,11 +362,10 @@ def _psum_lookup(
     local_table: jnp.ndarray,
     ids: jnp.ndarray,
     axis_name: str,
-    table_grad: str,
 ) -> jnp.ndarray:
     """Dense zeros-plus-psum assembly (the original path; also the
     capacity-overflow fallback of the alltoall exchange)."""
-    from ..ops.embedding import segsum_lookup
+    from ..ops.embedding import dense_lookup
 
     rows = local_table.shape[0]
     shard = lax.axis_index(axis_name)
@@ -378,10 +373,7 @@ def _psum_lookup(
     local_ids = ids - lo
     in_range = (local_ids >= 0) & (local_ids < rows)
     clipped = jnp.clip(local_ids, 0, rows - 1)
-    if table_grad == "segsum":
-        gathered = segsum_lookup(local_table, clipped)
-    else:
-        gathered = jnp.take(local_table, clipped, axis=0)
+    gathered = dense_lookup(local_table, clipped)
     mask = in_range if gathered.ndim == ids.ndim else in_range[..., None]
     gathered = jnp.where(mask, gathered, 0)
     return lax.psum(gathered, axis_name)
@@ -392,7 +384,6 @@ def sharded_lookup(
     ids: jnp.ndarray,
     *,
     axis_name: str = MODEL_AXIS,
-    table_grad: str = "scatter",
     exchange: str = "psum",
     capacity: float = 0.0,
 ) -> jnp.ndarray:
@@ -402,10 +393,9 @@ def sharded_lookup(
     ids: global ids [B, F] (replicated across the model axis)
     returns: full rows [B, F] or [B, F, K] (replicated across the model axis)
 
-    ``table_grad="segsum"`` swaps the local gather's backward for the
-    sorted-unique-write variant (ops/embedding.py segsum_lookup) — the
-    shard-local scatter-add has the same colliding-rows pattern XLA:TPU
-    serializes on the dense path.
+    The shard-local gather is ``ops/embedding.py dense_lookup``, whose
+    backward combines duplicate ids before it writes the table-shaped
+    gradient (``ModelConfig.table_grad`` selects nothing).
 
     ``exchange`` selects the assembly collective (module docstring): "psum"
     = dense zeros-plus-psum; "alltoall" = deduplicated owned-rows-only
@@ -420,7 +410,7 @@ def sharded_lookup(
             f"resolve_shard_exchange first), got {exchange!r}"
         )
     if exchange == "psum":
-        return _psum_lookup(local_table, ids, axis_name, table_grad)
+        return _psum_lookup(local_table, ids, axis_name)
 
     rows = local_table.shape[0]
     num_shards = int(lax.psum(1, axis_name))
@@ -430,9 +420,7 @@ def sharded_lookup(
     plan = exchange_plan(flat, rows, num_shards, cap)
 
     def exchange_branch(table):
-        return _exchange_collect(
-            table, plan, cap, num_shards, axis_name, table_grad
-        )
+        return _exchange_collect(table, plan, cap, num_shards, axis_name)
 
     # a shard owns at most ``rows`` rows and a batch has at most ``n``
     # uniques, so capacity >= min(n, rows) makes overflow impossible —
@@ -442,7 +430,7 @@ def sharded_lookup(
     else:
         out = lax.cond(
             plan.overflow,
-            lambda t: _psum_lookup(t, flat, axis_name, table_grad),
+            lambda t: _psum_lookup(t, flat, axis_name),
             exchange_branch,
             local_table,
         )
@@ -456,23 +444,20 @@ def sharded_l2(local_table: jnp.ndarray, axis_name: str = MODEL_AXIS) -> jnp.nda
 
 
 def make_sharded_lookup_fn(axis_name: str = MODEL_AXIS,
-                           table_grad: str = "scatter",
                            exchange: str = "psum",
                            capacity: float = 0.0):
-    """A ``lookup_fn`` for model.apply, closing over the axis name, gradient
-    strategy, and exchange mode (``lookup_fn_from_config`` resolves all
-    three from a Config)."""
+    """A ``lookup_fn`` for model.apply, closing over the axis name and the
+    exchange mode (``lookup_fn_from_config`` resolves them from a Config)."""
 
     def lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
         return sharded_lookup(table, ids, axis_name=axis_name,
-                              table_grad=table_grad, exchange=exchange,
-                              capacity=capacity)
+                              exchange=exchange, capacity=capacity)
 
     return lookup
 
 
 def lookup_fn_from_config(cfg, axis_name: str = MODEL_AXIS):
-    """The sharded ``lookup_fn`` a Config asks for: table_grad + resolved
+    """The sharded ``lookup_fn`` a Config asks for: resolved
     shard_exchange + capacity, in one place (spmd.py and retrieval.py both
     build their model-apply lookups here).
 
@@ -485,7 +470,6 @@ def lookup_fn_from_config(cfg, axis_name: str = MODEL_AXIS):
         mode = "psum"
     return make_sharded_lookup_fn(
         axis_name=axis_name,
-        table_grad=cfg.model.table_grad,
         exchange=mode,
         capacity=cfg.model.shard_exchange_capacity,
     )

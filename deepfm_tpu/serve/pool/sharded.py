@@ -178,7 +178,6 @@ def build_sharded_predict_with(ctx: ServeGroupContext) -> Callable:
     cfg = ctx.cfg
     model = get_model(cfg.model)
     lookup = make_sharded_lookup_fn(
-        table_grad=cfg.model.table_grad,
         exchange=ctx.exchange,
         capacity=cfg.model.shard_exchange_capacity,
     )

@@ -8,7 +8,8 @@ seconds of steps, and joins each ``XLA Ops`` event of the TPU plane — the bare
 HLO instruction, which carries no ``op_name`` — to its ``op_name`` by
 instruction name, and that to the step's named scope
 (``deepfm_tpu/obs/trace.scope_of``).  A fusion spans scopes; the name XLA
-keeps on it is its root's.  Prints the ten longest ops with their scope, the
+keeps on it is its root's; a ``while`` is left out of the sums, since the ops of
+its body have events of their own.  Prints the ten longest ops with their scope, the
 time per scope, and the share of the step's device time under no scope; the
 same goes to ``chiprun_out/step_scopes/<cell>.json``.  What ``PERF.md`` §5's
 scope column is made with, until a reader under ``perf/`` can do it (§7).
@@ -29,6 +30,7 @@ sys.path.insert(0, str(ROOT))
 
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CONTAINER = re.compile(r" = .*? (?:while|conditional|call)\(")
 
 
 def op_names(hlo_text: str) -> dict:
@@ -102,6 +104,10 @@ def main() -> int:
                     steps += sum("local_step" in ev.name for ev in line.events)
                 elif line.name == perf_trace.OPS_LINE:
                     for ev in line.events:
+                        # a loop's event spans its body's ops, which have
+                        # events of their own: count the ops, not the span
+                        if _CONTAINER.search(ev.name):
+                            continue
                         by_op[ev.name] = by_op.get(ev.name, 0) + ev.duration_ns
     shutil.rmtree(trace_dir, ignore_errors=True)
 

@@ -73,13 +73,11 @@ def _zipf_ids(b, f, v, seed=0, oor=True):
     return ids
 
 
-def _lookup(mesh, table, ids, mode, table_grad="scatter", capacity=0.0):
+def _lookup(mesh, table, ids, mode, capacity=0.0):
     table_specs = P(MODEL_AXIS) if table.ndim == 1 else P(MODEL_AXIS, None)
     out_specs = P(DATA_AXIS, *([None] * table.ndim))
     fn = shard_map(
-        lambda t, i: sharded_lookup(t, i, exchange=mode,
-                                    table_grad=table_grad,
-                                    capacity=capacity),
+        lambda t, i: sharded_lookup(t, i, exchange=mode, capacity=capacity),
         mesh=mesh,
         in_specs=(table_specs, P(DATA_AXIS, None)),
         out_specs=out_specs,
@@ -133,22 +131,26 @@ def test_exchange_forward_with_permuted_ids():
     )
 
 
-@pytest.mark.parametrize("table_grad", ["scatter", "segsum"])
-def test_exchange_table_grads_match_psum(table_grad):
+@pytest.mark.parametrize("tail", [(), (4,)], ids=["scalars", "rows"])
+def test_exchange_table_grads_match_psum(tail):
+    """Both shapes of table: a table of scalars keeps XLA's scatter-add in
+    the local gather's backward, a table of rows combines duplicates first
+    (ops/embedding.py ``_lookup_bwd``)."""
     mesh = _mesh(2, 4)
     rng = np.random.default_rng(2)
-    table = rng.normal(size=(VOCAB_PADDED, 4)).astype(np.float32)
+    table = rng.normal(size=(VOCAB_PADDED,) + tail).astype(np.float32)
     ids = _zipf_ids(32, 6, 117, oor=True)
+    spec = P(MODEL_AXIS, *([None] * len(tail)))
 
     def grad_of(mode):
         def loss(t, i):
-            out = sharded_lookup(t, i, exchange=mode, table_grad=table_grad)
+            out = sharded_lookup(t, i, exchange=mode)
             return jnp.sum(out * out * 0.5)
 
         fn = shard_map(
             jax.grad(loss), mesh=mesh,
-            in_specs=(P(MODEL_AXIS, None), P(DATA_AXIS, None)),
-            out_specs=P(MODEL_AXIS, None), check_vma=False,
+            in_specs=(spec, P(DATA_AXIS, None)),
+            out_specs=spec, check_vma=False,
         )
         return np.asarray(jax.jit(fn)(table, ids))
 

@@ -502,6 +502,62 @@ def test_step_instructions_carry_their_scope(model):
     assert adam and all(scope_of(n)[0] == "optimizer" for n in adam)
 
 
+_SHAPE = re.compile(r" = \(?([a-z]\d+)\[([\d,]*)\]")
+
+
+def _tiny_cell_config(name):
+    """The Config of a ``perf/configs/tiny-*.json`` file, as
+    ``perf/entries/train.build_config`` makes it."""
+    over = json.loads((Path(__file__).parents[1] / "perf" / "configs"
+                       / f"{name}.json").read_text())["overrides"]
+    over = {sec: {k: tuple(v) if isinstance(v, list) else v
+                  for k, v in fields.items()} for sec, fields in over.items()}
+    over.setdefault("data", {})["batch_size"] = 64
+    return Config().with_overrides(**over)
+
+
+@pytest.mark.parametrize("name", ["tiny-deepfm", "tiny-xdeepfm"])
+def test_table_gradient_lowering_contract(name):
+    """The cells' ``table_grad: "scatter"`` runs the combining backward: in
+    the compiled SPMD step every scatter into a table of rows promises sorted
+    and unique indices (one write per distinct row), the one scatter without
+    the promise is XLA's own scatter-add into the table of scalars (FM_W:
+    chosen on the table's rank, ops/embedding.py ``_lookup_bwd``), and every
+    op of the backward — sorts, scatters, the chunk loop — reads the
+    table-grad scope through ``scope_of``."""
+    cfg = _tiny_cell_config(name)
+    assert cfg.model.table_grad == "scatter"
+    mesh = build_mesh(MeshConfig(data_parallel=1, model_parallel=1),
+                      devices=jax.devices()[:1])
+    ctx = make_context(cfg, mesh)
+    state = create_spmd_state(ctx)
+    batch = shard_batch(ctx, _host_batch(ctx.cfg, 64))
+    hlo = make_spmd_train_step(ctx, donate=False).lower(
+        state, batch).compile().as_text()
+    rows = state.params["fm_v"].shape[0]
+    table_scatters, kinds = [], {}
+    for line in hlo.splitlines():
+        m, shape = _INSTR.match(line), _SHAPE.search(line)
+        if not m or m.group(2) not in ("scatter", "sort", "while"):
+            continue
+        name_ = _OP_NAME.search(line)
+        assert name_, line
+        if m.group(2) == "while" and "lookup" not in name_.group(1):
+            continue                 # XLA:CPU's threefry loops
+        assert scope_of(name_.group(1)) == (
+            "lookup", "transpose(jvp(lookup))"), line
+        kinds[m.group(2)] = kinds.get(m.group(2), 0) + 1
+        dims = [int(d) for d in shape.group(2).split(",") if d]
+        if m.group(2) == "scatter" and dims and dims[0] == rows:
+            promised = ("unique_indices=true" in line
+                        and "indices_are_sorted=true" in line)
+            table_scatters.append((len(dims), promised))
+            assert ("/while/body/" in name_.group(1)) == (len(dims) > 1)
+    # FM_V by the chunk loop, FM_W by XLA's scatter-add
+    assert sorted(table_scatters) == [(1, False), (2, True)]
+    assert kinds["while"] == 1 and kinds["sort"] >= 2
+
+
 def test_scopes_leave_the_lowered_step_as_it_was():
     """Scopes are metadata: the lowered module (locations stripped, which is
     what the compile cache keys on) does not name them."""
