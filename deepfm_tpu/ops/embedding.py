@@ -46,10 +46,12 @@ def narrow_ids(ids, vocab_size: int, enabled: bool = True):
     return ids
 
 
-# Chunk of distinct rows one pass of the table-gradient write carries.  The
-# write is priced by the index on the chip (≈ 0.13 µs a row of 32 floats into
-# a 12.5M-row table, PERF.md §6 PR 27), so the tail chunk's dropped sentinels
-# cost what live rows do: half a chunk a step on average.
+# Chunk of distinct rows one trip of the forward's gather and of the
+# table-gradient write carries.  Both are priced by the index on the chip
+# (≈ 0.13 µs a row of 32 floats written into a 12.5M-row table, 0.035 µs
+# read out of it; PERF.md §5), so the tail chunk's fillers cost what live
+# rows do — half a chunk a step on average — and the chunk's size hardly
+# matters (1,024 to 4,096 read the same, PERF.md §6 PR 27, PR 30).
 _WRITE_CHUNK = 2048
 
 
@@ -60,60 +62,120 @@ def _gather_rows(meta, table, ids):
     return jnp.take(table, ids, axis=0, mode="clip")
 
 
+def _chunks(meta, ids):
+    """``(chunk, n_pad)`` where the differentiated lookup works on the step's
+    distinct rows, ``None`` where it keeps XLA's gather and scatter-add: a
+    static choice on the table's rank and the shapes, one for both halves."""
+    shape, _ = meta
+    n = ids.size
+    chunk = max(1, min(_WRITE_CHUNK, n))
+    n_pad = -(-n // chunk) * chunk
+    # the filler indices rows + position must stay representable, and a
+    # negative index would wrap python-style instead of being dropped
+    if len(shape) > 1 and n > 0 and (
+            shape[0] + n_pad <= jnp.iinfo(ids.dtype).max):
+        return chunk, n_pad
+    return None
+
+
 def _lookup_fwd(meta, table, ids):
-    return _gather_rows(meta, table, ids), ids
+    """The gather under differentiation: every distinct row is read from the
+    table once, and the batch is expanded from a compact buffer.
+
+    XLA:TPU's gather, like its scatter, is priced by the index and by the
+    size of what is indexed (38 ns an index out of the 1.6 GB table, 10 out
+    of a 41 MB buffer; PERF.md §5–§6, PR 30), and five lookups in six of a
+    step read a row the same step has already read.  So the run structure
+    the backward needs is built here: sort the clipped ids (a two-operand
+    sort), number the runs of equal ids, carry each position's run number
+    back to its place with a second sort; gather the distinct rows chunk by
+    chunk into an ``[n_pad, K]`` buffer (the trip count follows the batch's
+    distinct rows: no capacity, no fallback), and expand ``out =
+    compact[run_of]``.  Copies of table rows only: bit for bit
+    ``jnp.take(table, ids, mode="clip")``.  The structure goes to the
+    backward as residuals, which no longer sorts.
+
+    A table of scalars (FM_W) keeps XLA's gather: an n-index scalar gather
+    out of 1.3 MB costs what the one out of 50 MB does."""
+    shape, _ = meta
+    rows, tail = shape[0], tuple(shape[1:])
+    plan = _chunks(meta, ids)
+    logging.getLogger(__name__).info(
+        "table lookup: %s, n=%d rows=%d row=%s",
+        "distinct rows, then expand" if plan else "xla gather",
+        ids.size, rows, tail)
+    if plan is None:
+        return _gather_rows(meta, table, ids), (ids, None)
+    chunk, n_pad = plan
+    flat_ids = ids.reshape(-1)
+    n = flat_ids.shape[0]
+    # runs of the clipped ids: an id outside [0, rows) reads the edge row
+    order, run, row_id, live = sort_segments(
+        jnp.clip(flat_ids, 0, rows - 1), rows)
+    distinct = jnp.sum(live, dtype=jnp.int32)
+    # each position's run number, back in the order of the ids
+    _, run_of = lax.sort((order, run), num_keys=1)
+    # the distinct ids ascending, then fillers past the table's end that keep
+    # the vector sorted and unique: the gather clips them onto the last row,
+    # the backward's write drops them
+    at_pad = jnp.arange(n_pad, dtype=row_id.dtype)
+    row_of = jnp.where(
+        at_pad < distinct, jnp.pad(row_id, (0, n_pad - n)), rows + at_pad)
+
+    def read(i, compact):
+        at = i * chunk
+        got = jnp.take(table, lax.dynamic_slice_in_dim(row_of, at, chunk),
+                       axis=0, mode="clip")
+        return lax.dynamic_update_slice_in_dim(compact, got, at, 0)
+
+    compact = lax.fori_loop(
+        0, (distinct + chunk - 1) // chunk, read,
+        jnp.zeros((n_pad,) + tail, table.dtype))
+    out = jnp.take(compact, run_of.reshape(ids.shape), axis=0, mode="clip")
+    return out, (ids, (run_of, row_of, distinct))
 
 
-def _lookup_bwd(meta, ids, g):
-    """Table gradient of the row gather: the cotangents of equal ids are
-    combined first, then every distinct row is written once.
+def _lookup_bwd(meta, residuals, g):
+    """Table gradient of the row gather, the forward's transpose op for op:
+    the cotangents of equal ids are combined first, then every distinct row
+    is written once.
 
     XLA:TPU's scatter-add into a table-sized operand pays ≈ 0.13 µs an index
     whatever the index vector promises (sorted, unique, dropped: all the
     same; PERF.md §6 PR 27), while the same n updates into a buffer of a few
-    MB cost a seventh of that.  So: sort the ids (a two-operand sort, ≈ 1 ms
-    for 3·10⁵ ids), number the runs of equal ids, carry each position's run
-    number back to its place with a second sort, scatter-add ``g`` by run
-    number into a compact ``[n, K]`` buffer whose live prefix is the distinct
-    rows, and write that prefix into the table-shaped gradient chunk by
-    chunk: the trip count follows the batch's distinct rows, so there is no
-    capacity and no fallback.  Ids outside ``[0, rows)`` contribute nothing.
+    MB cost a seventh of that.  So: scatter-add ``g`` by the forward's run
+    numbers into a compact ``[n_pad, K]`` buffer whose live prefix is the
+    distinct rows, and write that prefix into the table-shaped gradient chunk
+    by chunk: the trip count follows the batch's distinct rows, so there is
+    no capacity and no fallback.  Ids outside ``[0, rows)`` contribute
+    nothing (the forward clipped them onto an edge row's run: their
+    cotangent is dropped here).
 
     A table of scalars (FM_W) keeps XLA's own scatter-add: at one float a
     row it is the compact buffer's price already (3.9 ms against 2.9 + 0.7
     at n = 319,488, 2.3 against 1.9 + 0.6 at 159,744; same chip call)."""
     shape, dtype = meta
     rows, tail = shape[0], tuple(shape[1:])
+    ids, runs = residuals
     flat_ids = ids.reshape(-1)
     n = flat_ids.shape[0]
     flat_g = g.reshape((n,) + tail).astype(dtype)
-    chunk = max(1, min(_WRITE_CHUNK, n))
-    n_pad = -(-n // chunk) * chunk
-    # the filler indices rows + position must stay representable, and a
-    # negative index would wrap python-style instead of being dropped
-    combine = bool(tail) and n > 0 and (
-        rows + n_pad <= jnp.iinfo(flat_ids.dtype).max)
     logging.getLogger(__name__).info(
         "table gradient: %s, n=%d rows=%d row=%s",
-        "combine-then-write" if combine else "xla scatter-add", n, rows, tail)
-    sentinel = jnp.asarray(rows, flat_ids.dtype)
-    flat_ids = jnp.where(
-        (flat_ids >= 0) & (flat_ids < rows), flat_ids, sentinel)
+        "combine-then-write" if runs else "xla scatter-add", n, rows, tail)
+    in_range = (flat_ids >= 0) & (flat_ids < rows)
     zero_ids = np.zeros(ids.shape, jax.dtypes.float0)
-    if not combine:
-        grad = jnp.zeros(shape, dtype).at[flat_ids].add(flat_g, mode="drop")
+    if runs is None:
+        grad = jnp.zeros(shape, dtype).at[
+            jnp.where(in_range, flat_ids, rows)].add(flat_g, mode="drop")
         return grad, zero_ids
 
-    order, run, row_id, live = sort_segments(flat_ids, rows + 1)
-    distinct = jnp.sum(live, dtype=jnp.int32)
-    # each position's run number, back in the order of ``g``
-    _, run_of = lax.sort((order, run), num_keys=1)
-    combined = jnp.zeros((n_pad,) + tail, dtype).at[run_of].add(flat_g)
-    # the distinct ids ascending (the out-of-range run's is ``rows``), then
-    # fillers past the table's end that keep the vector sorted and unique
-    at_pad = jnp.arange(n_pad, dtype=row_id.dtype)
-    row_of = jnp.where(
-        at_pad < distinct, jnp.pad(row_id, (0, n_pad - n)), rows + at_pad)
+    run_of, row_of, distinct = runs
+    chunk, n_pad = _chunks(meta, ids)
+    # an id the forward clipped onto an edge row's run adds past the buffer's
+    # end, where the scatter drops it
+    combined = jnp.zeros((n_pad,) + tail, dtype).at[
+        jnp.where(in_range, run_of, n_pad)].add(flat_g, mode="drop")
 
     def write(i, grad):
         at = i * chunk
@@ -135,13 +197,21 @@ def dense_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     ``mode="clip"`` matches XLA:TPU's in-bounds guarantee while keeping the
     op fully vectorizable (no dynamic bounds checks in the hot path).
 
-    The backward combines duplicate ids before it touches the table-shaped
-    gradient (``_lookup_bwd``): float32 sums of the same addends as the
-    gather's default scatter-add VJP in another order, so the two agree to
-    float tolerance, not bit for bit (tests/test_segsum_grad.py).  It is the
-    one local row gather of every training path — the dense step, the SPMD
-    step's shard-local gather and the all-to-all exchange's owner side —
-    for either value of ``ModelConfig.table_grad``."""
+    Called outside differentiation (serve/, eval, the lazy and the tiered
+    step) this is the one plain gather and nothing else: an inference bucket
+    of 8–512 rows pays no sort.  Under differentiation the two halves share
+    one run structure of the step's ids (``_lookup_fwd``, ``_lookup_bwd``):
+    the forward reads every distinct row from the table once and expands to
+    the batch from a compact buffer — copies of table rows, so bit for bit
+    this same gather — and the backward combines the cotangents of equal ids
+    before it touches the table-shaped gradient: float32 sums of the same
+    addends as the gather's default scatter-add VJP in another order, so the
+    two agree to float tolerance, not bit for bit
+    (tests/test_segsum_grad.py).  A table of scalars keeps XLA's gather and
+    scatter-add both ways.  It is the one local row gather of every training
+    path — the dense step, the SPMD step's shard-local gather and the
+    all-to-all exchange's owner side — for either value of
+    ``ModelConfig.table_grad``."""
     return _gather_rows((tuple(table.shape), str(table.dtype)), table, ids)
 
 
@@ -162,7 +232,7 @@ def sort_segments(flat_ids: jnp.ndarray, id_bound: int | None = None):
     ``seg[p]`` is the segment index of sorted position p, ``row_id[s]`` the
     id shared by segment s, ``valid[s]`` whether segment s exists (segments
     form a prefix).  One structure serves every table gathered with the
-    same ids (the lazy-Adam update, ``dense_lookup``'s backward above, and
+    same ids (the lazy-Adam update, ``dense_lookup``'s forward rule above, and
     the all-to-all shard exchange's routing plan, parallel/embedding.py).
 
     ``id_bound`` is the caller's STATIC promise that every id lies in

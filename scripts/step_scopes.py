@@ -9,7 +9,7 @@ HLO instruction, which carries no ``op_name`` — to its ``op_name`` by
 instruction name, and that to the step's named scope
 (``deepfm_tpu/obs/trace.scope_of``).  A fusion spans scopes; the name XLA
 keeps on it is its root's; a ``while`` is left out of the sums, since the ops of
-its body have events of their own.  Prints the ten longest ops with their scope, the
+its body have events of their own.  Prints the 40 longest ops with their scope, the
 time per scope, and the share of the step's device time under no scope; the
 same goes to ``chiprun_out/step_scopes/<cell>.json``.  What ``PERF.md`` §5's
 scope column is made with, until a reader under ``perf/`` can do it (§7).
@@ -125,7 +125,7 @@ def main() -> int:
     out = {
         "workload": cell.name, "steps": steps,
         "step_device_ms_sum_of_ops": total / 1e6 / steps,
-        "top_ops": rows[:10],
+        "top_ops": rows[:40],
         "ms_per_step_by_scope": {k: v / 1e6 / steps for k, v in sorted(
             by_scope.items(), key=lambda kv: -kv[1])},
         "unscoped_share_pct": 100.0 * by_scope.get("(none)", 0) / total,

@@ -1,17 +1,26 @@
-"""The table gradient of the row gather (ops/embedding.py ``dense_lookup``).
+"""The row gather under differentiation (ops/embedding.py ``dense_lookup``).
 
 The gather's default VJP scatter-adds one update per lookup into the
-table-shaped gradient, which on the chip costs by the index (PERF.md §6,
-PR 27).  ``dense_lookup``'s backward combines the cotangents of equal ids
-first and writes every distinct row once, chunk by chunk.  These tests pin:
-exact forward equality, gradient equality against XLA's own scatter-add VJP
-(``jax.grad`` through plain ``jnp.take``; to f32 tolerance — duplicate
-contributions are summed in another order), for tables of scalars and of
-rows, ids that repeat, ids out of range, id streams that do and do not fit
-the packed sort, several write chunks, ids outside a shard's window, and
-full-model and SPMD step parity for both values of ``table_grad`` (which
-selects nothing any more).
+table-shaped gradient, and its forward reads one row per lookup out of the
+table; on the chip both cost by the index and by the size of what is indexed
+(PERF.md §6, PR 27 and PR 30).  ``dense_lookup``'s forward rule reads every
+distinct row once and expands to the batch from a compact buffer; its
+backward combines the cotangents of equal ids on the same run structure and
+writes every distinct row once, chunk by chunk.  These tests pin: the
+differentiated forward bit for bit against ``jnp.take(mode="clip")`` (read
+through ``jax.vjp`` and through ``jax.value_and_grad`` with an aux output),
+the plain gather outside differentiation, gradient equality against XLA's
+own scatter-add VJP (``jax.grad`` through plain ``jnp.take``; to f32
+tolerance — duplicate contributions are summed in another order), for
+tables of scalars and of rows, ids that repeat, ids out of range (clipped on
+the way in, dropped on the way back), id streams that do and do not fit the
+packed sort, several chunks, ids outside a shard's window, the trace-time
+log lines of both halves, and full-model and SPMD step parity for both
+values of ``table_grad`` (which selects nothing any more).
 """
+
+import logging
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,19 +113,109 @@ def _case_ids(case, rng, rows):
 @pytest.mark.parametrize("case", ["zipf", "all_duplicate", "out_of_range",
                                   "criteo", "all_distinct"])
 def test_backward_matches_xla_scatter_add(case, tail):
-    rows = 6000 if case in ("criteo", "all_distinct") else V
-    rng = np.random.default_rng(3)
-    ids = _case_ids(case, rng, rows)
+    rows, ids, table, w = _case(case, tail)
     if case == "all_distinct":
         assert ids.size > 2 * embedding._WRITE_CHUNK
         assert ids.size % embedding._WRITE_CHUNK
-    table = jnp.asarray(rng.standard_normal((rows,) + tail), jnp.float32)
-    w = jnp.asarray(rng.standard_normal(ids.shape + tail), jnp.float32)
     g_xla, g_new = _grads(table, jnp.asarray(ids), w)
     np.testing.assert_allclose(g_xla, g_new, rtol=1e-5, atol=1e-5)
     inside = ids[(ids >= 0) & (ids < rows)]
     untouched = np.setdiff1d(np.arange(rows), inside)
     assert not np.any(g_new[untouched])
+
+
+def _case(case, tail):
+    rows = 6000 if case in ("criteo", "all_distinct") else V
+    rng = np.random.default_rng(3)
+    ids = _case_ids(case, rng, rows)
+    table = jnp.asarray(rng.standard_normal((rows,) + tail), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(ids.shape + tail), jnp.float32)
+    return rows, ids, table, w
+
+
+@pytest.mark.parametrize("through", ["vjp", "value_and_grad"])
+@pytest.mark.parametrize("tail", [(), (10,), (32,)],
+                         ids=["scalars", "K10", "K32"])
+@pytest.mark.parametrize("case", ["zipf", "all_duplicate", "out_of_range",
+                                  "criteo", "all_distinct"])
+def test_differentiated_forward_is_bit_equal_to_take(case, tail, through):
+    """Under differentiation the rows come out of a compact buffer of the
+    step's distinct rows: copies of table rows, so bit for bit the clip-mode
+    gather — an id outside ``[0, rows)`` reads the edge row."""
+    rows, ids, table, w = _case(case, tail)
+    want = np.asarray(jnp.take(table, ids, axis=0, mode="clip"))
+    if case == "out_of_range":
+        np.testing.assert_array_equal(want[0, 0], np.asarray(table[0]))
+        np.testing.assert_array_equal(want[0, 3], np.asarray(table[rows - 1]))
+    ids = jnp.asarray(ids)
+    if through == "vjp":
+        got, pull = jax.jit(
+            lambda t: jax.vjp(lambda t_: dense_lookup(t_, ids), t))(table)
+        assert pull(w)[0].shape == table.shape
+    else:
+        def loss(t):
+            out = dense_lookup(t, ids)
+            return jnp.sum(out * w), out
+
+        (_, got), _ = jax.jit(jax.value_and_grad(loss, has_aux=True))(table)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_out_of_range_ids_read_the_edge_row_and_write_nothing():
+    """Clip on the way in, drop on the way back: the two behaviours of the
+    parent's gather and of its backward, on one id stream."""
+    rows = 50
+    table = jnp.arange(rows * 4, dtype=jnp.float32).reshape(rows, 4) + 1.0
+    ids = jnp.asarray([[-3, 0, rows - 1, rows + 9]], jnp.int32)
+    out, pull = jax.vjp(lambda t: dense_lookup(t, ids), table)
+    np.testing.assert_array_equal(
+        np.asarray(out[0]), np.asarray(table)[[0, 0, rows - 1, rows - 1]])
+    grad = np.asarray(pull(jnp.ones_like(out))[0])
+    # the edge rows take their own lookup's cotangent and not the clipped ids'
+    assert grad[0].tolist() == [1.0] * 4 and grad[-1].tolist() == [1.0] * 4
+    assert np.count_nonzero(grad) == 8
+
+
+def _indexed_ops(fn, *args):
+    """The gather / scatter / sort / while instructions ``fn`` lowers to."""
+    text = jax.jit(fn).lower(*args).as_text()
+    return re.findall(r'"?stablehlo\.(gather|scatter|sort|while)"?\(', text)
+
+
+@pytest.mark.parametrize("tail", [(), (10,)], ids=["scalars", "K10"])
+def test_lookup_outside_differentiation_is_the_one_plain_gather(tail):
+    """serve/, eval, the lazy and the tiered step call ``dense_lookup``
+    outside ``grad``: one gather, no sort, no loop, whatever the table."""
+    table = jnp.zeros((V,) + tail, jnp.float32)
+    ids = jnp.zeros((8, 13), jnp.int32)
+    assert _indexed_ops(dense_lookup, table, ids) == ["gather"]
+    diff = _indexed_ops(
+        lambda t, i: jax.vjp(lambda t_: dense_lookup(t_, i), t)[0], table, ids)
+    if tail:    # distinct rows out of the table in a loop, then the expansion
+        assert sorted(diff) == ["gather", "gather", "sort", "sort", "sort",
+                                "while"]
+    else:       # a table of scalars keeps XLA's gather
+        assert diff == ["gather"]
+
+
+@pytest.mark.parametrize("tail,fwd,bwd", [
+    ((), "xla gather", "xla scatter-add"),
+    ((10,), "distinct rows, then expand", "combine-then-write"),
+], ids=["scalars", "K10"])
+def test_both_halves_say_what_they_chose_once_per_trace(
+        tail, fwd, bwd, caplog):
+    """The choice is static, so there is no rate to count: one log line a
+    half a trace names it."""
+    table = jnp.zeros((V,) + tail, jnp.float32)
+    ids = jnp.zeros((8, 13), jnp.int32)
+    step = jax.jit(jax.grad(lambda t: jnp.sum(dense_lookup(t, ids))))
+    with caplog.at_level(logging.INFO, logger=embedding.__name__):
+        step(table)
+        step(table)                  # cached: traced once
+    lines = [r.getMessage() for r in caplog.records]
+    assert lines == [f"table lookup: {fwd}, n=104 rows={V} row={tail}",
+                     f"table gradient: {bwd}, n=104 rows={V} row={tail}"]
 
 
 @pytest.mark.parametrize("tail", [(), (10,)], ids=["scalars", "K10"])

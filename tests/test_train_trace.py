@@ -516,15 +516,23 @@ def _tiny_cell_config(name):
     return Config().with_overrides(**over)
 
 
+_OPERANDS = re.compile(r"\(([^()]*)\)")
+
+
 @pytest.mark.parametrize("name", ["tiny-deepfm", "tiny-xdeepfm"])
 def test_table_gradient_lowering_contract(name):
-    """The cells' ``table_grad: "scatter"`` runs the combining backward: in
-    the compiled SPMD step every scatter into a table of rows promises sorted
-    and unique indices (one write per distinct row), the one scatter without
-    the promise is XLA's own scatter-add into the table of scalars (FM_W:
-    chosen on the table's rank, ops/embedding.py ``_lookup_bwd``), and every
-    op of the backward — sorts, scatters, the chunk loop — reads the
-    table-grad scope through ``scope_of``."""
+    """The cells' ``table_grad: "scatter"`` runs the lookup on the step's
+    distinct rows, both ways (ops/embedding.py ``_lookup_fwd`` /
+    ``_lookup_bwd``).  In the compiled SPMD step: every sort of the run
+    structure reads the forward's scope and none the backward's; there are
+    two loops, the forward's gather of the distinct rows and the backward's
+    write of them; exactly one gather reads the ``[rows, K]`` table and it
+    sits inside the forward's loop (the expansion to the batch reads the
+    compact buffer); every scatter into a table of rows promises sorted and
+    unique indices (one write per distinct row) inside the backward's loop;
+    the one scatter without the promise is XLA's own scatter-add into the
+    table of scalars (FM_W: chosen on the table's rank), whose gather stays
+    XLA's too."""
     cfg = _tiny_cell_config(name)
     assert cfg.model.table_grad == "scatter"
     mesh = build_mesh(MeshConfig(data_parallel=1, model_parallel=1),
@@ -534,28 +542,51 @@ def test_table_gradient_lowering_contract(name):
     batch = shard_batch(ctx, _host_batch(ctx.cfg, 64))
     hlo = make_spmd_train_step(ctx, donate=False).lower(
         state, batch).compile().as_text()
-    rows = state.params["fm_v"].shape[0]
-    table_scatters, kinds = [], {}
+    rows, k = state.params["fm_v"].shape
+    n = batch["feat_ids"].size
+    forward, backward = "jvp(lookup)", "transpose(jvp(lookup))"
+    shape_of = {}                    # instruction -> dims, for the operands
+    kinds, table_scatters, gathers = {}, [], []
     for line in hlo.splitlines():
         m, shape = _INSTR.match(line), _SHAPE.search(line)
-        if not m or m.group(2) not in ("scatter", "sort", "while"):
+        if m and shape:
+            shape_of[m.group(1)] = [
+                int(d) for d in shape.group(2).split(",") if d]
+        if not m or m.group(2) not in ("gather", "scatter", "sort", "while"):
             continue
         name_ = _OP_NAME.search(line)
         assert name_, line
         if m.group(2) == "while" and "lookup" not in name_.group(1):
             continue                 # XLA:CPU's threefry loops
-        assert scope_of(name_.group(1)) == (
-            "lookup", "transpose(jvp(lookup))"), line
-        kinds[m.group(2)] = kinds.get(m.group(2), 0) + 1
-        dims = [int(d) for d in shape.group(2).split(",") if d]
+        scope, part = scope_of(name_.group(1))
+        assert scope == "lookup" and part in (forward, backward), line
+        kinds[part, m.group(2)] = kinds.get((part, m.group(2)), 0) + 1
+        dims = shape_of[m.group(1)]
+        in_loop = "/while/body/" in name_.group(1)
+        if m.group(2) == "gather":
+            operand = _OPERANDS.search(line[m.end() - 1:]).group(1).split(
+                ",")[0].strip().lstrip("%")
+            gathers.append((tuple(shape_of[operand]), part, in_loop))
         if m.group(2) == "scatter" and dims and dims[0] == rows:
             promised = ("unique_indices=true" in line
                         and "indices_are_sorted=true" in line)
             table_scatters.append((len(dims), promised))
-            assert ("/while/body/" in name_.group(1)) == (len(dims) > 1)
+            assert part == backward and in_loop == (len(dims) > 1)
+    # the run structure is the forward's: ids, runs' ids, run numbers back
+    assert kinds[forward, "sort"] >= 3 and (backward, "sort") not in kinds
+    assert kinds[forward, "while"] == 1 and kinds[backward, "while"] == 1
+    # FM_V's rows leave the table once a distinct row, inside the loop; the
+    # batch is expanded from the compact buffer; FM_W keeps XLA's gather
+    by_operand = {}
+    for operand, part, in_loop in gathers:
+        assert part == forward
+        by_operand.setdefault(operand, []).append(in_loop)
+    assert by_operand.pop((rows, k)) == [True]
+    assert by_operand.pop((rows,)) == [False]
+    (compact, where), = by_operand.items()
+    assert where == [False] and compact[1] == k and n <= compact[0] < 2 * n
     # FM_V by the chunk loop, FM_W by XLA's scatter-add
     assert sorted(table_scatters) == [(1, False), (2, True)]
-    assert kinds["while"] == 1 and kinds["sort"] >= 2
 
 
 def test_scopes_leave_the_lowered_step_as_it_was():
