@@ -10,9 +10,8 @@ def save_paged(trainer, directory: str) -> dict:
     """Streaming paged checkpoint for a tiered trainer
     (deepfm_tpu/tiered): flush dirty rows+moments hot→host→cold, then
     commit a small metadata record — bytes moved scale with DIRTY rows,
-    never the table, unlike the gather-everything Orbax path above
-    (3.96 GB state took 322 s to even dispatch at 10M rows,
-    docs/BENCH_LARGE_VOCAB.json).  Thin indirection so checkpoint/ is
+    never the table, unlike the gather-everything Orbax path above.
+    Thin indirection so checkpoint/ is
     the one place callers look for every save flavor; the mechanics
     live in ``tiered.trainer.TieredTrainer.save``/``restore``."""
     return trainer.save(directory)

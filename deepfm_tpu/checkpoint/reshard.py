@@ -22,8 +22,7 @@ restored at the SAVED shape sharded over the target mesh, then sliced or
 zero-padded to the target padded vocab on-device (a jitted, distributed
 reshape — the all-zero-pad-rows verification is a sharded reduction, not a
 host scan).  Host memory stays O(checkpoint-chunk buffer) regardless of
-vocabulary size; `benchmarks/large_vocab.py` exercises this at 10M-100M
-rows and records peak RSS.
+vocabulary size.
 """
 
 from __future__ import annotations

@@ -56,9 +56,9 @@ EXECUTABLE_SPEC_FIELDS = (
     "model_name", "feature_size", "field_size", "embedding_size",
     "deep_layers", "cin_layers", "cross_layers", "batch_norm",
     "tower_layers", "tower_dim", "user_vocab_size", "item_vocab_size",
-    "user_field_size", "item_field_size", "compute_dtype", "narrow_ids",
-    "table_grad", "fused_kernel", "shard_exchange",
-    "shard_exchange_capacity", "tiered_embeddings",
+    "user_field_size", "item_field_size", "compute_dtype", "table_grad",
+    "fused_kernel", "shard_exchange", "shard_exchange_capacity",
+    "tiered_embeddings",
 )
 
 # keys a fleet tenant entry may carry (core/config.py and fleet/registry.py
@@ -194,10 +194,6 @@ class ModelConfig:
     temperature: float = 0.05
     # compute dtype for the MLP/FM math (params stay f32; bf16 feeds the MXU)
     compute_dtype: str = "bfloat16"
-    # int64->int32 id narrowing when the vocab is int32-addressable (TPU has
-    # no native 64-bit integer datapath).  On by default; the switch exists
-    # for the id-dtype cost ablation (benchmarks/attribution.py)
-    narrow_ids: bool = True
     # "scatter" | "segsum": selects nothing since PR 27.  The chip decided
     # (PERF.md §6): the local row gather's backward combines duplicate ids
     # and writes each distinct row once for either value

@@ -2,7 +2,7 @@
 process got.
 
 ``configure_runtime()`` is called once by each entry point (the launcher
-CLI, ``serve/server.py main``, the pool member, ``bench.py``'s children,
+CLI, ``serve/server.py main``, the pool member, ``perf/entries/train.py``,
 ``__graft_entry__``) BEFORE the first jax backend initialisation: it places
 the persistent compile cache and, on the CPU platform only, relaxes XLA:CPU's
 collective watchdogs.  It never initialises a backend itself, so a

@@ -92,7 +92,7 @@ def apply_dcnv2(
     lookup_fn=dense_lookup,
 ) -> tuple[jnp.ndarray, dict]:
     feat_ids = narrow_ids(feat_ids.reshape(-1, cfg.field_size),
-                          cfg.feature_size, cfg.narrow_ids)
+                          cfg.feature_size)
     feat_vals = feat_vals.reshape(-1, cfg.field_size).astype(jnp.float32)
 
     with jax.named_scope("lookup"):

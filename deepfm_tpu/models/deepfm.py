@@ -139,7 +139,7 @@ def apply_deepfm(
 ) -> tuple[jnp.ndarray, dict]:
     """Forward pass: [B, F] int ids + [B, F] f32 vals -> [B] logits."""
     feat_ids = narrow_ids(feat_ids.reshape(-1, cfg.field_size),
-                          cfg.feature_size, cfg.narrow_ids)
+                          cfg.feature_size)
     feat_vals = feat_vals.reshape(-1, cfg.field_size).astype(jnp.float32)
 
     if cfg.fused_kernel == "on" and lookup_fn is not dense_lookup:

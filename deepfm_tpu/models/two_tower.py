@@ -116,8 +116,7 @@ def encode_tower(
     encoding query users or corpus items independently."""
     field = cfg.user_field_size if side == "user" else cfg.item_field_size
     ids = narrow_ids(ids.reshape(-1, field),
-                     user_vocab(cfg) if side == "user" else item_vocab(cfg),
-                     cfg.narrow_ids)
+                     user_vocab(cfg) if side == "user" else item_vocab(cfg))
     vals = vals.reshape(-1, field).astype(jnp.float32)
     with jax.named_scope("lookup"):
         emb = lookup_fn(params[f"{side}_embedding"], ids) * vals[..., None]

@@ -43,8 +43,7 @@ TRACE_HEADER = "X-Trace-Id"
 SPAN_HEADER = "X-Span-Id"
 
 # the serving tier's shipped head-sampling rate: fresh requests trace at
-# this probability (BENCH_OBS gates the throughput tax of exactly this
-# config); a request that ARRIVES with an X-Trace-Id — from the router
+# this probability; a request that ARRIVES with an X-Trace-Id — from the router
 # head or the client — is always recorded, so end-to-end traces are
 # never half-collected and tests/debugging pin a trace by supplying the
 # id.  Override per server via --trace-sample / Tracer(sample_rate=...).

@@ -21,8 +21,7 @@ every production event bus converges on:
   (see ``online/trainer.py``).
 * **Watermarks.**  ``EventLogReader.watermark()`` is the publish time of the
   newest fully-consumed segment: every event at or before it has been read.
-  The freshness benchmark (benchmarks/online_freshness.py) measures
-  event→served lag against exactly this quantity.
+  Event→served lag is measured against exactly this quantity.
 
 Both tails share one reader; only listing/opening differ:
 ``DirectoryTail`` stats the filesystem, ``PrefixTail`` lists an

@@ -26,13 +26,12 @@ from ..core.config import packed_sort_id_bound
 _INT32_MAX_ROWS = 2**31 - 1
 
 
-def narrow_ids(ids, vocab_size: int, enabled: bool = True):
+def narrow_ids(ids, vocab_size: int):
     """Cast int64 ids to int32 when every row of a ``vocab_size``-row table
     is addressable in 32 bits.  Works on host numpy arrays (cast before the
     device transfer — halves the id bytes moved) and on traced/device
-    arrays (a cheap elementwise op XLA fuses away).  No-op for int32 input,
-    an int32-unsafe vocabulary, or ``enabled=False``
-    (``ModelConfig.narrow_ids``, the ablation switch).
+    arrays (a cheap elementwise op XLA fuses away).  No-op for int32 input
+    or an int32-unsafe vocabulary.
 
     The dense path does NOT validate ids before this cast (train/step.py
     feeds raw batch ids straight in), so a stray id >= 2**31 would WRAP
@@ -41,7 +40,7 @@ def narrow_ids(ids, vocab_size: int, enabled: bool = True):
     exactly the row the downstream clip-mode gather (``dense_lookup``)
     would have produced for the original int64 value, so the cast stays a
     pure representation change for every input."""
-    if enabled and ids.dtype == np.int64 and vocab_size <= _INT32_MAX_ROWS:
+    if ids.dtype == np.int64 and vocab_size <= _INT32_MAX_ROWS:
         return ids.clip(0, vocab_size - 1).astype(np.int32)
     return ids
 

@@ -82,9 +82,9 @@ def resolve_shard_exchange(cfg, backend: str | None = None) -> str:
     over a real wire — a row-sharded table (model_parallel > 1) or the lazy
     path's data-axis grad gather (data_parallel > 1) on an ICI-connected
     pod.  On the CPU backend (the virtual shared-memory mesh) "auto" stays
-    on psum: there the dense assembly is a ~17 GB/s memcpy while the
-    exchange's sort/index work is compute-bound — measured 0.8x at the
-    flagship shape (docs/ARCHITECTURE.md "Sharded embeddings"), the same
+    on psum: there the dense assembly is a memcpy while the exchange's
+    sort/index work is compute-bound (a CPU-backend timing, never a device
+    number: docs/ARCHITECTURE.md "Sharded embeddings"), the same
     backend-conditional resolution ``fused_kernel="auto"`` uses.  Takes the
     full :class:`~..core.config.Config` (the mesh section must carry the
     RESOLVED axis sizes, as ``make_context`` writes them); ``backend``

@@ -496,8 +496,7 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
         tables = {k: params[k] for k in keys}          # local row shards
         from ..ops.embedding import narrow_ids
 
-        ids2d = narrow_ids(batch["feat_ids"], cfg.model.feature_size,
-                           cfg.model.narrow_ids)
+        ids2d = narrow_ids(batch["feat_ids"], cfg.model.feature_size)
         ids2d = ids2d.reshape(-1, cfg.model.field_size)
         # Invalid-id remap (see the sentinel comment below) happens BEFORE
         # the forward lookup so the grad-dedup and the exchange plan sort
@@ -800,7 +799,7 @@ def _narrow_id_fields(ctx: SPMDContext, batch: dict) -> dict:
     vocab = max(m.feature_size, getattr(m, "user_vocab_size", 0) or 0,
                 getattr(m, "item_vocab_size", 0) or 0)
     return {
-        k: narrow_ids(v, vocab, m.narrow_ids) if k.endswith("_ids") else v
+        k: narrow_ids(v, vocab) if k.endswith("_ids") else v
         for k, v in batch.items()
     }
 

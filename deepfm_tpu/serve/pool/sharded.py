@@ -204,7 +204,7 @@ def build_sharded_predict_with(ctx: ServeGroupContext) -> Callable:
         # clip-mode id semantics (dense_lookup parity) + int64->int32
         # narrowing while still replicated — before rows shard out
         ids = jnp.clip(feat_ids, 0, true_vocab - 1)
-        ids = narrow_ids(ids, true_vocab, cfg.model.narrow_ids)
+        ids = narrow_ids(ids, true_vocab)
         return mapped(payload, ids, feat_vals)
 
     return predict_with

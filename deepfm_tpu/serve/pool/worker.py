@@ -357,8 +357,7 @@ class GroupMember:
             # the funnel scorer brackets its warm-up so compile time never
             # lands in the serving metrics.  Tenant 0's precompile builds
             # the shared bucket executables; every further tenant's is a
-            # jit cache hit (same specs, payload as argument) — the
-            # near-zero marginal cost BENCH_MULTITENANT measures
+            # jit cache hit (same specs, payload as argument)
             if self.funnel:
                 self.compile_secs = self._scorer.precompile()
             else:

@@ -112,8 +112,8 @@ class Scorer:
 
     This is the pre-batcher single-lock engine: every request serializes
     behind one lock and chunks through ONE fixed padded shape.  Kept as
-    the baseline the micro-batching engine is benchmarked against
-    (benchmarks/serving.py) — production serving goes through
+    the baseline the micro-batching engine is compared against —
+    production serving goes through
     :class:`deepfm_tpu.serve.batcher.MicroBatcher`."""
 
     def __init__(self, predict: Callable, field_size: int, batch_size: int = 256):
@@ -329,8 +329,7 @@ class ScoringHTTPServer(ThreadingHTTPServer):
 
     The stdlib default (request_queue_size=5) drops SYNs under a modest
     connection burst — 16 simultaneous clients saw ~1s TCP-retransmit
-    stalls (p95 1033 ms on an idle host, docs/BENCH_SERVING.json) before
-    this override.  ``reuse_port`` lets N worker processes share one port
+    stalls before this override.  ``reuse_port`` lets N worker processes share one port
     (the kernel load-balances accepted connections across listeners) —
     the TF-Serving-style multi-worker front, see :func:`serve_pool`."""
 
@@ -621,7 +620,7 @@ def make_handler(scorer, model_name: str, reload_status=None,
         def _predict_binary(self):
             # the gRPC-role analog, dependency-free: JSON encode/decode of
             # ~80k numbers dominates the HTTP layer at large client batches
-            # (53 ms http vs 11.5 ms scorer at batch 1024, BENCH_SERVING).
+            # (not measured on the chip).
             # Wire format (all little-endian):
             #   request:  u32 n, u32 f, n*f int64 feat_ids, n*f f32 feat_vals
             #   response: n f32 probabilities (Content-Type octet-stream)

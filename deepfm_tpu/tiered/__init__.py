@@ -1,9 +1,7 @@
 """Tiered giant-vocab embedding store: HBM hot cache ← host ← object store.
 
 Production CTR vocabularies are 10⁸–10⁹ rows; a fully-resident table (and
-its two Adam moments) cannot live in device memory, and
-``docs/BENCH_LARGE_VOCAB.json`` shows the resident design already straining
-at 10M rows.  This package pages embedding rows through three tiers:
+its two Adam moments) cannot live in device memory.  This package pages embedding rows through three tiers:
 
 * **hot** — a fixed-capacity device-resident cache of rows *plus their
   lazy-Adam moments* (the lazy step only ever touches seen rows, so rows

@@ -12,8 +12,8 @@ write back to the host tier (fixed-shape jitted gathers), dirty host rows
 flush to cold-tier page overlays, and a small metadata record (cold
 snapshot + rest-params leaves + step/rng) commits atomically — bytes
 moved scale with DIRTY rows, not table size, and peak RSS stays bounded
-by one page, attacking the measured 322 s / 2.4×-RSS resident save path
-(docs/BENCH_LARGE_VOCAB.json).  Restore is cache-COLD by design: the hot
+by one page, where the resident save path gathers the whole state (not
+measured on the chip).  Restore is cache-COLD by design: the hot
 and host tiers refill on demand, and training converges to bit-identical
 losses (tests/test_tiered.py).
 """

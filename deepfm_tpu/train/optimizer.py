@@ -255,8 +255,7 @@ def zero_sharded(
       tree (``zero_layout_size``), so every moment leaf is born
       flattened: per shard the moments are 1/dp-sized, and per step they
       are read and written once by one owner instead of dp times by
-      everybody — the dominant train-hot-path HBM traffic term
-      (bench.py roofline ``dense_state_bytes_per_step``).
+      everybody — the dominant train-hot-path HBM traffic term.
 
     Row-sharded table leaves (path under ``table_keys`` with a
     ``vocab``-row leading dim) shard their per-model-shard flatten over

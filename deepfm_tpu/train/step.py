@@ -204,8 +204,7 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
         # raw batch ids are UNVALIDATED here; narrow_ids clips to
         # [0, feature_size) before its int32 cast so an out-of-range int64
         # id cannot wrap onto an arbitrary row (see its docstring)
-        ids = narrow_ids(batch["feat_ids"], cfg.model.feature_size,
-                         cfg.model.narrow_ids)
+        ids = narrow_ids(batch["feat_ids"], cfg.model.feature_size)
         ids = ids.reshape(-1, cfg.model.field_size)
         with jax.named_scope("lookup"):
             rows = {k: dense_lookup(tables[k], ids) for k in keys}

@@ -24,15 +24,13 @@ fi
 # and restore virtual-mesh devices mid-run ([2,4]→[1,4]→[2,4]) and hold the
 # run to the ISSUE-9 acceptance bar: loss-curve continuity vs an
 # uninterrupted baseline, exactly-once cursor lineage, 0 failed /
-# 0 mixed-version predicts at the serving pool (tests/test_elastic_chaos.py;
-# same code path emits docs/BENCH_ELASTIC.json via `python bench.py
-# --elastic`); (2) the MULTI-HOST drill (tests/test_elastic_multihost.py):
+# 0 mixed-version predicts at the serving pool (tests/test_elastic_chaos.py
+# drives tests/drills/elastic_drill.py); (2) the MULTI-HOST drill
+# (tests/test_elastic_multihost.py, tests/drills/elastic_multihost.py):
 # the same mesh cycle under lease-fenced epoch consensus with the MPMD
 # trainer/publisher split across real processes, a FaultPlan-scripted
 # coordinator outage (frozen-topology training), and a stale-token writer
-# refused on both the commit and publish path (emits
-# docs/BENCH_ELASTIC_MULTIHOST.json via `python bench.py
-# --elastic-multihost`); (3) the OVERLOAD drill
+# refused on both the commit and publish path; (3) the OVERLOAD drill
 # (tests/test_control_chaos.py): a FaultPlan latency window stalls one
 # shard-group mid-load — hedges must engage, the stalled group must NOT
 # be ejected, the hedge rate must decay to zero after the heal, and zero
@@ -42,8 +40,8 @@ fi
 # from the home root — one region killed mid-load must fail over with 0
 # admitted-then-failed requests and an in-SLO tail, and the restored
 # region must stay OUT while its store is stale beyond the version-skew
-# SLO, re-admitting only after the replicator catches it up (emits
-# docs/BENCH_MULTIREGION.json via `python bench.py --multiregion`).
+# SLO, re-admitting only after the replicator catches it up
+# (tests/drills/multiregion.py).
 # Off by default: each drill trains two full runs and serves under load
 # (~minutes), which does not belong in the per-commit static gate.
 if [[ "${CHECK_SLOW:-0}" == "1" || "${1:-}" == "--slow" || "${2:-}" == "--slow" ]]; then

@@ -19,7 +19,7 @@ plus a streaming-AUC vs exact-AUC (Mann-Whitney) cross-check per eval.
 
 Writes docs/convergence_results.json and docs/CONVERGENCE.md.
 
-    python benchmarks/convergence.py [--epochs 60] [--out docs]
+    python scripts/convergence.py [--epochs 60] [--out docs]
 """
 
 from __future__ import annotations
@@ -32,12 +32,10 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from deepfm_tpu.core.platform import configure_runtime  # noqa: E402
-
-configure_runtime()
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
+
+from deepfm_tpu.core.platform import configure_runtime  # noqa: E402
 
 VAL_TFRECORDS = "/root/reference/data/val.tfrecords"
 HOLDOUT_MOD = 5  # record i is eval iff i % 5 == 0 (deterministic 80/20)
@@ -414,6 +412,20 @@ def run_matched_steps(
     return curve, round(time.time() - t0, 1)
 
 
+def rescale_schedule(opt: dict, steps: int) -> dict:
+    """Re-derive warmup/decay for a new training horizon, keeping the
+    schedule SHAPE a sweep picked (same ~5% warmup fraction, decay to the
+    end of training).  No-op for constant-lr dicts."""
+    if opt.get("lr_schedule", "constant") == "constant":
+        return opt
+    out = dict(opt)
+    out["decay_steps"] = steps
+    # clamp below the horizon: for tiny horizons (steps <= 100)
+    # warmup==decay would make build_lr_schedule raise
+    out["warmup_steps"] = min(max(100, steps // 20), max(steps - 1, 0))
+    return out
+
+
 def run_synthetic(args) -> None:
     """VERDICT r02 #2: convergence evidence that can't be dismissed as
     overfit noise — >=5M Criteo-shaped records with planted teacher-FM
@@ -442,9 +454,7 @@ def run_synthetic(args) -> None:
         # the sweep sized warmup/decay to ITS horizon; rescale to this
         # run's matched step count or the cosine would end a fifth of the
         # way through training (the sweep runs 1M records, this runs 5M)
-        import _bench_util as bu
-
-        tuned = bu.rescale_schedule(
+        tuned = rescale_schedule(
             tuned, (len(train_ds) // args.batch_size) * study_epochs
         )
         meta["tuned_optimizer"] = tuned
@@ -625,6 +635,7 @@ def run_opt_sweep(args) -> None:
 
 
 def main() -> None:
+    configure_runtime()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", choices=("bundled", "synthetic", "sweep"),
                     default="bundled")
@@ -748,7 +759,7 @@ def write_md(out_dir: str) -> None:
             f"## 1. {n_label}-record synthetic study (matched steps, "
             "multi-seed)",
             "",
-            f"`python benchmarks/convergence.py --dataset synthetic` — "
+            f"`python scripts/convergence.py --dataset synthetic` — "
             f"{meta['dataset']}: Criteo-shaped fields (13 numeric + 26 "
             f"categorical, per-field Zipf marginals, field vocabularies "
             f"{meta['field_vocab_min']}-{meta['field_vocab_max']}), labels "
@@ -857,7 +868,7 @@ def write_md(out_dir: str) -> None:
         lines += [
             "## 2. Bundled real-data study (8k train / 2k holdout)",
             "",
-            "`python benchmarks/convergence.py` — flagship config "
+            "`python scripts/convergence.py` — flagship config "
             "(reference notebook cell 4: V=117,581, F=39, K=32, deep "
             "128/64/32, dropout keep 0.5, Adam 5e-4, l2 1e-4) on a "
             "deterministic 80/20 split of the bundled real "
@@ -894,87 +905,6 @@ def write_md(out_dir: str) -> None:
             "",
         ]
 
-    dev_path = os.path.join(out_dir, "BENCH_CONVERGENCE_DEVICE.json")
-    if os.path.exists(dev_path):
-        with open(dev_path) as f:
-            dev = json.load(f)
-        # report the BEST committed run (TPU preferred, then final AUC):
-        # `latest` is merely the most recent, and optimizer-variant probes
-        # legitimately land below the best flat run
-        candidates = [r for r in dev.get("runs", []) + [dev.get("latest")]
-                      if r and r.get("epochs")]
-        latest = max(
-            candidates,
-            key=lambda r: (r.get("platform") == "tpu",
-                           len(r["epochs"]) > 1,  # multi-epoch > probes
-                           r["epochs"][-1]["eval_auc"]),
-            default=dev.get("latest", dev),
-        )
-        eps = latest.get("epochs", [])
-        if eps:
-            aucs = " → ".join(f"{e['eval_auc']:.4f}" for e in eps)
-            ceiling = eps[-1]["teacher_bayes_auc"]
-            gap = eps[-1]["auc_gap_to_bayes"]
-            total = sum(e["records"] for e in eps)
-            opt = latest.get("optimizer", {})
-            is_default = (
-                opt.get("lr_schedule", "constant") == "constant"
-                and opt.get("embedding_lr_multiplier", 1.0) == 1.0
-                and opt.get("warmup_steps", 0) == 0
-                and opt.get("learning_rate", 0.0005) == 0.0005
-            )
-            opt_note = (
-                " (flat Adam 5e-4)" if is_default
-                else f"; optimizer `{json.dumps(opt)}`"
-            )
-            # one comparison line per distinct (variant, optimizer) final
-            finals = {}
-            for r in candidates:
-                o = r.get("optimizer", {})
-                tag = r.get("variant", "?")
-                if o.get("embedding_lr_multiplier", 1.0) != 1.0 \
-                        or o.get("lr_schedule", "constant") != "constant" \
-                        or o.get("learning_rate", 0.0005) != 0.0005:
-                    tag += "+tuned" if "lr_schedule" in o else "+opt"
-                key = (tag, len(r["epochs"]))
-                finals[key] = max(finals.get(key, 0.0),
-                                  r["epochs"][-1]["eval_auc"])
-            cmp_note = "; ".join(
-                f"{t} ({n} ep): {v:.4f}" for (t, n), v in sorted(finals.items())
-            )
-            lines += [
-                "## 3. On-device study at Criteo-Kaggle scale",
-                "",
-                "`python benchmarks/convergence_device.py` — the SAME "
-                "planted-teacher generative process as §1, re-expressed as "
-                "pure JAX so every batch is synthesized **on-chip inside a "
-                "`lax.scan` epoch**: zero per-step host dispatch, which "
-                "unlocks BASELINE config #2's scale (45M records/epoch) on "
-                "one chip regardless of host/feed speed.  The device "
-                "teacher's Bayes AUC matches §1's host teacher, tying both "
-                "studies to the same ceiling (Zipf tail by inverse-CDF "
-                "approximation, bias re-calibrated against the device "
-                "sampler; the artifact records it).",
-                "",
-                f"Best committed run (`docs/BENCH_CONVERGENCE_DEVICE.json`"
-                f", platform **{latest.get('platform')}**): "
-                f"{total / 1e6:.0f}M total records, batch "
-                f"{latest.get('batch')}, eval AUC {aucs} against the "
-                f"{ceiling:.5f} Bayes ceiling — final gap {gap:.4f}"
-                f"{opt_note}.  Optimizer-variant runs in the artifact: "
-                f"{cmp_note}.  NOTE the batch-1024 tuned configuration of "
-                "§1 does NOT transfer to this study's batch 8192: "
-                "dense+tuned trails a SAME-SEED flat epoch by ~0.012 AUC "
-                "(outside seed noise — 4x table lr hurts at 8x the batch), "
-                "while lazy+tuned lands within seed noise of flat (the "
-                "best flat run predates a round-3 init-seed change, so its "
-                "+0.0015 final margin over lazy+tuned is not significant). "
-                "An honest mixed result the artifact preserves.  "
-                "Earlier runs (2M-scale ramp, a "
-                "3-seed matched set with early-training spread 0.0097 — "
-                "the seed yardstick at that scale; §1's converged "
-                "yardstick is 0.0007) live in the `runs` history.",
-            ]
     with open(os.path.join(out_dir, "CONVERGENCE.md"), "w") as f:
         f.write("\n".join(lines) + "\n")
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # Mechanical style/correctness gate: ruff over deepfm_tpu/ + tests/ +
-# benchmarks/ (config: ruff.toml at the repo root).
+# scripts/ (config: ruff.toml at the repo root).
 # Usage: scripts/lint.sh [--fix]
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -12,4 +12,4 @@ if ! command -v ruff >/dev/null 2>&1; then
     exit 0
 fi
 
-exec ruff check "$@" deepfm_tpu tests benchmarks
+exec ruff check "$@" deepfm_tpu tests scripts

@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
 # One-command gate: static analysis (scripts/check.sh — ruff when present
 # + the JAX-aware analyzer ratcheted against analysis_baseline.json) + the
-# tier-1 test suite (ROADMAP.md's verify command, minus the log plumbing).
+# tier-1 test suite, serially.  The driver's form — six xdist workers,
+# `--dist loadfile`, ~5 min — is the command in /root/TESTS_LAST_RUN.json.
 # Usage: scripts/test.sh [extra pytest args]
 set -euo pipefail
 cd "$(dirname "$0")/.."

@@ -1,8 +1,8 @@
-"""Shared serving-pool helpers for the drill benchmarks.
+"""Shared serving-pool helpers for the acceptance drills.
 
-The elastic drills (``elastic_drill.py``, ``elastic_multihost.py``) and the
-fleet drill (``multitenant.py``) all need the same two things and used to
-copy them:
+The elastic drills (``elastic_drill.py``, ``elastic_multihost.py``), the
+region drill (``multiregion.py``) and the flywheel drill (``flywheel.py``)
+need the same two things:
 
 * a **process-isolated pool**: the serving pool spawned as its OWN process
   tree (`python -m deepfm_tpu.serve.pool`) — the real topology, and the
@@ -12,8 +12,7 @@ copy them:
 * **closed-loop HTTP clients** with the shared percentile math and
   keep-alive connection plumbing.
 
-One copy each, here.  Import alongside ``_bench_util`` (the benchmarks
-directory rides ``sys.path`` in every drill's bootstrap).
+One copy each, here.
 """
 
 from __future__ import annotations
@@ -45,7 +44,7 @@ def post_json(url: str, payload: dict, timeout: float = 60) -> dict:
 
 
 def connect(port: int):
-    """Keep-alive HTTP connection with Nagle off (latency benches)."""
+    """Keep-alive HTTP connection with Nagle off."""
     import http.client
 
     conn = http.client.HTTPConnection("127.0.0.1", port)
@@ -216,60 +215,11 @@ def closed_loop(port: int, body_fn, *, n_clients: int, per_client: int,
     for t in threads:
         t.start()
     start.wait()
-    t0 = time.perf_counter()
     for t in threads:
         t.join()
-    dt = time.perf_counter() - t0
     row = {"clients": n_clients, "requests": len(lat),
-           "requests_per_sec": round(len(lat) / dt, 1),
            **percentiles_ms(lat)}
     if errors:
         row["errors"] = errors[:3]
         row["error_count"] = len(errors)
     return row
-
-
-def timed_window(port: int, body_fn, *, n_clients: int, secs: float,
-                 headers=None,
-                 path: str = "/v1/models/deepfm:predict") -> float:
-    """Stop-driven window; returns requests/sec (the paired-window unit)."""
-    import numpy as np
-
-    done = 0
-    lock = threading.Lock()
-    stop = threading.Event()
-    start = threading.Barrier(n_clients + 1)
-
-    def client(seed: int):
-        nonlocal done
-        rng = np.random.default_rng(seed)
-        conn = connect(port)
-        mine = 0
-        try:
-            start.wait()
-            while not stop.is_set():
-                conn.request("POST", path, json.dumps(body_fn(rng)),
-                             {"Content-Type": "application/json",
-                              **(headers or {})})
-                r = conn.getresponse()
-                r.read()
-                if r.status == 200:
-                    mine += 1
-        except Exception:  # pragma: no cover - window edge
-            pass
-        finally:
-            conn.close()
-            with lock:
-                done += mine
-
-    threads = [threading.Thread(target=client, args=(3000 + i,))
-               for i in range(n_clients)]
-    for t in threads:
-        t.start()
-    start.wait()
-    t0 = time.perf_counter()
-    time.sleep(secs)
-    stop.set()
-    for t in threads:
-        t.join()
-    return done / (time.perf_counter() - t0)

@@ -1,12 +1,11 @@
 """Elastic chaos drill: shrink the training mesh [2,4]→[1,4] mid-run and
 grow it back, while the serving pool consumes the publishes under client
-load — the acceptance drill for the elastic subsystem (deepfm_tpu/elastic)
-and the source of ``docs/BENCH_ELASTIC.json``.
+load — the acceptance drill for the elastic subsystem (deepfm_tpu/elastic).
+A test helper: its value is the pass/fail tests/test_elastic_chaos.py reads
+off the document ``run_drill`` returns, not a time.
 
-What it measures and asserts:
+What it checks:
 
-* **reshard wall-time** — detect→drain→commit→replan→restore→recompile,
-  per topology change;
 * **steps lost** — optimizer steps replayed from the last commit (zero
   with drain+commit; the commit-cadence tail without it);
 * **exactly-once** — the cursor lineage is strictly increasing and covers
@@ -19,25 +18,20 @@ What it measures and asserts:
   clients across the shrink: 0 failed predicts, 0 mixed-version scores
   (every response's (generation, version) pair is a committed state).
 
-Run directly (``python benchmarks/elastic_drill.py``) or via
-``python bench.py --elastic``; the slow-marked chaos test
-(tests/test_elastic_chaos.py) drives ``run_drill`` with assertions and
-scripts/check.sh wires it as the elastic gate.
+The slow-marked chaos test (tests/test_elastic_chaos.py) drives
+``run_drill`` with assertions and ``scripts/check.sh --slow`` wires it as
+the elastic gate.
 """
 
 from __future__ import annotations
 
-import json
 import os
-import sys
 import threading
 import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-import _pool_util as pu
+from . import _pool_util as pu
 
 FEATURE, FIELD = 64, 5
 LOSS_TOLERANCE = 5e-3
@@ -133,6 +127,8 @@ def run_drill(
     """One full drill; returns the metrics document (see module doc)."""
     import jax
 
+    from deepfm_tpu.elastic import ElasticTrainer, VirtualDeviceRegistry
+    from deepfm_tpu.online import list_versions
     from deepfm_tpu.serve import export_servable
     from deepfm_tpu.train.step import create_train_state
 
@@ -201,158 +197,95 @@ def run_drill(
             pool.stop(clients=clients, stop_clients=stop_clients)
 
     try:
-        return _run_and_measure(
-            cfg, root, devs, serving, results, errors, _stop_pool,
-            segments=segments, rows=rows, batch=batch,
-            shrink_at=shrink_at, grow_at=grow_at,
-            drain_commit=drain_commit, serve=serve,
-            total_steps=total_steps,
+        # -- the elastic run: shrink [2,4] -> [1,4] mid-stream, grow back --
+        reg = VirtualDeviceRegistry(devs[:8])
+        trainer = ElasticTrainer(cfg, registry=reg)
+        recorder = _LossRecorder(script={
+            shrink_at: lambda: reg.fail(4, 5, 6, 7),
+            grow_at: lambda: reg.restore(4, 5, 6, 7),
+        })
+        trainer._log = recorder
+        state = trainer.run(follow=False)
+
+        if serve:
+            # let the swapper ingest the final (post-grow) publish UNDER LOAD,
+            # then stop: the post-shrink versions going live without a single
+            # failed or mixed-version predict is the drill's serving claim
+            want = max(list_versions(cfg.run.servable_model_dir), default=0)
+            deadline = time.time() + 60
+            while time.time() < deadline:
+                if any(v >= want for _, v in set(results)):
+                    break
+                time.sleep(0.3)
+            _stop_pool()
+            seen = sorted(set(results))
+            mixed = pu.mixed_version_pairs(seen)
+            serving.update({
+                "predicts": len(results),
+                "failed": len(errors),
+                "errors_sample": errors[:3],
+                "mixed_version": len(mixed),
+                "mixed_pairs": mixed,
+                "observed_pairs": seen,
+                "final_version": max((v for _, v in seen), default=0),
+                "versions_ingested": len({v for _, v in seen}),
+            })
+
+        # -- the uninterrupted fixed-mesh baseline --------------------------
+        oroot = os.path.join(root, "baseline")
+        ocfg = _cfg(oroot, batch=batch, drain_commit=drain_commit)
+        _fill_stream(ocfg.data.training_data_dir, segments=segments, rows=rows)
+        oracle_trainer = ElasticTrainer(
+            ocfg, registry=VirtualDeviceRegistry(devs[:8])
         )
+        oracle_rec = _LossRecorder()
+        oracle_trainer._log = oracle_rec
+        oracle = oracle_trainer.run(follow=False)
+
+        common = sorted(set(recorder.losses) & set(oracle_rec.losses))
+        loss_diffs = [abs(recorder.losses[s] - oracle_rec.losses[s])
+                      for s in common]
+        param_diff = 0.0
+        for a, b in zip(
+            jax.tree_util.tree_leaves(state.params),
+            jax.tree_util.tree_leaves(oracle.params),
+        ):
+            param_diff = max(param_diff, float(np.max(np.abs(
+                np.asarray(jax.device_get(a)) - np.asarray(jax.device_get(b))
+            ))))
+
+        lineage = trainer.cursor_lineage
+        return {
+            "drill": {
+                "shrink": [[2, 4], [1, 4]],
+                "grow_back": True,
+                "segments": segments,
+                "rows_per_segment": rows,
+                "batch_size": batch,
+                "total_steps": total_steps,
+                "drain_commit": drain_commit,
+            },
+            "reshards": trainer.reshards,
+            "steps_lost": sum(r["steps_replayed"] for r in trainer.reshards),
+            "exactly_once": {
+                "batches_applied": len(lineage),
+                "expected": total_steps,
+                "lineage_strictly_increasing": all(
+                    a < b for a, b in zip(lineage, lineage[1:])
+                ),
+            },
+            "loss_continuity": {
+                "steps_compared": len(common),
+                "max_abs_diff": round(max(loss_diffs), 6) if loss_diffs else None,
+                "final_param_max_abs_diff": round(param_diff, 8),
+                "tolerance": LOSS_TOLERANCE,
+                "pass": bool(loss_diffs) and max(loss_diffs) < LOSS_TOLERANCE,
+            },
+            "serving": serving,
+            "versions_published": len(
+                list_versions(cfg.run.servable_model_dir)
+            ),
+            "final_step": int(state.step),
+        }
     finally:
         _stop_pool()
-
-
-def _run_and_measure(
-    cfg, root, devs, serving, results, errors, stop_pool, *,
-    segments, rows, batch, shrink_at, grow_at, drain_commit, serve,
-    total_steps,
-) -> dict:
-    import jax
-
-    from deepfm_tpu.elastic import ElasticTrainer, VirtualDeviceRegistry
-    from deepfm_tpu.online import list_versions
-
-    # -- the elastic run: shrink [2,4] -> [1,4] mid-stream, grow back ------
-    reg = VirtualDeviceRegistry(devs[:8])
-    trainer = ElasticTrainer(cfg, registry=reg)
-    recorder = _LossRecorder(script={
-        shrink_at: lambda: reg.fail(4, 5, 6, 7),
-        grow_at: lambda: reg.restore(4, 5, 6, 7),
-    })
-    trainer._log = recorder
-    t0 = time.perf_counter()
-    state = trainer.run(follow=False)
-    train_wall = time.perf_counter() - t0
-
-    if serve:
-        # let the swapper ingest the final (post-grow) publish UNDER LOAD,
-        # then stop: the post-shrink versions going live without a single
-        # failed or mixed-version predict is the drill's serving claim
-        want = max(list_versions(cfg.run.servable_model_dir), default=0)
-        deadline = time.time() + 60
-        while time.time() < deadline:
-            with_lock = sorted(set(results))
-            if any(v >= want for _, v in with_lock):
-                break
-            time.sleep(0.3)
-        stop_pool()
-        seen = sorted(set(results))
-        mixed = pu.mixed_version_pairs(seen)
-        serving.update({
-            "predicts": len(results),
-            "failed": len(errors),
-            "errors_sample": errors[:3],
-            "mixed_version": len(mixed),
-            "mixed_pairs": mixed,
-            "observed_pairs": seen,
-            "final_version": max((v for _, v in seen), default=0),
-            "versions_ingested": len({v for _, v in seen}),
-        })
-
-    # -- the uninterrupted fixed-mesh baseline ------------------------------
-    oroot = os.path.join(root, "baseline")
-    ocfg = _cfg(oroot, batch=batch, drain_commit=drain_commit)
-    _fill_stream(ocfg.data.training_data_dir, segments=segments, rows=rows)
-    oracle_trainer = ElasticTrainer(
-        ocfg, registry=VirtualDeviceRegistry(devs[:8])
-    )
-    oracle_rec = _LossRecorder()
-    oracle_trainer._log = oracle_rec
-    oracle = oracle_trainer.run(follow=False)
-
-    common = sorted(set(recorder.losses) & set(oracle_rec.losses))
-    loss_diffs = [abs(recorder.losses[s] - oracle_rec.losses[s])
-                  for s in common]
-    param_diff = 0.0
-    for a, b in zip(
-        jax.tree_util.tree_leaves(state.params),
-        jax.tree_util.tree_leaves(oracle.params),
-    ):
-        param_diff = max(param_diff, float(np.max(np.abs(
-            np.asarray(jax.device_get(a)) - np.asarray(jax.device_get(b))
-        ))))
-
-    lineage = trainer.cursor_lineage
-    doc = {
-        "drill": {
-            "shrink": [[2, 4], [1, 4]],
-            "grow_back": True,
-            "segments": segments,
-            "rows_per_segment": rows,
-            "batch_size": batch,
-            "total_steps": total_steps,
-            "drain_commit": drain_commit,
-            "train_wall_secs": round(train_wall, 3),
-        },
-        "reshards": trainer.reshards,
-        "reshard_wall_secs": [r["wall_secs"] for r in trainer.reshards],
-        "steps_lost": sum(r["steps_replayed"] for r in trainer.reshards),
-        "exactly_once": {
-            "batches_applied": len(lineage),
-            "expected": total_steps,
-            "lineage_strictly_increasing": all(
-                a < b for a, b in zip(lineage, lineage[1:])
-            ),
-        },
-        "loss_continuity": {
-            "steps_compared": len(common),
-            "max_abs_diff": round(max(loss_diffs), 6) if loss_diffs else None,
-            "final_param_max_abs_diff": round(param_diff, 8),
-            "tolerance": LOSS_TOLERANCE,
-            "pass": bool(loss_diffs) and max(loss_diffs) < LOSS_TOLERANCE,
-        },
-        "serving": serving,
-        "versions_published": len(
-            list_versions(cfg.run.servable_model_dir)
-        ),
-        "final_step": int(state.step),
-    }
-    return doc
-
-
-def main() -> None:
-    import tempfile
-
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out_path = os.path.join(repo_root, "docs", "BENCH_ELASTIC.json")
-    with tempfile.TemporaryDirectory(prefix="elastic_drill_") as root:
-        doc = run_drill(root)
-    doc["recorded_unix_time"] = int(time.time())
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    with open(out_path, "w") as f:
-        json.dump(doc, f, indent=1)
-    print(json.dumps({
-        "metric": "elastic_reshard_wall_secs",
-        "value": (max(doc["reshard_wall_secs"])
-                  if doc["reshard_wall_secs"] else None),
-        "steps_lost": doc["steps_lost"],
-        "serving_failed": doc["serving"].get("failed"),
-        "serving_mixed_version": doc["serving"].get("mixed_version"),
-        "loss_continuity_pass": doc["loss_continuity"]["pass"],
-        "artifact": out_path,
-    }))
-    if doc["serving"].get("failed") or doc["serving"].get("mixed_version") \
-            or not doc["loss_continuity"]["pass"]:
-        sys.exit(1)
-
-
-if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    main()

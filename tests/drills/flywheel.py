@@ -17,15 +17,13 @@ The ISSUE-17 acceptance loop, run for real on one host:
    beat the static servable's AUC on a fresh labeled population.
 
 Pass bar: 0 failed predicts, bit-exact join across the crash, and
-``auc.self_trained > auc.static``.  Persists the ``flywheel`` section of
-docs/BENCH_ONLINE.json ({latest, runs, flywheel}).
-
-Run:  JAX_PLATFORMS=cpu python benchmarks/flywheel.py --persist
+``auc.self_trained > auc.static``.  A test helper: the slow-marked case of
+tests/test_flywheel.py asserts on the document ``run_flywheel_drill``
+returns.
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import sys
@@ -34,11 +32,7 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-import _bench_util as bu
-import _pool_util as pu
+from . import _pool_util as pu
 
 V, F = 200, 5
 
@@ -286,7 +280,7 @@ def run_flywheel_drill(*, n_requests: int = 240, rows: int = 2,
     auc_self = _auc_of(self_dir, eval_ids, eval_vals, eval_labels)
 
     return {
-        "bench": "flywheel",
+        "drill": "flywheel",
         "config": {
             "n_requests": n_requests, "rows": rows, "n_eval": n_eval,
             "crash_at_segment": crash_at, "seed": seed,
@@ -309,44 +303,3 @@ def run_flywheel_drill(*, n_requests: int = 240, rows: int = 2,
         "ok": bool(failed == 0 and exactly_once
                    and auc_self > auc_static),
     }
-
-
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--requests", type=int, default=240)
-    ap.add_argument("--rows", type=int, default=2,
-                    help="instances per request")
-    ap.add_argument("--eval", type=int, default=2000)
-    ap.add_argument("--crash-at", type=int, default=2,
-                    help="output segment publish that raises the "
-                         "injected join crash")
-    ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--persist", action="store_true")
-    args = ap.parse_args()
-
-    from deepfm_tpu.core.platform import configure_runtime
-
-    configure_runtime()
-    platform, device = bu.backend_platform()
-    out = run_flywheel_drill(
-        n_requests=args.requests, rows=args.rows, n_eval=args.eval,
-        crash_at=args.crash_at, seed=args.seed)
-    out["platform"], out["device"] = platform, device
-    print(json.dumps(out, indent=2))
-    if args.persist:
-        path = os.path.normpath(os.path.join(
-            os.path.dirname(os.path.abspath(__file__)),
-            "..", "docs", "BENCH_ONLINE.json"))
-        doc = {}
-        if os.path.exists(path):
-            with open(path) as f:
-                doc = json.load(f)
-        doc["flywheel"] = out
-        with open(path, "w") as f:
-            json.dump(doc, f, indent=1)
-            f.write("\n")
-    return 0 if out["ok"] else 1
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

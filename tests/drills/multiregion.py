@@ -24,16 +24,13 @@ rendezvous home region with staleness-gated failover.
 
 Pass bar: 0 failed requests in every phase, failover p95 <= --slo-ms,
 the stale-but-healthy window never re-admits, and post-catch-up traffic
-serves home-region on the latest version.  Persists
-docs/BENCH_MULTIREGION.json.
-
-Run:  JAX_PLATFORMS=cpu python benchmarks/multiregion.py --persist
+serves home-region on the latest version.  A test helper: the
+slow-marked tests/test_region_chaos.py asserts on the document
+``run_multiregion_drill`` returns (``scripts/check.sh --slow``).
 """
 
 from __future__ import annotations
 
-import argparse
-import json
 import os
 import sys
 import tempfile
@@ -42,11 +39,7 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-import _bench_util as bu
-import _pool_util as pu
+from . import _pool_util as pu
 
 V, F = 200, 5
 REGIONS = ("use1", "euw1")
@@ -161,7 +154,7 @@ def run_multiregion_drill(*, n_clients: int = 4, per_client: int = 25,
     probe = [{"feat_ids": [0] * F, "feat_vals": [0.0] * F}]
     pools = {name: boot_pool(name) for name in REGIONS}
     httpd = front = None
-    doc: dict = {"bench": "multiregion", "config": {
+    doc: dict = {"drill": "multiregion", "config": {
         "regions": list(REGIONS), "n_clients": n_clients,
         "per_client": per_client, "slo_ms": slo_ms, "seed": seed,
         "model": {"feature_size": V, "field_size": F},
@@ -301,36 +294,3 @@ def run_multiregion_drill(*, n_clients: int = 4, per_client: int = 25,
         and home_recovered)
     doc["admitted_then_failed"] = failed
     return doc
-
-
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--clients", type=int, default=4)
-    ap.add_argument("--per-client", type=int, default=25)
-    ap.add_argument("--slo-ms", type=float, default=1500.0,
-                    help="post-failover tail-latency bar")
-    ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--persist", action="store_true")
-    args = ap.parse_args()
-
-    from deepfm_tpu.core.platform import configure_runtime
-
-    configure_runtime()
-    platform, device = bu.backend_platform()
-    out = run_multiregion_drill(
-        n_clients=args.clients, per_client=args.per_client,
-        slo_ms=args.slo_ms, seed=args.seed)
-    out["platform"], out["device"] = platform, device
-    print(json.dumps(out, indent=2))
-    if args.persist:
-        path = os.path.normpath(os.path.join(
-            os.path.dirname(os.path.abspath(__file__)),
-            "..", "docs", "BENCH_MULTIREGION.json"))
-        with open(path, "w") as f:
-            json.dump(out, f, indent=1)
-            f.write("\n")
-    return 0 if out["ok"] else 1
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

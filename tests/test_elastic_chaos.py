@@ -3,8 +3,7 @@ via CHECK_SLOW=1): shrink the training mesh [2,4]→[1,4] mid-run and grow
 it back while the serving pool consumes the publishes under client load.
 
 Asserts the ISSUE-9 acceptance criteria directly on the drill's metrics
-document (benchmarks/elastic_drill.run_drill — the same code path that
-emits docs/BENCH_ELASTIC.json):
+document (tests/drills/elastic_drill.run_drill):
 
 * loss-curve continuity vs the uninterrupted fixed-mesh baseline,
 * zero double-applied stream events (strictly-increasing cursor lineage
@@ -12,14 +11,7 @@ emits docs/BENCH_ELASTIC.json):
 * 0 failed / 0 mixed-version predicts at the serving pool throughout.
 """
 
-import os
-import sys
-
 import pytest
-
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks"))
 
 pytestmark = [pytest.mark.chaos, pytest.mark.slow]
 
@@ -29,7 +21,7 @@ def test_shrink_grow_drill_full_acceptance(tmp_path):
 
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device virtual mesh")
-    from elastic_drill import run_drill
+    from drills.elastic_drill import run_drill
 
     doc = run_drill(str(tmp_path))
 
@@ -69,7 +61,7 @@ def test_drill_without_drain_replays_the_tail(tmp_path):
 
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device virtual mesh")
-    from elastic_drill import run_drill
+    from drills.elastic_drill import run_drill
 
     # commit cadence 4: shrink after step 6 -> steps 5..6 replay; the
     # grow lands on the step-12 commit boundary -> nothing more replays

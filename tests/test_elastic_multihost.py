@@ -5,8 +5,7 @@ coordinator+trainer, a real `--task_type publish` publisher subprocess,
 and the serving pool under client load.
 
 Asserts the ISSUE-12 acceptance criteria directly on the drill's metrics
-document (benchmarks/elastic_multihost.run_drill — the same code path
-that emits docs/BENCH_ELASTIC_MULTIHOST.json):
+document (tests/drills/elastic_multihost.run_drill):
 
 * [2,4]→[1,4]→[2,4] under consensus, 0.0 loss divergence vs an
   uninterrupted replay, every event exactly-once along the surviving
@@ -18,14 +17,7 @@ that emits docs/BENCH_ELASTIC_MULTIHOST.json):
   manifest still hashes to the trainer's final state).
 """
 
-import os
-import sys
-
 import pytest
-
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks"))
 
 pytestmark = [pytest.mark.chaos, pytest.mark.slow]
 
@@ -35,7 +27,7 @@ def test_multihost_drill_full_acceptance(tmp_path):
 
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device virtual mesh")
-    from elastic_multihost import run_drill
+    from drills.elastic_multihost import run_drill
 
     doc = run_drill(str(tmp_path))
 

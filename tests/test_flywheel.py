@@ -7,12 +7,11 @@ out-of-order / late-click / window-expiry semantics, and the
 crash-resume exactly-once guarantee — the emitted output stream after a
 kill-anywhere resume is BIT-EXACT against an uninterrupted run.  The
 slow end-to-end drill (pool serves a score-dependent click population;
-feedback-train beats the static model's AUC) lives with the benchmark
-(benchmarks/flywheel.py) and is exercised by its slow-marked test here.
+feedback-train beats the static model's AUC) lives in
+tests/drills/flywheel.py and is exercised by its slow-marked test here.
 """
 
 import os
-import sys
 import threading
 
 import numpy as np
@@ -661,10 +660,7 @@ def test_flywheel_drill_full_acceptance():
     click population with the impression logger armed; the delayed-label
     join survives an injected crash bit-exactly; feedback-train beats the
     static servable's AUC with 0 failed predicts."""
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks"))
-    from flywheel import run_flywheel_drill
+    from drills.flywheel import run_flywheel_drill
 
     doc = run_flywheel_drill()
 

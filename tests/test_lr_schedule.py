@@ -288,3 +288,24 @@ def test_spmd_lazy_schedule_matches_single_controller():
             np.testing.assert_allclose(
                 np.asarray(sharded.params[k]), np.asarray(single.params[k]),
                 rtol=1e-5, atol=1e-6, err_msg=f"step {i+1} table {k}")
+
+
+def test_rescale_schedule_clamps_tiny_horizons():
+    """scripts/convergence.py re-derives a swept schedule's warmup/decay for
+    the study's horizon; what it returns must still build."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "scripts" / "convergence.py"
+    spec = importlib.util.spec_from_file_location("convergence_study", path)
+    study = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(study)
+
+    out = study.rescale_schedule(
+        {"lr_schedule": "cosine", "warmup_steps": 500, "decay_steps": 9999},
+        steps=50)
+    assert out["warmup_steps"] < out["decay_steps"] == 50
+    build_lr_schedule(OptimizerConfig(learning_rate=0.01, **out))
+    # constant schedules pass through untouched
+    const = {"lr_schedule": "constant", "learning_rate": 1.0}
+    assert study.rescale_schedule(const, steps=50) is const

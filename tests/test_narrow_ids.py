@@ -16,11 +16,10 @@ from deepfm_tpu.core.config import Config
 from deepfm_tpu.ops.embedding import narrow_ids
 
 
-def _cfg(narrow: bool = True, **model):
+def _cfg(**model):
     base = {
         "feature_size": 1000, "field_size": 39, "embedding_size": 8,
         "deep_layers": (16, 8), "dropout_keep": (1.0, 1.0),
-        "narrow_ids": narrow,
     }
     base.update(model)
     return Config.from_dict({
@@ -42,7 +41,6 @@ def test_narrow_rules():
     ids = np.arange(10, dtype=np.int64)
     assert narrow_ids(ids, 1000).dtype == np.int32
     assert narrow_ids(ids, 2**31).dtype == np.int64       # too big to cast
-    assert narrow_ids(ids, 1000, enabled=False).dtype == np.int64
     ids32 = ids.astype(np.int32)
     assert narrow_ids(ids32, 1000) is ids32               # no-op passthrough
     # values preserved
@@ -51,9 +49,8 @@ def test_narrow_rules():
 
 @pytest.mark.parametrize("model_name", ["deepfm", "xdeepfm", "dcnv2"])
 def test_forward_bit_exact_across_cast(model_name):
-    """int64-staged (narrowing in-graph), int32-staged, and narrowing-off
-    int64 must produce BIT-IDENTICAL logits: the cast is representation
-    only."""
+    """int64-staged (narrowing in-graph) and int32-staged ids must produce
+    BIT-IDENTICAL logits from one config: the cast is representation only."""
     from deepfm_tpu.models.base import get_model
 
     rng = np.random.default_rng(0)
@@ -62,16 +59,13 @@ def test_forward_bit_exact_across_cast(model_name):
     model = get_model(cfg.model)
     params, mstate = model.init(jax.random.PRNGKey(0), cfg.model)
 
-    def logits(ids, mcfg):
+    def logits(ids):
         out, _ = model.apply(params, mstate, ids, host["feat_vals"],
-                             cfg=mcfg, train=False, rng=None)
+                             cfg=cfg.model, train=False, rng=None)
         return np.asarray(out)
 
-    l64 = logits(host["feat_ids"], cfg.model)
-    l32 = logits(host["feat_ids"].astype(np.int32), cfg.model)
-    loff = logits(host["feat_ids"], _cfg(False, model_name=model_name).model)
-    np.testing.assert_array_equal(l64, l32)
-    np.testing.assert_array_equal(l64, loff)
+    np.testing.assert_array_equal(
+        logits(host["feat_ids"]), logits(host["feat_ids"].astype(np.int32)))
 
 
 def test_train_step_parity_across_cast():
@@ -125,10 +119,3 @@ def test_shard_batch_narrows_on_device():
                                   host["feat_ids"])
     stacked = shard_batch_stacked(ctx, [host, host], validate_ids=False)
     assert stacked["feat_ids"].dtype == np.int32
-
-    # narrowing disabled: the device array is STILL int32 — JAX's default
-    # x64-disabled mode demotes int64 on device_put.  narrow_ids therefore
-    # makes an invariant explicit (and keeps it true under
-    # jax_enable_x64) rather than changing what the device sees.
-    ctx_off = make_context(_cfg(False), mesh)
-    assert shard_batch(ctx_off, host)["feat_ids"].dtype == np.int32

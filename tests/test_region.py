@@ -517,6 +517,12 @@ class TestRegionFront:
             publish_fake(home_root, 3)
             publish_fake(str(tmp_path / "store_use1"), 2)
             publish_fake(str(tmp_path / "store_use1"), 3)
+            # a probe pass reads the home version first and the regions
+            # after: heal only once the front has seen v3, or a pass that
+            # read v1 can find the healed router and re-admit on skew 0
+            deadline = time.time() + 5
+            while time.time() < deadline and front._home_version < 3:
+                time.sleep(0.05)
             # the router heals — but the store is 2 behind (> SLO 1):
             # re-admission must NOT happen on health alone
             stubs["euw1"].plan.clear()

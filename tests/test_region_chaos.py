@@ -5,8 +5,7 @@ the manifest replicator tailing the home publish root, then one whole
 region killed mid-load and restored stale.
 
 Asserts the ISSUE-18 acceptance criteria directly on the drill's result
-document (benchmarks/multiregion.run_multiregion_drill — the same code
-path that emits docs/BENCH_MULTIREGION.json):
+document (tests/drills/multiregion.run_multiregion_drill):
 
 * 0 admitted-then-failed requests across every phase (steady state, the
   kill window, post-failover, post-recovery),
@@ -16,20 +15,13 @@ path that emits docs/BENCH_MULTIREGION.json):
 * post-recovery traffic is 100% home-region on the newest version.
 """
 
-import os
-import sys
-
 import pytest
-
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmarks"))
 
 pytestmark = [pytest.mark.chaos, pytest.mark.slow]
 
 
 def test_region_loss_drill_full_acceptance():
-    from multiregion import run_multiregion_drill
+    from drills.multiregion import run_multiregion_drill
 
     doc = run_multiregion_drill(n_clients=4, per_client=15)
 

@@ -315,7 +315,7 @@ def test_resolve_auto_and_validation():
     mp2 = CFG.with_overrides(mesh={"data_parallel": 2, "model_parallel": 4})
     # auto is backend-conditional: alltoall where a real wire exists,
     # psum on the shared-memory CPU mesh (dense assembly is a memcpy
-    # there; the exchange's sort work loses — measured, ARCHITECTURE.md)
+    # there; the exchange's sort work loses — ARCHITECTURE.md)
     assert resolve_shard_exchange(mp2, backend="tpu") == "alltoall"
     assert resolve_shard_exchange(mp2, backend="cpu") == "psum"
     mp1 = CFG.with_overrides(mesh={"data_parallel": 8, "model_parallel": 1})
@@ -323,7 +323,7 @@ def test_resolve_auto_and_validation():
     lazy1 = mp1.with_overrides(optimizer={"lazy_embedding_updates": True})
     assert resolve_shard_exchange(lazy1, backend="tpu") == "alltoall"
     # lazy wins on the CPU mesh too (the dedup sort is shared with the
-    # update machinery it shrinks — 1.4x measured, ARCHITECTURE.md)
+    # update machinery it shrinks — ARCHITECTURE.md)
     assert resolve_shard_exchange(lazy1, backend="cpu") == "alltoall"
     dense_cpu = CFG.with_overrides(
         mesh={"data_parallel": 2, "model_parallel": 4})
