@@ -8,9 +8,11 @@ Every model family is a pair of pure functions over explicit pytrees:
 
 ``params`` are trainable; ``model_state`` is non-trainable (e.g. batch-norm
 moving stats) — the functional replacement for the reference's TF graph
-collections.  ``lookup_fn`` abstracts embedding gathers so the same model
-runs with replicated tables (single chip) or row-sharded tables
-(``deepfm_tpu/parallel``) without modification.
+collections.  ``lookup_fn(tables, ids)`` abstracts embedding gathers so the
+same model runs with replicated tables (single chip) or row-sharded tables
+(``deepfm_tpu/parallel``) without modification: ``tables`` is one table or a
+tuple of tables read with the same ids (FM_W and FM_V come in one call), and
+the rows come back in the same structure.
 """
 
 from __future__ import annotations

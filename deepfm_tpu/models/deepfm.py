@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from ..core.config import ModelConfig
 from ..ops.batch_norm import batch_norm, bn_init
-from ..ops.embedding import dense_lookup, narrow_ids, scaled_embedding
+from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.fm import fm_first_order, fm_second_order
 from ..ops.initializers import glorot_normal, glorot_uniform
 from ..ops.pallas_ctr import fused_ctr_interaction, resolve_fused
@@ -159,20 +159,14 @@ def apply_deepfm(
                 params["fm_w"], params["fm_v"], feat_ids, feat_vals
             )
     else:
-        # first order (ps:206-209)
+        # one lookup for the two tables the ids index: [B, F], [B, F, K]
         with jax.named_scope("lookup"):
-            feat_w = lookup_fn(params["fm_w"], feat_ids)        # [B, F]
+            feat_w, rows_v = lookup_fn(
+                (params["fm_w"], params["fm_v"]), feat_ids)
+            emb = rows_v * feat_vals[..., None]     # e = V[ids] * vals
         with jax.named_scope("fm"):
-            y_w = fm_first_order(feat_w, feat_vals)
-
-        # second order (ps:211-217): e = V[ids] * vals
-        with jax.named_scope("lookup"):
-            if lookup_fn is dense_lookup:
-                emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
-            else:
-                emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
-        with jax.named_scope("fm"):
-            y_v = fm_second_order(emb)
+            y_w = fm_first_order(feat_w, feat_vals)     # ps:206-209
+            y_v = fm_second_order(emb)                  # ps:211-217
 
     # deep tower (ps:228-255)
     deep_in = emb.reshape(emb.shape[0], cfg.field_size * cfg.embedding_size)

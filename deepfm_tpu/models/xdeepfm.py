@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..core.config import ModelConfig
 from ..ops.batch_norm import bn_init
-from ..ops.embedding import dense_lookup, narrow_ids, scaled_embedding
+from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.fm import fm_first_order
 from ..ops.initializers import glorot_normal, glorot_uniform
 from .base import register_model
@@ -128,16 +128,12 @@ def apply_xdeepfm(
                           cfg.feature_size)
     feat_vals = feat_vals.reshape(-1, cfg.field_size).astype(jnp.float32)
 
+    # one lookup for the two tables the ids index: [B, F], [B, F, K]
     with jax.named_scope("lookup"):
-        feat_w = lookup_fn(params["fm_w"], feat_ids)
+        feat_w, rows_v = lookup_fn((params["fm_w"], params["fm_v"]), feat_ids)
+        emb = rows_v * feat_vals[..., None]
     with jax.named_scope("fm"):
         y_w = fm_first_order(feat_w, feat_vals)
-
-    with jax.named_scope("lookup"):
-        if lookup_fn is dense_lookup:
-            emb = scaled_embedding(params["fm_v"], feat_ids, feat_vals)
-        else:
-            emb = lookup_fn(params["fm_v"], feat_ids) * feat_vals[..., None]
 
     y_cin = apply_cin(params["cin"], emb, cfg=cfg)
 

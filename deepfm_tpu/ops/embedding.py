@@ -3,14 +3,16 @@
 Dense (replicated-table) path for single-chip / small-vocab runs — the
 ``tf.nn.embedding_lookup`` capability (reference ps:206, ps:212).  The
 row-sharded multi-chip lookup lives in ``deepfm_tpu/parallel/embedding.py``;
-both expose the same ``lookup(table, ids) -> rows`` signature so models are
-agnostic to the sharding strategy.
+both expose the same ``lookup(tables, ids) -> rows`` signature (one table, or
+a tuple of tables read with the same ids) so models are agnostic to the
+sharding strategy.
 """
 
 from __future__ import annotations
 
 import functools
 import logging
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,30 +57,55 @@ _WRITE_CHUNK = 2048
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _gather_rows(meta, table, ids):
-    """The clip-mode gather; ``meta`` = (table shape, dtype), static, is all
-    the backward keeps of the table."""
-    return jnp.take(table, ids, axis=0, mode="clip")
+def _gather_rows(meta, tables, ids):
+    """The clip-mode gather of every table of the call; ``meta`` = (rows,
+    dtype, each table's row shape), static, is all the backward keeps of
+    them."""
+    return tuple(jnp.take(t, ids, axis=0, mode="clip") for t in tables)
 
 
 def _chunks(meta, ids):
     """``(chunk, n_pad)`` where the differentiated lookup works on the step's
     distinct rows, ``None`` where it keeps XLA's gather and scatter-add: a
-    static choice on the table's rank and the shapes, one for both halves."""
-    shape, _ = meta
+    static choice on the tables' ranks and the shapes, one for both halves.
+    A table of scalars rides with a table of rows read by the same ids;
+    scalars alone gain nothing from a compact buffer of their own."""
+    rows, _, tails = meta
     n = ids.size
     chunk = max(1, min(_WRITE_CHUNK, n))
     n_pad = -(-n // chunk) * chunk
     # the filler indices rows + position must stay representable, and a
     # negative index would wrap python-style instead of being dropped
-    if len(shape) > 1 and n > 0 and (
-            shape[0] + n_pad <= jnp.iinfo(ids.dtype).max):
+    if any(tails) and n > 0 and (
+            rows + n_pad <= jnp.iinfo(ids.dtype).max):
         return chunk, n_pad
     return None
 
 
-def _lookup_fwd(meta, table, ids):
-    """The gather under differentiation: every distinct row is read from the
+def _beside(parts, tails):
+    """Each table's ``[m, ...row]`` part as ``[m, width]`` columns (width 1
+    for a table of scalars), side by side in the order of the tables."""
+    flat = [p.reshape(p.shape[0], math.prod(tail))
+            for p, tail in zip(parts, tails)]
+    return flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=1)
+
+
+def _apart(wide, tails):
+    """``_beside`` undone: ``[..., sum of widths]`` -> one ``[..., ...row]``
+    a table."""
+    if len(tails) == 1:      # (a slice of the whole width is still an op)
+        return (wide.reshape(wide.shape[:-1] + tails[0]),)
+    parts, at = [], 0
+    for tail in tails:
+        width = math.prod(tail)
+        parts.append(lax.slice_in_dim(wide, at, at + width, axis=wide.ndim - 1)
+                     .reshape(wide.shape[:-1] + tail))
+        at += width
+    return tuple(parts)
+
+
+def _lookup_fwd(meta, tables, ids):
+    """The gather under differentiation: every distinct row is read from its
     table once, and the batch is expanded from a compact buffer.
 
     XLA:TPU's gather, like its scatter, is priced by the index and by the
@@ -88,23 +115,28 @@ def _lookup_fwd(meta, table, ids):
     the backward needs is built here: sort the clipped ids (a two-operand
     sort), number the runs of equal ids, carry each position's run number
     back to its place with a second sort; gather the distinct rows chunk by
-    chunk into an ``[n_pad, K]`` buffer (the trip count follows the batch's
+    chunk into an ``[n_pad, ΣK]`` buffer (the trip count follows the batch's
     distinct rows: no capacity, no fallback), and expand ``out =
     compact[run_of]``.  Copies of table rows only: bit for bit
-    ``jnp.take(table, ids, mode="clip")``.  The structure goes to the
-    backward as residuals, which no longer sorts.
+    ``jnp.take(table, ids, mode="clip")`` for every table.  The structure
+    goes to the backward as residuals, which no longer sorts.
 
-    A table of scalars (FM_W) keeps XLA's gather: an n-index scalar gather
-    out of 1.3 MB costs what the one out of 50 MB does."""
-    shape, _ = meta
-    rows, tail = shape[0], tuple(shape[1:])
+    Tables read with one id array share all of it: a trip reads its chunk of
+    rows from each table into columns of the one buffer (FM_V's K and FM_W's
+    one: 33 / 11), the one expansion carries them all, and the results are
+    column slices of it.  An n-index op is priced by the index, not by the
+    width, so what a second table pays of its own is a read a distinct row
+    (PERF.md §6, PR 32).  A call whose tables are all scalars keeps XLA's
+    gather: an n-index scalar gather out of 1.3 MB costs what the one out of
+    50 MB does."""
+    rows, dtype, tails = meta
     plan = _chunks(meta, ids)
     logging.getLogger(__name__).info(
-        "table lookup: %s, n=%d rows=%d row=%s",
+        "table lookup: %s, tables=%s n=%d rows=%d",
         "distinct rows, then expand" if plan else "xla gather",
-        ids.size, rows, tail)
+        list(tails), ids.size, rows)
     if plan is None:
-        return _gather_rows(meta, table, ids), (ids, None)
+        return _gather_rows(meta, tables, ids), (ids, None)
     chunk, n_pad = plan
     flat_ids = ids.reshape(-1)
     n = flat_ids.shape[0]
@@ -123,95 +155,133 @@ def _lookup_fwd(meta, table, ids):
 
     def read(i, compact):
         at = i * chunk
-        got = jnp.take(table, lax.dynamic_slice_in_dim(row_of, at, chunk),
-                       axis=0, mode="clip")
+        at_rows = lax.dynamic_slice_in_dim(row_of, at, chunk)
+        got = _beside([jnp.take(t, at_rows, axis=0, mode="clip")
+                       for t in tables], tails)
         return lax.dynamic_update_slice_in_dim(compact, got, at, 0)
 
     compact = lax.fori_loop(
         0, (distinct + chunk - 1) // chunk, read,
-        jnp.zeros((n_pad,) + tail, table.dtype))
+        jnp.zeros((n_pad, sum(math.prod(tail) for tail in tails)), dtype))
     out = jnp.take(compact, run_of.reshape(ids.shape), axis=0, mode="clip")
-    return out, (ids, (run_of, row_of, distinct))
+    return _apart(out, tails), (ids, (run_of, row_of, distinct))
 
 
-def _lookup_bwd(meta, residuals, g):
-    """Table gradient of the row gather, the forward's transpose op for op:
+def _lookup_bwd(meta, residuals, gs):
+    """Table gradients of the row gather, the forward's transpose op for op:
     the cotangents of equal ids are combined first, then every distinct row
     is written once.
 
     XLA:TPU's scatter-add into a table-sized operand pays ≈ 0.13 µs an index
     whatever the index vector promises (sorted, unique, dropped: all the
     same; PERF.md §6 PR 27), while the same n updates into a buffer of a few
-    MB cost a seventh of that.  So: scatter-add ``g`` by the forward's run
-    numbers into a compact ``[n_pad, K]`` buffer whose live prefix is the
-    distinct rows, and write that prefix into the table-shaped gradient chunk
-    by chunk: the trip count follows the batch's distinct rows, so there is
-    no capacity and no fallback.  Ids outside ``[0, rows)`` contribute
-    nothing (the forward clipped them onto an edge row's run: their
-    cotangent is dropped here).
+    MB cost a seventh of that.  So: lay the tables' cotangents side by side,
+    scatter-add them by the forward's run numbers into a compact
+    ``[n_pad, ΣK]`` buffer whose live prefix is the distinct rows, and write
+    that prefix into the table-shaped gradients chunk by chunk, each table's
+    columns into its own: the trip count follows the batch's distinct rows,
+    so there is no capacity and no fallback.  Ids outside ``[0, rows)``
+    contribute nothing (the forward clipped them onto an edge row's run:
+    their cotangent is dropped here).  A table whose rows the loss does not
+    use arrives with a cotangent of zeros and gets a gradient of zeros.
 
-    A table of scalars (FM_W) keeps XLA's own scatter-add: at one float a
-    row it is the compact buffer's price already (3.9 ms against 2.9 + 0.7
-    at n = 319,488, 2.3 against 1.9 + 0.6 at 159,744; same chip call)."""
-    shape, dtype = meta
-    rows, tail = shape[0], tuple(shape[1:])
+    A call whose tables are all scalars keeps XLA's own scatter-add: at one
+    float a row it is the compact buffer's price already (3.9 ms against
+    2.9 + 0.7 at n = 319,488, 2.3 against 1.9 + 0.6 at 159,744; same chip
+    call)."""
+    rows, dtype, tails = meta
     ids, runs = residuals
     flat_ids = ids.reshape(-1)
     n = flat_ids.shape[0]
-    flat_g = g.reshape((n,) + tail).astype(dtype)
+    flat_gs = [g.reshape((n,) + tail).astype(dtype)
+               for g, tail in zip(gs, tails)]
     logging.getLogger(__name__).info(
-        "table gradient: %s, n=%d rows=%d row=%s",
-        "combine-then-write" if runs else "xla scatter-add", n, rows, tail)
+        "table gradient: %s, tables=%s n=%d rows=%d",
+        "combine-then-write" if runs else "xla scatter-add",
+        list(tails), n, rows)
     in_range = (flat_ids >= 0) & (flat_ids < rows)
     zero_ids = np.zeros(ids.shape, jax.dtypes.float0)
     if runs is None:
-        grad = jnp.zeros(shape, dtype).at[
-            jnp.where(in_range, flat_ids, rows)].add(flat_g, mode="drop")
-        return grad, zero_ids
+        grads = [jnp.zeros((rows,) + tail, dtype) for tail in tails]
+        at = jnp.where(in_range, flat_ids, rows)
+        return tuple(grad.at[at].add(g, mode="drop")
+                     for grad, g in zip(grads, flat_gs)), zero_ids
 
     run_of, row_of, distinct = runs
     chunk, n_pad = _chunks(meta, ids)
+    flat_g = _beside(flat_gs, tails)
     # an id the forward clipped onto an edge row's run adds past the buffer's
     # end, where the scatter drops it
-    combined = jnp.zeros((n_pad,) + tail, dtype).at[
+    combined = jnp.zeros((n_pad, flat_g.shape[1]), dtype).at[
         jnp.where(in_range, run_of, n_pad)].add(flat_g, mode="drop")
 
-    def write(i, grad):
+    def write(i, grads):
         at = i * chunk
-        return grad.at[lax.dynamic_slice_in_dim(row_of, at, chunk)].add(
-            lax.dynamic_slice_in_dim(combined, at, chunk),
-            indices_are_sorted=True, unique_indices=True, mode="drop")
+        at_rows = lax.dynamic_slice_in_dim(row_of, at, chunk)
+        parts = _apart(lax.dynamic_slice_in_dim(combined, at, chunk), tails)
+        return tuple(
+            grad.at[at_rows].add(part, indices_are_sorted=True,
+                                 unique_indices=True, mode="drop")
+            for grad, part in zip(grads, parts))
 
-    grad = lax.fori_loop(
-        0, (distinct + chunk - 1) // chunk, write, jnp.zeros(shape, dtype))
-    return grad, zero_ids
+    grads = lax.fori_loop(
+        0, (distinct + chunk - 1) // chunk, write,
+        tuple(jnp.zeros((rows,) + tail, dtype) for tail in tails))
+    return grads, zero_ids
 
 
 _gather_rows.defvjp(_lookup_fwd, _lookup_bwd)
 
 
-def dense_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
-    """Gather rows: table [V] or [V, K], ids [B, F] -> [B, F] or [B, F, K].
+def dense_lookup(tables, ids: jnp.ndarray):
+    """Gather rows: table [V] or [V, K], ids [B, F] -> [B, F] or [B, F, K];
+    a tuple of tables read with the same ids -> a tuple of their rows.
 
-    ``mode="clip"`` matches XLA:TPU's in-bounds guarantee while keeping the
-    op fully vectorizable (no dynamic bounds checks in the hot path).
+    This is the ``lookup_fn(tables, ids)`` protocol of the models: tables
+    that share an id array (FM_W and FM_V) come in one call, with the same
+    row count and dtype.  ``mode="clip"`` matches XLA:TPU's in-bounds
+    guarantee while keeping the op fully vectorizable (no dynamic bounds
+    checks in the hot path).
 
     Called outside differentiation (serve/, eval, the lazy and the tiered
-    step) this is the one plain gather and nothing else: an inference bucket
-    of 8–512 rows pays no sort.  Under differentiation the two halves share
-    one run structure of the step's ids (``_lookup_fwd``, ``_lookup_bwd``):
-    the forward reads every distinct row from the table once and expands to
-    the batch from a compact buffer — copies of table rows, so bit for bit
-    this same gather — and the backward combines the cotangents of equal ids
-    before it touches the table-shaped gradient: float32 sums of the same
-    addends as the gather's default scatter-add VJP in another order, so the
-    two agree to float tolerance, not bit for bit
-    (tests/test_segsum_grad.py).  A table of scalars keeps XLA's gather and
-    scatter-add both ways.  It is the one local row gather of every training
-    path — the dense step, the SPMD step's shard-local gather and the
-    all-to-all exchange's owner side — for either value of
-    ``ModelConfig.table_grad``."""
-    return _gather_rows((tuple(table.shape), str(table.dtype)), table, ids)
+    step) this is the one plain gather a table and nothing else: an
+    inference bucket of 8–512 rows pays no sort.  Under differentiation the
+    two halves share one run structure of the step's ids (``_lookup_fwd``,
+    ``_lookup_bwd``), and so do the tables of one call: the forward reads
+    every distinct row from each table once into one compact buffer and
+    expands to the batch from it — copies of table rows, so bit for bit this
+    same gather — and the backward combines the cotangents of equal ids, all
+    tables' side by side, before it touches the table-shaped gradients:
+    float32 sums of the same addends as the gather's default scatter-add VJP
+    in another order, so the two agree to float tolerance, not bit for bit
+    (tests/test_segsum_grad.py).  The static rule (``_chunks``): the
+    distinct-rows path where at least one table of the call holds rows
+    (rank > 1); a call of scalars only keeps XLA's gather and scatter-add
+    both ways.  It is the one local row gather of every training path — the
+    dense step, the SPMD step's shard-local gather and the all-to-all
+    exchange's owner side — for either value of ``ModelConfig.table_grad``."""
+    if not isinstance(tables, tuple):
+        return dense_lookup((tables,), ids)[0]
+    (rows, dtype), *others = [(t.shape[0], str(t.dtype)) for t in tables]
+    if any(other != (rows, dtype) for other in others):
+        raise ValueError(
+            "tables of one lookup share a row count and a dtype, got "
+            f"{[(t.shape, str(t.dtype)) for t in tables]}")
+    tails = tuple(tuple(t.shape[1:]) for t in tables)
+    return _gather_rows((rows, dtype, tails), tables, ids)
+
+
+def gathered_rows_lookup(rows: dict):
+    """A ``lookup_fn`` that hands out rows gathered beforehand: the lazy and
+    the tiered step differentiate with respect to the gathered rows, not the
+    tables.  The CTR families read ``fm_w`` (rank 1) and ``fm_v`` (rank 2)
+    once each, so a table's rank names its rows; one table or a tuple."""
+
+    def lookup(tables, _ids):
+        return jax.tree_util.tree_map(
+            lambda t: rows["fm_w"] if t.ndim == 1 else rows["fm_v"], tables)
+
+    return lookup
 
 
 def scaled_embedding(

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -98,12 +99,8 @@ def resolve_shard_exchange(cfg, backend: str | None = None) -> str:
     if not sharded:
         return "psum"
     if backend is None:
-        import jax
-
         backend = jax.default_backend()
     if backend == "cpu":
-        import jax
-
         if jax.process_count() > 1:
             # cross-process CPU collectives (gloo) have no verified
             # all-to-all here — auto stays conservative; TPU pods below
@@ -259,7 +256,6 @@ def _assemble_bwd(buf_len, res, ct):
     occurrence gather would be an unsorted colliding scatter-add into the
     response buffer — the exact pattern XLA serializes and this exchange
     exists to avoid."""
-    import jax
     import numpy as _np
 
     gidx_shape, order, seg, scat, ok = res
@@ -286,8 +282,6 @@ def _assemble_bwd(buf_len, res, ct):
 
 
 def _make_assemble_call():
-    import jax
-
     call = jax.custom_vjp(_assemble_impl, nondiff_argnums=(0,))
     call.defvjp(_assemble_fwd, _assemble_bwd)
     return call
@@ -358,44 +352,53 @@ def _exchange_collect(
     )
 
 
-def _psum_lookup(
-    local_table: jnp.ndarray,
-    ids: jnp.ndarray,
-    axis_name: str,
-) -> jnp.ndarray:
+def _psum_lookup(local_tables, ids: jnp.ndarray, axis_name: str):
     """Dense zeros-plus-psum assembly (the original path; also the
-    capacity-overflow fallback of the alltoall exchange)."""
+    capacity-overflow fallback of the alltoall exchange).  A tuple of tables
+    goes to the shard-local gather as one call, so they share its run
+    structure (``ops/embedding.py dense_lookup``)."""
     from ..ops.embedding import dense_lookup
 
-    rows = local_table.shape[0]
+    rows = jax.tree_util.tree_leaves(local_tables)[0].shape[0]
     shard = lax.axis_index(axis_name)
     lo = shard * rows
     local_ids = ids - lo
     in_range = (local_ids >= 0) & (local_ids < rows)
     clipped = jnp.clip(local_ids, 0, rows - 1)
-    gathered = dense_lookup(local_table, clipped)
-    mask = in_range if gathered.ndim == ids.ndim else in_range[..., None]
-    gathered = jnp.where(mask, gathered, 0)
-    return lax.psum(gathered, axis_name)
+
+    def owned(gathered):
+        mask = in_range if gathered.ndim == ids.ndim else in_range[..., None]
+        return jnp.where(mask, gathered, 0)
+
+    return lax.psum(
+        jax.tree_util.tree_map(owned, dense_lookup(local_tables, clipped)),
+        axis_name)
 
 
 def sharded_lookup(
-    local_table: jnp.ndarray,
+    local_table,
     ids: jnp.ndarray,
     *,
     axis_name: str = MODEL_AXIS,
     exchange: str = "psum",
     capacity: float = 0.0,
-) -> jnp.ndarray:
+):
     """Gather rows from a row-sharded table, inside shard_map.
 
-    local_table: this shard's rows — [V/M] or [V/M, K]
+    local_table: this shard's rows — [V/M] or [V/M, K] — or a tuple of such
+        tables read with the same ids (the ``lookup_fn(tables, ids)``
+        protocol, ``models/base.py``)
     ids: global ids [B, F] (replicated across the model axis)
-    returns: full rows [B, F] or [B, F, K] (replicated across the model axis)
+    returns: full rows [B, F] or [B, F, K] (replicated across the model
+        axis), in the structure of ``local_table``
 
-    The shard-local gather is ``ops/embedding.py dense_lookup``, whose
-    backward combines duplicate ids before it writes the table-shaped
-    gradient (``ModelConfig.table_grad`` selects nothing).
+    The shard-local gather is ``ops/embedding.py dense_lookup``: under
+    differentiation it reads every distinct row once and its backward
+    combines duplicate ids before it writes the table-shaped gradient
+    (``ModelConfig.table_grad`` selects nothing).  Under "psum" a tuple goes
+    to it as one call, so its tables share one run structure, one compact
+    buffer and one write loop; the "alltoall" exchange stays one table a
+    call.
 
     ``exchange`` selects the assembly collective (module docstring): "psum"
     = dense zeros-plus-psum; "alltoall" = deduplicated owned-rows-only
@@ -411,6 +414,10 @@ def sharded_lookup(
         )
     if exchange == "psum":
         return _psum_lookup(local_table, ids, axis_name)
+    if isinstance(local_table, tuple):
+        return tuple(
+            sharded_lookup(t, ids, axis_name=axis_name, exchange=exchange,
+                           capacity=capacity) for t in local_table)
 
     rows = local_table.shape[0]
     num_shards = int(lax.psum(1, axis_name))
@@ -449,8 +456,8 @@ def make_sharded_lookup_fn(axis_name: str = MODEL_AXIS,
     """A ``lookup_fn`` for model.apply, closing over the axis name and the
     exchange mode (``lookup_fn_from_config`` resolves them from a Config)."""
 
-    def lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
-        return sharded_lookup(table, ids, axis_name=axis_name,
+    def lookup(tables, ids: jnp.ndarray):
+        return sharded_lookup(tables, ids, axis_name=axis_name,
                               exchange=exchange, capacity=capacity)
 
     return lookup

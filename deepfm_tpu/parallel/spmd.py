@@ -494,7 +494,7 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
         keys = [k for k in LAZY_TABLE_KEYS if k in params]
         rest = {k: v for k, v in params.items() if k not in keys}
         tables = {k: params[k] for k in keys}          # local row shards
-        from ..ops.embedding import narrow_ids
+        from ..ops.embedding import gathered_rows_lookup, narrow_ids
 
         ids2d = narrow_ids(batch["feat_ids"], cfg.model.feature_size)
         ids2d = ids2d.reshape(-1, cfg.model.field_size)
@@ -521,9 +521,6 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
             }
 
         def loss_fn(rest, rows):
-            def row_lookup(table, _ids):
-                return rows["fm_w"] if table.ndim == 1 else rows["fm_v"]
-
             logits, new_state = model.apply(
                 {**rest, **tables},
                 state.model_state,
@@ -532,7 +529,7 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
                 cfg=cfg.model,
                 train=True,
                 rng=step_rng,
-                lookup_fn=row_lookup,
+                lookup_fn=gathered_rows_lookup(rows),
             )
             with jax.named_scope("loss"):
                 labels = batch["label"].reshape(-1).astype(jnp.float32)

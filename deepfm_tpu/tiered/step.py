@@ -37,7 +37,7 @@ import optax
 
 from ..core.config import Config
 from ..models.base import get_model
-from ..ops.embedding import dense_lookup
+from ..ops.embedding import dense_lookup, gathered_rows_lookup
 from ..train.lazy import lazy_adam_update, shared_segments
 from ..train.optimizer import build_lr_schedule, build_optimizer, schedule_value
 from ..train.step import _dp_size, sigmoid_cross_entropy
@@ -113,11 +113,6 @@ def make_paged_train_step(
         rows = {k: dense_lookup(hot.rows[k], slot_ids) for k in keys}
 
         def loss_fn(rest, rows):
-            def row_lookup(table, _ids):
-                # CTR families gather fm_w (1-D) and fm_v (2-D) exactly
-                # once each; ndim disambiguates (train/step.py)
-                return rows["fm_w"] if table.ndim == 1 else rows["fm_v"]
-
             logits, new_state = model.apply(
                 {**rest, **hot.rows},
                 state.model_state,
@@ -126,7 +121,7 @@ def make_paged_train_step(
                 cfg=cfg.model,
                 train=True,
                 rng=step_rng,
-                lookup_fn=row_lookup,
+                lookup_fn=gathered_rows_lookup(rows),
             )
             labels = batch["label"].reshape(-1).astype(jnp.float32)
             return jnp.mean(sigmoid_cross_entropy(logits, labels)), (
@@ -202,10 +197,6 @@ def make_paged_predict(cfg: Config) -> Callable:
     def predict(rest, model_state, hot_rows, batch):
         slot_ids = batch["slot_ids"]
         rows = {k: dense_lookup(hot_rows[k], slot_ids) for k in hot_rows}
-
-        def row_lookup(table, _ids):
-            return rows["fm_w"] if table.ndim == 1 else rows["fm_v"]
-
         logits, _ = model.apply(
             {**rest, **hot_rows},
             model_state,
@@ -214,7 +205,7 @@ def make_paged_predict(cfg: Config) -> Callable:
             cfg=cfg.model,
             train=False,
             rng=None,
-            lookup_fn=row_lookup,
+            lookup_fn=gathered_rows_lookup(rows),
         )
         return jax.nn.sigmoid(logits)
 

@@ -183,7 +183,8 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
     via touched-rows-only lazy Adam.  The CE loss drops the dense table-L2
     term (ps:275-279); its gradient ``l2·w`` is applied inside the lazy
     update on touched rows instead (see train/lazy.py semantics notes)."""
-    from ..ops.embedding import dense_lookup, narrow_ids
+    from ..ops.embedding import (
+        dense_lookup, gathered_rows_lookup, narrow_ids)
     from .lazy import LazyAdamState, lazy_adam_update, shared_segments
 
     from .optimizer import build_lr_schedule, schedule_value
@@ -210,11 +211,6 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
             rows = {k: dense_lookup(tables[k], ids) for k in keys}
 
         def loss_fn(rest, rows):
-            # row substitution: the CTR families gather fm_w (1-D) and fm_v
-            # (2-D) exactly once through lookup_fn, so ndim disambiguates
-            def row_lookup(table, _ids):
-                return rows["fm_w"] if table.ndim == 1 else rows["fm_v"]
-
             logits, new_state = model.apply(
                 {**rest, **tables},
                 state.model_state,
@@ -223,7 +219,7 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
                 cfg=cfg.model,
                 train=True,
                 rng=step_rng,
-                lookup_fn=row_lookup,
+                lookup_fn=gathered_rows_lookup(rows),
             )
             with jax.named_scope("loss"):
                 labels = batch["label"].reshape(-1).astype(jnp.float32)

@@ -17,6 +17,14 @@ the way in, dropped on the way back), id streams that do and do not fit the
 packed sort, several chunks, ids outside a shard's window, the trace-time
 log lines of both halves, and full-model and SPMD step parity for both
 values of ``table_grad`` (which selects nothing any more).
+
+Tables read with one id array come in one call (``lookup_fn(tables, ids)``:
+FM_W and FM_V) and share the run structure, the compact buffer, the
+expansion and the write loop (PR 32): the same pins for the pair — every
+table's forward bit for bit, every table's gradient against XLA's, ids out of
+range in both tables, a table the loss does not use, what an all-scalars tuple
+and a tuple outside differentiation lower to, the log lines, and the tuple
+through the shard-local gather on [1, 4] and [2, 2] virtual meshes.
 """
 
 import logging
@@ -125,12 +133,20 @@ def test_backward_matches_xla_scatter_add(case, tail):
 
 
 def _case(case, tail):
-    rows = 6000 if case in ("criteo", "all_distinct") else V
-    rng = np.random.default_rng(3)
-    ids = _case_ids(case, rng, rows)
-    table = jnp.asarray(rng.standard_normal((rows,) + tail), jnp.float32)
-    w = jnp.asarray(rng.standard_normal(ids.shape + tail), jnp.float32)
+    rows, ids, (table,), (w,) = _pair_case(case, (tail,), seed=3)
     return rows, ids, table, w
+
+
+def _pair_case(case, tails, seed=7):
+    """Ids of ``case``, a table a tail, and a cotangent for each table."""
+    rows = 6000 if case in ("criteo", "all_distinct") else V
+    rng = np.random.default_rng(seed)
+    ids = _case_ids(case, rng, rows)
+    tables = tuple(jnp.asarray(rng.standard_normal((rows,) + tail),
+                               jnp.float32) for tail in tails)
+    ws = tuple(jnp.asarray(rng.standard_normal(ids.shape + tail),
+                           jnp.float32) for tail in tails)
+    return rows, ids, tables, ws
 
 
 @pytest.mark.parametrize("through", ["vjp", "value_and_grad"])
@@ -160,6 +176,95 @@ def test_differentiated_forward_is_bit_equal_to_take(case, tail, through):
         (_, got), _ = jax.jit(jax.value_and_grad(loss, has_aux=True))(table)
     assert got.dtype == want.dtype and got.shape == want.shape
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+PAIRS = [((), (10,)), ((), (32,))]
+PAIR_IDS = ["w_K10", "w_K32"]
+
+
+def _weighted(outs, ws):
+    return sum(jnp.sum(out * w) for out, w in zip(outs, ws))
+
+
+@pytest.mark.parametrize("through", ["vjp", "value_and_grad"])
+@pytest.mark.parametrize("tails", PAIRS, ids=PAIR_IDS)
+@pytest.mark.parametrize("case", ["zipf", "all_duplicate", "out_of_range",
+                                  "criteo", "all_distinct"])
+def test_pair_is_bit_equal_forward_and_matches_xla_backward(
+        case, tails, through):
+    """FM_W and FM_V in one call: each table's rows are column slices of the
+    one expansion — still copies of table entries, bit for bit the clip-mode
+    gather — and each table's gradient is XLA's scatter-add VJP to float
+    tolerance, with nothing written outside the rows the ids name."""
+    rows, ids_np, tables, ws = _pair_case(case, tails)
+    ids = jnp.asarray(ids_np)
+    want = [np.asarray(jnp.take(t, ids, axis=0, mode="clip")) for t in tables]
+    g_xla = jax.grad(lambda ts: _weighted(
+        [_xla_take(t, ids) for t in ts], ws))(tables)
+    if through == "vjp":
+        got, pull = jax.jit(
+            lambda ts: jax.vjp(lambda ts_: dense_lookup(ts_, ids), ts))(tables)
+        g_new, = pull(ws)
+    else:
+        def loss(ts):
+            outs = dense_lookup(ts, ids)
+            return _weighted(outs, ws), outs
+
+        (_, got), g_new = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(tables)
+    assert isinstance(got, tuple) and isinstance(g_new, tuple)
+    inside = ids_np[(ids_np >= 0) & (ids_np < rows)]
+    untouched = np.setdiff1d(np.arange(rows), inside)
+    for out, ref, grad, grad_ref, table in zip(
+            got, want, g_new, g_xla, tables):
+        assert out.dtype == ref.dtype and out.shape == ref.shape
+        np.testing.assert_array_equal(np.asarray(out), ref)
+        assert grad.shape == table.shape
+        np.testing.assert_allclose(np.asarray(grad), np.asarray(grad_ref),
+                                   rtol=1e-5, atol=1e-5)
+        assert not np.any(np.asarray(grad)[untouched])
+
+
+def test_out_of_range_ids_of_a_pair_read_the_edge_row_and_write_nothing():
+    """Clip on the way in, drop on the way back, in both tables of a call."""
+    rows = 50
+    fm_v = jnp.arange(rows * 4, dtype=jnp.float32).reshape(rows, 4) + 1.0
+    fm_w = -jnp.arange(rows, dtype=jnp.float32) - 1.0
+    ids = jnp.asarray([[-3, 0, rows - 1, rows + 9]], jnp.int32)
+    (out_w, out_v), pull = jax.vjp(
+        lambda ts: dense_lookup(ts, ids), (fm_w, fm_v))
+    edges = [0, 0, rows - 1, rows - 1]
+    np.testing.assert_array_equal(np.asarray(out_w[0]), np.asarray(fm_w)[edges])
+    np.testing.assert_array_equal(np.asarray(out_v[0]), np.asarray(fm_v)[edges])
+    (g_w, g_v), = pull((jnp.ones_like(out_w), jnp.ones_like(out_v)))
+    g_w, g_v = np.asarray(g_w), np.asarray(g_v)
+    assert g_w[0] == 1.0 and g_w[-1] == 1.0 and np.count_nonzero(g_w) == 2
+    assert g_v[0].tolist() == [1.0] * 4 and g_v[-1].tolist() == [1.0] * 4
+    assert np.count_nonzero(g_v) == 8
+
+
+@pytest.mark.parametrize("used", [0, 1], ids=["only_w_used", "only_v_used"])
+def test_pair_with_a_table_the_loss_does_not_use(used):
+    """A missing cotangent is zeros: the unused table's gradient is zeros and
+    the used one's is what it is alone."""
+    rows, ids_np, tables, ws = _pair_case("zipf", ((), (10,)))
+    ids = jnp.asarray(ids_np)
+    g_pair = jax.jit(jax.grad(
+        lambda ts: jnp.sum(dense_lookup(ts, ids)[used] * ws[used])))(tables)
+    g_alone = jax.grad(
+        lambda t: jnp.sum(_xla_take(t, ids) * ws[used]))(tables[used])
+    assert not np.any(np.asarray(g_pair[1 - used]))
+    assert g_pair[1 - used].shape == tables[1 - used].shape
+    np.testing.assert_allclose(np.asarray(g_pair[used]), np.asarray(g_alone),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_tables_of_one_lookup_share_rows_and_dtype():
+    ids = jnp.zeros((4, 3), jnp.int32)
+    with pytest.raises(ValueError, match="row count"):
+        dense_lookup((jnp.zeros((V,)), jnp.zeros((V + 1, 8))), ids)
+    with pytest.raises(ValueError, match="dtype"):
+        dense_lookup((jnp.zeros((V,), jnp.bfloat16), jnp.zeros((V, 8))), ids)
 
 
 def test_out_of_range_ids_read_the_edge_row_and_write_nothing():
@@ -199,23 +304,61 @@ def test_lookup_outside_differentiation_is_the_one_plain_gather(tail):
         assert diff == ["gather"]
 
 
-@pytest.mark.parametrize("tail,fwd,bwd", [
-    ((), "xla gather", "xla scatter-add"),
-    ((10,), "distinct rows, then expand", "combine-then-write"),
-], ids=["scalars", "K10"])
-def test_both_halves_say_what_they_chose_once_per_trace(
-        tail, fwd, bwd, caplog):
-    """The choice is static, so there is no rate to count: one log line a
-    half a trace names it."""
-    table = jnp.zeros((V,) + tail, jnp.float32)
+@pytest.mark.parametrize("tails,rides", [
+    (((), (10,)), True), (((10,), ()), True), (((), ()), False),
+], ids=["w_K10", "K10_w", "scalars_only"])
+def test_what_a_tuple_of_tables_lowers_to(tails, rides):
+    """Outside differentiation any tuple is one plain gather a table.  Under
+    it, a tuple with a table of rows runs ONE run structure, one loop a half
+    (a gather a table a trip forward, a write a table a trip back), one
+    expansion and one compact scatter-add; a tuple of scalars only keeps
+    XLA's gather and scatter-add, one a table."""
+    tables = tuple(jnp.zeros((V,) + tail, jnp.float32) for tail in tails)
     ids = jnp.zeros((8, 13), jnp.int32)
-    step = jax.jit(jax.grad(lambda t: jnp.sum(dense_lookup(t, ids))))
+    plain = _indexed_ops(dense_lookup, tables, ids)
+    # (two tables of one shape share the text of one ``_take``)
+    assert plain == ["gather"] * len({t.shape for t in tables})
+    fwd = _indexed_ops(
+        lambda ts, i: jax.vjp(lambda ts_: dense_lookup(ts_, i), ts)[0],
+        tables, ids)
+    both = _indexed_ops(
+        jax.grad(lambda ts, i: sum(
+            jnp.sum(r * r) for r in dense_lookup(ts, i))), tables, ids)
+    if rides:
+        assert sorted(fwd) == ["gather", "gather", "gather", "sort", "sort",
+                               "sort", "while"]
+        # the backward adds the compact scatter-add (XLA sorts nothing in the
+        # lowered text) and its loop of two writes
+        assert sorted(both) == sorted(fwd + ["scatter"] * 3 + ["while"])
+    else:
+        assert fwd == plain
+        assert sorted(both) == plain + ["scatter", "scatter"]
+
+
+@pytest.mark.parametrize("tails,fwd,bwd", [
+    (((),), "xla gather", "xla scatter-add"),
+    (((10,),), "distinct rows, then expand", "combine-then-write"),
+    (((), (10,)), "distinct rows, then expand", "combine-then-write"),
+    (((), (32,)), "distinct rows, then expand", "combine-then-write"),
+    (((), ()), "xla gather", "xla scatter-add"),
+], ids=["scalars", "K10", "w_K10", "w_K32", "scalars_only_pair"])
+def test_both_halves_say_what_they_chose_once_per_trace(
+        tails, fwd, bwd, caplog):
+    """The choice is static, so there is no rate to count: one log line a
+    half a trace names it, and the tables that ride together."""
+    tables = tuple(jnp.zeros((V,) + tail, jnp.float32) for tail in tails)
+    if len(tables) == 1:
+        tables, = tables                         # the single-table call
+    ids = jnp.zeros((8, 13), jnp.int32)
+    step = jax.jit(jax.grad(lambda ts: sum(
+        jnp.sum(r) for r in jax.tree_util.tree_leaves(dense_lookup(ts, ids)))))
     with caplog.at_level(logging.INFO, logger=embedding.__name__):
-        step(table)
-        step(table)                  # cached: traced once
+        step(tables)
+        step(tables)                 # cached: traced once
     lines = [r.getMessage() for r in caplog.records]
-    assert lines == [f"table lookup: {fwd}, n=104 rows={V} row={tail}",
-                     f"table gradient: {bwd}, n=104 rows={V} row={tail}"]
+    named = list(tails)
+    assert lines == [f"table lookup: {fwd}, tables={named} n=104 rows={V}",
+                     f"table gradient: {bwd}, tables={named} n=104 rows={V}"]
 
 
 @pytest.mark.parametrize("tail", [(), (10,)], ids=["scalars", "K10"])
@@ -240,7 +383,8 @@ def _xla_backward(monkeypatch):
     """The gather with XLA's own VJP in ``dense_lookup``'s place."""
     monkeypatch.setattr(
         embedding, "_gather_rows",
-        lambda meta, table, ids: jnp.take(table, ids, axis=0, mode="clip"))
+        lambda meta, tables, ids: tuple(
+            jnp.take(t, ids, axis=0, mode="clip") for t in tables))
 
 
 @pytest.mark.parametrize("tail", [(), (10,)], ids=["scalars", "K10"])
@@ -280,6 +424,63 @@ def test_backward_outside_the_shard_window(tail, monkeypatch):
         g_xla = sharded_grad()
     assert np.any(g_xla)
     np.testing.assert_allclose(g_xla, g_new, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dp,mp", [(1, 4), (2, 2)],
+                         ids=["mesh_1x4", "mesh_2x2"])
+def test_pair_through_the_shard_local_gather(dp, mp, monkeypatch):
+    """``sharded_lookup`` hands a tuple to the shard-local gather as one call
+    (``_psum_lookup``): on [1, 4] and [2, 2] virtual meshes both tables' rows
+    are the full tables' (ids no shard owns read zero) and both sharded
+    gradients are what XLA's scatter-add gives in the same place."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from deepfm_tpu.core.config import MeshConfig
+    from deepfm_tpu.parallel import build_mesh
+    from deepfm_tpu.parallel.embedding import sharded_lookup
+    from deepfm_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    rows = 1000
+    rng = np.random.default_rng(6)
+    ids = _criteo_ids(rng, 32, rows)
+    ids[0, :3] = [-2, rows, 7 * rows]
+    tails = ((), (10,))
+    tables = tuple(jnp.asarray(rng.standard_normal((rows,) + tail),
+                               jnp.float32) for tail in tails)
+    ws = tuple(jnp.asarray(rng.standard_normal(ids.shape + tail),
+                           jnp.float32) for tail in tails)
+    specs = (P(MODEL_AXIS), P(MODEL_AXIS, None))
+    bspecs = (P(DATA_AXIS, None), P(DATA_AXIS, None, None))
+    mesh = build_mesh(MeshConfig(data_parallel=dp, model_parallel=mp),
+                      devices=jax.devices()[:dp * mp])
+
+    def run():
+        def local(ts, i, ws):
+            def loss(ts_):
+                outs = sharded_lookup(ts_, i)
+                return _weighted(outs, ws), outs
+
+            (_, outs), grads = jax.value_and_grad(loss, has_aux=True)(ts)
+            return outs, grads
+
+        fn = shard_map(
+            local, mesh=mesh, in_specs=(specs, P(DATA_AXIS, None), bspecs),
+            out_specs=(bspecs, specs), check_vma=False)
+        outs, grads = jax.jit(fn)(tables, jnp.asarray(ids), ws)
+        return ([np.asarray(o) for o in outs], [np.asarray(g) for g in grads])
+
+    outs, g_new = run()
+    with monkeypatch.context() as patch:
+        _xla_backward(patch)
+        outs_xla, g_xla = run()
+    for out, ref, table, grad, grad_ref in zip(
+            outs, outs_xla, tables, g_new, g_xla):
+        np.testing.assert_array_equal(out, ref)
+        np.testing.assert_array_equal(
+            out, np.asarray(_xla_take(table, jnp.asarray(ids))))
+        assert np.any(grad_ref)
+        np.testing.assert_allclose(grad_ref, grad, rtol=1e-5, atol=1e-5)
 
 
 def _cfg(table_grad: str, lazy: bool = False):
@@ -332,9 +533,12 @@ def test_model_step_parity(model_name, monkeypatch):
                                        rtol=2e-4, atol=1e-6)
 
 
-def test_spmd_step_parity(monkeypatch):
-    """The sharded product path on a [2, 4] virtual mesh: XLA's scatter-add
-    against the combining local-gather backward agree after one step."""
+@pytest.mark.parametrize("dp,mp", [(2, 4), (1, 4), (2, 2)],
+                         ids=["mesh_2x4", "mesh_1x4", "mesh_2x2"])
+def test_spmd_step_parity(dp, mp, monkeypatch):
+    """The sharded product path on a virtual mesh (FM_W and FM_V in one
+    shard-local lookup): XLA's scatter-add against the combining
+    local-gather backward agree after one step, on both tables."""
     from deepfm_tpu.core.config import MeshConfig
     from deepfm_tpu.parallel import (
         build_mesh, create_spmd_state, make_context, make_spmd_train_step,
@@ -346,21 +550,22 @@ def test_spmd_step_parity(monkeypatch):
 
     def one_step(tg):
         cfg = _cfg(tg)
-        mesh = build_mesh(MeshConfig(data_parallel=2, model_parallel=4))
+        mesh = build_mesh(MeshConfig(data_parallel=dp, model_parallel=mp),
+                          devices=jax.devices()[:dp * mp])
         ctx = make_context(cfg, mesh)
         step = make_spmd_train_step(ctx)
         s, m = step(create_spmd_state(ctx), shard_batch(ctx, host))
-        return (np.asarray(s.params["fm_v"]),
+        return ((np.asarray(s.params["fm_v"]), np.asarray(s.params["fm_w"])),
                 float(np.asarray(m["loss"]).reshape(-1)[-1]))
 
     outs = {tg: one_step(tg) for tg in ("scatter", "segsum")}
-    with monkeypatch.context() as mp:
-        _xla_backward(mp)
+    with monkeypatch.context() as patch:
+        _xla_backward(patch)
         outs["xla"] = one_step("scatter")
     for tg in ("scatter", "segsum"):
         assert outs["xla"][1] == pytest.approx(outs[tg][1], rel=1e-5)
-        np.testing.assert_allclose(outs["xla"][0], outs[tg][0],
-                                   rtol=2e-4, atol=1e-6)
+        for a, b in zip(outs["xla"][0], outs[tg][0]):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6)
 
 
 def test_config_rejects_unknown_table_grad():

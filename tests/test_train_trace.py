@@ -519,22 +519,31 @@ def _tiny_cell_config(name):
 _OPERANDS = re.compile(r"\(([^()]*)\)")
 
 
-@pytest.mark.parametrize("name", ["tiny-deepfm", "tiny-xdeepfm"])
-def test_table_gradient_lowering_contract(name):
+@pytest.mark.parametrize("name,model", [
+    ("tiny-deepfm", None), ("tiny-xdeepfm", None), ("tiny-deepfm", "dcnv2"),
+], ids=["tiny-deepfm", "tiny-xdeepfm", "dcnv2"])
+def test_table_gradient_lowering_contract(name, model):
     """The cells' ``table_grad: "scatter"`` runs the lookup on the step's
-    distinct rows, both ways (ops/embedding.py ``_lookup_fwd`` /
-    ``_lookup_bwd``).  In the compiled SPMD step: every sort of the run
-    structure reads the forward's scope and none the backward's; there are
-    two loops, the forward's gather of the distinct rows and the backward's
-    write of them; exactly one gather reads the ``[rows, K]`` table and it
-    sits inside the forward's loop (the expansion to the batch reads the
-    compact buffer); every scatter into a table of rows promises sorted and
-    unique indices (one write per distinct row) inside the backward's loop;
-    the one scatter without the promise is XLA's own scatter-add into the
-    table of scalars (FM_W: chosen on the table's rank), whose gather stays
-    XLA's too."""
+    distinct rows, both ways, and ONE lookup serves the tables that share the
+    step's ids (ops/embedding.py ``_lookup_fwd`` / ``_lookup_bwd``; FM_W rides
+    FM_V's structure since PR 32).  In the compiled SPMD step: every sort of
+    the run structure reads the forward's scope and none the backward's;
+    there is one loop a half, the forward's gather of the distinct rows and
+    the backward's write of them; every table is read by exactly one gather,
+    a chunk of distinct rows a trip inside the forward's loop, into columns
+    of one compact buffer, and the one gather of n indices is the expansion
+    out of that buffer; every scatter into a table-shaped gradient promises
+    sorted and unique indices (one write per distinct row) and sits inside
+    the backward's loop, and the one scatter-add of n indices goes into the
+    compact buffer.  So the step holds no n-index gather of, and no n-index
+    scatter-add into, the table of scalars, which is read and written only
+    inside the two loops.  ``dcnv2`` looks up one table a call: one column
+    block, nothing laid side by side (its lowered step is byte for byte the
+    parent's: CHANGES.md, PR 32)."""
     cfg = _tiny_cell_config(name)
     assert cfg.model.table_grad == "scatter"
+    if model:
+        cfg = cfg.with_overrides(model={"model_name": model})
     mesh = build_mesh(MeshConfig(data_parallel=1, model_parallel=1),
                       devices=jax.devices()[:1])
     ctx = make_context(cfg, mesh)
@@ -543,50 +552,72 @@ def test_table_gradient_lowering_contract(name):
     hlo = make_spmd_train_step(ctx, donate=False).lower(
         state, batch).compile().as_text()
     rows, k = state.params["fm_v"].shape
+    tables = {(rows, k)} | ({(rows,)} if "fm_w" in state.params else set())
+    width = k + len(tables) - 1      # the compact buffers' columns
     n = batch["feat_ids"].size
     forward, backward = "jvp(lookup)", "transpose(jvp(lookup))"
     shape_of = {}                    # instruction -> dims, for the operands
-    kinds, table_scatters, gathers = {}, [], []
+    kinds, gathers, scatters, beside = {}, [], [], []
     for line in hlo.splitlines():
         m, shape = _INSTR.match(line), _SHAPE.search(line)
         if m and shape:
             shape_of[m.group(1)] = [
                 int(d) for d in shape.group(2).split(",") if d]
-        if not m or m.group(2) not in ("gather", "scatter", "sort", "while"):
+        if not m or m.group(2) not in (
+                "gather", "scatter", "sort", "while", "concatenate"):
             continue
         name_ = _OP_NAME.search(line)
         assert name_, line
-        if m.group(2) == "while" and "lookup" not in name_.group(1):
-            continue                 # XLA:CPU's threefry loops
+        if "lookup" not in name_.group(1):
+            # XLA:CPU's threefry loops, the tower's concatenates
+            assert m.group(2) in ("while", "concatenate"), line
+            continue
         scope, part = scope_of(name_.group(1))
         assert scope == "lookup" and part in (forward, backward), line
-        kinds[part, m.group(2)] = kinds.get((part, m.group(2)), 0) + 1
-        dims = shape_of[m.group(1)]
         in_loop = "/while/body/" in name_.group(1)
+        if m.group(2) == "concatenate" and not shape:
+            continue                 # the run numbering's, of pred
+        dims = tuple(shape_of[m.group(1)])
+        if m.group(2) == "concatenate":
+            beside.append((dims, part, in_loop))
+            continue
+        kinds[part, m.group(2)] = kinds.get((part, m.group(2)), 0) + 1
         if m.group(2) == "gather":
             operand = _OPERANDS.search(line[m.end() - 1:]).group(1).split(
                 ",")[0].strip().lstrip("%")
-            gathers.append((tuple(shape_of[operand]), part, in_loop))
-        if m.group(2) == "scatter" and dims and dims[0] == rows:
+            gathers.append((tuple(shape_of[operand]), dims[0], part, in_loop))
+        if m.group(2) == "scatter":
             promised = ("unique_indices=true" in line
                         and "indices_are_sorted=true" in line)
-            table_scatters.append((len(dims), promised))
-            assert part == backward and in_loop == (len(dims) > 1)
+            scatters.append((dims, promised, part, in_loop))
     # the run structure is the forward's: ids, runs' ids, run numbers back
     assert kinds[forward, "sort"] >= 3 and (backward, "sort") not in kinds
     assert kinds[forward, "while"] == 1 and kinds[backward, "while"] == 1
-    # FM_V's rows leave the table once a distinct row, inside the loop; the
-    # batch is expanded from the compact buffer; FM_W keeps XLA's gather
+    # every table's rows leave it once a distinct row, a chunk a trip inside
+    # the loop; the batch is expanded from the compact buffer, all columns
+    chunk = 2048
     by_operand = {}
-    for operand, part, in_loop in gathers:
+    for operand, indices, part, in_loop in gathers:
         assert part == forward
-        by_operand.setdefault(operand, []).append(in_loop)
-    assert by_operand.pop((rows, k)) == [True]
-    assert by_operand.pop((rows,)) == [False]
-    (compact, where), = by_operand.items()
-    assert where == [False] and compact[1] == k and n <= compact[0] < 2 * n
-    # FM_V by the chunk loop, FM_W by XLA's scatter-add
-    assert sorted(table_scatters) == [(1, False), (2, True)]
+        by_operand.setdefault(operand, []).append((indices, in_loop))
+    for table in tables:
+        assert by_operand.pop(table) == [(chunk, True)], table
+    (compact, expansion), = by_operand.items()
+    assert expansion == [(n, False)]
+    assert compact[1] == width and n <= compact[0] < 2 * n
+    # every table's gradient by the chunk loop, promised; the cotangents of
+    # all tables combined by one scatter-add into the compact buffer
+    assert all(part == backward for _, _, part, _ in scatters)
+    assert sorted((dims, promised, in_loop)
+                  for dims, promised, _, in_loop in scatters) == sorted(
+        [(table, True, True) for table in tables]
+        + [(compact, False, False)])
+    # side by side: a chunk's columns a trip forward, the cotangents backward
+    if len(tables) > 1:
+        assert sorted(beside) == sorted([((chunk, width), forward, True),
+                                         ((n, width), backward, False)])
+    else:
+        assert not beside
 
 
 def test_scopes_leave_the_lowered_step_as_it_was():
