@@ -17,7 +17,8 @@ has to come out NOT correct:
 * for the first ``--fault-seeds`` seeds, the reference with half of the batch
   left out of the loss's mean;
 * for ``--program-fault-seeds`` further builds, the program itself with that
-  fault planted inside its step's loss (its metrics see the whole batch).
+  fault planted inside its step's loss, in every loaded module that binds the
+  loss by name (its metrics see the whole batch).
 
 A state left unchanged reads 1 and needs no run.  Exits 1 if a verdict is not
 as it has to be (where the cell has no limits file yet, readings only).  Not
@@ -39,15 +40,42 @@ LOW = {"control": {"main": "bfloat16", "mlp_fp8": True},
        "control_fp8_only": {"mlp_fp8": True}}
 
 
+LOSS = "sigmoid_cross_entropy"
+
+
 def plant_half_batch_in_program():
     """The program's loss takes its mean over the first half of the rows;
-    everything else of the step, its metrics too, sees them all."""
-    from deepfm_tpu.parallel import spmd
+    everything else of the step, its metrics too, sees them all.  The loss is
+    swapped in every loaded ``deepfm_tpu`` module that binds it by name
+    (``from ... import`` copies the binding, and each step builder calls its
+    module's own), so the fault follows the loss wherever a later PR moves
+    it.  Returns the call that puts every binding back."""
+    from deepfm_tpu.parallel import spmd  # builds the cells' step: loaded
 
-    real = spmd.sigmoid_cross_entropy
-    spmd.sigmoid_cross_entropy = (
-        lambda logits, labels: real(logits, labels)[: labels.shape[0] // 2])
-    return lambda: setattr(spmd, "sigmoid_cross_entropy", real)
+    bound = [(mod, getattr(mod, LOSS))
+             for name, mod in sorted(sys.modules.items())
+             if name.split(".")[0] == "deepfm_tpu"
+             and callable(getattr(mod, LOSS, None))]
+    if not bound:
+        raise RuntimeError(f"no loaded deepfm_tpu module binds {LOSS}")
+
+    def half(real):
+        return lambda logits, labels: real(logits, labels)[
+            : labels.shape[0] // 2]
+
+    for mod, real in bound:
+        setattr(mod, LOSS, half(real))
+    # tests/test_perf_seam.py (tier-1) holds perf/ to naming the loss on spmd
+    # for as long as spmd binds it
+    if hasattr(spmd, LOSS) and any(
+            spmd.sigmoid_cross_entropy is real for _, real in bound):
+        raise RuntimeError("the step builder's loss was not swapped")
+
+    def unplant():
+        for mod, real in bound:
+            setattr(mod, LOSS, real)
+
+    return unplant
 
 
 def main(argv=None) -> int:

@@ -25,6 +25,55 @@ def test_short_op_names():
                           "kind=kLoop") == "t fusion:kLoop tuple"
 
 
+def test_a_loop_is_left_out_of_device_ops_where_its_body_is_listed(
+        monkeypatch):
+    """A synthetic TPU plane, two steps of: one fusion, a ``while`` of two
+    trips whose two body ops have events of their own, and a ``while`` with
+    nothing inside.  The first loop's time is its body's and is listed once;
+    the second stays; busy time and the step's device time, unions of
+    intervals, are what they were."""
+    from types import SimpleNamespace as NS
+
+    import jax.profiler
+
+    names = {
+        "adam": "%fusion.7 = (f32[8]{0}, f32[8]{0}) fusion(f32[8]{0} %p), "
+                "kind=kLoop, calls=%a",
+        "loop": "%while.3 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %t), "
+                "condition=%c, body=%b",
+        "read": "%fusion.271 = f32[4]{0} fusion(f32[8]{0} %p), kind=kCustom",
+        "write": "%fusion.274 = f32[8]{0} fusion(f32[4]{0} %r), kind=kCustom",
+        "bare": "%while.9 = (s32[]) while((s32[]) %u), condition=%d, body=%e",
+    }
+    ops, modules = [], []
+    for t in (0, 1000):
+        ops += [("adam", t, 300), ("loop", t + 300, 400),
+                ("read", t + 300, 100), ("write", t + 400, 100),
+                ("read", t + 500, 100), ("write", t + 600, 100),
+                ("bare", t + 700, 50)]
+        modules.append(("jit_local_step(1)", t, 750))
+    event = lambda name, start, dur: NS(name=name, start_ns=start,
+                                        duration_ns=dur)
+    plane = NS(name="/device:TPU:0", lines=[
+        NS(name=trace.OPS_LINE,
+           events=[event(names[k], s, d) for k, s, d in ops]),
+        NS(name=trace.MODULES_LINE,
+           events=[event(n, s, d) for n, s, d in modules])])
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file",
+                        lambda path: NS(planes=[plane]))
+    got = trace.reduce_xplane("synthetic", "local_step")
+    listed = dict(got["device_ops"])
+    assert listed == pytest.approx({
+        "fusion.7 fusion:kLoop tuple": 600e-9,
+        "fusion.271 fusion:kCustom f32[4]": 400e-9,
+        "fusion.274 fusion:kCustom f32[8]": 400e-9,
+        "while.9 while tuple": 100e-9})
+    assert sum(listed.values()) == pytest.approx(got["busy_s"])
+    assert got["busy_s"] == pytest.approx(1500e-9)
+    assert got["step_device_s"] == pytest.approx(1500e-9)
+    assert got["steps"] == 2 and got["window_s"] == pytest.approx(1750e-9)
+
+
 @pytest.fixture(scope="module")
 def reduced():
     return trace.reduce_xplane(str(XPLANE), "local_step")

@@ -76,6 +76,39 @@ def _break_timed_path(monkeypatch, kind):
     return lambda: None
 
 
+def test_the_planted_fault_follows_the_loss_into_every_module_that_binds_it():
+    """``from ..train.step import sigmoid_cross_entropy`` copies the binding,
+    and each step builder calls its module's own: the fault is planted in
+    every loaded ``deepfm_tpu`` module that binds the name (today
+    ``train.step``, ``train``, ``parallel.spmd``, ``tiered.step``), halves
+    what each returns, and every binding is put back."""
+    import numpy as np
+
+    from deepfm_tpu.parallel import spmd
+    from deepfm_tpu.tiered import step as tiered_step
+    from deepfm_tpu.train import step as train_step
+
+    from perf import control
+
+    binders = {name: mod for name, mod in sys.modules.items()
+               if name.split(".")[0] == "deepfm_tpu"
+               and hasattr(mod, control.LOSS)}
+    assert {spmd.__name__, train_step.__name__,
+            tiered_step.__name__} <= set(binders)
+    real = {name: getattr(mod, control.LOSS) for name, mod in binders.items()}
+    logits, labels = np.zeros(8, np.float32), np.ones(8, np.float32)
+    unplant = control.plant_half_batch_in_program()
+    try:
+        for name, mod in binders.items():
+            planted = getattr(mod, control.LOSS)
+            assert planted is not real[name], name
+            assert planted(logits, labels).shape == (4,), name
+    finally:
+        unplant()
+    for name, mod in binders.items():
+        assert getattr(mod, control.LOSS) is real[name], name
+
+
 @pytest.mark.parametrize("kind", ["unchanged", "half_batch_in_loss",
                                   "half_batch_fed"])
 @pytest.mark.parametrize("name", TINY_CELLS)

@@ -60,7 +60,12 @@ def test_flops_against_xla_cost_analysis(name, work):
     dense = {k: v for k, v in params.items() if k not in ("fm_w", "fm_v")}
 
     def loss(dense, emb_rows, w_rows):
-        lookup = lambda table, _ids: w_rows if table.ndim == 1 else emb_rows
+        # the models' protocol since PR 32: one table, or a tuple of tables
+        # read with the same ids, rows back in the same structure
+        pick = lambda table: w_rows if table.ndim == 1 else emb_rows
+        lookup = lambda tables, _ids: (
+            tuple(map(pick, tables)) if isinstance(tables, tuple)
+            else pick(tables))
         logits, _ = md.apply({**dense, "fm_w": params["fm_w"],
                               "fm_v": params["fm_v"]}, state, ids, vals,
                              cfg=cfg, train=False, lookup_fn=lookup)

@@ -63,6 +63,23 @@ def _label(gap, spans) -> str:
     return best
 
 
+_CONTAINER = re.compile(r" = .*? (?:while|conditional|call)\(")
+
+
+def leaf_ops(ops: list) -> list:
+    """The ``(start, end, name)`` op events without a ``while`` (or
+    ``conditional`` or ``call``) that has other ops' events inside its
+    interval: those are its body's, its time is theirs, and listing both
+    gives one time twice.  One whose body has no event of its own stays."""
+    ordered = sorted(ops, key=lambda o: (o[0], -o[1]))
+    out = []
+    for i, op in enumerate(ordered):
+        holds_another = i + 1 < len(ordered) and ordered[i + 1][1] <= op[1]
+        if not (holds_another and _CONTAINER.search(op[2])):
+            out.append(op)
+    return out
+
+
 _KIND = re.compile(r"kind=(\w+)")
 _OPCODE = re.compile(r"\b([a-z][a-z\-]*)\(")
 
@@ -86,7 +103,9 @@ def short_op(text: str) -> str:
 
 def reduce_xplane(path: str, step_module: str = "") -> dict:
     """-> {devices, busy_s, window_s, steps, step_device_s, device_ops,
-    idle_gaps}, averaged over the device planes.  ``step_module`` (a
+    idle_gaps}, averaged over the device planes.  ``device_ops`` lists a
+    loop's body ops and not the loop (``leaf_ops``); everything else is a
+    union of intervals and counts each moment once.  ``step_module`` (a
     substring of the train step's module name) picks which module events are
     counted as steps; empty counts the most frequent module."""
     from jax.profiler import ProfileData
@@ -138,7 +157,7 @@ def reduce_xplane(path: str, step_module: str = "") -> dict:
             lo, hi = min(s for s, _ in mods), max(e for _, e in mods)
             step_busy += union_seconds(
                 [(s, e) for s, e in iv if s >= lo and e <= hi]) / 1e9
-        for s, e, name in ops:
+        for s, e, name in leaf_ops(ops):
             by_op[name] = by_op.get(name, 0.0) + (e - s) / 1e9
         gaps_all += _gaps(iv)
     by_label = {}
