@@ -2251,7 +2251,8 @@ def audit_zero_update(cfg=None, context_builder=None) -> list[Finding]:
         return []
     from ..core.config import MeshConfig
     from ..parallel import abstract_spmd_state, build_mesh, make_context
-    from ..parallel.spmd import TABLE_KEYS, make_spmd_train_step
+    from ..models.base import table_keys
+    from ..parallel.spmd import make_spmd_train_step
     from ..train.optimizer import zero_layout_size
 
     dp, mp = _ZERO_AUDIT_MESH
@@ -2268,7 +2269,7 @@ def audit_zero_update(cfg=None, context_builder=None) -> list[Finding]:
     def _sharded_leaf(path, leaf):
         keys = {getattr(p, "key", None) for p in path}
         shape = tuple(leaf.shape)
-        shards = mp if (keys & set(TABLE_KEYS) and shape
+        shards = mp if (keys & set(table_keys()) and shape
                         and shape[0] == pv) else 1
         n = 1
         for d in shape:

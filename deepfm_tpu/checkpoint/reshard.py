@@ -67,13 +67,13 @@ def jit_row_adapter(sharding, rows_to: int):
 
 
 def _is_table_leaf(path) -> bool:
-    # the authoritative row-sharded-table key list, read at CALL time (a
-    # module-level import would drag the parallel -> models chain into
-    # this module's import; a copy would silently miss new tables)
-    from ..parallel.spmd import TABLE_KEYS
+    # every registered family's declared tables, read at CALL time (a
+    # module-level import would drag the models chain into this module's
+    # import; a copy would silently miss new tables)
+    from ..models.base import table_keys
 
     keys = {getattr(p, "key", None) for p in path}
-    return bool(keys & set(TABLE_KEYS))
+    return bool(keys & set(table_keys()))
 
 
 def _is_zero_leaf(path) -> bool:
@@ -229,14 +229,12 @@ def restore_resharded(
     restores through a template of ITS layout and relays on-device
     (:func:`relayout_state`).
     """
-    from ..parallel.spmd import _build_full_init
+    from ..parallel.spmd import abstract_spmd_state
 
     if plan is not None:
         plan.validate_target(ctx)
     # target template (shape inference only — nothing materializes)
-    init_fn = _build_full_init(ctx.cfg, ctx.true_feature_size,
-                               ctx.zero_layout)
-    target_shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    target_shapes = abstract_spmd_state(ctx)
 
     def alt_candidate():
         # the checkpoint may hold the OTHER opt-state layout (committed
@@ -245,12 +243,7 @@ def restore_resharded(
         # Built lazily — the steady state restores under the target
         # template and never pays this second abstract init trace.
         alt = _alt_layout_context(ctx)
-        alt_shapes = jax.eval_shape(
-            _build_full_init(alt.cfg, alt.true_feature_size,
-                             alt.zero_layout),
-            jax.random.PRNGKey(0),
-        )
-        return (alt_shapes, alt.state_shardings,
+        return (abstract_spmd_state(alt), alt.state_shardings,
                 lambda got: relayout_state(
                     got, target_shapes, ctx.state_shardings))
 
@@ -278,15 +271,13 @@ def restore_resharded_payload(
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..online.trainer import _CURSOR_BYTES, OnlinePayload
-    from ..parallel.spmd import _build_full_init
+    from ..parallel.spmd import abstract_spmd_state
 
     if plan is not None:
         plan.validate_target(ctx)
 
     def payload_templates(c):
-        init_fn = _build_full_init(c.cfg, c.true_feature_size,
-                                   c.zero_layout)
-        train_shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+        train_shapes = abstract_spmd_state(c)
         shapes = OnlinePayload(
             step=jax.ShapeDtypeStruct((), jnp.int32),
             train=train_shapes,

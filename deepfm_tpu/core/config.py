@@ -375,13 +375,18 @@ class DataConfig:
     permute_ids: bool = False
 
 
+# the mesh's axis names: fixed framework-wide — they appear in every sharding
+# rule and in the model contract's losses (models/base.py), so they are
+# constants, not configuration (re-exported by parallel/mesh.py)
+DATA_AXIS = "data"
+MODEL_AXIS = "model"
+
+
 @dataclass(frozen=True)
 class MeshConfig:
     """Device mesh topology.  Replaces PS topology flags (ps:38-48) and
-    Horovod rank plumbing (hvd:333-350) with named mesh axes.  The axis
-    NAMES are fixed framework-wide ("data"/"model",
-    parallel/mesh.DATA_AXIS/MODEL_AXIS) — they appear in every sharding
-    rule, so they are constants, not configuration."""
+    Horovod rank plumbing (hvd:333-350) with named mesh axes
+    (``DATA_AXIS`` / ``MODEL_AXIS`` above)."""
 
     # -1 = all remaining devices on that axis
     data_parallel: int = -1

@@ -76,7 +76,7 @@ from ..parallel import (
 )
 from ..obs import flight as obs_flight
 from ..obs.metrics import MetricsRegistry
-from ..parallel.spmd import TABLE_KEYS
+from ..models.base import get_model, require_fields, table_keys
 from ..train.step import TrainState
 from ..utils import MetricLogger
 from .coord import Fence, StaleFencingTokenError
@@ -129,11 +129,10 @@ class ElasticTrainer:
                 "consensus + lease fencing there is no single enforced "
                 "logical writer over the event log (elastic/coord.py)"
             )
-        if cfg.model.model_name == "two_tower":
-            raise ValueError(
-                "elastic training covers the CTR families (the event-log "
-                "schema; online/trainer.py has the same boundary)"
-            )
+        # the event-log schema (online/trainer.py has the same boundary)
+        require_fields(get_model(cfg.model), cfg.model,
+                       ("feat_ids", "feat_vals", "label"),
+                       "elastic training's event log")
         self.cfg = cfg
         self.registry = registry if registry is not None \
             else VirtualDeviceRegistry()
@@ -343,7 +342,7 @@ class ElasticTrainer:
         true_vocab = topo.ctx.true_feature_size
         params = {}
         for k, v in state.params.items():
-            if k in TABLE_KEYS and hasattr(v, "shape") and v.ndim >= 1 \
+            if k in table_keys() and hasattr(v, "shape") and v.ndim >= 1 \
                     and v.shape[0] != true_vocab:
                 params[k] = np.asarray(jax.device_get(v))[:true_vocab]
             else:

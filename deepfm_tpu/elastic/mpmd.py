@@ -44,7 +44,7 @@ from ..obs import flight as obs_flight
 from ..obs.metrics import MetricsRegistry
 from ..online.publisher import ModelPublisher, latest_manifest
 from ..online.trainer import cursor_from_arrays
-from ..parallel.spmd import TABLE_KEYS
+from ..models.base import table_keys
 from ..utils import MetricLogger
 from .coord import (
     CoordClient,
@@ -107,7 +107,7 @@ def servable_from_payload(cfg: Config, tree: dict):
     train = tree["train"]
     params = dict(train["params"])
     true_vocab = cfg.model.feature_size
-    for k in TABLE_KEYS:
+    for k in table_keys():
         v = params.get(k)
         if v is not None and hasattr(v, "shape") and v.ndim >= 1 \
                 and v.shape[0] != true_vocab:

@@ -37,7 +37,7 @@ from typing import Any
 
 # the authoritative row-sharded-table key list (parallel/spmd.py drives
 # every sharding rule from it; a copy here would silently miss new tables)
-from ..parallel.spmd import TABLE_KEYS
+from ..models.base import table_keys
 
 
 def choose_mesh(
@@ -126,14 +126,14 @@ class ReshardPlan:
 
 def _is_table_path(path) -> bool:
     keys = {getattr(p, "key", None) for p in path}
-    return bool(keys & set(TABLE_KEYS))
+    return bool(keys & set(table_keys()))
 
 
 def plan_reshard(old_ctx, new_ctx) -> ReshardPlan:
     """Draw the minimal-traffic plan between two SPMD contexts.
 
     Shape inference only: table leaves are identified by path (the
-    TABLE_KEYS discipline of ``parallel/spmd._spec_for_leaf``), their
+    declared-tables discipline of ``parallel/spmd._spec_for_leaf``), their
     per-device row windows intersected between topologies, and the
     residual — window rows that existed in the old table but were not
     held by the device that now owns them — is the plan's traffic.  Rows

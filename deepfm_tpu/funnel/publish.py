@@ -180,9 +180,9 @@ def load_funnel_artifact(directory: str) -> FunnelArtifact:
         meta = json.load(f)
     rank_dir = os.path.join(directory, "rank")
     rank_cfg = _load_config(rank_dir)
-    if rank_cfg.model.model_name == "two_tower":
-        raise ValueError("the funnel's rank/ servable must be a CTR model")
     model = get_model(rank_cfg.model)
+    if model.apply is None:  # no scoring call to rank with
+        raise ValueError("the funnel's rank/ servable must be a CTR model")
     rank_params, rank_state = _restore_payload(
         rank_dir, lambda: model.init(jax.random.PRNGKey(0), rank_cfg.model)
     )

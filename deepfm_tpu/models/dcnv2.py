@@ -26,8 +26,8 @@ from ..core.config import ModelConfig
 from ..ops.batch_norm import bn_init
 from ..ops.embedding import dense_lookup, narrow_ids, scaled_embedding
 from ..ops.initializers import glorot_normal, glorot_uniform
-from .base import register_model
-from .deepfm import apply_mlp, deepfm_l2_penalty, init_mlp
+from .click_through import register_click_through
+from .deepfm import apply_mlp, init_mlp
 
 
 def init_cross(key: jax.Array, dim: int, num_layers: int) -> dict:
@@ -120,4 +120,7 @@ def apply_dcnv2(
     return logits, new_state
 
 
-register_model("dcnv2", init_dcnv2, apply_dcnv2, deepfm_l2_penalty)
+register_click_through(
+    "dcnv2", init_dcnv2, apply_dcnv2,
+    tables={"fm_v": "feature_size"},
+)

@@ -29,7 +29,7 @@ from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.fm import fm_first_order, fm_second_order
 from ..ops.initializers import glorot_normal, glorot_uniform
 from ..ops.pallas_ctr import fused_ctr_interaction, resolve_fused
-from .base import register_model
+from .click_through import register_click_through
 
 
 def init_mlp(key: jax.Array, in_dim: int, cfg: ModelConfig) -> dict:
@@ -187,15 +187,7 @@ def apply_deepfm(
     return logits, new_state
 
 
-def deepfm_l2_penalty(params: dict, l2_reg: float) -> jnp.ndarray:
-    """``l2_reg·(l2_loss(FM_W)+l2_loss(FM_V))`` where l2_loss = ½Σx²
-    (ps:275-279).  The MLP L2 in the reference went to a collection that was
-    never added to the loss (SURVEY §2a) — intentionally not applied."""
-    total = jnp.zeros(())
-    for key in ("fm_w", "fm_v", "embedding"):  # sparse tables only, per reference
-        if key in params:
-            total = total + jnp.sum(jnp.square(params[key]))
-    return l2_reg * 0.5 * total
-
-
-register_model("deepfm", init_deepfm, apply_deepfm, deepfm_l2_penalty)
+register_click_through(
+    "deepfm", init_deepfm, apply_deepfm,
+    tables={"fm_w": "feature_size", "fm_v": "feature_size"},
+)

@@ -15,15 +15,17 @@ Architecture (dual encoder, Yi et al. RecSys'19 style):
 
 Batch schema: ``{"user_ids" [B,Fu] i64, "user_vals" [B,Fu] f32,
 "item_ids" [B,Fi] i64, "item_vals" [B,Fi] f32}`` (vals of 1.0 for pure-id
-features).  This family has its own train/eval steps (train/retrieval.py
-dense, parallel/retrieval.py sharded) because the loss couples examples
-across the batch — the sharded step all-gathers item encodings over the
-``data`` axis so every chip scores its queries against the GLOBAL batch's
-items, with the gather riding ICI.
+features).  The family trains through the shared step builders like every
+other (``models/base.py``): what is its own is its loss, which couples
+examples across the batch — called on one data shard's rows, it all-gathers
+the item encodings over the ``data`` axis so every chip scores its queries
+against the GLOBAL batch's items, with the gather (and its transpose, the
+reduce-scatter of item-encoder gradients) riding ICI.  Sharded loss == dense
+full-batch loss, because softmax rows are complete on every shard: sharding
+changes WHERE rows are computed, never the candidate pool.
 
-Tables are row-shardable over the ``model`` axis exactly like FM_W/FM_V
-(params keys "user_embedding"/"item_embedding" are in parallel.spmd
-TABLE_KEYS).
+Both tables are row-shardable over the ``model`` axis exactly like FM_W/FM_V,
+each with a vocabulary of its own.
 """
 
 from __future__ import annotations
@@ -32,10 +34,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from ..core.config import ModelConfig
+from ..core.config import DATA_AXIS, ModelConfig
 from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.initializers import glorot_normal, glorot_uniform
+from .base import BatchField, ModelDef, register_model
 
 
 class TowerOutputs(NamedTuple):
@@ -133,19 +137,15 @@ def apply_two_tower(
     *,
     cfg: ModelConfig,
     lookup_fn=dense_lookup,
-    user_lookup_fn=None,
-    item_lookup_fn=None,
 ) -> TowerOutputs:
-    """Encode the batch's users and items.  ``user_lookup_fn``/
-    ``item_lookup_fn`` override ``lookup_fn`` per table (the sharded path
-    passes per-table lookups since the two vocabs shard independently)."""
+    """Encode the batch's users and items."""
     u = encode_tower(
         params, batch["user_ids"], batch["user_vals"],
-        cfg=cfg, side="user", lookup_fn=user_lookup_fn or lookup_fn,
+        cfg=cfg, side="user", lookup_fn=lookup_fn,
     )
     i = encode_tower(
         params, batch["item_ids"], batch["item_vals"],
-        cfg=cfg, side="item", lookup_fn=item_lookup_fn or lookup_fn,
+        cfg=cfg, side="item", lookup_fn=lookup_fn,
     )
     return TowerOutputs(user=u, item=i)
 
@@ -183,10 +183,70 @@ def retrieval_metrics(
     }
 
 
-def two_tower_l2_penalty(params: dict, l2_reg: float) -> jnp.ndarray:
-    """Reference-style sparse-table L2 (ps:275-279 semantics) over both
-    embedding tables; tower dense weights excluded."""
-    total = jnp.zeros(())
-    for k in ("user_embedding", "item_embedding"):
-        total = total + jnp.sum(jnp.square(params[k]))
-    return l2_reg * 0.5 * total
+def two_tower_batch(cfg: ModelConfig) -> dict[str, BatchField]:
+    fu, fi = cfg.user_field_size, cfg.item_field_size
+    return {
+        "user_ids": BatchField((fu,), "int64", table="user_embedding"),
+        "user_vals": BatchField((fu,), "float32"),
+        "item_ids": BatchField((fi,), "int64", table="item_embedding"),
+        "item_vals": BatchField((fi,), "float32"),
+    }
+
+
+def two_tower_loss(params, model_state, batch, *, cfg, train=False, rng=None,
+                   lookup_fn=None):
+    """Local towers -> global item pool -> mean in-batch-softmax CE of this
+    shard's queries (equal-sized shards: the step's pmean of local means is
+    the global batch mean); ``outputs`` are the [b, B_global] scores and the
+    index of each query's positive in the pool."""
+    towers = apply_two_tower(
+        params, batch, cfg=cfg, lookup_fn=lookup_fn or dense_lookup)
+    b = towers.user.shape[0]
+    items_all = lax.all_gather(towers.item, DATA_AXIS, axis=0, tiled=True)
+    labels = lax.axis_index(DATA_AXIS) * b + jnp.arange(b)
+    with jax.named_scope("loss"):
+        ce, scores = in_batch_softmax_loss(
+            towers.user, items_all, labels, temperature=cfg.temperature
+        )
+        loss = jnp.mean(ce)
+    return loss, model_state, (scores, labels)
+
+
+TWO_TOWER_METRICS = {
+    k: (lambda outputs, batch, k=k: retrieval_metrics(*outputs)[k])
+    for k in ("top1_acc", "recall_at_10")
+}
+
+
+def two_tower_evaluate(acc, params, model_state, batch, weight, *, cfg,
+                       lookup_fn=None):
+    """Mean loss and retrieval metrics of one full global batch: in-batch
+    metrics need a constant candidate pool, so there is no padded tail and
+    no row weights, and nothing to accumulate beyond the step's means."""
+    if weight is not None:
+        raise ValueError(
+            "two_tower evaluates full batches only: a padded, weighted tail "
+            "would change every row's candidate pool"
+        )
+    loss, _, outputs = two_tower_loss(
+        params, model_state, batch, cfg=cfg, lookup_fn=lookup_fn)
+    scalars = {"loss": loss, **retrieval_metrics(*outputs)}
+    scalars = {k: lax.pmean(v, DATA_AXIS) for k, v in scalars.items()}
+    scalars["count"] = lax.psum(
+        jnp.asarray(outputs[0].shape[0], jnp.float32), DATA_AXIS)
+    return acc, scalars
+
+
+register_model(ModelDef(
+    name="two_tower",
+    init=init_two_tower,
+    apply=None,
+    tables={"user_embedding": "user_vocab_size",
+            "item_embedding": "item_vocab_size"},
+    batch=two_tower_batch,
+    loss=two_tower_loss,
+    metrics=TWO_TOWER_METRICS,
+    eval_init=tuple,
+    evaluate=two_tower_evaluate,
+    eval_summary=lambda acc: {},
+))

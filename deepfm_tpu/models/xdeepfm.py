@@ -31,8 +31,8 @@ from ..ops.batch_norm import bn_init
 from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.fm import fm_first_order
 from ..ops.initializers import glorot_normal, glorot_uniform
-from .base import register_model
-from .deepfm import apply_mlp, deepfm_l2_penalty, init_mlp
+from .click_through import register_click_through
+from .deepfm import apply_mlp, init_mlp
 
 
 def init_cin(key: jax.Array, cfg: ModelConfig) -> dict:
@@ -155,4 +155,7 @@ def apply_xdeepfm(
     return logits, new_state
 
 
-register_model("xdeepfm", init_xdeepfm, apply_xdeepfm, deepfm_l2_penalty)
+register_click_through(
+    "xdeepfm", init_xdeepfm, apply_xdeepfm,
+    tables={"fm_w": "feature_size", "fm_v": "feature_size"},
+)

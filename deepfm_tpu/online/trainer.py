@@ -35,6 +35,7 @@ import numpy as np
 
 from ..checkpoint import make_checkpointer
 from ..core.config import Config
+from ..models.base import get_model, require_fields
 from ..train.step import TrainState, create_train_state, jitted_train_step
 from ..utils import MetricLogger
 from .publisher import ModelPublisher
@@ -221,11 +222,11 @@ class OnlineTrainer:
                 "online training is single-process (one logical writer); "
                 "multi-host serving scales on the read side instead"
             )
-        if cfg.model.model_name == "two_tower":
-            raise ValueError(
-                "online training covers the CTR families; the two-tower "
-                "ratings feed has no event-log schema yet"
-            )
+        # the event log holds click-through records; no other batch has an
+        # event-log schema yet
+        require_fields(get_model(cfg.model), cfg.model,
+                       ("feat_ids", "feat_vals", "label"),
+                       "online training's event log")
         self.cfg = cfg
         self._stream_root = stream_root or cfg.data.training_data_dir
         self._publish_root = publish_root or cfg.run.servable_model_dir

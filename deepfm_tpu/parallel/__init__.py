@@ -23,11 +23,3 @@ from .spmd import (  # noqa: F401
     shard_batch,
     shard_batch_stacked,
 )
-from .retrieval import (  # noqa: F401
-    RetrievalContext,
-    create_retrieval_spmd_state,
-    make_retrieval_context,
-    make_retrieval_spmd_eval_step,
-    make_retrieval_spmd_train_step,
-    shard_retrieval_batch,
-)

@@ -20,10 +20,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from ..core.config import MeshConfig
-
-DATA_AXIS = "data"
-MODEL_AXIS = "model"
+from ..core.config import DATA_AXIS, MODEL_AXIS, MeshConfig  # noqa: F401
 
 
 def initialize_distributed(cfg: MeshConfig) -> None:

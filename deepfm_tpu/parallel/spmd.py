@@ -23,7 +23,7 @@ the only (infinitesimal) effect.
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -32,15 +32,14 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import Config
-from ..models.base import get_model
+from ..models.base import ModelDef, get_model, require_fields, table_rows
 from ..obs.trace import get_span_recorder
-from ..ops.auc import AUCState, auc_init, auc_update
 from ..train.optimizer import (
     build_optimizer,
     resolve_zero_sharding,
     zero_sharded,
 )
-from ..train.step import TrainState, sigmoid_cross_entropy
+from ..train.step import TrainState
 from .embedding import (
     exchange_capacity,
     lookup_fn_from_config,
@@ -49,17 +48,19 @@ from .embedding import (
 )
 from .mesh import DATA_AXIS, MODEL_AXIS, mesh_shape
 
-# params keys treated as row-sharded embedding tables (must match the model
-# families' table naming and ModelDef.l2_penalty conventions)
-TABLE_KEYS = ("fm_w", "fm_v", "embedding", "user_embedding", "item_embedding")
+# the evaluation step's own optional batch field ([B] f32 row weights): not
+# part of any family's declared batch, placed and sharded like one
+WEIGHT_FIELD = "weight"
 
 
 class SPMDContext(NamedTuple):
     """Everything needed to run sharded steps: the padded config, mesh, and
     the sharding pytrees for state and batches."""
 
-    cfg: Config                 # with feature_size padded for the mesh
-    true_feature_size: int      # pre-padding vocab (for data validation)
+    cfg: Config                 # with every declared table padded for the mesh
+    # pre-padding ``model.feature_size``: what the click-through families'
+    # readers and generators bound their ids by (``table_rows`` holds it too)
+    true_feature_size: int
     mesh: Mesh
     state_specs: Any            # PartitionSpec pytree matching TrainState
     state_shardings: Any        # NamedSharding pytree matching TrainState
@@ -72,6 +73,9 @@ class SPMDContext(NamedTuple):
     # of (cfg.optimizer, dp); make_context's ``zero_layout`` override
     # exists for restore templates that must describe the OTHER layout.
     zero_layout: bool = False
+    # TRUE (pre-padding) row count of every table the model declares: what
+    # the placer validates each id field against and init zeroes rows past
+    table_rows: Mapping[str, int] = {}
 
 
 def padded_vocab(
@@ -97,13 +101,15 @@ def _window_multiple(cfg: Config) -> int:
 
 
 def _spec_for_leaf(
-    path, shape: tuple[int, ...], vocab: int, dp: int = 1, mp: int = 1
+    path, shape: tuple[int, ...], rows: Mapping[str, int], dp: int = 1,
+    mp: int = 1
 ) -> P:
-    """Row-shard exactly the leaves living under a TABLE_KEYS dict key whose
-    leading dim is the (padded) vocab — this covers the params and their
-    optimizer-state moments (optax states mirror the param tree, so the same
-    dict keys appear in their paths).  Path-based matching cannot collide
-    with an MLP kernel that happens to share a dimension.
+    """Row-shard exactly the leaves living under a declared table's dict key
+    (``rows``: table key -> padded row count) whose leading dim is that
+    table's rows — this covers the params and their optimizer-state moments
+    (optax states mirror the param tree, so the same dict keys appear in
+    their paths).  Path-based matching cannot collide with an MLP kernel that
+    happens to share a dimension.
 
     Leaves under a ``zero_dp`` marker (train/optimizer.ZeroDpState — the
     dp-partitioned weight-update state) are the FLATTENED canonical
@@ -114,9 +120,12 @@ def _spec_for_leaf(
     the standard row-shard rule; eligibility is a pure function of
     (length, mp, dp), so the 1-D fm_w ambiguity resolves itself: the
     flat layout EXISTS exactly when the divisibility test passes."""
-    keys = {getattr(p, "key", None) for p in path}
+    table = next(
+        (k for k in (getattr(p, "key", None) for p in path) if k in rows),
+        None,
+    )
     if any(getattr(p, "name", None) == "zero_dp" for p in path):
-        if keys & set(TABLE_KEYS):
+        if table is not None:
             if (len(shape) == 1 and shape[0] > 0 and shape[0] % mp == 0
                     and (shape[0] // mp) % dp == 0):
                 return P((MODEL_AXIS, DATA_AXIS))
@@ -125,7 +134,7 @@ def _spec_for_leaf(
             return P(DATA_AXIS)
         elif len(shape) == 0:
             return P()
-    if keys & set(TABLE_KEYS) and len(shape) >= 1 and shape[0] == vocab:
+    if table is not None and len(shape) >= 1 and shape[0] == rows[table]:
         return P(MODEL_AXIS, *([None] * (len(shape) - 1)))
     return P()
 
@@ -143,18 +152,18 @@ def _build_tx(cfg: Config, zero_layout: bool):
             tx,
             dp=cfg.mesh.data_parallel,
             mp=cfg.mesh.model_parallel,
-            vocab=cfg.model.feature_size,
+            table_rows=table_rows(get_model(cfg.model), cfg.model),
             data_axis=DATA_AXIS,
             model_axis=MODEL_AXIS,
-            table_keys=TABLE_KEYS,
         )
     return tx
 
 
 def _build_full_init(
-    cfg: Config, true_vocab: int, zero_layout: bool = False
+    cfg: Config, true_rows: Mapping[str, int], zero_layout: bool = False
 ) -> Callable:
-    """Initializer for the full TrainState with zeroed pad rows."""
+    """Initializer for the full TrainState with zeroed pad rows (``cfg``
+    holds the padded row counts, ``true_rows`` each table's own)."""
     model = get_model(cfg.model)
     tx = _build_tx(cfg, zero_layout)
 
@@ -163,12 +172,11 @@ def _build_full_init(
 
         init_key, step_key = jax.random.split(key)
         params, model_state = model.init(init_key, cfg.model)
-        for k in TABLE_KEYS:
-            if k in params:
-                rows = jnp.arange(params[k].shape[0])
-                keep = rows < true_vocab
-                mask = keep if params[k].ndim == 1 else keep[:, None]
-                params[k] = jnp.where(mask, params[k], 0)
+        for k, true in true_rows.items():
+            rows = jnp.arange(params[k].shape[0])
+            keep = rows < true
+            mask = keep if params[k].ndim == 1 else keep[:, None]
+            params[k] = jnp.where(mask, params[k], 0)
         return TrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,
@@ -184,53 +192,55 @@ def make_context(
     cfg: Config, mesh: Mesh, *, zero_layout: bool | None = None
 ) -> SPMDContext:
     """Compute sharding specs for the TrainState via shape inference only —
-    no parameter materialization (the 100M-vocab table never touches a host).
+    no parameter materialization (the 100M-vocab table never touches a host)
+    — and for the batch from the fields the model declares.
 
     ``zero_layout`` overrides the ``optimizer.zero_sharding`` resolution
     (None = resolve from config) — used by the cross-topology restore to
     build a template describing the OTHER opt-state layout
     (checkpoint/reshard.py); training contexts leave it None."""
     dp, mp = mesh_shape(mesh)
-    true_vocab = cfg.model.feature_size
-    pv = padded_vocab(true_vocab, mp, _window_multiple(cfg))
+    model = get_model(cfg.model)
+    true_rows = table_rows(model, cfg.model)
+    true_feature_size = cfg.model.feature_size
+    window = _window_multiple(cfg)
     cfg = cfg.with_overrides(
-        model={"feature_size": pv},
+        model={field: padded_vocab(true_rows[k], mp, window)
+               for k, field in model.tables.items()},
         mesh={"data_parallel": dp, "model_parallel": mp},
     )
+    padded_rows = table_rows(model, cfg.model)
     if zero_layout is None:
         zero_layout = resolve_zero_sharding(cfg.optimizer, dp)
-    init_fn = _build_full_init(cfg, true_vocab, zero_layout)
+    init_fn = _build_full_init(cfg, true_rows, zero_layout)
     shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
     state_specs = jax.tree_util.tree_map_with_path(
-        lambda p, s: _spec_for_leaf(p, s.shape, pv, dp, mp), shapes
+        lambda p, s: _spec_for_leaf(p, s.shape, padded_rows, dp, mp), shapes
     )
     state_shardings = jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), state_specs
     )
     batch_specs = {
-        "feat_ids": P(DATA_AXIS, None),
-        "feat_vals": P(DATA_AXIS, None),
-        "label": P(DATA_AXIS),
+        name: P(DATA_AXIS, *([None] * len(field.shape)))
+        for name, field in model.batch(cfg.model).items()
     }
-    batch_shardings = jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), batch_specs,
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    batch_shardings = {
+        name: NamedSharding(mesh, spec) for name, spec in batch_specs.items()
+    }
     # eval-only optional field (not part of batch_specs: train steps never
     # receive it, and shard_map in_specs must match the pytree exactly)
-    batch_shardings["weight"] = NamedSharding(mesh, P(DATA_AXIS))
+    batch_shardings[WEIGHT_FIELD] = NamedSharding(mesh, P(DATA_AXIS))
     return SPMDContext(
-        cfg, true_vocab, mesh, state_specs, state_shardings, batch_specs,
-        batch_shardings, zero_layout,
+        cfg, true_feature_size, mesh, state_specs, state_shardings,
+        batch_specs, batch_shardings, zero_layout, true_rows,
     )
 
 
 def abstract_spmd_state(ctx: SPMDContext) -> TrainState:
     """ShapeDtypeStruct pytree of the TrainState — for lowering-only
-    consumers (the trace-time collective audit) that must never
-    materialize the tables."""
-    init_fn = _build_full_init(ctx.cfg, ctx.true_feature_size,
-                               ctx.zero_layout)
+    consumers (the trace-time collective audit, the restore templates) that
+    must never materialize the tables."""
+    init_fn = _build_full_init(ctx.cfg, ctx.table_rows, ctx.zero_layout)
     return jax.eval_shape(init_fn, jax.random.PRNGKey(0))
 
 
@@ -239,21 +249,19 @@ def create_spmd_state(ctx: SPMDContext, key: jax.Array | None = None) -> TrainSt
     each table shard on its own device (deterministic across replicas — the
     BroadcastGlobalVariablesHook capability, hvd:417-418, by construction)."""
     key = jax.random.PRNGKey(ctx.cfg.run.seed) if key is None else key
-    init_fn = _build_full_init(ctx.cfg, ctx.true_feature_size,
-                               ctx.zero_layout)
+    init_fn = _build_full_init(ctx.cfg, ctx.table_rows, ctx.zero_layout)
     with ctx.mesh:
         return jax.jit(init_fn, out_shardings=ctx.state_shardings)(key)
 
 
 @jax.named_scope("l2_penalty")
-def _sharded_penalty(params: dict, l2_reg: float) -> jnp.ndarray:
+def _sharded_penalty(model: ModelDef, params: dict, l2_reg: float) -> jnp.ndarray:
     """Reference loss regularizer (ps:275-279) over row-sharded tables:
-    ½·psum_model(Σ local²) per table.  Mirrors ModelDef.l2_penalty's
-    TABLE_KEYS convention for the sharded case."""
+    ½·psum_model(Σ local²) per declared table — ``ModelDef.l2_penalty`` for
+    the sharded case."""
     total = jnp.zeros(())
-    for k in TABLE_KEYS:
-        if k in params:
-            total = total + sharded_l2(params[k])
+    for k in model.tables:
+        total = total + sharded_l2(params[k])
     return l2_reg * total
 
 
@@ -274,7 +282,7 @@ def _sync_model_state(model_state):
 
 
 @jax.named_scope("grad_sync")
-def _pmean_grads(grads: dict) -> dict:
+def _pmean_grads(model: ModelDef, grads: dict) -> dict:
     """Sync gradients: every leaf pmean-ed over the data axis (the Horovod
     DistributedOptimizer capability, hvd:296); replicated (non-table) leaves
     additionally pmean-ed over the model axis — numerically a no-op since
@@ -284,46 +292,58 @@ def _pmean_grads(grads: dict) -> dict:
     def sync_entry(path, g):
         g = lax.pmean(g, DATA_AXIS)
         top = getattr(path[0], "key", None) if path else None
-        if top not in TABLE_KEYS:
+        if top not in model.tables:
             g = lax.pmean(g, MODEL_AXIS)
         return g
 
     return jax.tree_util.tree_map_with_path(sync_entry, grads)
 
 
-def _local_loss(cfg: Config, model, params, model_state, batch, rng, train):
-    lookup = lookup_fn_from_config(cfg)
-    logits, new_state = model.apply(
+def _local_loss(cfg: Config, model: ModelDef, params, model_state, batch,
+                rng, train):
+    """One data shard's loss: the family's data loss plus the table L2."""
+    data_loss, new_state, outputs = model.loss(
         params,
         model_state,
-        batch["feat_ids"],
-        batch["feat_vals"],
+        batch,
         cfg=cfg.model,
         train=train,
         rng=rng,
-        lookup_fn=lookup,
+        lookup_fn=lookup_fn_from_config(cfg),
     )
-    with jax.named_scope("loss"):
-        labels = batch["label"].reshape(-1).astype(jnp.float32)
-        ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
-    loss = ce + _sharded_penalty(params, cfg.model.l2_reg)
-    return loss, (ce, logits, new_state)
+    loss = data_loss + _sharded_penalty(model, params, cfg.model.l2_reg)
+    return loss, (data_loss, outputs, new_state)
 
 
-_TRAIN_METRIC_SPECS = {
-    "loss": P(),
-    "ce": P(),
-    "pred_mean": P(),
-    "label_mean": P(),
-    "loss_per_shard": P(DATA_AXIS),
-}
+def _train_metric_specs(model: ModelDef) -> dict:
+    """What every train step returns: the step's own scalars, the family's
+    (``metrics``), and the per-shard loss."""
+    return {
+        **{k: P() for k in ("loss", "ce", *model.metrics)},
+        "loss_per_shard": P(DATA_AXIS),
+    }
+
+
+def _train_metrics(model: ModelDef, loss, ce, outputs, batch) -> dict:
+    with jax.named_scope("metrics"):
+        return {
+            "loss": lax.pmean(loss, DATA_AXIS),
+            # the bare data loss: the cross-path comparable quantity
+            # (docs/PARITY.md)
+            "ce": lax.pmean(ce, DATA_AXIS),
+            **{k: lax.pmean(fn(outputs, batch), DATA_AXIS)
+               for k, fn in model.metrics.items()},
+            # per-data-shard local loss, [dp] — observability into shard
+            # skew (and the per-shard dropout-mask invariant, see tests)
+            "loss_per_shard": loss[None],
+        }
 
 
 def _build_local_train_step(ctx: SPMDContext) -> Callable:
     """The per-shard ``(state, batch) -> (state, metrics)`` body (dense or
     lazy by config) — shared by the one-step dispatcher
     (``make_spmd_train_step``) and the scanned multi-step loop
-    (``make_spmd_train_loop``).  Metrics follow ``_TRAIN_METRIC_SPECS``."""
+    (``make_spmd_train_loop``).  Metrics follow ``_train_metric_specs``."""
     cfg = ctx.cfg
     model = get_model(cfg.model)
     tx = _build_tx(cfg, ctx.zero_layout)
@@ -340,7 +360,7 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
                 cfg, model, params, state.model_state, batch, step_rng, True
             )
 
-        (loss, (ce, logits, new_model_state)), grads = jax.value_and_grad(
+        (loss, (ce, outputs, new_model_state)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params)
         new_model_state = _sync_model_state(new_model_state)
@@ -353,25 +373,13 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
                 grads, state.opt_state, state.params
             )
         else:
-            grads = _pmean_grads(grads)
+            grads = _pmean_grads(model, grads)
             with jax.named_scope("optimizer"):
                 updates, new_opt_state = tx.update(
                     grads, state.opt_state, state.params
                 )
                 new_params = optax.apply_updates(state.params, updates)
-        with jax.named_scope("metrics"):
-            metrics = {
-                "loss": lax.pmean(loss, DATA_AXIS),
-                "ce": lax.pmean(ce, DATA_AXIS),
-                "pred_mean": lax.pmean(
-                    jnp.mean(jax.nn.sigmoid(logits)), DATA_AXIS),
-                "label_mean": lax.pmean(
-                    jnp.mean(batch["label"].astype(jnp.float32)), DATA_AXIS
-                ),
-                # per-data-shard local loss, [dp] — observability into shard
-                # skew (and the per-shard dropout-mask invariant, see tests)
-                "loss_per_shard": loss[None],
-            }
+        metrics = _train_metrics(model, loss, ce, outputs, batch)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -394,7 +402,8 @@ def make_spmd_train_step(ctx: SPMDContext, *, donate: bool = True) -> Callable:
         _build_local_train_step(ctx),
         mesh=ctx.mesh,
         in_specs=(ctx.state_specs, ctx.batch_specs),
-        out_specs=(ctx.state_specs, _TRAIN_METRIC_SPECS),
+        out_specs=(ctx.state_specs,
+                   _train_metric_specs(get_model(ctx.cfg.model))),
         check_vma=False,  # grads of psum-assembled lookups defeat replication checking
     )
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
@@ -426,7 +435,8 @@ def make_spmd_train_loop(
         k: _stack_leading(s) for k, s in ctx.batch_specs.items()
     }
     stacked_metric_specs = {
-        k: _stack_leading(s) for k, s in _TRAIN_METRIC_SPECS.items()
+        k: _stack_leading(s)
+        for k, s in _train_metric_specs(get_model(ctx.cfg.model)).items()
     }
     mapped = shard_map(
         local_loop,
@@ -461,6 +471,9 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
     from ..train.step import LAZY_TABLE_KEYS
 
     cfg = ctx.cfg
+    # the touched rows are the click-through batch's ids
+    require_fields(model, cfg.model, ("feat_ids",),
+                   "lazy_embedding_updates")
     true_vocab = ctx.true_feature_size
     from ..train.optimizer import build_lr_schedule, schedule_value
 
@@ -521,19 +534,15 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
             }
 
         def loss_fn(rest, rows):
-            logits, new_state = model.apply(
+            ce, new_state, logits = model.loss(
                 {**rest, **tables},
                 state.model_state,
-                batch["feat_ids"],
-                batch["feat_vals"],
+                batch,
                 cfg=cfg.model,
                 train=True,
                 rng=step_rng,
                 lookup_fn=gathered_rows_lookup(rows),
             )
-            with jax.named_scope("loss"):
-                labels = batch["label"].reshape(-1).astype(jnp.float32)
-                ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
             return ce, (logits, new_state)
 
         (loss, (logits, new_model_state)), (g_rest, g_rows) = jax.value_and_grad(
@@ -547,7 +556,7 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
                 g_rest, rest_opt, rest
             )
         else:
-            g_rest = _pmean_grads(g_rest)
+            g_rest = _pmean_grads(model, g_rest)
             with jax.named_scope("optimizer"):
                 updates, new_rest_opt = tx.update(g_rest, rest_opt, rest)
                 new_rest = optax.apply_updates(rest, updates)
@@ -645,19 +654,8 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
         new_tables = {k: updated[k][0] for k in keys}
         new_m = {k: updated[k][1] for k in keys}
         new_v = {k: updated[k][2] for k in keys}
-        with jax.named_scope("metrics"):
-            metrics = {
-                # CE only (table-L2 folds into the lazy update); 'ce' is the
-                # cross-path comparable quantity (docs/PARITY.md)
-                "loss": lax.pmean(loss, DATA_AXIS),
-                "ce": lax.pmean(loss, DATA_AXIS),
-                "pred_mean": lax.pmean(
-                    jnp.mean(jax.nn.sigmoid(logits)), DATA_AXIS),
-                "label_mean": lax.pmean(
-                    jnp.mean(batch["label"].astype(jnp.float32)), DATA_AXIS
-                ),
-                "loss_per_shard": loss[None],
-            }
+        # CE only: the table-L2 folds into the lazy update
+        metrics = _train_metrics(model, loss, loss, logits, batch)
         new_state = TrainState(
             step=step1,
             params={**new_rest, **new_tables},
@@ -671,52 +669,39 @@ def _build_lazy_local_step(ctx: SPMDContext, model, tx) -> Callable:
 
 
 def make_spmd_eval_step(ctx: SPMDContext) -> Callable:
-    """``(state, auc_state, batch) -> (auc_state, metrics)`` with confusion
-    counts psum-merged across the data axis (ops.auc counts are additive).
+    """``(state, acc, batch) -> (acc, metrics)``: the family's evaluation
+    (``ModelDef.evaluate``: streaming-AUC counts for the click-through
+    families, retrieval metrics for the two-tower one) with the table L2
+    added to its ``loss``; ``acc`` starts as ``ModelDef.eval_init()``.
 
     The batch may carry an optional ``weight`` field ([B] f32): zero-weight
-    rows contribute nothing to AUC counts, loss, or the example count — how
-    tail batches padded up to the data-parallel multiple stay exact.
+    rows contribute nothing to the accumulator, loss, or the example count —
+    how tail batches padded up to the data-parallel multiple stay exact.
     """
     cfg = ctx.cfg
     model = get_model(cfg.model)
 
-    def local_eval(state: TrainState, auc_state: AUCState, batch: dict):
-        weight = batch.get("weight")
-        model_batch = {k: v for k, v in batch.items() if k != "weight"}
-        _, (_, logits, _) = _local_loss(
-            cfg, model, state.params, state.model_state, model_batch, None, False
+    def local_eval(state: TrainState, acc, batch: dict):
+        weight = batch.get(WEIGHT_FIELD)
+        model_batch = {k: v for k, v in batch.items() if k != WEIGHT_FIELD}
+        acc, metrics = model.evaluate(
+            acc, state.params, state.model_state, model_batch, weight,
+            cfg=cfg.model, lookup_fn=lookup_fn_from_config(cfg),
         )
-        with jax.named_scope("loss"):
-            labels = batch["label"].reshape(-1).astype(jnp.float32)
-            w = jnp.ones_like(labels) if weight is None else weight.reshape(-1)
-            ce = sigmoid_cross_entropy(logits, labels)
-            loss_sum = lax.psum(jnp.sum(ce * w), DATA_AXIS)
-            w_sum = lax.psum(jnp.sum(w), DATA_AXIS)
-        penalty = _sharded_penalty(state.params, cfg.model.l2_reg)
-        with jax.named_scope("metrics"):
-            preds = jax.nn.sigmoid(logits)
-            local_counts = auc_update(
-                auc_init(auc_state.num_thresholds), labels, preds, weights=w
-            ).counts
-            new_counts = auc_state.counts + lax.psum(local_counts, DATA_AXIS)
-        return AUCState(new_counts), {
-            "loss": loss_sum / jnp.maximum(w_sum, 1.0) + penalty,
-            "count": w_sum,
-        }
-
-    auc_specs = AUCState(P())
+        penalty = _sharded_penalty(model, state.params, cfg.model.l2_reg)
+        return acc, {**metrics, "loss": metrics["loss"] + penalty}
 
     def build(with_weight: bool):
         specs = dict(ctx.batch_specs)
         if with_weight:
-            specs["weight"] = P(DATA_AXIS)
+            specs[WEIGHT_FIELD] = P(DATA_AXIS)
         return jax.jit(
             shard_map(
                 local_eval,
                 mesh=ctx.mesh,
-                in_specs=(ctx.state_specs, auc_specs, specs),
-                out_specs=(auc_specs, {"loss": P(), "count": P()}),
+                # the accumulator and every metric are replicated
+                in_specs=(ctx.state_specs, P(), specs),
+                out_specs=(P(), P()),
                 check_vma=False,
             )
         )
@@ -724,17 +709,24 @@ def make_spmd_eval_step(ctx: SPMDContext) -> Callable:
     weighted = build(True)
     unweighted = build(False)
 
-    def eval_step(state, auc_state, batch):
-        fn = weighted if "weight" in batch else unweighted
-        return fn(state, auc_state, batch)
+    def eval_step(state, acc, batch):
+        fn = weighted if WEIGHT_FIELD in batch else unweighted
+        return fn(state, acc, batch)
 
     return eval_step
 
 
 def make_spmd_predict_step(ctx: SPMDContext) -> Callable:
-    """``(state, batch) -> prob [B]``, probabilities sharded over data."""
+    """``(state, batch) -> prob [B]``, probabilities sharded over data: the
+    click-through families' scoring call."""
     cfg = ctx.cfg
     model = get_model(cfg.model)
+    if model.apply is None:
+        raise ValueError(
+            f"predict scores a row with the model's apply; model "
+            f"{model.name!r} declares none"
+        )
+    require_fields(model, cfg.model, ("feat_ids", "feat_vals"), "predict")
 
     def local_predict(state: TrainState, batch: dict):
         logits, _ = model.apply(
@@ -759,10 +751,31 @@ def make_spmd_predict_step(ctx: SPMDContext) -> Callable:
     return jax.jit(mapped)
 
 
-def _validate_local_batch(ctx: SPMDContext, b: int, ids) -> int:
-    """Shared batch checks for both placers: per-(process-)data-parallel
-    divisibility and (when ``ids`` is given) the true-vocab range guard.
-    Returns ``jax.process_count()``."""
+def _validate_local_batch(ctx: SPMDContext, model: ModelDef, fields: dict,
+                          batch: dict, stacked: bool,
+                          validate_ids: bool) -> int:
+    """Shared batch checks for both placers: the batch holds exactly the
+    fields the model declares (and, optionally, the evaluation's weight),
+    all with one row count, divisible by the per-(process-)data-parallel
+    degree; each id field (when ``validate_ids``) within the TRUE rows of
+    its own table.  Returns ``jax.process_count()``."""
+    import numpy as np
+
+    got = set(batch) - {WEIGHT_FIELD}
+    if got != set(fields):
+        raise ValueError(
+            f"model {model.name!r} declares the batch fields "
+            f"{sorted(fields)}; got {sorted(got)} (missing "
+            f"{sorted(set(fields) - got)}, undeclared "
+            f"{sorted(got - set(fields))})"
+        )
+    rows = {np.shape(v)[1 if stacked else 0] for v in batch.values()}
+    if len(rows) != 1:
+        raise ValueError(
+            f"model {model.name!r}: batch fields disagree on the row count: "
+            f"{ {k: np.shape(v) for k, v in batch.items()} }"
+        )
+    b = rows.pop()
     dp, _ = mesh_shape(ctx.mesh)
     nproc = jax.process_count()
     local_dp = max(1, dp // nproc)
@@ -771,32 +784,31 @@ def _validate_local_batch(ctx: SPMDContext, b: int, ids) -> int:
             f"{'local' if nproc > 1 else 'global'} batch {b} not divisible "
             f"by {'per-process ' if nproc > 1 else ''}data_parallel {local_dp}"
         )
-    if ids is not None:
-        import numpy as np
-
-        ids = np.asarray(ids)
-        if ids.size and (ids.min() < 0 or ids.max() >= ctx.true_feature_size):
-            raise ValueError(
-                f"feat_ids out of range [0, {ctx.true_feature_size}): "
-                f"min={ids.min()} max={ids.max()}"
-            )
+    if validate_ids:
+        for name, field in fields.items():
+            if not field.table:
+                continue
+            ids, bound = np.asarray(batch[name]), ctx.table_rows[field.table]
+            if ids.size and (ids.min() < 0 or ids.max() >= bound):
+                raise ValueError(
+                    f"{name} out of range [0, {bound}): "
+                    f"min={ids.min()} max={ids.max()}"
+                )
     return nproc
 
 
-def _narrow_id_fields(ctx: SPMDContext, batch: dict) -> dict:
-    """Host-side int64→int32 cast of every ``*_ids`` field when the padded
-    vocabulary is int32-addressable: TPUs have no native 64-bit integer
+def _narrow_id_fields(ctx: SPMDContext, model: ModelDef, fields: dict,
+                      batch: dict) -> dict:
+    """Host-side int64→int32 cast of every declared id field when the padded
+    tables are int32-addressable: TPUs have no native 64-bit integer
     datapath, and casting BEFORE device_put also halves the id bytes on the
-    wire (ops/embedding.py narrow_ids)."""
+    wire (ops/embedding.py narrow_ids).  The cast is safe only if the
+    LARGEST declared table stays int32-addressable."""
     from ..ops.embedding import narrow_ids
 
-    m = ctx.cfg.model
-    # the two-tower vocabs may differ from feature_size; the cast is safe
-    # only if the LARGEST table stays int32-addressable
-    vocab = max(m.feature_size, getattr(m, "user_vocab_size", 0) or 0,
-                getattr(m, "item_vocab_size", 0) or 0)
+    largest = max(table_rows(model, ctx.cfg.model).values())
     return {
-        k: narrow_ids(v, vocab) if k.endswith("_ids") else v
+        k: narrow_ids(v, largest) if k in fields and fields[k].table else v
         for k, v in batch.items()
     }
 
@@ -814,18 +826,18 @@ def shard_batch(ctx: SPMDContext, batch: dict, *, validate_ids: bool = True) -> 
     host — the per-host input-sharding capability of the reference's
     per-rank pipelines (hvd:127-149).
 
-    Batch size must be divisible by the (local) data-parallel degree.  Ids
-    are range-checked against the TRUE vocab by default: out-of-range ids
-    behave differently sharded (masked to zero rows) than dense (clipped),
-    and ids in the padding range would silently train pad rows — fail loudly
-    instead.  Set ``validate_ids=False`` on a hot path that has already
-    validated.
+    The batch must hold the fields the model declares (``ModelDef.batch``),
+    its size divisible by the (local) data-parallel degree.  Every declared
+    id field is range-checked against the TRUE rows of its table by default:
+    out-of-range ids behave differently sharded (masked to zero rows) than
+    dense (clipped), and ids in the padding range would silently train pad
+    rows — fail loudly instead.  Set ``validate_ids=False`` on a hot path
+    that has already validated.
     """
-    return _place(ctx, batch, batch["label"].shape[0], ctx.batch_shardings,
-                  validate_ids)
+    return _place(ctx, batch, False, ctx.batch_shardings, validate_ids)
 
 
-def _place(ctx: SPMDContext, batch: dict, rows: int, shardings: dict,
+def _place(ctx: SPMDContext, batch: dict, stacked: bool, shardings: dict,
            validate_ids: bool) -> dict:
     """Validate, narrow and place one host batch (or K stacked ones) — the
     body both placers share, under the feed's spans (``obs/trace.py``
@@ -833,11 +845,13 @@ def _place(ctx: SPMDContext, batch: dict, rows: int, shardings: dict,
     import numpy as np
 
     rec = get_span_recorder()
-    ids = batch.get("feat_ids") if validate_ids else None
+    model = get_model(ctx.cfg.model)
+    fields = model.batch(ctx.cfg.model)
     with rec.span("feed.validate"):
-        nproc = _validate_local_batch(ctx, rows, ids)
+        nproc = _validate_local_batch(ctx, model, fields, batch, stacked,
+                                      validate_ids)
     with rec.span("feed.narrow"):
-        batch = _narrow_id_fields(ctx, batch)
+        batch = _narrow_id_fields(ctx, model, fields, batch)
     with rec.span("feed.device_put"):
         if nproc > 1:
             placed = {
@@ -870,5 +884,4 @@ def shard_batch_stacked(
         )
         for k in stacked
     }
-    return _place(ctx, stacked, stacked["label"].shape[1], shardings,
-                  validate_ids)
+    return _place(ctx, stacked, True, shardings, validate_ids)

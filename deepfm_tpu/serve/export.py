@@ -84,7 +84,7 @@ def load_servable(directory: str | os.PathLike) -> tuple[Callable, Config]:
     """
     directory = os.path.abspath(directory)
     cfg = _load_config(directory)
-    if cfg.model.model_name == "two_tower":
+    if get_model(cfg.model).apply is None:  # no scoring call
         raise ValueError(
             "this servable is a two-tower retrieval model; "
             "use serve.load_retrieval_servable"

@@ -105,7 +105,7 @@ def make_serve_context(
     import jax
     from jax.sharding import NamedSharding
 
-    from ...models.base import get_model
+    from ...models.base import get_model, table_rows
     from ...parallel.mesh import mesh_shape
     from ...parallel.spmd import _spec_for_leaf, _window_multiple, padded_vocab
 
@@ -122,7 +122,9 @@ def make_serve_context(
     )
     payload_shapes = {"params": params, "model_state": model_state}
     specs = jax.tree_util.tree_map_with_path(
-        lambda p, s: _spec_for_leaf(p, s.shape, pv), payload_shapes
+        lambda p, s: _spec_for_leaf(
+            p, s.shape, table_rows(model, cfg.model)),
+        payload_shapes,
     )
     shardings = jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), specs
@@ -217,10 +219,10 @@ def _pad_tables(params: dict, padded_rows: int) -> dict:
     in the predict — never gathered."""
     import jax.numpy as jnp
 
-    from ...parallel.spmd import TABLE_KEYS
+    from ...models.base import table_keys
 
     out = dict(params)
-    for k in TABLE_KEYS:
+    for k in table_keys():
         if k in out and out[k].shape[0] < padded_rows:
             t = out[k]
             pad = [(0, padded_rows - t.shape[0])] + [(0, 0)] * (t.ndim - 1)
@@ -293,7 +295,7 @@ def load_sharded_servable(
 
     directory = os.path.abspath(directory)
     cfg = _load_config(directory)
-    if cfg.model.model_name == "two_tower":
+    if get_model(cfg.model).apply is None:  # no scoring call
         raise ValueError(
             "shard-group serving supports CTR servables; two-tower "
             "retrieval has no sharded predict path yet"

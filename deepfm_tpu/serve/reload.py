@@ -152,7 +152,7 @@ def load_swappable_servable(
     """
     directory = os.path.abspath(directory)
     cfg = _load_config(directory)
-    if cfg.model.model_name == "two_tower":
+    if get_model(cfg.model).apply is None:  # no scoring call
         raise ValueError(
             "hot reload supports CTR servables; two-tower retrieval "
             "serving does not take --reload-url yet"

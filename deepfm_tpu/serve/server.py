@@ -881,6 +881,7 @@ def serve_forever(
     import os
 
     from ..funnel.publish import is_funnel_servable
+    from ..models.base import get_model
     from .export import _load_config, load_retrieval_servable, load_servable
 
     buckets = _parse_buckets(buckets)
@@ -909,7 +910,9 @@ def serve_forever(
         )
         return
     cfg = _load_config(os.path.abspath(servable_dir))
-    if reload_url and cfg.model.model_name == "two_tower":
+    # a family with no scoring call serves its encoders (:encode / :retrieve)
+    encoders_only = get_model(cfg.model).apply is None
+    if reload_url and encoders_only:
         raise ValueError(
             "--reload-url supports CTR servables only (two-tower serving "
             "has no hot-swap path yet)"
@@ -922,7 +925,7 @@ def serve_forever(
     registry = MetricsRegistry()
     tracer = Tracer("server", sample_rate=trace_sample_rate,
                     export_path=trace_export)
-    if cfg.model.model_name == "two_tower":
+    if encoders_only:
         encode_user, encode_item, cfg = load_retrieval_servable(servable_dir)
         rscorer = RetrievalScorer(
             encode_user, encode_item, cfg, buckets=buckets,

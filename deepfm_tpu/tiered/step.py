@@ -40,7 +40,7 @@ from ..models.base import get_model
 from ..ops.embedding import dense_lookup, gathered_rows_lookup
 from ..train.lazy import lazy_adam_update, shared_segments
 from ..train.optimizer import build_lr_schedule, build_optimizer, schedule_value
-from ..train.step import _dp_size, sigmoid_cross_entropy
+from ..train.step import _dp_size, _step_metrics
 
 
 class PagedHot(NamedTuple):
@@ -112,21 +112,21 @@ def make_paged_train_step(
         slot_ids = batch["slot_ids"]
         rows = {k: dense_lookup(hot.rows[k], slot_ids) for k in keys}
 
+        # the slots stand where the family's loss reads its ids
+        slot_batch = {"feat_ids": slot_ids, "feat_vals": batch["feat_vals"],
+                      "label": batch["label"]}
+
         def loss_fn(rest, rows):
-            logits, new_state = model.apply(
+            ce, new_state, logits = model.loss(
                 {**rest, **hot.rows},
                 state.model_state,
-                slot_ids,
-                batch["feat_vals"],
+                slot_batch,
                 cfg=cfg.model,
                 train=True,
                 rng=step_rng,
                 lookup_fn=gathered_rows_lookup(rows),
             )
-            labels = batch["label"].reshape(-1).astype(jnp.float32)
-            return jnp.mean(sigmoid_cross_entropy(logits, labels)), (
-                logits, new_state,
-            )
+            return ce, (logits, new_state)
 
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
         (loss, (logits, new_model_state)), (g_rest, g_rows) = grad_fn(
@@ -147,12 +147,7 @@ def make_paged_train_step(
                 flat_slots, g_rows[k], step1, cfg.optimizer,
                 learning_rate=lr, l2_reg=cfg.model.l2_reg, segmented=segs,
             )
-        metrics = {
-            "loss": loss,
-            "ce": loss,
-            "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
-            "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
-        }
+        metrics = _step_metrics(model, loss, loss, logits, slot_batch)
         return (
             PagedState(
                 step=step1,

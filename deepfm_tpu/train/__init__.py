@@ -10,8 +10,3 @@ from .step import (  # noqa: F401
     new_auc_state,
     sigmoid_cross_entropy,
 )
-from .retrieval import (  # noqa: F401
-    create_retrieval_state,
-    make_retrieval_eval_step,
-    make_retrieval_train_step,
-)

@@ -7,7 +7,7 @@ config knob applied by the caller via ``data_parallel_size``.
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Sequence
+from typing import Any, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -233,10 +233,9 @@ def zero_sharded(
     *,
     dp: int,
     mp: int,
-    vocab: int,
+    table_rows: Mapping[str, int],
     data_axis: str,
     model_axis: str,
-    table_keys: Sequence[str],
 ) -> ZeroShardedOptimizer:
     """Wrap an optax chain so the weight update is SHARDED across the
     ``data_axis`` instead of redundantly replicated (ZeRO / arxiv
@@ -257,8 +256,8 @@ def zero_sharded(
       are read and written once by one owner instead of dp times by
       everybody — the dominant train-hot-path HBM traffic term.
 
-    Row-sharded table leaves (path under ``table_keys`` with a
-    ``vocab``-row leading dim) shard their per-model-shard flatten over
+    Row-sharded table leaves (path under a ``table_rows`` key, with that
+    table's padded rows as leading dim) shard their per-model-shard flatten over
     dp on top of the existing model-axis row sharding; the rare
     ineligible leaf (per-model-shard size not divisible by dp, see
     ``zero_layout_size``) keeps the replicated pmean update, bit-exactly
@@ -266,16 +265,14 @@ def zero_sharded(
     tests/test_zero_sharding.py; the lowering contract (reduce-scatter,
     not all-reduce, on dense grads) by ``analysis.trace_audit.
     audit_zero_update``."""
-    table_set = frozenset(table_keys)
-
     def _shards(path, shape, *, local: bool) -> int:
         # mirrors parallel/spmd._spec_for_leaf's row-sharding rule: only
         # leaves it row-shards over the model axis have mp-way shards
-        # (local view: the per-shard leading dim is vocab // mp)
-        keys = {getattr(p, "key", None) for p in path}
-        rows = vocab // mp if local else vocab
-        if keys & table_set and len(shape) >= 1 and shape[0] == rows:
-            return mp
+        # (local view: the per-shard leading dim is rows // mp)
+        for k in (getattr(p, "key", None) for p in path):
+            if k in table_rows:
+                rows = table_rows[k] // mp if local else table_rows[k]
+                return mp if len(shape) >= 1 and shape[0] == rows else 1
         return 1
 
     def _size(shape) -> int:

@@ -17,6 +17,7 @@ import optax
 
 from ..core.config import Config
 from ..models.base import get_model
+from ..models.click_through import sigmoid_cross_entropy  # noqa: F401
 from ..ops.auc import AUCState, auc_init, auc_update
 from .optimizer import build_optimizer
 
@@ -29,42 +30,32 @@ class TrainState(NamedTuple):
     rng: jax.Array             # dropout key, folded per step
 
 
-def sigmoid_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
-    """Elementwise ``tf.nn.sigmoid_cross_entropy_with_logits`` (ps:276)."""
-    return jnp.maximum(logits, 0.0) - logits * labels + jnp.log1p(
-        jnp.exp(-jnp.abs(logits))
-    )
-
-
 def make_loss_fn(cfg: Config, model, lookup_fn=None) -> Callable:
-    """loss = mean CE + the model family's L2 penalty (reference: ps:275-279
-    applies l2_reg·(½‖FM_W‖²+½‖FM_V‖²); each ModelDef declares its own).
+    """loss = the family's data loss (``ModelDef.loss``) + the L2 penalty over
+    its tables (reference: ps:275-279 applies l2_reg·(½‖FM_W‖²+½‖FM_V‖²)).
 
-    Aux carries the bare CE so every path (dense and lazy — whose 'loss' is
-    CE-only, the table L2 being folded into the lazy update) can log a
-    comparable ``ce`` metric; see docs/PARITY.md."""
-    apply_fn, l2_penalty = model.apply, model.l2_penalty
+    Aux carries the bare data loss so every path (dense and lazy — whose
+    'loss' is CE-only, the table L2 being folded into the lazy update) can log
+    a comparable ``ce`` metric; see docs/PARITY.md.  Outside ``shard_map``, so
+    only for a family whose loss stays off the data axis (the click-through
+    ones)."""
 
     def loss_fn(params, model_state, batch, rng, train: bool):
-        kwargs = {} if lookup_fn is None else {"lookup_fn": lookup_fn}
-        logits, new_state = apply_fn(
-            params,
-            model_state,
-            batch["feat_ids"],
-            batch["feat_vals"],
-            cfg=cfg.model,
-            train=train,
-            rng=rng,
-            **kwargs,
+        ce, new_state, outputs = model.loss(
+            params, model_state, batch, cfg=cfg.model, train=train, rng=rng,
+            lookup_fn=lookup_fn,
         )
-        with jax.named_scope("loss"):
-            labels = batch["label"].reshape(-1).astype(jnp.float32)
-            ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
         with jax.named_scope("l2_penalty"):
-            loss = ce + l2_penalty(params, cfg.model.l2_reg)
-        return loss, (ce, logits, new_state)
+            loss = ce + model.l2_penalty(params, cfg.model.l2_reg)
+        return loss, (ce, outputs, new_state)
 
     return loss_fn
+
+
+def _step_metrics(model, loss, ce, outputs, batch) -> dict:
+    with jax.named_scope("metrics"):
+        return {"loss": loss, "ce": ce,
+                **{k: fn(outputs, batch) for k, fn in model.metrics.items()}}
 
 
 # tables eligible for lazy updates: the CTR families gather fm_w (1-D, the
@@ -147,20 +138,14 @@ def make_train_step(cfg: Config, lookup_fn=None) -> Callable:
     def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         step_rng = jax.random.fold_in(state.rng, state.step)
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-        (loss, (ce, logits, new_model_state)), grads = grad_fn(
+        (loss, (ce, outputs, new_model_state)), grads = grad_fn(
             state.params, state.model_state, batch, step_rng, True
         )
         with jax.named_scope("optimizer"):
             updates, new_opt_state = tx.update(
                 grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
-        with jax.named_scope("metrics"):
-            metrics = {
-                "loss": loss,
-                "ce": ce,
-                "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
-                "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
-            }
+        metrics = _step_metrics(model, loss, ce, outputs, batch)
         return (
             TrainState(
                 step=state.step + 1,
@@ -211,19 +196,15 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
             rows = {k: dense_lookup(tables[k], ids) for k in keys}
 
         def loss_fn(rest, rows):
-            logits, new_state = model.apply(
+            ce, new_state, logits = model.loss(
                 {**rest, **tables},
                 state.model_state,
-                batch["feat_ids"],
-                batch["feat_vals"],
+                batch,
                 cfg=cfg.model,
                 train=True,
                 rng=step_rng,
                 lookup_fn=gathered_rows_lookup(rows),
             )
-            with jax.named_scope("loss"):
-                labels = batch["label"].reshape(-1).astype(jnp.float32)
-                ce = jnp.mean(sigmoid_cross_entropy(logits, labels))
             return ce, (logits, new_state)
 
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
@@ -249,16 +230,10 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
                     flat_ids, g_rows[key], step1, cfg.optimizer,
                     learning_rate=lr, l2_reg=cfg.model.l2_reg, segmented=segs,
                 )
-        with jax.named_scope("metrics"):
-            metrics = {
-                # CE only: the table-L2 gradient is folded into the lazy
-                # update, so no dense penalty term exists here; 'ce' is the
-                # cross-path comparable quantity (docs/PARITY.md)
-                "loss": loss,
-                "ce": loss,
-                "pred_mean": jnp.mean(jax.nn.sigmoid(logits)),
-                "label_mean": jnp.mean(batch["label"].astype(jnp.float32)),
-            }
+        # CE only: the table-L2 gradient is folded into the lazy update, so
+        # no dense penalty term exists here; 'ce' is the cross-path
+        # comparable quantity (docs/PARITY.md)
+        metrics = _step_metrics(model, loss, loss, logits, batch)
         return (
             TrainState(
                 step=step1,

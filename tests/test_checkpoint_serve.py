@@ -214,11 +214,7 @@ def test_export_padded_vocab_roundtrip(tmp_path):
     probs = np.asarray(predict(ids, np.ones((1, 5), np.float32)))
     assert probs.shape == (1,) and np.isfinite(probs).all()
 
-    # retrieval family, same padding contract
-    from deepfm_tpu.parallel.retrieval import (
-        create_retrieval_spmd_state,
-        make_retrieval_context,
-    )
+    # retrieval family, same padding contract through the same builders
     from deepfm_tpu.serve import load_retrieval_servable
 
     rcfg = cfg.with_overrides(
@@ -232,9 +228,9 @@ def test_export_padded_vocab_roundtrip(tmp_path):
             "tower_dim": 4,
         }
     )
-    rctx = make_retrieval_context(rcfg, mesh)
+    rctx = make_context(rcfg, mesh)
     assert rctx.cfg.model.user_vocab_size == 204
-    rstate = create_retrieval_spmd_state(rctx)
+    rstate = create_spmd_state(rctx)
     rout = export_servable(rctx.cfg, rstate, tmp_path / "rservable")
     encode_user, encode_item, _ = load_retrieval_servable(rout)
     u = np.asarray(encode_user(np.array([[202]], np.int64),

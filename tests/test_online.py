@@ -461,7 +461,7 @@ def test_online_trainer_rejects_two_tower_and_missing_roots(tmp_path):
     cfg = _cfg(str(tmp_path)).with_overrides(
         model={"model_name": "two_tower"}
     )
-    with pytest.raises(ValueError, match="two-tower"):
+    with pytest.raises(ValueError, match="two_tower"):
         OnlineTrainer(cfg)
     cfg2 = _cfg(str(tmp_path)).with_overrides(
         data={"training_data_dir": ""}

@@ -3,14 +3,16 @@
 ``perf/`` (BENCHMARK.json's command) calls the package by name —
 ``Config().with_overrides``, ``spmd.make_context``, ``create_spmd_state``,
 ``make_spmd_train_step``, ``shard_batch``, ``DevicePrefetcher`` — and
-``perf/control.py`` swaps ``spmd.sigmoid_cross_entropy`` by name.  Its own
-tests (``perf/tests/``) are run by hand, so a rename or a deleted config field
-would otherwise show first on the chip.  Here, on the CPU: every configuration
-the benchmark and its fixture name builds its ``Config``; each ``tiny-*``
-fixture cell runs a window end to end and is ``correct``; the planted
-half-batch fault comes out not ``correct``; every name ``perf/`` imports from
-the package resolves.  Reads ``perf/``, edits nothing there.  The feed's
-readers are ``tests/test_train_trace.py``'s.
+``perf/control.py`` swaps ``sigmoid_cross_entropy`` by name in every loaded
+module of the package that binds it.  Its own tests (``perf/tests/``) are run
+by hand, so a rename or a deleted config field would otherwise show first on
+the chip.  Here, on the CPU: every configuration the benchmark and its fixture
+name builds its ``Config``; each ``tiny-*`` fixture cell runs a window end to
+end and is ``correct``; the planted half-batch fault comes out not
+``correct``; every name ``perf/`` imports from the package resolves; each
+benchmark cell's step lowers with the metric names and the state tree the
+benchmark's reference and readers know.  Reads ``perf/``, edits nothing there.
+The feed's readers are ``tests/test_train_trace.py``'s.
 """
 
 import ast
@@ -30,9 +32,9 @@ MANIFESTS = {
     "benchmark": ROOT / "BENCHMARK.json",
     "fixture": ROOT / "perf" / "tests" / "fixture_manifest.json",
 }
-# what perf/entries/train.py and perf/control.py call on parallel/spmd.py
+# what perf/entries/train.py calls on parallel/spmd.py
 SPMD_SEAM = {"make_context", "create_spmd_state", "make_spmd_train_step",
-             "shard_batch", "sigmoid_cross_entropy"}
+             "shard_batch"}
 
 
 def _manifest(which: str) -> dict:
@@ -94,39 +96,79 @@ def test_tiny_cell_window_is_correct_with_the_contracts_keys(name):
 
 @pytest.mark.parametrize("name", TINY_CELLS)
 def test_planted_half_batch_comes_out_not_correct(name):
-    from deepfm_tpu.parallel import spmd
+    """The fault is planted in every loaded ``deepfm_tpu`` module that binds
+    the loss by name — the one whose global the shared click-through loss
+    calls through (``models/click_through.py``) among them — halves what
+    each returns, and every binding is put back."""
+    import numpy as np
+
+    from deepfm_tpu.models import click_through
 
     from perf import control
 
-    real = spmd.sigmoid_cross_entropy
+    binders = {n: mod for n, mod in sys.modules.items()
+               if n.split(".")[0] == "deepfm_tpu"
+               and callable(getattr(mod, control.LOSS, None))}
+    assert click_through.__name__ in binders
+    real = {n: getattr(mod, control.LOSS) for n, mod in binders.items()}
+    logits, labels = np.zeros(8, np.float32), np.ones(8, np.float32)
     unplant = control.plant_half_batch_in_program()
     try:
-        assert spmd.sigmoid_cross_entropy is not real
+        for n, mod in binders.items():
+            planted = getattr(mod, control.LOSS)
+            assert planted is not real[n], n
+            assert planted(logits, labels).shape == (4,), n
         result = _run(_cell("fixture", name))
     finally:
         unplant()
-    assert spmd.sigmoid_cross_entropy is real
+    for n, mod in binders.items():
+        assert getattr(mod, control.LOSS) is real[n], n
     assert result["correct"] is False
     failed = {k for k, r in result["checks"].items() if r["value"] > r["limit"]}
     assert {"grad_diff", "row_diff"} <= failed
 
 
+def _spmd_reads(tree: ast.AST) -> tuple[set, set]:
+    """``spmd.<attr>`` reads in ``tree``: (those read for real, those read
+    only inside a test that itself holds a ``hasattr(spmd, …)`` call — what
+    ``perf/`` reads only where the attribute is there)."""
+    def is_guard(n):
+        return (isinstance(n, ast.Call) and isinstance(n.func, ast.Name)
+                and n.func.id == "hasattr" and n.args
+                and isinstance(n.args[0], ast.Name) and n.args[0].id == "spmd")
+
+    under_guard = set()
+    for node in ast.walk(tree):
+        if (isinstance(node, (ast.If, ast.IfExp, ast.While))
+                and any(is_guard(n) for n in ast.walk(node.test))):
+            under_guard |= {id(n) for n in ast.walk(node.test)}
+    required, guarded = set(), set()
+    for n in ast.walk(tree):
+        if (isinstance(n, ast.Attribute) and isinstance(n.value, ast.Name)
+                and n.value.id == "spmd"):
+            (guarded if id(n) in under_guard else required).add(n.attr)
+    return required, guarded - required
+
+
 def test_every_package_name_the_benchmark_uses_resolves():
     """By ``ast`` over ``perf/**/*.py`` outside ``perf/tests/``: every
     ``from deepfm_tpu.<module> import <name>`` resolves, and every
-    ``spmd.<attr>`` is an attribute of ``parallel/spmd.py``."""
-    imported, spmd_attrs = set(), set()
+    ``spmd.<attr>`` is an attribute of ``parallel/spmd.py`` — but for one
+    that ``perf/`` reads only inside a test guarded by ``hasattr(spmd, …)``
+    (``perf/control.py``'s post-condition on a name ``spmd`` may no longer
+    bind), found by the guard, not by a list of names."""
+    imported, spmd_attrs, guarded = set(), set(), set()
     for path in sorted((ROOT / "perf").rglob("*.py")):
         if "tests" in path.relative_to(ROOT / "perf").parts:
             continue
-        for node in ast.walk(ast.parse(path.read_text())):
+        tree = ast.parse(path.read_text())
+        required, only_guarded = _spmd_reads(tree)
+        spmd_attrs |= required
+        guarded |= only_guarded
+        for node in ast.walk(tree):
             if (isinstance(node, ast.ImportFrom) and node.level == 0
                     and (node.module or "").split(".")[0] == "deepfm_tpu"):
                 imported |= {(node.module, a.name) for a in node.names}
-            elif (isinstance(node, ast.Attribute)
-                  and isinstance(node.value, ast.Name)
-                  and node.value.id == "spmd"):
-                spmd_attrs.add(node.attr)
     assert ("deepfm_tpu.parallel", "spmd") in imported
     assert ("deepfm_tpu.data.pipeline", "DevicePrefetcher") in imported
     for module, name in sorted(imported):
@@ -137,3 +179,95 @@ def test_every_package_name_the_benchmark_uses_resolves():
     spmd = importlib.import_module("deepfm_tpu.parallel.spmd")
     missing = sorted(a for a in spmd_attrs if not hasattr(spmd, a))
     assert not missing, missing
+    # the excuse is as narrow as the guard: an attribute read anywhere
+    # without one is required above, and the guarded one is the loss
+    assert guarded - spmd_attrs == {"sigmoid_cross_entropy"}
+
+
+def test_the_hasattr_excuse_is_found_by_its_guard_not_by_name():
+    tree = ast.parse(
+        "if hasattr(spmd, NAME) and spmd.maybe_gone is real:\n"
+        "    spmd.used_in_the_body()\n"
+        "x = spmd.plain_read\n"
+        "if other and spmd.not_guarded:\n    pass\n"
+        "y = spmd.both if hasattr(spmd, 'both') else spmd.both_fallback\n"
+        "z = spmd.both\n")
+    required, guarded = _spmd_reads(tree)
+    assert guarded == {"maybe_gone"}
+    assert required == {"used_in_the_body", "plain_read", "not_guarded",
+                        "both", "both_fallback"}
+
+
+BENCHMARK_CELLS = [w["name"] for w in _manifest("benchmark")["workloads"]]
+# the parent's: what perf/reference/ rebuilds from the seed and compares by
+# leaf name, and what the benchmark's readers and the loop's log lines read
+STEP_METRICS = {"loss", "ce", "pred_mean", "label_mean", "loss_per_shard"}
+PARAM_LEAVES = {
+    "deepfm": {
+        "fm_b": (1,), "fm_v": (12_500_000, 32), "fm_w": (12_500_000,),
+        "mlp/layer_0/bias": (128,), "mlp/layer_0/kernel": (1248, 128),
+        "mlp/layer_1/bias": (64,), "mlp/layer_1/kernel": (128, 64),
+        "mlp/layer_2/bias": (32,), "mlp/layer_2/kernel": (64, 32),
+        "mlp/out/bias": (1,), "mlp/out/kernel": (32, 1),
+    },
+    "xdeepfm": {
+        "cin/filter_0": (39, 39, 200), "cin/filter_1": (200, 39, 200),
+        "cin/filter_2": (200, 39, 200), "cin/out/bias": (1,),
+        "cin/out/kernel": (600, 1),
+        "fm_b": (1,), "fm_v": (12_500_000, 10), "fm_w": (12_500_000,),
+        "mlp/layer_0/bias": (400,), "mlp/layer_0/kernel": (390, 400),
+        "mlp/layer_1/bias": (400,), "mlp/layer_1/kernel": (400, 400),
+        "mlp/out/bias": (1,), "mlp/out/kernel": (400, 1),
+    },
+}
+
+
+@pytest.mark.parametrize("name", BENCHMARK_CELLS)
+def test_benchmark_cell_step_lowers_with_the_parents_names_and_state(name):
+    """At the cell's own size, abstract state, on the CPU: the jitted
+    function is still ``local_step`` (``reduce_xplane`` finds the step by
+    it), its metrics are the parent's five, and the state is the parent's
+    tree — parameter leaves by name and shape, Adam's ``mu`` / ``nu``
+    mirroring them, nothing else."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from deepfm_tpu.parallel import spmd
+    from deepfm_tpu.parallel.mesh import build_mesh
+    from perf.entries import train
+
+    cfg = train.build_config(_cell("benchmark", name), seed=1)
+    mesh = build_mesh(cfg.mesh, devices=jax.devices()[:1])
+    ctx = spmd.make_context(cfg, mesh)
+    assert ctx.true_feature_size == 12_500_000 == ctx.cfg.model.feature_size
+    abstract = spmd.abstract_spmd_state(ctx)
+
+    def leaves(tree):
+        return {"/".join(str(k.key) for k in path): leaf.shape for path, leaf
+                in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+    want = PARAM_LEAVES[cfg.model.model_name]
+    assert leaves(abstract.params) == want
+    (adam, _empty), = [abstract.opt_state]
+    assert leaves(adam.mu) == want and leaves(adam.nu) == want
+    assert abstract.model_state == {} and abstract.step.shape == ()
+    assert len(jax.tree_util.tree_leaves(abstract)) == 3 * len(want) + 3
+
+    state = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        abstract, ctx.state_shardings)
+    b, f = cfg.data.batch_size, cfg.model.field_size
+    batch = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+        mesh, ctx.batch_specs[k])) for k, (shape, dtype) in {
+            "feat_ids": ((b, f), jnp.int32), "feat_vals": ((b, f), jnp.float32),
+            "label": ((b,), jnp.float32)}.items()}
+    assert set(ctx.batch_specs) == set(batch)
+    lowered = spmd.make_spmd_train_step(ctx).lower(state, batch)
+    assert "jit_local_step" in lowered.as_text()[:400]
+    new_state, metrics = lowered.out_info
+    assert set(metrics) == STEP_METRICS
+    assert metrics["loss_per_shard"].shape == (1,)
+    assert (jax.tree_util.tree_structure(new_state)
+            == jax.tree_util.tree_structure(abstract))
+

@@ -116,5 +116,5 @@ def test_two_tower_cli_rejects_infer(ratings_dir, tmp_path):
         "--model_name", "two_tower",
         "--no_env",
     ]
-    with pytest.raises(ValueError, match="unsupported for two_tower"):
+    with pytest.raises(ValueError, match="predict.*two_tower"):
         cli_main(args)
