@@ -10,6 +10,7 @@ sharding strategy.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 import math
@@ -131,12 +132,29 @@ def _lookup_fwd(meta, tables, ids):
     50 MB does."""
     rows, dtype, tails = meta
     plan = _chunks(meta, ids)
+    _say_plan(meta, ids, plan)
+    if plan is None:
+        return _gather_rows(meta, tables, ids), (ids, None)
+    run_of, row_of, distinct = _row_runs(meta, ids, plan)
+    compact = _read_rows(meta, tables, row_of, distinct, plan)
+    out = jnp.take(compact, run_of.reshape(ids.shape), axis=0, mode="clip")
+    return _apart(out, tails), (ids, (run_of, row_of, distinct))
+
+
+def _say_plan(meta, ids, plan):
+    """The forward's choice (``_chunks``), once at trace time."""
+    rows, _, tails = meta
     logging.getLogger(__name__).info(
         "table lookup: %s, tables=%s n=%d rows=%d",
         "distinct rows, then expand" if plan else "xla gather",
         list(tails), ids.size, rows)
-    if plan is None:
-        return _gather_rows(meta, tables, ids), (ids, None)
+
+
+def _row_runs(meta, ids, plan):
+    """The run structure of a call's ids: ``run_of`` (each position's run
+    number, in the order of the ids), ``row_of`` (``[n_pad]``: the distinct
+    rows ascending, then fillers past the table's end) and ``distinct``."""
+    rows = meta[0]
     chunk, n_pad = plan
     flat_ids = ids.reshape(-1)
     n = flat_ids.shape[0]
@@ -152,6 +170,14 @@ def _lookup_fwd(meta, tables, ids):
     at_pad = jnp.arange(n_pad, dtype=row_id.dtype)
     row_of = jnp.where(
         at_pad < distinct, jnp.pad(row_id, (0, n_pad - n)), rows + at_pad)
+    return run_of, row_of, distinct
+
+
+def _read_rows(meta, tables, row_of, distinct, plan):
+    """The distinct rows of every table of the call, chunk by chunk, side by
+    side in one ``[n_pad, ΣK]`` buffer."""
+    _, dtype, tails = meta
+    chunk, n_pad = plan
 
     def read(i, compact):
         at = i * chunk
@@ -160,11 +186,44 @@ def _lookup_fwd(meta, tables, ids):
                        for t in tables], tails)
         return lax.dynamic_update_slice_in_dim(compact, got, at, 0)
 
-    compact = lax.fori_loop(
-        0, (distinct + chunk - 1) // chunk, read,
+    return lax.fori_loop(
+        0, _trips(distinct, chunk), read,
         jnp.zeros((n_pad, sum(math.prod(tail) for tail in tails)), dtype))
-    out = jnp.take(compact, run_of.reshape(ids.shape), axis=0, mode="clip")
-    return _apart(out, tails), (ids, (run_of, row_of, distinct))
+
+
+def _combine(flat_g, run_of, in_range, n_pad):
+    """The cotangents of equal ids added by run number into a compact
+    ``[n_pad, ΣK]`` buffer whose live prefix is the distinct rows.  An id the
+    forward clipped onto an edge row's run adds past the buffer's end, where
+    the scatter drops it."""
+    return jnp.zeros((n_pad, flat_g.shape[1]), flat_g.dtype).at[
+        jnp.where(in_range, run_of, n_pad)].add(flat_g, mode="drop")
+
+
+def _trips(distinct, chunk):
+    return (distinct + chunk - 1) // chunk
+
+
+def add_rows(row_of, trips, chunk, buffers, parts_of, targets):
+    """``targets[j][row_of[r]] += parts_of(*buffers' rows r)[j]`` for the rows
+    ``r`` of the first ``trips`` chunks: the one loop that carries a step's
+    distinct rows into table-shaped operands (the gradients of
+    ``_lookup_bwd``; Adam's moments, ``parallel/spmd.py``).  Every target of
+    a trip shares the trip's slice of ``row_of``; the fillers past the
+    distinct rows lie outside every table and are dropped.  The trip count
+    follows the batch's distinct rows: no capacity, no fallback."""
+
+    def trip(i, targets):
+        at = i * chunk
+        at_rows = lax.dynamic_slice_in_dim(row_of, at, chunk)
+        parts = parts_of(
+            *[lax.dynamic_slice_in_dim(b, at, chunk) for b in buffers])
+        return tuple(
+            target.at[at_rows].add(part, indices_are_sorted=True,
+                                   unique_indices=True, mode="drop")
+            for target, part in zip(targets, parts))
+
+    return lax.fori_loop(0, trips, trip, tuple(targets))
 
 
 def _lookup_bwd(meta, residuals, gs):
@@ -184,6 +243,12 @@ def _lookup_bwd(meta, residuals, gs):
     contribute nothing (the forward clipped them onto an edge row's run:
     their cotangent is dropped here).  A table whose rows the loss does not
     use arrives with a cotangent of zeros and gets a gradient of zeros.
+
+    This is where a table's gradient is table-shaped: every caller but the
+    dense SPMD step under Adam on a singleton data axis.  There a table of
+    rows gets no such gradient (``distinct_rows_gather``): the same combined
+    rows go to ``parallel/spmd.py _pre_add_rows``, which runs this loop
+    (``add_rows``) into Adam's moments.
 
     A call whose tables are all scalars keeps XLA's own scatter-add: at one
     float a row it is the compact buffer's price already (3.9 ms against
@@ -209,28 +274,25 @@ def _lookup_bwd(meta, residuals, gs):
 
     run_of, row_of, distinct = runs
     chunk, n_pad = _chunks(meta, ids)
-    flat_g = _beside(flat_gs, tails)
-    # an id the forward clipped onto an edge row's run adds past the buffer's
-    # end, where the scatter drops it
-    combined = jnp.zeros((n_pad, flat_g.shape[1]), dtype).at[
-        jnp.where(in_range, run_of, n_pad)].add(flat_g, mode="drop")
-
-    def write(i, grads):
-        at = i * chunk
-        at_rows = lax.dynamic_slice_in_dim(row_of, at, chunk)
-        parts = _apart(lax.dynamic_slice_in_dim(combined, at, chunk), tails)
-        return tuple(
-            grad.at[at_rows].add(part, indices_are_sorted=True,
-                                 unique_indices=True, mode="drop")
-            for grad, part in zip(grads, parts))
-
-    grads = lax.fori_loop(
-        0, (distinct + chunk - 1) // chunk, write,
-        tuple(jnp.zeros((rows,) + tail, dtype) for tail in tails))
+    combined = _combine(_beside(flat_gs, tails), run_of, in_range, n_pad)
+    grads = add_rows(
+        row_of, _trips(distinct, chunk), chunk, (combined,),
+        lambda part: _apart(part, tails),
+        [jnp.zeros((rows,) + tail, dtype) for tail in tails])
     return grads, zero_ids
 
 
 _gather_rows.defvjp(_lookup_fwd, _lookup_bwd)
+
+
+def _meta(tables):
+    """``(rows, dtype, each table's row shape)`` of the tables of one call."""
+    (rows, dtype), *others = [(t.shape[0], str(t.dtype)) for t in tables]
+    if any(other != (rows, dtype) for other in others):
+        raise ValueError(
+            "tables of one lookup share a row count and a dtype, got "
+            f"{[(t.shape, str(t.dtype)) for t in tables]}")
+    return rows, dtype, tuple(tuple(t.shape[1:]) for t in tables)
 
 
 def dense_lookup(tables, ids: jnp.ndarray):
@@ -259,16 +321,121 @@ def dense_lookup(tables, ids: jnp.ndarray):
     (rank > 1); a call of scalars only keeps XLA's gather and scatter-add
     both ways.  It is the one local row gather of every training path — the
     dense step, the SPMD step's shard-local gather and the all-to-all
-    exchange's owner side — for either value of ``ModelConfig.table_grad``."""
+    exchange's owner side — for either value of ``ModelConfig.table_grad``.
+
+    Its tables' gradients are table-shaped (``_lookup_bwd``).  The one caller
+    that takes them as rows instead is the dense SPMD step under Adam on a
+    singleton data axis, through ``distinct_rows_gather`` below: the same
+    forward, values bit for bit, and the backward's combined rows handed to
+    the step, which adds them into Adam's moments
+    (``parallel/spmd.py _pre_add_rows``)."""
     if not isinstance(tables, tuple):
         return dense_lookup((tables,), ids)[0]
-    (rows, dtype), *others = [(t.shape[0], str(t.dtype)) for t in tables]
-    if any(other != (rows, dtype) for other in others):
-        raise ValueError(
-            "tables of one lookup share a row count and a dtype, got "
-            f"{[(t.shape, str(t.dtype)) for t in tables]}")
-    tails = tuple(tuple(t.shape[1:]) for t in tables)
-    return _gather_rows((rows, dtype, tails), tables, ids)
+    return _gather_rows(_meta(tables), tables, ids)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _expand(tails, compact, sink, run_of, in_range):
+    """``compact[run_of]``, a part a table.  Differentiated, ``compact`` is a
+    constant and ``sink`` — zeros of its shape, a perturbation of the distinct
+    rows that is never added — takes the cotangents combined by run."""
+    return _apart(jnp.take(compact, run_of, axis=0, mode="clip"), tails)
+
+
+def _expand_fwd(tails, compact, sink, run_of, in_range):
+    return (_expand(tails, compact, sink, run_of, in_range),
+            (run_of, in_range, sink))
+
+
+def _expand_bwd(tails, residuals, gs):
+    run_of, in_range, sink = residuals
+    n = run_of.size
+    logging.getLogger(__name__).info(
+        "table gradient: combine, rows out, tables=%s n=%d", list(tails), n)
+    combined = _combine(
+        _beside([g.reshape((n,) + tail).astype(sink.dtype)
+                 for g, tail in zip(gs, tails)], tails),
+        run_of.reshape(-1), in_range.reshape(-1), sink.shape[0])
+    zero = np.zeros(run_of.shape, jax.dtypes.float0)
+    return None, combined, zero, zero
+
+
+_expand.defvjp(_expand_fwd, _expand_bwd)
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["row_of", "distinct", "compact"],
+                   meta_fields=["keys", "tails", "chunk"])
+@dataclasses.dataclass(frozen=True)
+class DistinctRows:
+    """What one call of ``distinct_rows_gather`` hands the step in place of
+    table-shaped gradients: the names and row shapes of the call's tables and
+    the loop's chunk (static), the forward's ``row_of`` and ``distinct``
+    (``_row_runs``) and its compact buffer of the tables' rows as the step
+    found them, side by side.  The rows' gradients, combined by run, arrive
+    in the same ``[n_pad, ΣK]`` layout as the cotangent of the call's sink."""
+
+    keys: tuple
+    tails: tuple
+    chunk: int
+    row_of: jnp.ndarray
+    distinct: jnp.ndarray
+    compact: jnp.ndarray
+
+    def add(self, combined, parts_of, targets):
+        """``add_rows`` of this call into the table-shaped ``targets``:
+        ``parts_of(gradient rows, table rows)``, each a part a table, of a
+        trip's chunk of ``combined`` and of the compact buffer."""
+        return add_rows(
+            self.row_of, _trips(self.distinct, self.chunk), self.chunk,
+            (combined, self.compact),
+            lambda s, p: parts_of(_apart(s, self.tails), _apart(p, self.tails)),
+            targets)
+
+
+def distinct_rows_gather(named: dict, sinks):
+    """``(gather, taken)``: a local row gather (``dense_lookup``'s signature,
+    values bit for bit) whose tables get NO table-shaped gradient, and the
+    list it fills with one ``DistinctRows`` a call.  Differentiated, a call's
+    tables are constants and the gradient of its distinct rows leaves through
+    a sink (``_expand``), for a step that adds rows into what it keeps a
+    table (``parallel/spmd.py``: Adam's moments).
+
+    ``named`` is {key: table} of the tables the step can take rows for,
+    recognised by identity; ``sinks`` the zero arrays of the calls in order,
+    each the shape of its call's compact buffer — ``None`` to find those
+    shapes (``jax.eval_shape`` of ``taken``).  The same static rule as the
+    materialised path and one more: a call takes this way where its lookup is
+    on the distinct-rows plan (``_chunks``), every table is one of ``named``
+    and one at least holds rows; any other call is ``dense_lookup``,
+    table-shaped gradient and all.  The run structure, the read loop, the
+    expansion, the compact scatter-add and the write loop are
+    ``dense_lookup``'s own."""
+    asked = sinks is None
+    sinks = iter(sinks or ())
+    taken = []
+
+    def gather(tables, ids):
+        each = tables if isinstance(tables, tuple) else (tables,)
+        keys = tuple(next((k for k, t in named.items() if t is table), None)
+                     for table in each)
+        meta = rows, _, tails = _meta(each)
+        plan = _chunks(meta, ids)
+        if None in keys or not any(tails) or plan is None:
+            return dense_lookup(tables, ids)
+        if not asked:
+            _say_plan(meta, ids, plan)
+        run_of, row_of, distinct = _row_runs(meta, ids, plan)
+        compact = _read_rows(meta, [lax.stop_gradient(t) for t in each],
+                             row_of, distinct, plan)
+        sink = jnp.zeros_like(compact) if asked else next(sinks)
+        out = _expand(tails, compact, sink, run_of.reshape(ids.shape),
+                      (ids >= 0) & (ids < rows))
+        taken.append(
+            DistinctRows(keys, tails, plan[0], row_of, distinct, compact))
+        return out if isinstance(tables, tuple) else out[0]
+
+    return gather, taken
 
 
 def gathered_rows_lookup(rows: dict):

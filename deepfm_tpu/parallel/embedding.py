@@ -352,13 +352,16 @@ def _exchange_collect(
     )
 
 
-def _psum_lookup(local_tables, ids: jnp.ndarray, axis_name: str):
+def _psum_lookup(local_tables, ids: jnp.ndarray, axis_name: str,
+                 gather=None):
     """Dense zeros-plus-psum assembly (the original path; also the
     capacity-overflow fallback of the alltoall exchange).  A tuple of tables
     goes to the shard-local gather as one call, so they share its run
-    structure (``ops/embedding.py dense_lookup``)."""
+    structure (``gather``: ``ops/embedding.py dense_lookup``, or the step's
+    ``distinct_rows_gather``)."""
     from ..ops.embedding import dense_lookup
 
+    gather = gather or dense_lookup
     rows = jax.tree_util.tree_leaves(local_tables)[0].shape[0]
     shard = lax.axis_index(axis_name)
     lo = shard * rows
@@ -371,7 +374,7 @@ def _psum_lookup(local_tables, ids: jnp.ndarray, axis_name: str):
         return jnp.where(mask, gathered, 0)
 
     return lax.psum(
-        jax.tree_util.tree_map(owned, dense_lookup(local_tables, clipped)),
+        jax.tree_util.tree_map(owned, gather(local_tables, clipped)),
         axis_name)
 
 
@@ -382,6 +385,7 @@ def sharded_lookup(
     axis_name: str = MODEL_AXIS,
     exchange: str = "psum",
     capacity: float = 0.0,
+    gather=None,
 ):
     """Gather rows from a row-sharded table, inside shard_map.
 
@@ -398,7 +402,10 @@ def sharded_lookup(
     (``ModelConfig.table_grad`` selects nothing).  Under "psum" a tuple goes
     to it as one call, so its tables share one run structure, one compact
     buffer and one write loop; the "alltoall" exchange stays one table a
-    call.
+    call.  ``gather`` puts another shard-local gather in ``dense_lookup``'s
+    place under "psum" (the train step's ``distinct_rows_gather``, whose
+    tables' gradients are rows); the exchange's owner side keeps
+    ``dense_lookup`` and its table-shaped gradient.
 
     ``exchange`` selects the assembly collective (module docstring): "psum"
     = dense zeros-plus-psum; "alltoall" = deduplicated owned-rows-only
@@ -413,7 +420,7 @@ def sharded_lookup(
             f"resolve_shard_exchange first), got {exchange!r}"
         )
     if exchange == "psum":
-        return _psum_lookup(local_table, ids, axis_name)
+        return _psum_lookup(local_table, ids, axis_name, gather)
     if isinstance(local_table, tuple):
         return tuple(
             sharded_lookup(t, ids, axis_name=axis_name, exchange=exchange,
@@ -452,31 +459,38 @@ def sharded_l2(local_table: jnp.ndarray, axis_name: str = MODEL_AXIS) -> jnp.nda
 
 def make_sharded_lookup_fn(axis_name: str = MODEL_AXIS,
                            exchange: str = "psum",
-                           capacity: float = 0.0):
+                           capacity: float = 0.0,
+                           gather=None):
     """A ``lookup_fn`` for model.apply, closing over the axis name and the
     exchange mode (``lookup_fn_from_config`` resolves them from a Config)."""
 
     def lookup(tables, ids: jnp.ndarray):
         return sharded_lookup(tables, ids, axis_name=axis_name,
-                              exchange=exchange, capacity=capacity)
+                              exchange=exchange, capacity=capacity,
+                              gather=gather)
 
     return lookup
 
 
-def lookup_fn_from_config(cfg, axis_name: str = MODEL_AXIS):
-    """The sharded ``lookup_fn`` a Config asks for: resolved
-    shard_exchange + capacity, in one place (spmd.py and retrieval.py both
-    build their model-apply lookups here).
-
-    A singleton model axis has no rows to exchange — there "alltoall"
-    would pay the dedup sort for nothing (mode can still resolve that way
-    when the LAZY grad gather wants it for the data axis), so the lookup
-    demotes to psum, mirroring ``fwd_exchange`` in the lazy step."""
-    mode = resolve_shard_exchange(cfg)
+def lookup_exchange(cfg) -> str:
+    """The exchange the model-apply lookup of a Config runs: the resolved
+    ``shard_exchange``, demoted to psum on a singleton model axis — there is
+    no row to exchange there, and "alltoall" would pay the dedup sort for
+    nothing (mode can still resolve that way when the LAZY grad gather wants
+    it for the data axis), mirroring ``fwd_exchange`` in the lazy step."""
     if cfg.mesh.model_parallel <= 1:
-        mode = "psum"
+        return "psum"
+    return resolve_shard_exchange(cfg)
+
+
+def lookup_fn_from_config(cfg, axis_name: str = MODEL_AXIS, gather=None):
+    """The sharded ``lookup_fn`` a Config asks for: resolved
+    shard_exchange (``lookup_exchange``) + capacity, in one place (spmd.py
+    and retrieval.py both build their model-apply lookups here), over the
+    shard-local ``gather`` (``sharded_lookup``)."""
     return make_sharded_lookup_fn(
         axis_name=axis_name,
-        exchange=mode,
+        exchange=lookup_exchange(cfg),
         capacity=cfg.model.shard_exchange_capacity,
+        gather=gather,
     )

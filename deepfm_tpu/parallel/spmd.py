@@ -23,6 +23,7 @@ the only (infinitesimal) effect.
 
 from __future__ import annotations
 
+import logging
 from typing import Any, Callable, Mapping, NamedTuple
 
 import jax
@@ -42,6 +43,7 @@ from ..train.optimizer import (
 from ..train.step import TrainState
 from .embedding import (
     exchange_capacity,
+    lookup_exchange,
     lookup_fn_from_config,
     resolve_shard_exchange,
     sharded_l2,
@@ -300,8 +302,9 @@ def _pmean_grads(model: ModelDef, grads: dict) -> dict:
 
 
 def _local_loss(cfg: Config, model: ModelDef, params, model_state, batch,
-                rng, train):
-    """One data shard's loss: the family's data loss plus the table L2."""
+                rng, train, gather=None):
+    """One data shard's loss: the family's data loss plus the table L2
+    (``gather``: the lookup's shard-local gather, ``sharded_lookup``)."""
     data_loss, new_state, outputs = model.loss(
         params,
         model_state,
@@ -309,7 +312,7 @@ def _local_loss(cfg: Config, model: ModelDef, params, model_state, batch,
         cfg=cfg.model,
         train=train,
         rng=rng,
-        lookup_fn=lookup_fn_from_config(cfg),
+        lookup_fn=lookup_fn_from_config(cfg, gather=gather),
     )
     loss = data_loss + _sharded_penalty(model, params, cfg.model.l2_reg)
     return loss, (data_loss, outputs, new_state)
@@ -339,30 +342,147 @@ def _train_metrics(model: ModelDef, loss, ce, outputs, batch) -> dict:
         }
 
 
+def _rows_into_moments(ctx: SPMDContext) -> bool:
+    """Whether the dense step hands a table's gradient to the optimizer as
+    the step's distinct rows, pre-added into Adam's moments
+    (``_pre_add_rows``), instead of table-shaped: static, from what the
+    builder can see.  The algebra is Adam's; at dp > 1 the rows differ by
+    replica and the ``pmean`` / the dp-sharded update's reduce-scatter need
+    the table-shaped leaf; the all-to-all exchange's owner side gathers
+    inside a ``lax.cond``, where no row leaves.  What remains is decided a
+    lookup call, by the same static rule as the lookup's own plan
+    (``ops/embedding.py distinct_rows_gather``)."""
+    opt = ctx.cfg.optimizer
+    return (opt.name.lower() == "adam" and opt.adam_b1 > 0 and opt.adam_b2 > 0
+            and ctx.cfg.mesh.data_parallel == 1 and not ctx.zero_layout
+            and lookup_exchange(ctx.cfg) == "psum")
+
+
+def _is_adam(x) -> bool:
+    return isinstance(x, optax.ScaleByAdamState)
+
+
+def _pre_add_rows(cfg: Config, opt_state, params, grads, taken, row_grads):
+    """Dense Adam without a dense gradient.  A table's gradient is ``g = d +
+    s``: ``d``, the L2 penalty's, elementwise in the table (``c·p``: XLA forms
+    it inside Adam's pass), and ``s``, non-zero on the step's distinct rows
+    only.  Adam's moments are linear accumulators of ``g`` and ``g²``, so
+
+        mu += (1−b1)/b1 · s            nu += (1−b2)/b2 · s·(s + 2·d)
+
+    on those rows (``d`` there from the rows the forward gathered), and then
+    the configured chain runs unchanged with ``d`` as that leaf's gradient:
+    ``b1·mu' + (1−b1)·d = b1·mu + (1−b1)(d+s)``, ``b2·nu' + (1−b2)·d² =
+    b2·nu + (1−b2)(d+s)²``.  The same mathematics in another order of
+    float32 sums (nu's addend floored a hair over ``−d²``, so that the sum
+    stays positive where ``s`` cancels ``d``); bias correction, schedule, the
+    embedding lr split and the state's tree stand as they are.  No
+    table-shaped zero fill, no write of the rows into it, and Adam's pass
+    reads six table-sized operands, not seven.  Holds for a table that
+    reaches the loss through the lookup and the step's penalty only (a
+    row-sharded leaf can do nothing else).
+
+    One loop a lookup call (``DistinctRows.add``), every table's targets in
+    the same trip: a table of rows adds into its ``mu`` and ``nu``; a table
+    of scalars (FM_W) keeps a table-shaped gradient, written in the same
+    trips — two scalar adds a distinct row in place of one would cost more
+    than its 50 MB pass saves (``_chunks``' rule once more).  Returns the
+    optimizer state and the gradients to hand the chain."""
+    b1, b2 = cfg.optimizer.adam_b1, cfg.optimizer.adam_b2
+    # nu's addend is g² − d², at least −d²: where s cancels d to the last
+    # bits (g under 4e-3 of d: no gradient to speak of) the pass's own
+    # (1−b2)·d² must still land on the positive side of a dozen roundings,
+    # or Adam takes the root of a negative number
+    nu_floor = -(1 - b2) / b2 * (1 - 2.0 ** -16)
+    # d = c·p with the step's own penalty differentiated, whatever the mesh
+    # does to a psum's transpose
+    c = jax.grad(lambda x: cfg.model.l2_reg * sharded_l2(x))(jnp.ones(()))
+    (adam,) = [x for x in jax.tree_util.tree_leaves(opt_state, is_leaf=_is_adam)
+               if _is_adam(x)]
+    mu, nu, grads = dict(adam.mu), dict(adam.nu), dict(grads)
+
+    def parts_of(s_parts, p_parts):
+        parts = []
+        for s, p in zip(s_parts, p_parts):
+            if s.ndim == 1:
+                parts.append(s)
+                continue
+            d = c * p
+            parts += [(1 - b1) / b1 * s, jnp.maximum(
+                (1 - b2) / b2 * s * (s + 2 * d), nu_floor * d * d)]
+        return parts
+
+    for rows, combined in zip(taken, row_grads):
+        added = iter(rows.add(combined, parts_of, [
+            t for k, tail in zip(rows.keys, rows.tails)
+            for t in ((mu[k], nu[k]) if tail else (jnp.zeros_like(params[k]),))
+        ]))
+        for k, tail in zip(rows.keys, rows.tails):
+            if tail:
+                mu[k], nu[k] = next(added), next(added)
+            else:
+                grads[k] = grads[k] + next(added)
+    adam = adam._replace(mu=mu, nu=nu)
+    return jax.tree_util.tree_map(
+        lambda x: adam if _is_adam(x) else x, opt_state, is_leaf=_is_adam
+    ), grads
+
+
 def _build_local_train_step(ctx: SPMDContext) -> Callable:
     """The per-shard ``(state, batch) -> (state, metrics)`` body (dense or
     lazy by config) — shared by the one-step dispatcher
     (``make_spmd_train_step``) and the scanned multi-step loop
-    (``make_spmd_train_loop``).  Metrics follow ``_train_metric_specs``."""
+    (``make_spmd_train_loop``).  Metrics follow ``_train_metric_specs``.
+
+    Where a table's gradient is rows and where it is table-shaped
+    (``_rows_into_moments``, then a lookup call's own static rule): under
+    Adam on a singleton data axis with the psum lookup, a table of rows read
+    on the distinct-rows plan has no table-shaped gradient — its distinct
+    rows are pre-added into Adam's moments (``_pre_add_rows``); everything
+    else keeps the materialised gradient.  Said once at trace time: ``table
+    update: moments pre-added by distinct rows | dense gradient, tables=``."""
     cfg = ctx.cfg
     model = get_model(cfg.model)
     tx = _build_tx(cfg, ctx.zero_layout)
     if cfg.optimizer.lazy_embedding_updates:
         return _build_lazy_local_step(ctx, model, tx)
+    by_rows = _rows_into_moments(ctx)
 
     def local_step(state: TrainState, batch: dict):
+        from ..ops.embedding import distinct_rows_gather
+
         # distinct dropout mask per data shard, identical across model shards
         step_rng = jax.random.fold_in(state.rng, state.step)
         step_rng = jax.random.fold_in(step_rng, lax.axis_index(DATA_AXIS))
 
-        def loss_fn(params):
-            return _local_loss(
-                cfg, model, params, state.model_state, batch, step_rng, True
+        def loss_fn(params, sinks):
+            gather, taken = distinct_rows_gather(
+                {k: params[k] for k in model.tables}, sinks
+            ) if by_rows else (None, [])
+            loss, aux = _local_loss(
+                cfg, model, params, state.model_state, batch, step_rng, True,
+                gather=gather,
             )
+            return loss, (aux, taken)
 
-        (loss, (ce, outputs, new_model_state)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params)
+        # a sink a call that takes rows, in the shape of its compact buffer
+        sinks = [
+            jnp.zeros(rows.compact.shape, rows.compact.dtype)
+            for rows in jax.eval_shape(
+                lambda params: loss_fn(params, None)[1][1], state.params)
+        ] if by_rows else []
+        (loss, ((ce, outputs, new_model_state), taken)), (
+            grads, row_grads) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1), has_aux=True
+        )(state.params, sinks)
+        by_row = sorted(k for rows in taken for k in rows.keys
+                        if state.params[k].ndim > 1)
+        for how, keys in (
+                ("moments pre-added by distinct rows", by_row),
+                ("dense gradient", sorted(set(model.tables) - set(by_row)))):
+            if keys:
+                logging.getLogger(__name__).info(
+                    "table update: %s, tables=%s", how, keys)
         new_model_state = _sync_model_state(new_model_state)
         if ctx.zero_layout:
             # RAW local grads go in — the wrapper reduce-scatters each
@@ -375,8 +495,12 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
         else:
             grads = _pmean_grads(model, grads)
             with jax.named_scope("optimizer"):
+                opt_state = state.opt_state
+                if taken:
+                    opt_state, grads = _pre_add_rows(
+                        cfg, opt_state, state.params, grads, taken, row_grads)
                 updates, new_opt_state = tx.update(
-                    grads, state.opt_state, state.params
+                    grads, opt_state, state.params
                 )
                 new_params = optax.apply_updates(state.params, updates)
         metrics = _train_metrics(model, loss, ce, outputs, batch)

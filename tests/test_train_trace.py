@@ -20,6 +20,7 @@ from deepfm_tpu.data.pipeline import DevicePrefetcher
 from deepfm_tpu.obs import trace as obs_trace
 from deepfm_tpu.obs.trace import (LOG_KEYS, SPANS, STEP_SCOPES, SpanRecorder,
                                   scope_of)
+from deepfm_tpu.parallel import spmd
 from deepfm_tpu.parallel import (
     build_mesh,
     create_spmd_state,
@@ -467,7 +468,13 @@ def test_scope_of_reads_through_the_transforms():
     {"model_name": "deepfm"},
     {"model_name": "xdeepfm", "cin_layers": (5, 4)},
 ], ids=["deepfm", "xdeepfm"])
-def test_step_instructions_carry_their_scope(model):
+@pytest.mark.parametrize("update", ["rows", "dense"])
+def test_step_instructions_carry_their_scope(model, update, monkeypatch):
+    """``update``: Adam on mesh [1, 1] pre-adds the step's distinct rows into
+    its moments, under ``optimizer`` (parallel/spmd.py ``_pre_add_rows``);
+    ``dense`` is the materialised gradient every other step keeps."""
+    if update == "dense":
+        monkeypatch.setattr(spmd, "_rows_into_moments", lambda ctx: False)
     ctx = _ctx(**model)
     state = create_spmd_state(ctx)
     batch = shard_batch(ctx, _host_batch(ctx.cfg, 16))
@@ -487,7 +494,8 @@ def test_step_instructions_carry_their_scope(model):
         found.setdefault(m.group(2), set()).add(part)
     # forward gathers and the table gradient's scatters
     assert "jvp(lookup)" in found["gather"]
-    assert found["scatter"] == {"transpose(jvp(lookup))"}
+    assert found["scatter"] == {"transpose(jvp(lookup))"} | (
+        {"optimizer"} if update == "rows" else set())
     dots = found.get("dot", set()) | found.get("convolution", set())
     assert {"jvp(mlp)", "transpose(jvp(mlp))"} <= dots
     if model["model_name"] == "xdeepfm":
@@ -522,7 +530,8 @@ _OPERANDS = re.compile(r"\(([^()]*)\)")
 @pytest.mark.parametrize("name,model", [
     ("tiny-deepfm", None), ("tiny-xdeepfm", None), ("tiny-deepfm", "dcnv2"),
 ], ids=["tiny-deepfm", "tiny-xdeepfm", "dcnv2"])
-def test_table_gradient_lowering_contract(name, model):
+@pytest.mark.parametrize("update", ["rows", "dense"])
+def test_table_gradient_lowering_contract(name, model, update, monkeypatch):
     """The cells' ``table_grad: "scatter"`` runs the lookup on the step's
     distinct rows, both ways, and ONE lookup serves the tables that share the
     step's ids (ops/embedding.py ``_lookup_fwd`` / ``_lookup_bwd``; FM_W rides
@@ -539,7 +548,19 @@ def test_table_gradient_lowering_contract(name, model):
     scatter-add into, the table of scalars, which is read and written only
     inside the two loops.  ``dcnv2`` looks up one table a call: one column
     block, nothing laid side by side (its lowered step is byte for byte the
-    parent's: CHANGES.md, PR 32)."""
+    parent's: CHANGES.md, PR 32).
+
+    ``update`` = ``dense`` is that contract, the materialised gradient every
+    step but this one keeps.  ``rows`` is the cells' own step since PR 35
+    (Adam, mesh [1, 1]: parallel/spmd.py ``_pre_add_rows``): the forward and
+    the compact scatter-add are the same, the backward holds no loop, and
+    the one write loop reads ``optimizer`` — a trip adds its chunk of
+    distinct rows into FM_V's two moments and writes FM_W's dense gradient,
+    so a table of rows is the target of two promised scatters and has no
+    gradient of its shape."""
+    by_rows = update == "rows"
+    if not by_rows:
+        monkeypatch.setattr(spmd, "_rows_into_moments", lambda ctx: False)
     cfg = _tiny_cell_config(name)
     assert cfg.model.table_grad == "scatter"
     if model:
@@ -568,12 +589,15 @@ def test_table_gradient_lowering_contract(name, model):
             continue
         name_ = _OP_NAME.search(line)
         assert name_, line
-        if "lookup" not in name_.group(1):
+        scope, part = scope_of(name_.group(1))
+        written = by_rows and part == "optimizer" and m.group(2) in (
+            "while", "scatter")
+        if "lookup" not in name_.group(1) and not written:
             # XLA:CPU's threefry loops, the tower's concatenates
             assert m.group(2) in ("while", "concatenate"), line
             continue
-        scope, part = scope_of(name_.group(1))
-        assert scope == "lookup" and part in (forward, backward), line
+        assert written or (
+            scope == "lookup" and part in (forward, backward)), line
         in_loop = "/while/body/" in name_.group(1)
         if m.group(2) == "concatenate" and not shape:
             continue                 # the run numbering's, of pred
@@ -592,7 +616,9 @@ def test_table_gradient_lowering_contract(name, model):
             scatters.append((dims, promised, part, in_loop))
     # the run structure is the forward's: ids, runs' ids, run numbers back
     assert kinds[forward, "sort"] >= 3 and (backward, "sort") not in kinds
-    assert kinds[forward, "while"] == 1 and kinds[backward, "while"] == 1
+    writer = "optimizer" if by_rows else backward
+    assert kinds[forward, "while"] == 1 and kinds[writer, "while"] == 1
+    assert not by_rows or (backward, "while") not in kinds
     # every table's rows leave it once a distinct row, a chunk a trip inside
     # the loop; the batch is expanded from the compact buffer, all columns
     chunk = 2048
@@ -607,11 +633,12 @@ def test_table_gradient_lowering_contract(name, model):
     assert compact[1] == width and n <= compact[0] < 2 * n
     # every table's gradient by the chunk loop, promised; the cotangents of
     # all tables combined by one scatter-add into the compact buffer
-    assert all(part == backward for _, _, part, _ in scatters)
-    assert sorted((dims, promised, in_loop)
-                  for dims, promised, _, in_loop in scatters) == sorted(
-        [(table, True, True) for table in tables]
-        + [(compact, False, False)])
+    # (by rows: a table of rows is written twice, into its mu and its nu)
+    assert sorted((dims, promised, in_loop, part)
+                  for dims, promised, part, in_loop in scatters) == sorted(
+        [(table, True, True, writer) for table in tables
+         for _ in range(2 if by_rows and len(table) > 1 else 1)]
+        + [(compact, False, False, backward)])
     # side by side: a chunk's columns a trip forward, the cotangents backward
     if len(tables) > 1:
         assert sorted(beside) == sorted([((chunk, width), forward, True),
