@@ -44,6 +44,15 @@ def _parse_float_list(s: str | Sequence[float]) -> tuple[float, ...]:
     return tuple(float(x) for x in s)
 
 
+def _parse_str_list(s: str | Sequence[str]) -> tuple[str, ...]:
+    if isinstance(s, str):    # --set model.layer_types=conv,full_attention
+        return tuple(
+            x.strip(" '\"") for x in _strip_list_wrappers(s).split(",")
+            if x.strip()
+        )
+    return tuple(str(x) for x in s)
+
+
 # ---- multi-tenant fleet (deepfm_tpu/fleet) --------------------------------
 
 # ModelConfig fields that determine the serving EXECUTABLES — the payload
@@ -178,7 +187,8 @@ class ModelConfig:
     batch_norm: bool = False          # ps:64-66
     batch_norm_decay: float = 0.9     # ps:67-69
     l2_reg: float = 0.0001            # ps:57; applied to FM_W/FM_V only (ps:275-279)
-    model_name: str = "deepfm"        # deepfm | xdeepfm | dcnv2 | two_tower
+    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe
+    model_name: str = "deepfm"
     # xDeepFM CIN layer sizes / DCN-v2 cross depth (ignored by plain deepfm)
     cin_layers: tuple[int, ...] = (128, 128)
     cross_layers: int = 3
@@ -192,6 +202,27 @@ class ModelConfig:
     tower_layers: tuple[int, ...] = (64, 32)
     tower_dim: int = 16
     temperature: float = 0.05
+    # token family (model_name="lfm2_moe", models/lfm2_moe.py; ignored by the
+    # others).  The hidden size rides ``embedding_size`` (a token row), the
+    # sequence length ``field_size``, the vocabulary held ``feature_size``.
+    # One entry a layer: "conv" (gated short convolution) | "full_attention"
+    layer_types: tuple[str, ...] = ()
+    num_dense_layers: int = 0         # leading layers with the dense SwiGLU
+    intermediate_size: int = 0        # the dense SwiGLU's width
+    moe_intermediate_size: int = 0    # one expert's width
+    num_experts: int = 0              # the router's outputs (all shares')
+    # experts held here, ids 0..held-1 of ``num_experts`` (0 = all of them):
+    # what the absent ones would add is left out (ops/experts.py)
+    experts_held: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    num_attention_heads: int = 0
+    num_key_value_heads: int = 0
+    conv_L_cache: int = 3             # the short convolution's kernel
+    norm_eps: float = 1e-5
+    rope_theta: float = 1_000_000.0
     # compute dtype for the MLP/FM math (params stay f32; bf16 feeds the MXU)
     compute_dtype: str = "bfloat16"
     # "scatter" | "segsum": selects nothing since PR 27.  The chip decided
@@ -245,6 +276,7 @@ class ModelConfig:
         object.__setattr__(self, "dropout_keep", _parse_float_list(self.dropout_keep))
         object.__setattr__(self, "cin_layers", _parse_int_list(self.cin_layers))
         object.__setattr__(self, "tower_layers", _parse_int_list(self.tower_layers))
+        object.__setattr__(self, "layer_types", _parse_str_list(self.layer_types))
         if len(self.dropout_keep) < len(self.deep_layers):
             raise ValueError(
                 f"dropout_keep has {len(self.dropout_keep)} entries for "

@@ -7,6 +7,7 @@ declares, never by its name:
 
     init(key, cfg)  -> (params, model_state)
     tables          {param key: ModelConfig field holding its row count}
+    read_whole      the tables the loss also reads whole (a tied head)
     batch(cfg)      -> {field: BatchField}
     loss(params, model_state, batch, *, cfg, train, rng, lookup_fn)
                     -> (local data loss, new model_state, outputs)
@@ -72,6 +73,10 @@ class ModelDef(NamedTuple):
     eval_init: Callable
     evaluate: Callable
     eval_summary: Callable
+    # tables the loss also reads whole, beside the lookup (a tied output
+    # head): their gradient holds more than the lookup's rows and the
+    # penalty, and every row takes part in every example
+    read_whole: frozenset = frozenset()
 
     def l2_penalty(self, params: dict, l2_reg: float) -> jnp.ndarray:
         """``l2_reg·Σ_tables l2_loss(table)`` where l2_loss = ½Σx²

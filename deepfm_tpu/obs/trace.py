@@ -438,7 +438,10 @@ def set_span_recorder(recorder: SpanRecorder) -> SpanRecorder:
 # the table gradient (the scatter) is ``transpose(jvp(lookup))`` and its L2
 # base ``transpose(jvp(l2_penalty))`` — there is no scope of their own.
 STEP_SCOPES = ("lookup", "fm", "cin", "cross", "mlp", "tower", "loss",
-               "l2_penalty", "grad_sync", "optimizer", "metrics")
+               "l2_penalty", "grad_sync", "optimizer", "metrics",
+               # the token family's (models/lfm2_moe.py)
+               "conv_mixer", "attention", "router", "experts", "dense_ffn",
+               "lm_head")
 
 
 def scope_of(op_name: str) -> tuple[str | None, str | None]:

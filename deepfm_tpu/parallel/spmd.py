@@ -204,6 +204,14 @@ def make_context(
     dp, mp = mesh_shape(mesh)
     model = get_model(cfg.model)
     true_rows = table_rows(model, cfg.model)
+    if mp > 1 and model.read_whole:
+        raise ValueError(
+            f"model {model.name!r} reads the table(s) "
+            f"{sorted(model.read_whole)} whole (a tied output head): "
+            f"row-sharding them over model_parallel={mp} needs a "
+            f"vocabulary-parallel loss, which the family does not have; use "
+            f"model_parallel=1"
+        )
     true_feature_size = cfg.model.feature_size
     window = _window_multiple(cfg)
     cfg = cfg.with_overrides(
@@ -379,8 +387,11 @@ def _pre_add_rows(cfg: Config, opt_state, params, grads, taken, row_grads):
     embedding lr split and the state's tree stand as they are.  No
     table-shaped zero fill, no write of the rows into it, and Adam's pass
     reads six table-sized operands, not seven.  Holds for a table that
-    reaches the loss through the lookup and the step's penalty only (a
-    row-sharded leaf can do nothing else).
+    reaches the loss through the lookup and the step's penalty only: a table
+    the loss also reads whole (``ModelDef.read_whole``, a tied output head)
+    has a third, dense part ``h`` in its gradient, ``nu`` would lose the
+    cross term ``2·s·h`` on every touched row, and the step declines the
+    pre-add for it — it keeps the materialised gradient.
 
     One loop a lookup call (``DistinctRows.add``), every table's targets in
     the same trip: a table of rows adds into its ``mu`` and ``nu``; a table
@@ -439,7 +450,8 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
     Adam on a singleton data axis with the psum lookup, a table of rows read
     on the distinct-rows plan has no table-shaped gradient — its distinct
     rows are pre-added into Adam's moments (``_pre_add_rows``); everything
-    else keeps the materialised gradient.  Said once at trace time: ``table
+    else keeps the materialised gradient, a table the loss also reads whole
+    (``ModelDef.read_whole``) among it.  Said once at trace time: ``table
     update: moments pre-added by distinct rows | dense gradient, tables=``."""
     cfg = ctx.cfg
     model = get_model(cfg.model)
@@ -457,7 +469,8 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
 
         def loss_fn(params, sinks):
             gather, taken = distinct_rows_gather(
-                {k: params[k] for k in model.tables}, sinks
+                {k: params[k] for k in model.tables
+                 if k not in model.read_whole}, sinks
             ) if by_rows else (None, [])
             loss, aux = _local_loss(
                 cfg, model, params, state.model_state, batch, step_rng, True,

@@ -86,8 +86,9 @@ def load_servable(directory: str | os.PathLike) -> tuple[Callable, Config]:
     cfg = _load_config(directory)
     if get_model(cfg.model).apply is None:  # no scoring call
         raise ValueError(
-            "this servable is a two-tower retrieval model; "
-            "use serve.load_retrieval_servable"
+            f"load_servable scores a row with the model's apply; model "
+            f"{cfg.model.model_name!r} declares none (a two-tower servable "
+            f"loads with serve.load_retrieval_servable)"
         )
     model = get_model(cfg.model)
     params, model_state = _restore_payload(

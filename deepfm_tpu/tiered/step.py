@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import optax
 
 from ..core.config import Config
-from ..models.base import get_model
+from ..models.base import get_model, require_fields
 from ..ops.embedding import dense_lookup, gathered_rows_lookup
 from ..train.lazy import lazy_adam_update, shared_segments
 from ..train.optimizer import build_lr_schedule, build_optimizer, schedule_value
@@ -98,6 +98,8 @@ def make_paged_train_step(
     + ``stage`` {table: {rows, m, v}} is the pager's miss pack for THIS
     batch — applied before the gather so every batch slot is live."""
     model = get_model(cfg.model)
+    require_fields(model, cfg.model, ("feat_ids", "feat_vals", "label"),
+                   "the tiered step")
     tx = build_optimizer(cfg.optimizer, data_parallel_size=_dp_size(cfg))
     lr_sched = build_lr_schedule(
         cfg.optimizer, data_parallel_size=_dp_size(cfg)

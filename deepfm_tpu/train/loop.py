@@ -133,13 +133,19 @@ def _cpu_serialize_dispatch() -> bool:
     return jax.default_backend() == "cpu"
 
 
+# what a record of ``data/pipeline.py`` holds
+RECORD_FIELDS = frozenset({"feat_ids", "feat_vals", "label"})
+
+
 def _record_reader(cfg: Config) -> str:
     """Which record reader feeds the model, by the batch it declares:
-    ``records`` (tfrecord / libsvm click-through examples,
-    ``data/pipeline.py``) or ``ratings`` (``data/ratings.py``)."""
+    ``records`` (tfrecord / libsvm examples, ``data/pipeline.py``: a record's
+    ``field_size`` ids, their values and a label — a family that declares some
+    of the three is handed those, ``_declared``; a record's ids alone are one
+    packed token sequence) or ``ratings`` (``data/ratings.py``)."""
     model = get_model(cfg.model)
     fields = set(model.batch(cfg.model))
-    if fields == {"feat_ids", "feat_vals", "label"}:
+    if fields and fields <= RECORD_FIELDS:
         return "records"
     if fields == {"user_ids", "user_vals", "item_ids", "item_vals"}:
         return "ratings"
@@ -147,6 +153,13 @@ def _record_reader(cfg: Config) -> str:
         f"no record reader yields model {model.name!r}'s declared batch "
         f"{sorted(fields)}"
     )
+
+
+def _declared(cfg: Config, batches: Iterator[dict]) -> Iterator[dict]:
+    """The record reader's batches cut to the fields the model declares (the
+    placer refuses an undeclared field)."""
+    fields = tuple(get_model(cfg.model).batch(cfg.model))
+    return ({k: b[k] for k in fields} for b in batches)
 
 
 def _rows(batch: dict, axis: int = 0) -> int:
@@ -169,7 +182,7 @@ def _train_batches(
             skip_batches, None,
         )
     else:
-        batches = make_input_pipeline(
+        batches = _declared(cfg, make_input_pipeline(
             cfg.data,
             worker_topology(cfg),
             field_size=cfg.model.field_size,
@@ -183,7 +196,7 @@ def _train_batches(
             # level; stream mode (live FIFO, fresh data) ignores the skip
             # inside make_input_pipeline
             skip_batches=skip_batches,
-        )
+        ))
     k = max(1, cfg.run.steps_per_loop)
     if k == 1:
         return DevicePrefetcher(
@@ -290,13 +303,13 @@ def _eval_batches(cfg: Config, ctx: SPMDContext) -> Iterator[dict]:
         sources = discover_files(base, patterns=("va", "val", "eval"), shuffle=False)
         if not sources:
             raise FileNotFoundError(f"no va*/val*/eval* tfrecords under {base!r}")
-    batches = ctr_batches_from_sources(
+    batches = _declared(cfg, ctr_batches_from_sources(
         sources,
         batch_size=cfg.data.batch_size,
         field_size=cfg.model.field_size,
         drop_remainder=False,
         permute_vocab=permute,
-    )
+    ))
     if cfg.data.stream_mode and cfg.data.eval_max_batches > 0:
         batches = itertools.islice(batches, cfg.data.eval_max_batches)
     for batch, true_count in _padded_batches(batches, ctx.mesh.shape["data"]):

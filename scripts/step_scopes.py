@@ -31,6 +31,10 @@ sys.path.insert(0, str(ROOT))
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CONTAINER = re.compile(r" = .*? (?:while|conditional|call)\(")
+# a Pallas call's instruction carries the kernel's name and no ``op_name``
+KERNEL_SCOPES = {"splash_mha_fwd_residuals": "attention kernel",
+                 "splash_mha_fwd_no_residuals": "attention kernel",
+                 "splash_mha_dkv_no_residuals": "attention kernel (backward)"}
 
 
 def op_names(hlo_text: str) -> dict:
@@ -118,7 +122,14 @@ def main() -> int:
     for text, ns in sorted(by_op.items(), key=lambda kv: -kv[1]):
         instr = text.split(" = ", 1)[0].strip().lstrip("%")
         op_name = names.get(instr, "")
-        written = scope_of(op_name)[1] or "(none)"
+        written = scope_of(op_name)[1] or KERNEL_SCOPES.get(
+            instr.split(".")[0], "(none)")
+        # inside a jax.checkpoint the transforms wrap the checkpoint, not the
+        # scope: its recomputation and its backward read the bare scope
+        if "/rematted_computation/" in op_name:
+            written += " (recomputed)"
+        elif "/checkpoint/" in op_name:
+            written += " (backward)"
         by_scope[written] = by_scope.get(written, 0) + ns
         rows.append({"op": perf_trace.short_op(text), "scope": written,
                      "op_name": op_name, "ms_per_step": ns / 1e6 / steps})

@@ -222,15 +222,62 @@ PARAM_LEAVES = {
 }
 
 
+def _lfm2_leaves() -> dict:
+    """The contract between ``models/lfm2_moe.py`` and
+    ``perf/reference/lfm2_moe.py`` at the published widths: published layers
+    1–5 as layer_0 … layer_4."""
+    norms = {"op_norm": (2048,), "ffn_norm": (2048,)}
+    conv = {"conv/in_proj": (2048, 6144), "conv/conv": (3, 2048),
+            "conv/out_proj": (2048, 2048)}
+    experts = {"experts/w1": (8, 2048, 1536), "experts/w3": (8, 2048, 1536),
+               "experts/w2": (8, 1536, 2048), "router/gate": (2048, 64)}
+    layers = [
+        {**norms, **conv, "dense_ffn/w1": (2048, 11776),
+         "dense_ffn/w3": (2048, 11776), "dense_ffn/w2": (11776, 2048)},
+        {**norms, **experts,
+         "attention/q_proj": (2048, 2048), "attention/k_proj": (2048, 512),
+         "attention/v_proj": (2048, 512), "attention/o_proj": (2048, 2048),
+         "attention/q_norm": (64,), "attention/k_norm": (64,)},
+    ] + [{**norms, **conv, **experts}] * 3
+    return {"tok_embedding": (8192, 2048), "out_norm": (2048,),
+            **{f"layer_{l}/{k}": shape for l, layer in enumerate(layers)
+               for k, shape in layer.items()}}
+
+
+# per family: the TRUE rows of its table share, its parameter leaves, the
+# non-trainable state beside them, its placed batch ((b, f) the traffic's
+# batch and the configuration's field_size) and its step's metrics
+CONTRACTS = {
+    **{name: {
+        "true_feature_size": 12_500_000,
+        "leaves": PARAM_LEAVES[name],
+        "model_state": {},
+        "batch": lambda b, f: {"feat_ids": ((b, f), "int32"),
+                               "feat_vals": ((b, f), "float32"),
+                               "label": ((b,), "float32")},
+        "metrics": STEP_METRICS,
+    } for name in PARAM_LEAVES},
+    "lfm2_moe": {
+        "true_feature_size": 8192,
+        "leaves": _lfm2_leaves(),
+        # the routers' selection biases: drawn from the seed, never stepped
+        "model_state": {f"layer_{l}/expert_bias": (64,) for l in (1, 2, 3, 4)},
+        "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
+        "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
+                    "loss_per_shard"},
+    },
+}
+
+
 @pytest.mark.parametrize("name", BENCHMARK_CELLS)
 def test_benchmark_cell_step_lowers_with_the_parents_names_and_state(name):
     """At the cell's own size, abstract state, on the CPU: the jitted
     function is still ``local_step`` (``reduce_xplane`` finds the step by
-    it), its metrics are the parent's five, and the state is the parent's
-    tree — parameter leaves by name and shape, Adam's ``mu`` / ``nu``
-    mirroring them, nothing else."""
+    it), its metrics are its family's (the click-through families' five are
+    the parent's), and the state is the tree the family's plain reference
+    rebuilds — parameter leaves by name and shape, Adam's ``mu`` / ``nu``
+    mirroring them, the family's non-trainable state, nothing else."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
     from deepfm_tpu.parallel import spmd
@@ -238,36 +285,36 @@ def test_benchmark_cell_step_lowers_with_the_parents_names_and_state(name):
     from perf.entries import train
 
     cfg = train.build_config(_cell("benchmark", name), seed=1)
+    want = CONTRACTS[cfg.model.model_name]
     mesh = build_mesh(cfg.mesh, devices=jax.devices()[:1])
     ctx = spmd.make_context(cfg, mesh)
-    assert ctx.true_feature_size == 12_500_000 == ctx.cfg.model.feature_size
+    assert (ctx.true_feature_size == want["true_feature_size"]
+            == ctx.cfg.model.feature_size)
     abstract = spmd.abstract_spmd_state(ctx)
 
     def leaves(tree):
         return {"/".join(str(k.key) for k in path): leaf.shape for path, leaf
                 in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
-    want = PARAM_LEAVES[cfg.model.model_name]
-    assert leaves(abstract.params) == want
+    assert leaves(abstract.params) == want["leaves"]
     (adam, _empty), = [abstract.opt_state]
-    assert leaves(adam.mu) == want and leaves(adam.nu) == want
-    assert abstract.model_state == {} and abstract.step.shape == ()
-    assert len(jax.tree_util.tree_leaves(abstract)) == 3 * len(want) + 3
+    assert leaves(adam.mu) == want["leaves"] == leaves(adam.nu)
+    assert leaves(abstract.model_state) == want["model_state"]
+    assert abstract.step.shape == ()
+    assert len(jax.tree_util.tree_leaves(abstract)) == (
+        3 * len(want["leaves"]) + 3 + len(want["model_state"]))
 
     state = jax.tree_util.tree_map(
         lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
         abstract, ctx.state_shardings)
-    b, f = cfg.data.batch_size, cfg.model.field_size
     batch = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
-        mesh, ctx.batch_specs[k])) for k, (shape, dtype) in {
-            "feat_ids": ((b, f), jnp.int32), "feat_vals": ((b, f), jnp.float32),
-            "label": ((b,), jnp.float32)}.items()}
+        mesh, ctx.batch_specs[k])) for k, (shape, dtype) in want["batch"](
+            cfg.data.batch_size, cfg.model.field_size).items()}
     assert set(ctx.batch_specs) == set(batch)
     lowered = spmd.make_spmd_train_step(ctx).lower(state, batch)
     assert "jit_local_step" in lowered.as_text()[:400]
     new_state, metrics = lowered.out_info
-    assert set(metrics) == STEP_METRICS
+    assert set(metrics) == want["metrics"]
     assert metrics["loss_per_shard"].shape == (1,)
     assert (jax.tree_util.tree_structure(new_state)
             == jax.tree_util.tree_structure(abstract))
-
