@@ -54,7 +54,7 @@ from ..ops.attention import (
     rope_tables,
 )
 from ..ops.embedding import dense_lookup, narrow_ids
-from ..ops.experts import held_experts_sum, route
+from ..ops.experts import compact_rows, held_experts_sum, route
 from .base import BatchField, ModelDef, register_model
 
 TABLE = "tok_embedding"
@@ -200,8 +200,8 @@ def sparse_ffn(p: dict, bias, x, cfg: ModelConfig, axis_name):
     with jax.named_scope("experts"):
         y, took = held_experts_sum(
             x, chosen, w, p["experts"]["w1"], p["experts"]["w3"],
-            p["experts"]["w2"], axis_name=axis_name,
-            compute_dtype=jnp.dtype(cfg.compute_dtype))
+            p["experts"]["w2"], num_experts=cfg.num_experts,
+            axis_name=axis_name, compute_dtype=jnp.dtype(cfg.compute_dtype))
     return y.reshape(shape), took.astype(jnp.float32)
 
 
@@ -275,7 +275,9 @@ def lfm2_moe_loss(params, model_state, batch, *, cfg, train=False, rng=None,
     ``tokens·top_k`` assignments that landed on held experts, the mean over
     the expert layers (held/num_experts under an even router), and
     ``expert_load_max_share``, the fullest held expert's rows over the held
-    experts' mean, the worst layer."""
+    experts' mean, the worst layer, and ``experts_compact_share``, the share
+    of the expert layers whose held rows fit the compact buffer
+    (``ops/experts.compact_rows``: 1 where that buffer is every row)."""
     ids = _ids(batch, cfg)
     hidden, took = hidden_states(
         params, model_state, ids, cfg=cfg,
@@ -290,18 +292,22 @@ def routing_counters(took: list, tokens: int, cfg: ModelConfig) -> dict:
     if not took:
         return {k: jnp.zeros(()) for k in LFM2_MOE_METRICS}
     took = lax.stop_gradient(jnp.stack(took))
-    mean = jnp.mean(took, axis=1)
+    mean, rows = jnp.mean(took, axis=1), jnp.sum(took, axis=1)
+    assignments = tokens * cfg.num_experts_per_tok
     return {
-        "rows_held_share": jnp.mean(jnp.sum(took, axis=1))
-        / (tokens * cfg.num_experts_per_tok),
+        "rows_held_share": jnp.mean(rows) / assignments,
         "expert_load_max_share": jnp.max(
             jnp.max(took, axis=1) / jnp.maximum(mean, 1.0 / took.shape[1])),
+        "experts_compact_share": jnp.mean(
+            rows <= compact_rows(assignments, took.shape[1], cfg.num_experts),
+            dtype=jnp.float32),
     }
 
 
 LFM2_MOE_METRICS = {
     k: (lambda outputs, batch, k=k: outputs[k])
-    for k in ("rows_held_share", "expert_load_max_share")
+    for k in ("rows_held_share", "expert_load_max_share",
+              "experts_compact_share")
 }
 
 

@@ -18,13 +18,23 @@ Nothing stands in for absent chips or their traffic.  It names its axis and
 needs nothing else of ``parallel/`` (``lax.psum`` and ``lax.axis_index`` by
 name), so it lives here and ``models/`` imports it like any other op.
 
-No capacity, no dropped row: the rows routed here are gathered into a static
-buffer of the worst case, every assignment (``tokens·top_k`` rows, of which
-``held/num_experts`` fill on average), grouped by expert
-(``ops/grouped.py``).
+No capacity, no dropped row: the rows routed here are grouped by expert
+(``ops/grouped.py``), the held ones first, and gathered into a static buffer
+that follows the rows the layer expects.  Every assignment (``A = tokens·
+top_k`` rows) is the worst case; an even router sends ``A·held/num_experts``
+of them here, and the gathers, masks and scatter-adds around the grouped
+products cost by the buffer's declared rows, live or not.  So the buffer is
+the prefix of ``compact_rows`` = ``C`` rows (``_ROOM`` times the even share,
+a multiple of 128, at most ``A``) whenever the step's held rows fit it, and
+all ``A`` rows otherwise: a ``lax.cond`` on the step's own count between two
+runs of the same computation, the same live rows in the same order, groups
+and dtypes.  Where ``C == A`` (``_ROOM·held >= num_experts``) there is one
+path and no ``cond``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -48,22 +58,75 @@ def route(x, gate, bias, *, top_k: int, norm_topk_prob: bool = True,
     return chosen, w * scale
 
 
-def held_experts_sum(x, chosen, weights, w1, w3, w2, *, axis_name=None,
-                     compute_dtype=jnp.bfloat16):
+# The compact buffer's rows over an even router's share.  A biased or trained
+# router strays from the even share (0.09 to 0.125 of a step's assignments
+# where the even share is 0.125: PERF.md §6) but passes twice of it only when
+# it has collapsed onto the held experts: 2 leaves the fallback to that case,
+# and takes the index ops' cost from ``num_experts/held`` to 2 times the live
+# rows'.  A larger factor costs in proportion and wins nothing back.
+_ROOM = 2
+_ROW_TILE = 128
+
+
+def compact_rows(assignments: int, held: int, num_experts: int) -> int:
+    """The static rows of the compact buffer: ``_ROOM`` times the
+    ``assignments·held/num_experts`` an even router sends here, rounded up to
+    a tile, and never more than every assignment."""
+    even = -(-_ROOM * assignments * held // num_experts)
+    return min(assignments, -(-even // _ROW_TILE) * _ROW_TILE)
+
+
+def _buffer_sum(rows, dtype, x, weights, order, live, sizes, w1, w3, w2):
+    """Gather, grouped SwiGLU in ``dtype``, weight and scatter-add over the
+    buffer of the first ``rows`` that ``order`` and ``live`` list: x [T, h],
+    weights [T, k] -> [T, h] float32."""
+    order, live = order[:rows], live[:rows]
+    t, k = weights.shape
+    token = order // k
+    y = grouped_swiglu(jnp.take(x.astype(dtype), token, axis=0), w1, w3, w2,
+                       sizes, live)
+    y = y.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
+    return jnp.zeros((t, x.shape[1]), jnp.float32).at[
+        jnp.where(live, token, t)].add(y, mode="drop")
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _either_buffer(compact, dtype, x, weights, order, live, sizes, *stacks):
+    """``_buffer_sum`` over the first ``compact`` rows where the held rows fit
+    them, over every row where they do not.
+
+    Each branch a ``jax.checkpoint``: differentiated bare, the forward
+    ``cond`` hands on both branches' residuals, the untaken branch's as zero
+    fills of its own size (worst-case arrays written on every compact step);
+    checkpointed, its residuals are the layer's inputs and each backward
+    branch recomputes its own forward.  A ``jit`` of its own, so that a
+    model's expert layers of one shape are traced and lowered once, both
+    branches and both ways of differentiation."""
+    fits, not_all = (jax.checkpoint(functools.partial(_buffer_sum, rows, dtype))
+                     for rows in (compact, order.shape[0]))
+    return lax.cond(jnp.sum(sizes) <= compact, fits, not_all, x, weights,
+                    order, live, sizes, *stacks)
+
+
+def held_experts_sum(x, chosen, weights, w1, w3, w2, *, num_experts: int,
+                     axis_name=None, compute_dtype=jnp.bfloat16):
     """The held experts' part of the layer's result: x [T, h], ``chosen`` and
-    ``weights`` [T, k] from ``route``, w1 and w3 [held, h, m], w2 [held, m,
-    h] -> ``(y [T, h] float32, sizes [held])``, ``sizes`` the rows each held
-    expert took."""
+    ``weights`` [T, k] from ``route`` over ``num_experts``, w1 and w3 [held,
+    h, m], w2 [held, m, h] -> ``(y [T, h] float32, sizes [held])``, ``sizes``
+    the rows each held expert took."""
     held = w1.shape[0]
     lo = lax.axis_index(axis_name) * held if axis_name else 0
-    t, k = chosen.shape
+    a = chosen.size
+    c = compact_rows(a, held, num_experts)
     order, sizes, live = group_rows((chosen - lo).reshape(-1), held)
-    token = order // k
-    rows = jnp.take(x.astype(compute_dtype), token, axis=0)
-    y = grouped_swiglu(rows, w1, w3, w2, sizes, live)
-    y = y.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
-    out = jnp.zeros((t, x.shape[1]), jnp.float32).at[
-        jnp.where(live, token, t)].add(y, mode="drop")
+    args = (x, weights, order, live, sizes, w1, w3, w2)
+    if c == a:
+        out = _buffer_sum(a, compute_dtype, *args)
+    else:
+        out = _either_buffer(c, compute_dtype, *args)
+    # each shard has its own count: the sum over the axis stays outside the
+    # choice, a collective inside a branch would wait for shards that took
+    # the other one
     if axis_name:
         out = lax.psum(out, axis_name)
     return out, sizes

@@ -1,11 +1,13 @@
 """Rows grouped by expert, and the grouped products over the experts held.
 
-No capacity and no dropped row: the buffer is the worst case (every
-assignment lands here), sorted so that each held expert's rows are
-contiguous, and the grouped product (``lax.ragged_dot``: XLA:TPU's own tiled
-kernel, whose trip count follows the live rows) runs over the live prefix.
-What lies past it is masked to zero on the way in and on the way out, both
-ways of differentiation.
+No capacity and no dropped row: the assignments are sorted so that each held
+expert's rows are contiguous, the held ones first, and the caller's buffer is
+a static prefix of that order — every assignment in the worst case, or the
+compact prefix ``ops/experts.py`` takes while the step's held rows fit it
+(``sum(sizes)`` never passes the buffer it hands over).  The grouped product
+(``lax.ragged_dot``: XLA:TPU's own tiled kernel, whose trip count follows the
+live rows) runs over the live prefix.  What lies past it is masked to zero on
+the way in and on the way out, both ways of differentiation.
 """
 
 from __future__ import annotations
@@ -34,9 +36,10 @@ def group_rows(expert_of, held: int):
 
 def grouped_swiglu(rows, w1, w3, w2, sizes, live):
     """``W₂ᵉ(silu(W₁ᵉx) ⊙ W₃ᵉx)`` of every live row ``x`` with its group's
-    expert ``e``: rows [A, h] grouped as ``sizes`` says, w1 and w3 [held, h,
-    m], w2 [held, m, h] -> [A, h]; zero past the live prefix.  silu and the
-    gate in float32, the products in the rows' dtype."""
+    expert ``e``: rows [R, h] grouped as ``sizes`` says (R any prefix of the
+    order that holds the ``sum(sizes)`` live rows), w1 and w3 [held, h, m],
+    w2 [held, m, h] -> [R, h]; zero past the live prefix.  silu and the gate
+    in float32, the products in the rows' dtype."""
     keep = live[:, None]
     rows = jnp.where(keep, rows, 0)
 

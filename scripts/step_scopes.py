@@ -122,13 +122,16 @@ def main() -> int:
     for text, ns in sorted(by_op.items(), key=lambda kv: -kv[1]):
         instr = text.split(" = ", 1)[0].strip().lstrip("%")
         op_name = names.get(instr, "")
-        written = scope_of(op_name)[1] or KERNEL_SCOPES.get(
-            instr.split(".")[0], "(none)")
-        # inside a jax.checkpoint the transforms wrap the checkpoint, not the
-        # scope: its recomputation and its backward read the bare scope
-        if "/rematted_computation/" in op_name:
+        scope = scope_of(op_name)[1]
+        written = scope or KERNEL_SCOPES.get(instr.split(".")[0], "(none)")
+        # inside a block's jax.checkpoint the transforms wrap the checkpoint,
+        # not the scope: its recomputation and its backward read the bare
+        # scope.  A checkpoint UNDER the scope (the expert layer's branches)
+        # is the scope's own: what it recomputes is part of its backward
+        around = op_name[:op_name.index(scope)] if scope else op_name
+        if "/rematted_computation/" in around:
             written += " (recomputed)"
-        elif "/checkpoint/" in op_name:
+        elif "/checkpoint/" in around:
             written += " (backward)"
         by_scope[written] = by_scope.get(written, 0) + ns
         rows.append({"op": perf_trace.short_op(text), "scope": written,

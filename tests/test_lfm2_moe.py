@@ -26,7 +26,11 @@ if str(ROOT) not in sys.path:
 from deepfm_tpu.core.config import Config, MeshConfig  # noqa: E402
 from deepfm_tpu.models import lfm2_moe  # noqa: E402
 from deepfm_tpu.ops.attention import causal_attention, kernel_tile  # noqa: E402
-from deepfm_tpu.ops.experts import held_experts_sum, route  # noqa: E402
+from deepfm_tpu.ops.experts import (  # noqa: E402
+    compact_rows,
+    held_experts_sum,
+    route,
+)
 from deepfm_tpu.parallel import MODEL_AXIS, build_mesh  # noqa: E402
 from perf.reference import _common as c  # noqa: E402
 from perf.reference import lfm2_moe as ref  # noqa: E402
@@ -142,13 +146,17 @@ def test_logits_loss_and_every_gradient_leaf_match_the_reference(routing):
 def test_the_eight_shares_add_up_to_the_uncut_layer():
     """The guide's share test: 16 experts over 8 shards of 2, the router
     computed once; each shard's partial sum comes from its own axis index and
-    the psum over the model axis is the uncut reference layer."""
+    the psum over the model axis is the uncut reference layer.  256 tokens,
+    so that each shard's buffer is the compact one (128 of 512 rows) under a
+    ``cond`` on its own count, and the psum stays outside the choice."""
     cfg = _config(experts_held=0)
     s = _sizes(cfg)
     assert s.held == s.experts == 16
+    tokens = 256
+    assert compact_rows(tokens * s.top_k, 2, 16) == 128 < tokens * s.top_k
     params, bias = ref.init(jax.random.PRNGKey(3), s)
     p = params["layer_1"]
-    x = jax.random.normal(jax.random.PRNGKey(4), (40, s.hidden))
+    x = jax.random.normal(jax.random.PRNGKey(4), (tokens, s.hidden))
     with jax.default_matmul_precision("highest"):
         want = ref._experts(p, bias[1], x, s, c.Policy(), jnp.float32)
         chosen, w = route(x, p["router"]["gate"], bias[1], top_k=s.top_k)
@@ -156,20 +164,23 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         def share(x, chosen, w, w1, w3, w2):
             assert w1.shape[0] == 2
             y, sizes = held_experts_sum(
-                x, chosen, w, w1, w3, w2, axis_name=MODEL_AXIS,
-                compute_dtype=jnp.float32)
+                x, chosen, w, w1, w3, w2, num_experts=16,
+                axis_name=MODEL_AXIS, compute_dtype=jnp.float32)
             mine = held_experts_sum(   # the same share, without its psum
                 x, chosen - 2 * jax.lax.axis_index(MODEL_AXIS), w, w1, w3,
-                w2, compute_dtype=jnp.float32)[0]
+                w2, num_experts=16, compute_dtype=jnp.float32)[0]
             return y, sizes, mine[None]
 
         split = P(MODEL_AXIS)
-        y, sizes, parts = shard_map(
+        sharded = shard_map(
             share, mesh=_mesh(1, 8), in_specs=(P(), P(), P(), split, split,
                                                split),
-            out_specs=(P(), split, split), check_vma=False,
-        )(x, chosen, w, *(p["experts"][k] for k in ("w1", "w3", "w2")))
-    assert int(jnp.sum(sizes)) == 40 * s.top_k       # every assignment, once
+            out_specs=(P(), split, split), check_vma=False)
+        args = (x, chosen, w, *(p["experts"][k] for k in ("w1", "w3", "w2")))
+        y, sizes, parts = sharded(*args)
+    prims = [e.primitive.name for e in _eqns(jax.make_jaxpr(sharded)(*args))]
+    assert prims.count("cond") == 2 and prims.count("psum") == 1
+    assert int(jnp.sum(sizes)) == tokens * s.top_k   # every assignment, once
     assert _rel(y, want) <= 1e-5
     assert _rel(jnp.sum(parts, axis=0), want) <= 1e-5
     assert float(jnp.max(jnp.abs(parts[0]))) > 0     # one share is a part
@@ -177,21 +188,144 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 
 
 def test_no_row_is_dropped_when_every_assignment_lands_on_one_held_expert():
-    """The worst case the static buffer is sized for: all tokens·top_k rows
-    on expert 0."""
+    """The worst case: all tokens·top_k rows on expert 0, twice the compact
+    buffer's 256 — through the fallback, no capacity anywhere."""
     cfg = _config()
     s = _sizes(cfg)
+    tokens = 256
+    assert compact_rows(tokens * 2, s.held, s.experts) == 256 < tokens * 2
     p = ref.init(jax.random.PRNGKey(8), s)[0]["layer_2"]["experts"]
-    x = jax.random.normal(jax.random.PRNGKey(9), (24, s.hidden))
-    chosen = jnp.zeros((24, 2), jnp.int32)
-    w = jnp.full((24, 2), 0.5)
+    x = jax.random.normal(jax.random.PRNGKey(9), (tokens, s.hidden))
+    chosen = jnp.zeros((tokens, 2), jnp.int32)
+    w = jnp.full((tokens, 2), 0.5)
     with jax.default_matmul_precision("highest"):
         y, sizes = held_experts_sum(x, chosen, w, p["w1"], p["w3"], p["w2"],
+                                    num_experts=s.experts,
                                     compute_dtype=jnp.float32)
         want = ref._swiglu(x, p["w1"][0], p["w3"][0], p["w2"][0], c.Policy(),
                            jnp.float32)
-    assert sizes.tolist() == [48, 0, 0, 0]
+    assert sizes.tolist() == [tokens * 2, 0, 0, 0]
     assert _rel(y, want) <= 1e-5
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+# 16 experts, 2 held, top-2, 256 tokens: 512 assignments, 64 of them held
+# under an even router, a compact buffer of 128
+_T, _K, _HELD, _EXPERTS = 256, 2, 2, 16
+
+
+def _routed(case: str):
+    """(chosen [T, k], held rows) of a routing that stays within the compact
+    buffer, one that passes it, and one with every row on held expert 0."""
+    token = np.arange(_T)
+    if case == "within":       # every eighth token on expert 0 or 1: 64 rows
+        first = np.where(token % 8 < 2, token % 8, 2 + token % 14)
+    elif case == "over":       # every other token on expert 0 or 1: 192 rows
+        first = np.where(token % 8 < 6, token % 2, 2 + token % 14)
+    else:
+        return jnp.zeros((_T, _K), jnp.int32), _T * _K
+    second = 2 + (token + 5) % 14
+    return (jnp.asarray(np.stack([first, second], 1), jnp.int32),
+            int(np.sum(first < _HELD)))
+
+
+def _layer_inputs(seed: int = 12):
+    s = _sizes(_config())
+    p = ref.init(jax.random.PRNGKey(seed), s)[0]["layer_2"]["experts"]
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+    x = jax.random.normal(keys[0], (_T, s.hidden))
+    w = jax.nn.softmax(jax.random.normal(keys[1], (_T, _K)))
+    return x, w, tuple(p[k][:_HELD] for k in ("w1", "w3", "w2"))
+
+
+@pytest.mark.parametrize("case", ["within", "over", "one-expert"])
+def test_the_compact_buffer_and_its_fallback_are_the_uncut_layer_both_ways(
+        case):
+    """Value and the gradients to x, the weights and the three stacks against
+    every held expert over every token, the tokens that did not choose it at
+    weight zero (the reference's form): through the compact buffer where the
+    held rows fit it, through all rows where they do not."""
+    a = _T * _K
+    c_rows = compact_rows(a, _HELD, _EXPERTS)
+    assert c_rows == 128 < a
+    chosen, rows = _routed(case)
+    assert (rows <= c_rows) == (case == "within")
+    x, w, stacks = _layer_inputs()
+    probe = jax.random.normal(jax.random.PRNGKey(14), x.shape)
+
+    def layer(x, w, w1, w3, w2):
+        y, sizes = held_experts_sum(x, chosen, w, w1, w3, w2,
+                                    num_experts=_EXPERTS,
+                                    compute_dtype=jnp.float32)
+        return jnp.sum(y * probe), (y, sizes)
+
+    def plain(x, w, w1, w3, w2):
+        y = sum(jnp.sum(jnp.where(chosen == e, w, 0), -1, keepdims=True)
+                * ref._swiglu(x, w1[e], w3[e], w2[e], c.Policy(), jnp.float32)
+                for e in range(_HELD))
+        return jnp.sum(y * probe), y
+
+    every = tuple(range(5))
+    with jax.default_matmul_precision("highest"):
+        (_, (y, sizes)), got = jax.jit(jax.value_and_grad(
+            layer, argnums=every, has_aux=True))(x, w, *stacks)
+        (_, want_y), want = jax.value_and_grad(
+            plain, argnums=every, has_aux=True)(x, w, *stacks)
+    assert int(jnp.sum(sizes)) == rows
+    assert _rel(y, want_y) <= 1e-5
+    for name, g, wg in zip(("x", "weights", "w1", "w3", "w2"), got, want):
+        assert float(jnp.max(jnp.abs(wg))) > 0, name
+        assert _rel(g, wg) <= 1e-5, name
+
+
+def _layer_grad_jaxpr(held: int):
+    """The layer's differentiated jaxpr with ``held`` of the 16 experts."""
+    x, w, (w1, w3, w2) = _layer_inputs()
+    stacks = [jnp.zeros((held, *leaf.shape[1:])) for leaf in (w1, w3, w2)]
+    chosen = _routed("within")[0]
+
+    def loss(x, w, w1, w3, w2):
+        return jnp.sum(held_experts_sum(
+            x, chosen, w, w1, w3, w2, num_experts=_EXPERTS,
+            compute_dtype=jnp.float32)[0])
+
+    return jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(5))))(
+        x, w, *stacks)
+
+
+def test_half_of_the_experts_held_is_one_path_and_no_cond():
+    """``2·held >= num_experts``: the compact buffer would be every row, so
+    the layer traces today's single path."""
+    assert compact_rows(_T * _K, 8, _EXPERTS) == _T * _K
+    assert compact_rows(_T * _K, 6, _EXPERTS) == 384
+    assert not [e for e in _eqns(_layer_grad_jaxpr(8))
+                if e.primitive.name == "cond"]
+    assert [e for e in _eqns(_layer_grad_jaxpr(6))
+            if e.primitive.name == "cond"]
+
+
+def test_the_forward_cond_hands_on_no_residual_of_the_worst_case():
+    """Differentiated bare, a ``cond`` returns both branches' residuals, the
+    untaken one's as zero fills: float arrays of all ``tokens·top_k`` rows
+    written on every compact step.  Each branch is a ``jax.checkpoint``, so
+    what the forward ``cond`` hands the backward one is the layer's inputs:
+    no float output of any ``cond`` has that many rows."""
+    a = _T * _K
+    conds = [e for e in _eqns(_layer_grad_jaxpr(_HELD))
+             if e.primitive.name == "cond"]
+    assert len(conds) == 2               # the forward's and the backward's
+    for eqn in conds:
+        big = [v.aval for v in eqn.outvars
+               if v.aval.shape[:1] == (a,)
+               and jnp.issubdtype(v.aval.dtype, jnp.floating)]
+        assert not big, big
 
 
 def _attention_inputs(b, s, hq, hkv, d, dtype=jnp.float32):

@@ -22,6 +22,7 @@ from test_lfm2_moe import MANIFEST, TINY, _config, _ids, _mesh, _rel, c, ref
 
 from deepfm_tpu.models import get_model, lfm2_moe, register_model
 from deepfm_tpu.obs.trace import scope_of
+from deepfm_tpu.ops.experts import compact_rows
 from deepfm_tpu.parallel import (
     create_spmd_state,
     make_context,
@@ -142,14 +143,35 @@ def test_data_parallel_gives_the_same_loss_and_model_parallel_is_refused():
             state, m = step(state, batch)
         losses[dp] = float(m["loss"])
         assert set(m) == {"loss", "ce", "loss_per_shard", "rows_held_share",
-                          "expert_load_max_share"}
+                          "expert_load_max_share", "experts_compact_share"}
         assert 0 < float(m["rows_held_share"]) < 1
         assert float(m["expert_load_max_share"]) >= 1
+        # 128 tokens: ≈ 64 of 256 assignments held, a compact buffer of 128;
+        # 64 tokens a shard: that buffer is every row, 1 by definition
+        assert float(m["experts_compact_share"]) == 1.0
     assert abs(losses[1] - losses[2]) <= 1e-5 * losses[1]
     for dp, mp in ((1, 2), (2, 2)):
         with pytest.raises(ValueError, match=r"lfm2_moe.*tok_embedding.*whole"
                            r".*vocabulary-parallel loss"):
             make_context(cfg, _mesh(dp, mp))
+
+
+@pytest.mark.parametrize("rows, want", [
+    ((64, 128), 1.0), ((129, 256), 0.0), ((128, 129), 0.5)],
+    ids=["all-compact", "all-fallback", "mixed"])
+def test_the_compact_share_counts_the_layers_whose_rows_fit_the_buffer(
+        rows, want):
+    """``routing_counters`` on the rows two expert layers took, the tiny
+    cell's step (128 tokens, top-2, 4 of 16 held: a buffer of 128 of 256
+    rows, by the function the layer calls): a layer at the buffer's edge is
+    compact, one row past it falls back."""
+    cfg = _config().model
+    assert compact_rows(128 * 2, 4, 16) == 128
+    took = [jnp.asarray([n - 3.0, 1.0, 2.0, 0.0]) for n in rows]
+    got = lfm2_moe.routing_counters(took, 128, cfg)
+    assert set(got) == set(lfm2_moe.LFM2_MOE_METRICS)
+    assert float(got["experts_compact_share"]) == want
+    assert float(got["rows_held_share"]) == pytest.approx(sum(rows) / 512)
 
 
 def test_the_steps_that_cannot_take_the_family_refuse_it_by_what_they_read():
@@ -197,7 +219,9 @@ def test_run_task_trains_and_evaluates_the_family_from_records(tmp_path,
     assert int(state.step) == 3          # 24 sequences / 8
     logged = capsys.readouterr()
     lines = logged.out + logged.err
-    assert "rows_held_share" in lines and "expert_load_max_share" in lines
+    for counter in ("rows_held_share", "expert_load_max_share",
+                    "experts_compact_share"):
+        assert counter in lines, counter
     result = run_task(cfg.with_overrides(run={"task_type": "eval"}))
     assert result["examples"] == 10 == result["sequences"]
     assert 0 < result["loss"] < 2 * np.log(m["feature_size"])

@@ -264,7 +264,7 @@ CONTRACTS = {
         "model_state": {f"layer_{l}/expert_bias": (64,) for l in (1, 2, 3, 4)},
         "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
         "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
-                    "loss_per_shard"},
+                    "experts_compact_share", "loss_per_shard"},
     },
 }
 
