@@ -125,9 +125,16 @@ def configure_runtime() -> str:
       a CPU child compiles do not, and were rebuilt by every process until
       the threshold went to 0 (CHANGES.md PR 21).
 
+    * jax's trace, lowering, compile and cache-load events go to the
+      training path's span recorder from here on
+      (``obs/trace.install_compile_listener``).
+
     Returns the cache directory in use."""
     import jax
 
+    from ..obs.trace import install_compile_listener
+
+    install_compile_listener()
     if _requested_platform() == "cpu":
         _relax_cpu_collective_timeouts()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):

@@ -14,7 +14,9 @@ One layer, three surfaces, shared by train→publish→serve:
   ``GET /v1/trace/recent``; the training path's span recorder
   (``SpanRecorder``: feed worker, consumer and loop spans in a ring, as
   per-step means on the log line, and as ``TraceAnnotation``s in a
-  profile).
+  profile; the set-up boundaries and jax's trace, lowering, compile and
+  cache-load events by function, as the loop's ``startup`` and
+  ``recompile`` events).
 * :mod:`.flight` — a bounded ring of structured events every subsystem
   appends to through one hook, dumped as JSONL on SIGTERM/crash (riding
   PreemptionGuard) and on demand via ``GET /v1/flight``.
@@ -37,7 +39,6 @@ from .trace import (
     Tracer,
     current_trace,
     get_span_recorder,
-    span,
 )
 
 __all__ = [
@@ -51,7 +52,6 @@ __all__ = [
     "SpanRecorder",
     "get_span_recorder",
     "current_trace",
-    "span",
     "TRACE_HEADER",
     "SPAN_HEADER",
     "FlightRecorder",

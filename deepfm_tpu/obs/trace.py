@@ -26,12 +26,19 @@ loop's own boundaries), on ``time.perf_counter`` and — through
 throughput regression is attributable to the source, the host's work per
 batch, a full or an empty queue, the dispatch, a log line or a
 checkpoint, from the metrics line alone or next to the device ops of a
-profile.  The vocabulary of its spans is ``SPANS`` below.
+profile.  The same recorder holds what a restart pays: the program's own
+set-up boundaries (``setup.*``: distributed, mesh, context, state)
+and, filed by ``install_compile_listener`` from ``jax.monitoring``, every
+trace, lowering and backend compile or cache load by function name
+(``compile.*``), with the cache's hits and misses as counters.  The loop
+reads them into one ``startup`` event after the first step and a
+``recompile`` event whenever something is traced or compiled later; the
+benchmark's ``setup_compile_s``, ``step_build_s`` and ``state_build_s`` read
+the ring.  The vocabulary of its spans is ``SPANS`` below.
 """
 
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import json
 import os
@@ -59,20 +66,6 @@ def current_trace() -> "TraceContext | None":
     request is unsampled or there is no request) — the one hook the
     MicroBatcher and handlers read; costs a ContextVar.get."""
     return _CURRENT.get()
-
-
-@contextlib.contextmanager
-def span(name: str, **attrs):
-    """Record a span on the current trace (no-op when none is active)."""
-    ctx = _CURRENT.get()
-    if ctx is None:
-        yield None
-        return
-    t0 = time.perf_counter()
-    try:
-        yield ctx
-    finally:
-        ctx.add_span(name, t0, time.perf_counter(), **attrs)
 
 
 class TraceContext:
@@ -167,11 +160,9 @@ class Tracer:
         self._export_path = export_path
         self._export_file = None
         # exports serialize on their own lock so a slow disk only stalls
-        # exporting threads — never the ring (recent() scrapes) or the
-        # counters under self._lock
+        # exporting threads — never the ring (recent() scrapes) under
+        # self._lock
         self._export_lock = threading.Lock()
-        self.traces_total = 0
-        self.dropped_unsampled_total = 0
 
     # -- lifecycle ----------------------------------------------------------
     def begin(self, name: str, headers=None) -> "TraceContext | None":
@@ -183,8 +174,6 @@ class Tracer:
             trace_id = headers.get(TRACE_HEADER) or None
             parent = headers.get(SPAN_HEADER) or None
         if trace_id is None and not self._sample():
-            with self._lock:
-                self.dropped_unsampled_total += 1
             return None
         return TraceContext(name, self.service, trace_id=trace_id,
                             parent_span_id=parent)
@@ -220,7 +209,6 @@ class Tracer:
         if status is not None:
             ctx.attrs["status"] = status
         with self._lock:
-            self.traces_total += 1
             self._recent.append(ctx)
         if self._export_path:
             # render + write OUTSIDE the ring lock: a stalled disk must
@@ -267,11 +255,27 @@ class Tracer:
 # the full queue), the consumer's ``take`` (inside ``q.get()``); the placers
 # of ``parallel/spmd.py`` inside ``put``: ``validate``, ``narrow``,
 # ``device_put`` — and ``train.*`` the loop's own boundaries
-# (``train/loop._run_train_guarded``).
+# (``train/loop._run_train_guarded``).  ``setup.*`` is what a process does
+# before its first step, each where the work happens: ``distributed`` (a
+# multi-process run's ``jax.distributed.initialize``; a single process
+# writes none) and ``mesh`` (``parallel/mesh.py``; ``build_mesh`` is where
+# the backend opens), ``context`` and ``state``
+# (``parallel/spmd.make_context`` / ``create_spmd_state``).  ``compile.*`` is jax's own work, filed by
+# ``install_compile_listener`` with the function's name as ``what``:
+# ``trace`` (events nest: read their union, never their sum), ``lower``,
+# ``backend`` (a compile or a load from the persistent cache: ``how`` says
+# which) and ``cache_load`` (the retrieval inside a loaded ``backend``);
+# ``trace_small`` counts the traces under ``TRACE_RING_MIN_S`` (count and
+# seconds, no ring entry: the ``startup`` event's ``traces_small`` /
+# ``traces_small_ms``), ``cache_hit`` / ``cache_miss`` are counters.
 SPANS = frozenset({
     "feed.source", "feed.put", "feed.validate", "feed.narrow",
     "feed.device_put", "feed.offer", "feed.take",
     "train.dispatch", "train.log", "train.checkpoint", "train.eval",
+    "setup.distributed", "setup.mesh", "setup.context", "setup.state",
+    "compile.trace", "compile.lower", "compile.backend",
+    "compile.cache_load", "compile.trace_small",
+    "compile.cache_hit", "compile.cache_miss",
 })
 
 # span name -> the key its per-step mean gets on a MetricLogger line
@@ -320,8 +324,10 @@ class _Span:
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         self._ann.__exit__(*exc)
+        # SpanRecorder.record, inlined: this is the hot path's exit
         rec = self._rec
-        rec._ring.append((self._name, self._t0, t1, threading.get_ident()))
+        rec._ring.append(
+            (self._name, self._t0, t1, threading.get_ident(), None, None))
         with rec._lock:
             entry = rec._sums.get(self._name)
             if entry is None:
@@ -337,11 +343,14 @@ class SpanRecorder:
     ``with recorder.span(name, seq=...)`` times its body on
     ``time.perf_counter`` and writes three surfaces:
 
-    * a bounded ring of finished spans ``(name, start, end, thread)``
-      (rendered only when read: :meth:`spans`) — what the benchmark's
-      readers and a post-mortem read;
+    * a bounded ring of finished spans ``(name, start, end, thread, what,
+      how)`` (rendered only when read: :meth:`spans`) — what the
+      benchmark's readers and a post-mortem read.  ``what`` is the function
+      a ``compile.*`` span is about and ``how`` a ``compile.backend``'s
+      ``"loaded"`` or ``"compiled"``; both ``None`` for every other span;
     * running ``[count, total_s]`` per name — what ``MetricLogger``'s
-      ``extra`` hook reads per logged window (:meth:`snapshot_ms`);
+      ``extra`` hook reads per logged window (:meth:`snapshot_ms`); a
+      counter is a name here with a count and no time (:meth:`count`);
     * a ``jax.profiler.TraceAnnotation(name, seq=seq)`` around the same
       body, so a profile (``run.profile_dir``, the benchmark's
       ``--trace 1``) shows the program's spans beside the device ops, on
@@ -349,6 +358,9 @@ class SpanRecorder:
       batch for its ``source`` / ``put`` / ``offer`` and the consumer's
       ``take`` of that batch carries the same number, so a batch is
       followed across the two threads' lines.
+
+    A span that is already over — jax's compile events arrive as a
+    duration — is filed with :meth:`record`: ring and sums, no annotation.
 
     Always on.  The ring is a ``deque`` (atomic appends).  The per-name
     sums live in one dict under one lock: in the train loop the writers use
@@ -372,6 +384,24 @@ class SpanRecorder:
     def span(self, name: str, seq: int | None = None) -> _Span:
         return _Span(self, name, seq)
 
+    def record(self, name: str, t_start: float, t_end: float,
+               what: str | None = None, how: str | None = None) -> None:
+        """File a finished span (perf_counter readings) on the calling
+        thread: one ring entry, one more in the sums."""
+        self._ring.append(
+            (name, t_start, t_end, threading.get_ident(), what, how))
+        self.add(name, t_end - t_start)
+
+    def add(self, name: str, seconds: float = 0.0) -> None:
+        """One more of ``name`` in the per-name sums and no ring entry: a
+        counter (no ``seconds``), or a span too small to keep."""
+        with self._lock:
+            entry = self._sums.get(name)
+            if entry is None:
+                entry = self._sums[name] = [0, 0.0]
+            entry[0] += 1
+            entry[1] += seconds
+
     def step_done(self, n: int = 1) -> None:
         """The loop dispatched ``n`` more optimizer steps: the divisor of
         :meth:`snapshot_ms`."""
@@ -382,13 +412,25 @@ class SpanRecorder:
               t_max: float | None = None) -> list[dict]:
         """The ring's finished spans, in the order they finished, that lie
         wholly inside ``[t_min, t_max]`` (perf_counter readings; None =
-        open), rendered: ``name, t_start, t_end, thread``."""
+        open), rendered: ``name, t_start, t_end, thread, what, how``."""
         return [
-            {"name": name, "t_start": t0, "t_end": t1, "thread": thread}
-            for name, t0, t1, thread in list(self._ring)
+            {"name": name, "t_start": t0, "t_end": t1, "thread": thread,
+             "what": what, "how": how}
+            for name, t0, t1, thread, what, how in list(self._ring)
             if (t_min is None or t0 >= t_min)
             and (t_max is None or t1 <= t_max)
         ]
+
+    def count(self, name: str) -> int:
+        """How many of ``name`` were recorded so far (0 for none)."""
+        with self._lock:
+            return self._sums.get(name, (0, 0.0))[0]
+
+    def seconds(self, name: str) -> float:
+        """The summed seconds of ``name`` so far (0.0 for none, and for a
+        counter)."""
+        with self._lock:
+            return self._sums.get(name, (0, 0.0))[1]
 
     def covers(self, t: float) -> bool:
         """Whether every span finished since ``t`` is still in the ring
@@ -426,6 +468,162 @@ def set_span_recorder(recorder: SpanRecorder) -> SpanRecorder:
     global _RECORDER
     prev, _RECORDER = _RECORDER, recorder
     return prev
+
+
+# -- jax's compile events, into the recorder -----------------------------------
+
+# jax.monitoring's duration events -> the span each is filed as.  The first
+# three carry ``fun_name``; the retrieval fires inside a backend event
+# whose executable came from the persistent cache and names no function.
+_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+_EVENT_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hit",
+    "/jax/compilation_cache/cache_misses": "compile.cache_miss",
+}
+# a trace shorter than this is counted (``compile.trace_small``), not ringed:
+# a step's trace holds hundreds of inner ``jnp`` functions of microseconds
+TRACE_RING_MIN_S = 1e-3
+
+# the hit or miss that fired on this thread since its last backend event:
+# jax reports both INSIDE the backend event that they belong to, before it
+_CACHE = threading.local()
+_LISTENING = False
+
+
+def _bare(fun_name: str | None) -> str | None:
+    """``jit(local_step)`` -> ``local_step``: the lowering and the backend
+    name the function with the wrapping, the trace without."""
+    while fun_name and fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]
+    return fun_name
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    name = _DURATION_SPANS.get(event)
+    if name is None:
+        return
+    rec = get_span_recorder()      # looked up as it fires: tests swap it
+    if name == "compile.trace" and duration_secs < TRACE_RING_MIN_S:
+        rec.add("compile.trace_small", duration_secs)
+        return
+    how = None
+    if name == "compile.backend":
+        how = "loaded" if getattr(_CACHE, "hit", False) else "compiled"
+        _CACHE.hit = False
+    # jax times the event on its own clock and hands over the length alone:
+    # it ended just now, on the recorder's clock
+    t_end = time.perf_counter()
+    rec.record(name, t_end - duration_secs, t_end,
+               what=_bare(kwargs.get("fun_name")), how=how)
+
+
+def _on_event(event: str, **kwargs) -> None:
+    name = _EVENT_COUNTERS.get(event)
+    if name is None:
+        return
+    get_span_recorder().add(name)
+    _CACHE.hit = name == "compile.cache_hit"
+
+
+def install_compile_listener() -> None:
+    """Register the two listeners with ``jax.monitoring``, once a process
+    (``core/platform.configure_runtime`` calls this before the backend
+    opens).  They fire only when something is traced, lowered, compiled or
+    loaded: the steady hot path never reaches them.  Every entry point
+    configures the runtime, so a serving process files its buckets'
+    compiles too (a few entries a bucket; nothing there reads them)."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+    _LISTENING = True
+
+
+def union_s(spans: list[dict]) -> float:
+    """Seconds that ``spans`` (rendered rows) cover, each thread's intervals
+    merged first: a trace event holds its inner functions' events, and a
+    plain sum would count those twice."""
+    by_thread: dict = {}
+    for s in spans:
+        by_thread.setdefault(s["thread"], []).append(
+            (s["t_start"], s["t_end"]))
+    total = 0.0
+    for intervals in by_thread.values():
+        end = float("-inf")
+        for a, b in sorted(intervals):
+            if b > end:
+                total += b - max(a, end)
+                end = b
+    return total
+
+
+def _within(rows: list[dict], parents: list[dict]) -> list[dict]:
+    """The rows that lie inside one of ``parents`` on its thread."""
+    return [r for r in rows if any(
+        r["thread"] == p["thread"] and p["t_start"] <= r["t_start"]
+        and r["t_end"] <= p["t_end"] for p in parents)]
+
+
+def startup_fields(rec: SpanRecorder, t_first_step: float) -> dict:
+    """What the process paid up to its first train step's return, for the
+    loop's ``startup`` event: per set-up boundary ``<name>_ms`` and
+    ``<name>_self_ms`` (less the compile events inside it), the step's own
+    ``trace_ms`` / ``lower_ms`` / ``backend_ms`` (the compile events inside
+    the first ``train.dispatch``, as unions), ``function`` (its name) and
+    ``backend`` (``loaded`` | ``compiled``), the cache's hits and misses,
+    ``traces_small`` / ``traces_small_ms`` (the traces under
+    ``TRACE_RING_MIN_S``, counted and not ringed: a sum, their nesting is
+    not kept), and ``to_first_step_ms`` from the start of the first
+    recorded span."""
+    rows = rec.spans(None, t_first_step)
+    compiles = [s for s in rows if s["name"].startswith("compile.")]
+    out: dict = {}
+    for name in sorted({s["name"] for s in rows
+                        if s["name"].startswith("setup.")}):
+        own = [s for s in rows if s["name"] == name]
+        total = union_s(own)
+        key = name.split(".", 1)[1]
+        out[f"{key}_ms"] = round(1e3 * total, 3)
+        out[f"{key}_self_ms"] = round(
+            1e3 * (total - union_s(_within(compiles, own))), 3)
+    built = _within(
+        compiles, [s for s in rows if s["name"] == "train.dispatch"][:1])
+    if built:
+        for kind in ("trace", "lower", "backend"):
+            out[f"{kind}_ms"] = round(1e3 * union_s(
+                [c for c in built if c["name"] == f"compile.{kind}"]), 3)
+        # the step's executable is the last one the dispatch built or loaded
+        for c in built:
+            if c["name"] == "compile.backend":
+                out["function"], out["backend"] = c["what"], c["how"]
+    out["cache_hits"] = rec.count("compile.cache_hit")
+    out["cache_misses"] = rec.count("compile.cache_miss")
+    out["traces_small"] = rec.count("compile.trace_small")
+    out["traces_small_ms"] = round(1e3 * rec.seconds("compile.trace_small"), 3)
+    if rows:
+        out["to_first_step_ms"] = round(
+            1e3 * (t_first_step - min(s["t_start"] for s in rows)), 3)
+    return out
+
+
+def compiled_since(rec: SpanRecorder, t: float) -> list[str]:
+    """The functions whose ``compile.trace`` or ``compile.backend`` ended
+    after ``t``, in order of first arrival (the loop's ``recompile``
+    event)."""
+    seen: dict = {}
+    for s in rec.spans():
+        if s["name"] in ("compile.trace", "compile.backend") \
+                and s["t_end"] > t:
+            seen.setdefault(s["what"] or "?", None)
+    return list(seen)
 
 
 # -- the jitted step's named scopes --------------------------------------------

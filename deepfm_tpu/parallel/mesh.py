@@ -21,17 +21,19 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..core.config import DATA_AXIS, MODEL_AXIS, MeshConfig  # noqa: F401
+from ..obs.trace import get_span_recorder
 
 
 def initialize_distributed(cfg: MeshConfig) -> None:
     """Multi-host bootstrap (the mpirun/TF_CONFIG analog).  No-op for
     single-process runs."""
     if cfg.coordinator_address and cfg.num_processes > 1:
-        jax.distributed.initialize(
-            coordinator_address=cfg.coordinator_address,
-            num_processes=cfg.num_processes,
-            process_id=cfg.process_id,
-        )
+        with get_span_recorder().span("setup.distributed"):
+            jax.distributed.initialize(
+                coordinator_address=cfg.coordinator_address,
+                num_processes=cfg.num_processes,
+                process_id=cfg.process_id,
+            )
 
 
 def build_mesh(cfg: MeshConfig, devices=None) -> Mesh:
@@ -42,7 +44,9 @@ def build_mesh(cfg: MeshConfig, devices=None) -> Mesh:
     data replica sit on ICI-adjacent chips — embedding all-to-all/psum
     traffic stays on the fastest links, gradient psum spans the outer axis.
     """
-    devices = jax.devices() if devices is None else devices
+    # with no devices handed in, this is where the backend opens
+    with get_span_recorder().span("setup.mesh"):
+        devices = jax.devices() if devices is None else devices
     n = len(devices)
     mp = max(1, cfg.model_parallel)
     if n % mp != 0:

@@ -201,6 +201,12 @@ def make_context(
     (None = resolve from config) — used by the cross-topology restore to
     build a template describing the OTHER opt-state layout
     (checkpoint/reshard.py); training contexts leave it None."""
+    with get_span_recorder().span("setup.context"):
+        return _make_context(cfg, mesh, zero_layout)
+
+
+def _make_context(cfg: Config, mesh: Mesh,
+                  zero_layout: bool | None) -> SPMDContext:
     dp, mp = mesh_shape(mesh)
     model = get_model(cfg.model)
     true_rows = table_rows(model, cfg.model)
@@ -258,10 +264,13 @@ def create_spmd_state(ctx: SPMDContext, key: jax.Array | None = None) -> TrainSt
     """Initialize the TrainState directly into its shardings: XLA materializes
     each table shard on its own device (deterministic across replicas — the
     BroadcastGlobalVariablesHook capability, hvd:417-418, by construction)."""
-    key = jax.random.PRNGKey(ctx.cfg.run.seed) if key is None else key
-    init_fn = _build_full_init(ctx.cfg, ctx.table_rows, ctx.zero_layout)
-    with ctx.mesh:
-        return jax.jit(init_fn, out_shardings=ctx.state_shardings)(key)
+    # setup.state: the initialiser's trace, lowering, compile or cache load
+    # (each a compile.* span inside it) and its dispatch, which is what is left
+    with get_span_recorder().span("setup.state"):
+        key = jax.random.PRNGKey(ctx.cfg.run.seed) if key is None else key
+        init_fn = _build_full_init(ctx.cfg, ctx.table_rows, ctx.zero_layout)
+        with ctx.mesh:
+            return jax.jit(init_fn, out_shardings=ctx.state_shardings)(key)
 
 
 @jax.named_scope("l2_penalty")

@@ -45,7 +45,8 @@ from ..parallel import (
     shard_batch,
     shard_batch_stacked,
 )
-from ..obs.trace import get_span_recorder
+from ..obs import flight as obs_flight
+from ..obs.trace import compiled_since, get_span_recorder, startup_fields
 from ..parallel.spmd import WEIGHT_FIELD
 from ..serve import export_servable, write_predictions
 from ..train.step import TrainState
@@ -505,6 +506,38 @@ def run_train_tiered(cfg: Config):
         return state
 
 
+class _BuildWatch:
+    """What the operator is told of jax's own work, from the span recorder's
+    ``setup.*`` and ``compile.*`` entries (obs/trace.py): one ``startup``
+    event when the first step has returned — what this start paid, boundary
+    by boundary, and the step's trace, lowering and compile or cache load —
+    and a ``recompile`` event (also on the flight recorder) for a logged
+    window in which something was traced or compiled, once the first window
+    is past: which functions.  An in-training eval's first compile is named
+    like any other; the event informs, it does not fail.  One counter
+    compare a logged window; per step the loop tests one local flag."""
+
+    def __init__(self, rec, log: MetricLogger):
+        self._rec, self._log = rec, log
+        # compile events counted at the last logged window, and when it was
+        self._compiles = self._at = None
+
+    def first_step(self, step: int) -> None:
+        """The loop calls this once, when its first step has returned."""
+        self._at = time.perf_counter()
+        self._log.event("startup", step=step,
+                        **startup_fields(self._rec, self._at))
+
+    def window(self, step: int) -> None:
+        rec = self._rec
+        n = rec.count("compile.trace") + rec.count("compile.backend")
+        if self._compiles not in (None, n):
+            functions = compiled_since(rec, self._at)
+            self._log.event("recompile", step=step, functions=functions)
+            obs_flight.record("recompile", step=step, functions=functions)
+        self._compiles, self._at = n, time.perf_counter()
+
+
 def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
     ctx = setup(cfg)
     maybe_clear(cfg.run.model_dir, cfg.run.clear_existing_model)
@@ -545,11 +578,13 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
     # annotations beside the device ops in a profile.  Evaluated only on
     # emitting calls (MetricLogger.step `extra`), like the scheduled lr.
     rec = get_span_recorder()
+    builds = _BuildWatch(rec, log)
 
     def lr_extra():
         out = rec.snapshot_ms()
         if callable(lr_sched):
             out["lr"] = float(schedule_value(lr_sched, max(0, step - 1)))
+        builds.window(step)
         return out
     # periodic in-training eval, the train_and_evaluate cadence (ps:510-520):
     # no eval before start_delay, then at most one per throttle interval.
@@ -570,6 +605,7 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
     profile = _ProfileWindow(cfg.run.profile_dir,
                              step + max(1, cfg.run.log_steps), log)
     _END = object()
+    started = False
     with feed_cm as batches, contextlib.closing(profile):
         it = iter(batches)
         while True:
@@ -604,6 +640,9 @@ def _run_train_guarded(cfg: Config, guard: PreemptionGuard) -> TrainState:
                     batch_size = _rows(batch)
                 step += inc
                 rec.step_done(inc)
+                if not started:
+                    started = True
+                    builds.first_step(step)
                 with rec.span("train.log"):
                     log.step(step, batch_size,
                              {k: v for k, v in metrics.items()
@@ -744,8 +783,6 @@ def run_task(cfg: Config):
     # serve task skips it here: serve processes have no guard and expose
     # the live ring at GET /v1/flight (plus --flight-dump on their CLIs).
     if cfg.run.model_dir and task != "serve":
-        from ..obs import flight as obs_flight
-
         obs_flight.install(os.path.join(cfg.run.model_dir, "flight.jsonl"))
     if task in ("feedback-train", "feedback_train"):
         # the data flywheel's training leg (deepfm_tpu/flywheel): the

@@ -1,9 +1,11 @@
 """The training path's measurement (obs/trace.SpanRecorder): the recorder
-itself, the spans the feed and the loop emit, the benchmark's
-readers of them, the bounded ``run.profile_dir`` trace, and the named scopes
-of the jitted step."""
+itself, the spans the feed and the loop emit, jax's compile events filed into
+it by function and the set-up boundaries around them, the benchmark's
+readers of all of these, the loop's ``startup`` and ``recompile`` events, the
+bounded ``run.profile_dir`` trace, and the named scopes of the jitted step."""
 
 import glob
+import io
 import json
 import re
 import sys
@@ -19,7 +21,7 @@ from deepfm_tpu.core.config import Config, MeshConfig
 from deepfm_tpu.data.pipeline import DevicePrefetcher
 from deepfm_tpu.obs import trace as obs_trace
 from deepfm_tpu.obs.trace import (LOG_KEYS, SPANS, STEP_SCOPES, SpanRecorder,
-                                  scope_of)
+                                  scope_of, union_s)
 from deepfm_tpu.parallel import spmd
 from deepfm_tpu.parallel import (
     build_mesh,
@@ -97,7 +99,14 @@ def annotations(monkeypatch):
 
 def test_vocabulary_is_defined_once():
     assert set(LOG_KEYS) <= SPANS
-    assert all(n.split(".")[0] in ("feed", "train") for n in SPANS)
+    assert all(n.split(".")[0] in ("feed", "train", "setup", "compile")
+               for n in SPANS)
+    # every name a call site of the package passes to the recorder is in it
+    named = set()
+    for path in (ROOT / "deepfm_tpu").rglob("*.py"):
+        named |= set(re.findall(
+            r'"((?:feed|train|setup|compile)\.[a-z_]+)"', path.read_text()))
+    assert named == SPANS
 
 
 def test_a_span_is_its_body_on_its_thread(rec):
@@ -108,8 +117,8 @@ def test_a_span_is_its_body_on_its_thread(rec):
         with rec.span("feed.device_put"):
             time.sleep(0.004)
     rows = {s["name"]: s for s in rec.spans()}
-    assert all(set(s) == {"name", "t_start", "t_end", "thread"}
-               for s in rows.values())
+    assert all(set(s) == {"name", "t_start", "t_end", "thread", "what", "how"}
+               and s["what"] is s["how"] is None for s in rows.values())
     assert {s["thread"] for s in rows.values()} == {threading.get_ident()}
     dur = lambda s: s["t_end"] - s["t_start"]
     put = rows["feed.put"]
@@ -248,6 +257,210 @@ def test_sums_lose_no_update_under_many_writers(rec):
     assert len(rec.spans()) == min(threads * each, 65536)
 
 
+# ------------------------------------------- jax's compile events, by function
+
+TRACE, LOWER, BACKEND = (f"/jax/core/compile/{n}_duration" for n in (
+    "jaxpr_trace", "jaxpr_to_mlir_module", "backend_compile"))
+CACHE = "/jax/compilation_cache/"
+
+
+@pytest.fixture
+def listening(rec, monkeypatch):
+    """The compile listener installed, filing into this test's recorder, and
+    every trace kept in the ring however short (a tiny function's trace is
+    well under a millisecond)."""
+    obs_trace.install_compile_listener()
+    monkeypatch.setattr(obs_trace, "TRACE_RING_MIN_S", 0.0)
+    return rec
+
+
+def _compile_rows(rec, what=None):
+    return [(s["name"], s["what"], s["how"]) for s in rec.spans()
+            if s["name"].startswith("compile.")
+            and (what is None or s["what"] == what)]
+
+
+def test_listener_files_a_fresh_jit_by_function_and_nothing_on_its_second_call(
+        listening):
+    def fresh_step(x):
+        return x * 2.0 + 1.0
+
+    f = jax.jit(fresh_step)
+    f(np.ones(4, np.float32)).block_until_ready()
+    # the persistent cache is off under test: neither a hit nor a miss fired,
+    # and what ran is a compile
+    assert _compile_rows(listening, "fresh_step") == [
+        ("compile.trace", "fresh_step", None),
+        ("compile.lower", "fresh_step", None),
+        ("compile.backend", "fresh_step", "compiled")]
+    rows = [s for s in listening.spans() if s["what"] == "fresh_step"]
+    assert all(s["thread"] == threading.get_ident()
+               and s["t_start"] <= s["t_end"] <= time.perf_counter()
+               for s in rows)
+    assert [a["t_end"] <= b["t_end"] for a, b in zip(rows, rows[1:])] == [
+        True, True]
+    n = len(listening.spans())
+    f(np.zeros(4, np.float32)).block_until_ready()
+    assert len(listening.spans()) == n
+    assert listening.count("compile.backend") >= 1
+    assert listening.count("compile.cache_hit") == 0
+
+
+def test_a_new_shape_files_one_more_backend_event(listening):
+    def reshaped(x):
+        return x.sum()
+
+    f = jax.jit(reshaped)
+    f(np.ones(4, np.float32)).block_until_ready()
+    before = _compile_rows(listening, "reshaped")
+    f(np.ones(6, np.float32)).block_until_ready()
+    after = _compile_rows(listening, "reshaped")
+    assert [r[0] for r in before].count("compile.backend") == 1
+    assert [r[0] for r in after].count("compile.backend") == 2
+    assert [r[0] for r in after].count("compile.trace") == 2
+
+
+def test_nested_traces_read_as_their_union_not_their_sum(listening):
+    # a real one: the outer function's trace event holds the inner's
+    @jax.jit
+    def inner_fn(x):
+        return x + 1.0
+
+    def outer_fn(x):
+        return inner_fn(x) * 2.0
+
+    jax.jit(outer_fn)(np.ones(3, np.float32)).block_until_ready()
+    traces = {s["what"]: s for s in listening.spans()
+              if s["name"] == "compile.trace"
+              and s["what"] in ("inner_fn", "outer_fn")}
+    inner, outer = traces["inner_fn"], traces["outer_fn"]
+    assert outer["t_start"] <= inner["t_start"] <= inner["t_end"] <= outer[
+        "t_end"]
+    assert union_s([inner, outer]) == pytest.approx(
+        outer["t_end"] - outer["t_start"])
+    # and by hand: two nested on this thread, a third on another thread
+    fresh = SpanRecorder()
+    fresh.record("compile.trace", 10.0, 10.5, what="step")
+    fresh.record("compile.trace", 10.1, 10.3, what="take")
+    fresh.record("compile.lower", 10.4, 10.7, what="step")
+    t = threading.Thread(target=fresh.record,
+                         args=("compile.trace", 10.0, 10.2, "eval"))
+    t.start()
+    t.join(5)
+    rows = fresh.spans()
+    assert union_s(rows) == pytest.approx(0.7 + 0.2)       # not 1.2
+    assert fresh._sums["compile.trace"] == [3, pytest.approx(0.9)]
+    assert _reader("_setup").union_s(rows) == pytest.approx(0.9)
+
+
+def test_installing_twice_registers_once(rec):
+    from jax._src import monitoring
+
+    obs_trace.install_compile_listener()
+    obs_trace.install_compile_listener()
+    assert monitoring.get_event_duration_listeners().count(
+        obs_trace._on_duration) == 1
+    assert monitoring.get_event_listeners().count(obs_trace._on_event) == 1
+    # and the runtime's set-up, which every entry point calls, installs it
+    from deepfm_tpu.core.platform import configure_runtime
+
+    configure_runtime()
+    assert monitoring.get_event_duration_listeners().count(
+        obs_trace._on_duration) == 1
+
+
+def test_only_a_multi_process_start_writes_setup_distributed(rec, monkeypatch):
+    from deepfm_tpu.parallel.mesh import initialize_distributed
+
+    asked = []
+    monkeypatch.setattr(jax.distributed, "initialize",
+                        lambda **kw: asked.append(kw))
+    initialize_distributed(MeshConfig())                 # one process
+    assert not asked and rec.spans() == []
+    initialize_distributed(MeshConfig(
+        coordinator_address="localhost:1", num_processes=2, process_id=1))
+    assert asked == [{"coordinator_address": "localhost:1",
+                      "num_processes": 2, "process_id": 1}]
+    assert [s["name"] for s in rec.spans()] == ["setup.distributed"]
+    assert "distributed_ms" in obs_trace.startup_fields(
+        rec, time.perf_counter())
+
+
+def test_a_swapped_recorder_receives_the_next_events(listening):
+    jax.monitoring.record_event_duration_secs(LOWER, 0.25, fun_name="jit(one)")
+    other = SpanRecorder()
+    prev = obs_trace.set_span_recorder(other)
+    try:
+        jax.monitoring.record_event_duration_secs(
+            LOWER, 0.5, fun_name="jit(two)")
+    finally:
+        obs_trace.set_span_recorder(prev)
+    assert _compile_rows(listening) == [("compile.lower", "one", None)]
+    assert _compile_rows(other) == [("compile.lower", "two", None)]
+    (row,) = other.spans()
+    assert row["t_end"] - row["t_start"] == pytest.approx(0.5)
+    assert row["t_end"] <= time.perf_counter()
+
+
+def test_a_sub_millisecond_trace_is_counted_and_not_ringed(rec):
+    obs_trace.install_compile_listener()
+    for _ in range(3):
+        jax.monitoring.record_event_duration_secs(
+            TRACE, 0.0004, fun_name="_where")
+    jax.monitoring.record_event_duration_secs(TRACE, 0.002, fun_name="step")
+    # an event that is nobody's: not filed, not counted
+    jax.monitoring.record_event_duration_secs(
+        CACHE + "compile_time_saved_sec", 3.0)
+    jax.monitoring.record_event(CACHE + "tasks_using_cache")
+    assert _compile_rows(rec) == [("compile.trace", "step", None)]
+    assert rec.count("compile.trace_small") == 3
+    assert rec.seconds("compile.trace_small") == pytest.approx(0.0012)
+    assert rec.seconds("compile.lower") == 0.0
+    # their reader: the loop's ``startup`` event
+    fields = obs_trace.startup_fields(rec, time.perf_counter())
+    assert fields["traces_small"] == 3
+    assert fields["traces_small_ms"] == pytest.approx(1.2)
+    assert rec.count("compile.trace") == 1 and rec.count("compile.lower") == 0
+    assert set(rec._sums) == {"compile.trace_small", "compile.trace"}
+
+
+def test_a_hit_inside_a_backend_event_marks_it_loaded(rec):
+    """jax reports the cache's hit, then the retrieval's time, then the
+    backend event they belong to, all on the compiling thread."""
+    obs_trace.install_compile_listener()
+    mon = jax.monitoring
+
+    def load(name):
+        mon.record_event(CACHE + "cache_hits")
+        mon.record_event_duration_secs(CACHE + "cache_retrieval_time_sec", 0.05)
+        mon.record_event_duration_secs(BACKEND, 0.06, fun_name=f"jit({name})")
+
+    load("local_step")
+    mon.record_event_duration_secs(BACKEND, 0.7, fun_name="jit(no_cache)")
+    mon.record_event(CACHE + "cache_misses")
+    mon.record_event_duration_secs(BACKEND, 0.9, fun_name="jit(missed)")
+    # a hit on another thread is that thread's
+    t = threading.Thread(target=load, args=("elsewhere",))
+    mon.record_event(CACHE + "cache_misses")
+    t.start()
+    t.join(5)
+    mon.record_event_duration_secs(BACKEND, 0.8, fun_name="jit(missed_too)")
+    assert _compile_rows(rec) == [
+        ("compile.cache_load", None, None),
+        ("compile.backend", "local_step", "loaded"),
+        ("compile.backend", "no_cache", "compiled"),
+        ("compile.backend", "missed", "compiled"),
+        ("compile.cache_load", None, None),
+        ("compile.backend", "elsewhere", "loaded"),
+        ("compile.backend", "missed_too", "compiled")]
+    assert rec.count("compile.cache_hit") == 2
+    assert rec.count("compile.cache_miss") == 2
+    assert rec._sums["compile.cache_hit"][1] == 0.0       # a count, no time
+    load_, backend = rec.spans()[:2]
+    assert backend["t_start"] <= load_["t_start"] <= load_["t_end"] <= backend[
+        "t_end"]
+
+
 # ------------------------------------------------------- the feed's spans
 
 def test_prefetcher_and_shard_batch_emit_the_feed_spans(rec, annotations):
@@ -260,7 +473,8 @@ def test_prefetcher_and_shard_batch_emit_the_feed_spans(rec, annotations):
     assert len(got) == n and got[0]["feat_ids"].dtype == np.int32
     by_name = {}
     for s in rec.spans():
-        by_name.setdefault(s["name"], []).append(s)
+        if s["name"].startswith("feed."):   # _ctx() left its setup.* spans
+            by_name.setdefault(s["name"], []).append(s)
     assert set(by_name) == {
         "feed.source", "feed.put", "feed.validate", "feed.narrow",
         "feed.device_put", "feed.offer", "feed.take"} <= SPANS
@@ -289,6 +503,12 @@ def test_prefetcher_and_shard_batch_emit_the_feed_spans(rec, annotations):
         assert put["t_end"] <= taken["t_end"]
 
 
+def _feed_names(rec):
+    """The feed's spans, in the order they finished (``_ctx()`` leaves the
+    context's ``setup.*`` spans, and what it traced, before them)."""
+    return [s["name"] for s in rec.spans() if s["name"].startswith("feed.")]
+
+
 def test_a_source_that_cannot_start_fails_the_take(rec):
     class Broken:
         def __iter__(self):
@@ -304,7 +524,7 @@ def test_stacked_placement_runs_under_the_same_spans(rec):
     pool = [_host_batch(ctx.cfg, 8, seed=i) for i in range(3)]
     placed = shard_batch_stacked(ctx, pool)
     assert placed["feat_ids"].shape == (3, 8, 6)
-    assert [s["name"] for s in rec.spans()] == [
+    assert _feed_names(rec) == [
         "feed.validate", "feed.narrow", "feed.device_put"]
 
 
@@ -314,7 +534,7 @@ def test_out_of_range_ids_still_fail_inside_the_validate_span(rec):
     hb["feat_ids"][0, 0] = ctx.cfg.model.feature_size
     with pytest.raises(ValueError, match="out of range"):
         shard_batch(ctx, hb)
-    assert [s["name"] for s in rec.spans()] == ["feed.validate"]
+    assert _feed_names(rec) == ["feed.validate"]
 
 
 # ------------------------------------------------- the benchmark's readers
@@ -324,6 +544,22 @@ READERS = {
     "feed_worker_busy_share": 100.0 * (0.010 + 0.030 + 0.030) / 2.0,
     "feed_put_ms": 30.0,
     "feed_take_share": 100.0 * 0.020 / 2.0,
+    # set-up's: unions of what ended before the window
+    "setup_compile_s": (0.3 + 0.2 + 2.0) + (0.5 + 0.2 + 0.3) + 0.25,
+    "step_build_s": 0.5 + 0.2 + 0.3,
+    "state_build_s": 3.0,
+}
+STDERR = {
+    "feed_put_ms": ("validate 10.000 + narrow 5.000 + device_put 12.000",
+                    "self 3.000 ms a batch (2 batches"),
+    "step_build_s": (
+        "perf build: step trace 0.500 + lower 0.200 + backend 0.300 "
+        "(loaded, cache_load 0.250); state 2.500; others 0.250 in 1 "
+        "functions; hits 2 misses 1; in the window: 1 traces, 1 compiles "
+        "['eval_step']; ring 26 entries, 3 small traces (0.001 s) counted",),
+    "state_build_s": (
+        "perf state: setup.state 3.000 = trace 0.300 + lower 0.200 + "
+        "backend 2.000 (compiled) + self 0.500 (1 span)",),
 }
 
 
@@ -338,10 +574,35 @@ def _reader(name):
 
 
 def _synthetic_ring(rec, t0):
-    """Spans written straight into the ring: two batches inside the window
-    [t0, t0 + 2], one put before it and one take that straddles its end."""
-    def add(name, start, dur):
-        rec._ring.append((name, t0 + start, t0 + start + dur, 1))
+    """Finished spans filed by hand.  The feed's: two batches inside the
+    window [t0, t0 + 2], one put before it and one take that straddles its
+    end.  Set-up's, before the window: the state's creation with the
+    initialiser's (nested) traces, lowering and compile inside it, the
+    step's trace, lowering and load from the cache, one more function
+    compiled; a trace astride the window's start (nobody's) and an eval
+    step traced and compiled inside the window."""
+    def add(name, start, dur, what=None, how=None):
+        rec.record(name, t0 + start, t0 + start + dur, what, how)
+
+    add("compile.trace", -9.9, 0.3, "init_fn")
+    add("compile.trace", -9.8, 0.1, "_uniform")      # inside init_fn's
+    add("compile.lower", -9.6, 0.2, "init_fn")
+    add("compile.backend", -9.4, 2.0, "init_fn", "compiled")
+    add("setup.state", -10.0, 3.0)
+    add("compile.trace", -4.9, 0.1, "_take")         # inside local_step's
+    add("compile.trace", -5.0, 0.5, "local_step")
+    add("compile.lower", -4.5, 0.2, "local_step")
+    add("compile.cache_load", -4.3, 0.25)
+    add("compile.backend", -4.3, 0.3, "local_step", "loaded")
+    add("compile.backend", -3.0, 0.25, "<lambda>", "compiled")
+    add("compile.trace", -0.1, 0.2, "astride")
+    add("compile.trace", 0.8, 0.2, "eval_step")
+    add("compile.backend", 1.0, 0.3, "eval_step", "loaded")
+    for _ in range(2):
+        rec.add("compile.cache_hit")
+    rec.add("compile.cache_miss")
+    for _ in range(3):
+        rec.add("compile.trace_small", 0.0004)
 
     add("feed.put", -0.5, 0.2)                       # before the window
     add("feed.source", 0.1, 0.010)
@@ -362,10 +623,8 @@ def test_reader_keeps_the_window_and_reads_nothing_as_none(rec, name, capsys):
     assert read({}) is None and read({"spans": {}}) is None
     _synthetic_ring(rec, 100.0)
     assert read(run) == pytest.approx(READERS[name])
-    if name == "feed_put_ms":
-        err = capsys.readouterr().err
-        assert "validate 10.000 + narrow 5.000 + device_put 12.000" in err
-        assert "self 3.000 ms a batch (2 batches" in err
+    err = capsys.readouterr().err
+    assert all(line in err for line in STDERR.get(name, ()))
     # a window the ring no longer covers reads as nothing
     small = SpanRecorder(maxlen=4)
     obs_trace.set_span_recorder(small)
@@ -382,9 +641,50 @@ def test_readers_read_the_live_feed(rec):
     run = {"spans": {"t_start": t0, "window_s": time.perf_counter() - t0}}
     for name in READERS:
         value = _reader(name).read(run)
+        if name.endswith("_s"):
+            # a feed alone builds no state and no step (its context may
+            # have traced the initialiser for its shapes)
+            assert value is None or name == "setup_compile_s"
+            continue
         assert value is not None and value > 0
         if name.endswith("_share"):
             assert value <= 100.0
+
+
+def test_setup_readers_read_a_live_tiny_cell(rec, capsys):
+    """The benchmark's own set-up of a fixture cell on the CPU: context,
+    state, step, the three checked steps."""
+    _reader("_setup")                    # perf/ is importable from here on
+    from perf import manifest
+    from perf.entries import train
+
+    fixture = json.loads(
+        (ROOT / "perf" / "tests" / "fixture_manifest.json").read_text())
+    cell = manifest.Cell(fixture, fixture["workloads"][0]["name"],
+                         manifest.PERF_DIR)
+    obs_trace.install_compile_listener()
+    env = train.build(cell, 2**31 + 38, require_chip=False)
+    try:
+        train.first_steps(env)
+    finally:
+        env.close()
+    run = {"spans": {"t_start": time.perf_counter(), "window_s": 0.5}}
+    value = {name: _reader(name).read(run)
+             for name in READERS if name.endswith("_s")}
+    assert 0 < value["step_build_s"] <= value["setup_compile_s"]
+    assert 0 < value["state_build_s"]
+    # all of it lies inside what set-up took
+    started = min(s["t_start"] for s in rec.spans())
+    assert value["setup_compile_s"] < run["spans"]["t_start"] - started
+    err = capsys.readouterr().err
+    assert "perf build: step trace " in err and "(compiled)" in err
+    assert "in the window: 0 traces, 0 compiles []" in err
+    assert "perf state: setup.state " in err
+    names = {s["name"] for s in rec.spans()}
+    assert {"setup.mesh", "setup.context", "setup.state", "compile.trace",
+            "compile.lower", "compile.backend"} <= names
+    # one process: no ``jax.distributed.initialize``, so no span of it
+    assert "setup.distributed" not in names
 
 
 # ---------------------------------- the loop: log line and bounded profile
@@ -407,12 +707,33 @@ def test_run_train_logs_the_spans_and_traces_a_bounded_window(
              "log_steps": 2, "checkpoint_every_steps": 4,
              "profile_dir": str(prof)},
     )
+    obs_trace.install_compile_listener()     # an entry point's first call
     state = loop.run_train(cfg)
     assert int(state.step) == 28
     lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
              if x.startswith("{")]
     train = [x for x in lines if x["kind"] == "train"]
     assert len(train) == 14
+    # what this start paid, once, before the second metrics line: the
+    # boundaries, the step's three parts, compiled (no cache under test)
+    (startup,) = [x for x in lines if x["kind"] == "startup"]
+    assert lines.index(startup) < lines.index(train[1])
+    assert startup["function"] == "local_step"
+    assert startup["backend"] == "compiled" and startup["cache_hits"] == 0
+    assert min(startup[k] for k in ("trace_ms", "lower_ms", "backend_ms")) > 0
+    for name in ("mesh", "context", "state"):
+        assert 0 <= startup[f"{name}_self_ms"] <= startup[f"{name}_ms"]
+    assert "distributed_ms" not in startup          # a single process
+    # the step's trace holds hundreds of inner functions of microseconds
+    assert startup["traces_small"] > 0 and startup["traces_small_ms"] > 0
+    assert startup["state_self_ms"] < startup["state_ms"] < startup[
+        "to_first_step_ms"]
+    assert (startup["trace_ms"] + startup["lower_ms"] + startup["backend_ms"]
+            < startup["to_first_step_ms"])
+    # whatever was built later is named, and it is never the step
+    for event in (x for x in lines if x["kind"] == "recompile"):
+        assert event["functions"] and "local_step" not in event["functions"]
+        assert lines.index(event) > lines.index(train[0])
     assert all({"data_wait_ms", "dispatch_ms", "log_ms"} <= set(x)
                and "host_ms" not in x for x in train)
     # the save at step 4 shows in the window logged at step 6
@@ -446,6 +767,43 @@ def test_run_train_logs_the_spans_and_traces_a_bounded_window(
     assert None not in seqs["feed.put"] | seqs["feed.take"]
     assert len(seqs["feed.take"]) >= loop.PROFILE_STEPS - 1
     assert seqs["feed.take"] & seqs["feed.put"]
+
+
+def test_the_loops_hook_names_what_was_built_after_the_first_window(rec):
+    """``train/loop._BuildWatch``: what the loop's ``extra`` hook calls once a
+    logged window."""
+    from deepfm_tpu.obs import flight
+    from deepfm_tpu.train.loop import _BuildWatch
+    from deepfm_tpu.utils import MetricLogger
+
+    obs_trace.install_compile_listener()
+    out = io.StringIO()
+    watch = _BuildWatch(rec, MetricLogger(stream=out))
+    jax.jit(lambda x: x - 1.0)(np.ones(2, np.float32))   # set-up's own work
+    with rec.span("train.dispatch"):
+        jax.jit(lambda x: x * 3.0)(np.ones(2, np.float32))
+    watch.first_step(1)
+    watch.window(2)            # the first window: the step's own compile
+    watch.window(4)            # nothing since
+
+    def late_eval_step(x):
+        return x + 2.0
+
+    jax.jit(late_eval_step)(np.ones(5, np.float32)).block_until_ready()
+    before = flight.get_recorder().events(kind="recompile")
+    watch.window(6)
+    watch.window(8)
+    events = [json.loads(x) for x in out.getvalue().splitlines()]
+    assert [e["kind"] for e in events] == ["startup", "recompile"]
+    assert events[0]["step"] == 1 and events[0]["backend"] == "compiled"
+    assert events[0]["function"] == "<lambda>" and events[0]["backend_ms"] > 0
+    # (the inner ``add`` is named too where its trace took a millisecond)
+    assert events[1]["step"] == 6 and set(events[1]) == {
+        "kind", "step", "functions"}
+    assert "late_eval_step" in events[1]["functions"]
+    flown = flight.get_recorder().events(kind="recompile")
+    assert len(flown) == len(before) + 1
+    assert flown[-1]["functions"] == events[1]["functions"]
 
 
 # ------------------------------------------------ named scopes in the step
