@@ -31,8 +31,9 @@ step leaves it as it is (the published config gives no update rule).
 
 Matmuls run in ``compute_dtype`` over float32 master weights; the residual
 stream, norms, router, softmaxes and the loss in float32.  Each block is a
-``jax.checkpoint`` that keeps the attention kernel's output
-(``ops/attention.py``): the backward holds one block's activations.  Named scopes
+``jax.checkpoint`` that keeps its products (``KEEP``: every projection's
+output, what the attention reads and writes, the routing) and forms the
+element-wise work between them again in its backward.  Named scopes
 (``obs/trace.STEP_SCOPES``): ``lookup``, ``conv_mixer``, ``attention``,
 ``dense_ffn``, ``router``, ``experts``, ``lm_head``, ``loss``.
 """
@@ -40,6 +41,7 @@ stream, norms, router, softmaxes and the loss in float32.  Each block is a
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -54,12 +56,23 @@ from ..ops.attention import (
     rope_tables,
 )
 from ..ops.embedding import dense_lookup, narrow_ids
-from ..ops.experts import compact_rows, held_experts_sum, route
+from ..ops.experts import (
+    ROUTING_RESIDUALS,
+    compact_rows,
+    held_experts_sum,
+    route,
+)
+from ..ops.kept import keep, tally
 from .base import BatchField, ModelDef, register_model
 
 TABLE = "tok_embedding"
 INIT_STD = 0.02     # every matrix and the table (the family's initializer_range)
 BIAS_STD = 0.01     # the selection bias: choice and weight really differ
+# checkpoint names: a projection's product, in the dtype it was computed in;
+# and the dense SwiGLU's two operands in ``compute_dtype`` (its normalised
+# input and ``silu(a)·b``)
+PROJECTIONS = "projections"
+SWIGLU_OPERANDS = "swiglu_operands"
 
 
 def held(cfg: ModelConfig) -> int:
@@ -149,16 +162,25 @@ def _mm(x, w, dt):
     return jnp.dot(x.astype(dt), w.astype(dt))
 
 
+def _kept_mm(x, w, dt):
+    """The product under its name, before any cast, reshape or norm: what
+    reads a product in the backward reads the value the name sits on."""
+    return keep(_mm(x, w, dt), PROJECTIONS)
+
+
 @jax.named_scope("conv_mixer")
 def conv_mixer(p: dict, x, cfg: ModelConfig):
     """The gated short convolution; no activation anywhere in it.  The gates
     and the taps in float32."""
     dt = jnp.dtype(cfg.compute_dtype)
-    b, c, u = jnp.split(_mm(x, p["in_proj"], dt).astype(jnp.float32), 3, -1)
+    # split, then cast: each reader of a gate converts its own third of the
+    # kept product, and no float32 [·, 3h] copy of it is written
+    b, c, u = (part.astype(jnp.float32) for part in jnp.split(
+        _kept_mm(x, p["in_proj"], dt), 3, -1))
     taps, s = cfg.conv_L_cache, x.shape[1]
     bu = jnp.pad(b * u, ((0, 0), (taps - 1, 0), (0, 0)))
     v = sum(p["conv"][j] * bu[:, j:j + s] for j in range(taps))
-    return _mm(c * v, p["out_proj"], dt)
+    return _kept_mm(c * v, p["out_proj"], dt)
 
 
 @jax.named_scope("attention")
@@ -168,9 +190,10 @@ def attention(p: dict, x, rope, cfg: ModelConfig):
     d = head_dim(cfg)
 
     def heads(w, gain=None):
-        y = _mm(x, w, dt).reshape(b, s, -1, d)
+        # v's product goes to the attention as it is, which names it
         if gain is None:
-            return y
+            return _mm(x, w, dt).reshape(b, s, -1, d)
+        y = _kept_mm(x, w, dt).reshape(b, s, -1, d)
         return apply_rope(rms_norm(y, gain, cfg.norm_eps), *rope).astype(dt)
 
     tile = kernel_tile(s)
@@ -178,15 +201,19 @@ def attention(p: dict, x, rope, cfg: ModelConfig):
                            heads(p["k_proj"], p["k_norm"]),
                            heads(p["v_proj"]),
                            kernel=tile is not None, block=tile)
-    return _mm(out.reshape(b, s, -1), p["o_proj"], dt)
+    return _kept_mm(out.reshape(b, s, -1), p["o_proj"], dt)
 
 
 @jax.named_scope("dense_ffn")
 def dense_ffn(p: dict, x, cfg: ModelConfig):
     dt = jnp.dtype(cfg.compute_dtype)
-    a, b = _mm(x, p["w1"], dt), _mm(x, p["w3"], dt)
-    return _mm(jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32),
-               p["w2"], dt)
+    # the three weight gradients are the step's widest products; an operand
+    # formed again inside one slows it by more than the pass that forms it
+    # (PERF.md §6, PR 39), so both are kept as the products read them
+    x = keep(x.astype(dt), SWIGLU_OPERANDS)
+    a, b = _kept_mm(x, p["w1"], dt), _kept_mm(x, p["w3"], dt)
+    h = jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)
+    return _mm(keep(h.astype(dt), SWIGLU_OPERANDS), p["w2"], dt)
 
 
 def sparse_ffn(p: dict, bias, x, cfg: ModelConfig, axis_name):
@@ -219,10 +246,22 @@ def block(p: dict, state: dict, x, rope, *, cfg: ModelConfig, layer: int,
     return x + y, took
 
 
-# what a rematerialised block keeps of its forward: the attention kernel's
-# output and log-sum-exp (69 MB a layer at the cell's size), so that its
-# backward does not run the kernel's forward again
-KEEP = jax.checkpoint_policies.save_only_these_names(ATTENTION_RESIDUALS)
+# What a rematerialised block keeps of its forward.  The rule: a block's
+# recomputation holds no matmul, no sort, no top-k, no gather by index and no
+# kernel; it holds element-wise work only (the block norms, gates, casts, the
+# taps, masks and counts).  So every product is kept where it leaves the MXU:
+# the operators' projections in and out and the dense SwiGLU's two wide ones
+# (``w2``'s output is read by nothing a backward needs); what the attention
+# reads and writes (q, k and v as it takes them, its output and log-sum-exp);
+# the router's logits, choice and chosen scores, the grouping's order and
+# sizes.  Past the rule, where a traced run showed that it pays (``PERF.md``
+# §6, PR 39): the dense SwiGLU's operands.  2.6 GB a step at the token cell's
+# size (``PERF.md`` §4).  The expert layer's buffers are checkpoints of their
+# own under a ``cond`` and keep their inputs only (``ops/experts.py``).  Fixed
+# here, no option: a configuration that cannot hold its products is where a
+# rule on the bytes observed from the shapes would enter.
+KEEP = jax.checkpoint_policies.save_only_these_names(
+    ATTENTION_RESIDUALS, PROJECTIONS, ROUTING_RESIDUALS, SWIGLU_OPERANDS)
 
 
 def hidden_states(params: dict, model_state: dict, ids, *, cfg: ModelConfig,
@@ -233,12 +272,19 @@ def hidden_states(params: dict, model_state: dict, ids, *, cfg: ModelConfig,
         x = lookup_fn(params[TABLE], ids).astype(jnp.float32)
     rope = rope_tables(ids.shape[1], head_dim(cfg), cfg.rope_theta)
     took = []
-    for l in range(len(cfg.layer_types)):
-        run = functools.partial(block, cfg=cfg, layer=l, axis_name=axis_name)
-        x, t = (jax.checkpoint(run, policy=KEEP) if remat else run)(
-            params[f"layer_{l}"], model_state.get(f"layer_{l}", {}), x, rope)
-        if t is not None:
-            took.append(t)
+    with tally() as kept:
+        for l in range(len(cfg.layer_types)):
+            run = functools.partial(block, cfg=cfg, layer=l,
+                                    axis_name=axis_name)
+            x, t = (jax.checkpoint(run, policy=KEEP) if remat else run)(
+                params[f"layer_{l}"], model_state.get(f"layer_{l}", {}), x,
+                rope)
+            if t is not None:
+                took.append(t)
+    if remat:
+        logging.getLogger(__name__).info(
+            "blocks keep: %s, %.3f MB a step", ", ".join(sorted(kept)),
+            sum(kept.values()) / 1e6)
     return rms_norm(x, params["out_norm"], cfg.norm_eps), took
 
 

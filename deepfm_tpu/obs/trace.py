@@ -653,3 +653,24 @@ def scope_of(op_name: str) -> tuple[str | None, str | None]:
         if inner in STEP_SCOPES:
             return inner, part
     return None, None
+
+
+# the last part of an ``op_name`` that is no element-wise work: what a
+# rematerialised block of the token family keeps instead of running it again
+# (``models/lfm2_moe.KEEP``'s rule)
+NOT_ELEMENT_WISE = ("dot_general", "ragged_dot", "sort", "top_k", "gather",
+                    "pallas_call")
+
+
+def recomputed_part(op_name: str) -> str | None:
+    """What follows ``…/checkpoint/rematted_computation/`` where the FIRST
+    ``jax.checkpoint`` on an ``op_name``'s path recomputes: the work an
+    outermost checkpoint (a block of the token family) runs again in its
+    backward; None elsewhere.  A checkpoint further down the path is an op's
+    own (the expert layer's branches, the blocked attention's query blocks)
+    and what it recomputes is part of its backward.
+    ``jit(local_step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/
+    router/mul`` -> ``router/mul``."""
+    _, _, below = op_name.partition("/checkpoint/")
+    first, _, rest = below.partition("/")
+    return rest if first == "rematted_computation" else None

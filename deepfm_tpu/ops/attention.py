@@ -5,9 +5,13 @@ attention whose grid follows the causal mask's live blocks, key-value heads
 shared by their query heads inside the kernel, a fused backward).  Its
 instructions carry the kernel's name in the compiled step
 (``splash_mha_fwd_residuals``, ``splash_mha_dkv_no_residuals``: what the
-benchmark's ``splash_attention_roofline`` reads), and its output and
-log-sum-exp carry ``ATTENTION_RESIDUALS`` for a ``jax.checkpoint`` policy, so
-that a rematerialised block keeps them and does not run the forward twice.
+benchmark's ``splash_attention_roofline`` reads), and its operands (q with
+the scale on it, k and v, heads first, as it reads them), its output and its
+log-sum-exp carry ``ATTENTION_RESIDUALS`` for a ``jax.checkpoint`` policy:
+a rematerialised block that keeps them (``models/lfm2_moe.KEEP``) does not
+run the forward twice, nor the norms, RoPE and layout copies that make its
+operands (6.1 ms a layer at the cell's size, heads of 64 being half a lane
+tile: PERF.md §6, PR 39).
 
 Elsewhere, and for a sequence none of the kernel's tiles divides, XLA's own
 ops (``kernel_tile`` decides, from the devices the step is traced for): a
@@ -16,10 +20,12 @@ at 32 heads of 8,192 positions); the queries go block by block, each
 block against the keys up to its own end (a static slice, so a block pays
 for the triangle it needs and half a block of its diagonal, (n+1)/2n of the
 square over n blocks), each block a ``jax.checkpoint`` of its own, sequences
-one by one (``lax.map``): the peak is one sequence's one block.  On the chip
-that path reads 1.15 s a step where the kernel and what surrounds it read
-0.06 (PERF.md §6, PR 36): its fusions of a 64-deep contraction run at 0.3
-TFLOP/s.
+one by one (``lax.map``): the peak is one sequence's one block; q, k, v and
+the output carry the same name, so a caller that keeps them runs these
+blocks' forward once, and each block's own checkpoint forms its scores again
+inside its backward.  On the chip that path reads 1.15 s a step where the
+kernel and what surrounds it read 0.06 (PERF.md §6, PR 36): its fusions of a
+64-deep contraction run at 0.3 TFLOP/s.
 """
 
 from __future__ import annotations
@@ -30,6 +36,8 @@ import logging
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .kept import count, keep
 
 # query rows a block: [heads, 512, S] float32 scores are 0.5 GB at 32 heads
 # of 8,192 keys, and 16 blocks unroll into the step
@@ -115,9 +123,15 @@ def _kernel_attention(q, k, v, *, block: int, interpret: bool):
         block_sizes=sizes, residual_checkpoint_name=ATTENTION_RESIDUALS,
         interpret=interpret)
     heads_first = lambda x: jnp.swapaxes(x, 1, 2)
-    # the kernel takes the scale on the queries
-    out = jax.vmap(attend)(heads_first(q * jnp.asarray(d ** -0.5, q.dtype)),
-                           heads_first(k), heads_first(v))
+    # the kernel takes the scale on the queries; its operands carry the name
+    # as it reads them, heads first: its backward reads them again
+    q, k, v = (keep(heads_first(x), ATTENTION_RESIDUALS)
+               for x in (q * jnp.asarray(d ** -0.5, q.dtype), k, v))
+    out = jax.vmap(attend)(q, k, v)
+    # the kernel names its own residuals: the output and a float32
+    # log-sum-exp a query row
+    count(ATTENTION_RESIDUALS, out.shape, out.dtype)
+    count(ATTENTION_RESIDUALS, out.shape[:-1], jnp.float32)
     return heads_first(out)
 
 
@@ -131,6 +145,7 @@ def causal_attention(q, k, v, *, kernel: bool = False,
         return _kernel_attention(q, k, v, block=block or KERNEL_TILES[0],
                                  interpret=interpret)
     block = block or QUERY_BLOCK
+    q, k, v = (keep(x, ATTENTION_RESIDUALS) for x in (q, k, v))
     b, s, hq, d = q.shape
     g = k.shape[2]
     if s % block:
@@ -143,4 +158,5 @@ def causal_attention(q, k, v, *, kernel: bool = False,
             _block(q1[at:at + block], k1[:at + block], v1[:at + block], at)
             for at in range(0, s, block)], axis=0)
 
-    return lax.map(one, (q, k, v)).reshape(b, s, hq, d)
+    return keep(lax.map(one, (q, k, v)).reshape(b, s, hq, d),
+                ATTENTION_RESIDUALS)

@@ -41,6 +41,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from .grouped import group_rows, grouped_swiglu
+from .kept import keep
+
+# checkpoint name of what the layer's backward needs of its routing: the
+# router's logits, choice and chosen scores, the grouping's order and sizes.
+# A rematerialised caller that keeps them runs no product, top-k, sort or
+# gather of this layer's routing twice; the masks and counts around them are
+# element-wise and cheap to form again
+ROUTING_RESIDUALS = "routing_residuals"
 
 
 def route(x, gate, bias, *, top_k: int, norm_topk_prob: bool = True,
@@ -49,10 +57,15 @@ def route(x, gate, bias, *, top_k: int, norm_topk_prob: bool = True,
     E], bias [E] or None -> ``(chosen [T, k] int32, weights [T, k])``.  The
     bias takes part in the choice only; the weights are the chosen experts'
     own scores, renormalised over the k where ``norm_topk_prob``."""
-    r = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
+    # the name sits on the product itself: the sigmoid's backward reads it
+    r = jax.nn.sigmoid(keep(
+        jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST), ROUTING_RESIDUALS))
     _, chosen = lax.top_k(r if bias is None else r + bias, top_k)
-    w = jnp.take_along_axis(r, chosen, axis=-1)
+    chosen = keep(chosen, ROUTING_RESIDUALS)
+    # a gather costs by the index, not by the byte (0.7 ms a layer at 65,536
+    # assignments for 262 kB: PERF.md §6, PR 39)
+    w = keep(jnp.take_along_axis(r, chosen, axis=-1), ROUTING_RESIDUALS)
     if norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
     return chosen, w * scale
@@ -119,6 +132,8 @@ def held_experts_sum(x, chosen, weights, w1, w3, w2, *, num_experts: int,
     a = chosen.size
     c = compact_rows(a, held, num_experts)
     order, sizes, live = group_rows((chosen - lo).reshape(-1), held)
+    order = keep(order, ROUTING_RESIDUALS)
+    sizes = keep(sizes, ROUTING_RESIDUALS)
     args = (x, weights, order, live, sizes, w1, w3, w2)
     if c == a:
         out = _buffer_sum(a, compute_dtype, *args)

@@ -10,14 +10,21 @@ What ``perf/rehearse_compile.py`` does (mesh from the described devices →
 rehearsed; that file builds the click-through batch by hand and only a
 ``benchmark`` PR may edit it (PERF.md §7).  Prints the compiler's bytes with
 and without the benchmark's ``p0`` copy of the parameters (4 B a parameter,
-``perf/entries/train.py first_steps``).  Nothing runs: no time, no result.
+``perf/entries/train.py first_steps``), and what the outermost
+``jax.checkpoint``s (the token family's blocks) run again in their backward:
+the instructions under their recomputation by the last part of their
+``op_name``, and those of them that are a product, a sort, a top-k, a gather
+or a kernel (``models/lfm2_moe.KEEP``'s rule: none).  Nothing runs: no time,
+no result.
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
+import re
 import sys
 import time
 from pathlib import Path
@@ -40,6 +47,7 @@ def main(argv=None) -> int:
     from jax.sharding import NamedSharding
 
     from deepfm_tpu.models.base import get_model
+    from deepfm_tpu.obs.trace import NOT_ELEMENT_WISE, recomputed_part
     from deepfm_tpu.parallel import spmd
     from deepfm_tpu.parallel.mesh import build_mesh
     from perf import manifest
@@ -66,8 +74,14 @@ def main(argv=None) -> int:
     t = time.perf_counter()
     compiled = spmd.make_spmd_train_step(ctx).lower(state, batch).compile()
     mem = compiled.memory_analysis()
+    hlo = compiled.as_text()
     if args.hlo:
-        Path(args.hlo).write_text(compiled.as_text())
+        Path(args.hlo).write_text(hlo)
+    # one entry an instruction: a fusion's name is its root's
+    again = collections.Counter(
+        part.rsplit("/", 1)[-1]
+        for part in map(recomputed_part,
+                        re.findall(r'op_name="([^"]*)"', hlo)) if part)
     p0 = sum(4 * int(np.prod(x.shape))
              for x in jax.tree_util.tree_leaves(abstract.params))
     step = mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -81,6 +95,9 @@ def main(argv=None) -> int:
         "with_p0_bytes": step + p0,
         "share_of_16GB": step / 16e9,
         "share_of_16GB_with_p0": (step + p0) / 16e9,
+        "blocks_recompute_instructions": sum(again.values()),
+        "blocks_recompute_not_element_wise": {
+            k: n for k, n in again.items() if k in NOT_ELEMENT_WISE},
     }, indent=1))
     return 0
 

@@ -7,6 +7,7 @@ the attention kernel: ``tests/test_lfm2_moe.py``, whose helpers these share.)
 
 import functools
 import logging
+import re
 import time
 from pathlib import Path
 
@@ -21,7 +22,11 @@ from jax.sharding import PartitionSpec as P
 from test_lfm2_moe import MANIFEST, TINY, _config, _ids, _mesh, _rel, c, ref
 
 from deepfm_tpu.models import get_model, lfm2_moe, register_model
-from deepfm_tpu.obs.trace import scope_of
+from deepfm_tpu.obs.trace import (
+    NOT_ELEMENT_WISE,
+    recomputed_part,
+    scope_of,
+)
 from deepfm_tpu.ops.experts import compact_rows
 from deepfm_tpu.parallel import (
     create_spmd_state,
@@ -246,37 +251,53 @@ def test_the_launcher_imports_with_the_family_registered():
             timeout=120)
 
 
+@pytest.fixture(scope="module")
+def chip():
+    """One chip of a described v5e host: no chip attached, the process's
+    backend the CPU."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0]
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _lowered_for(cfg, device, rows: int = 2):
+    """The step builders as they stand, lowered for ``device`` from shapes."""
+    from jax.sharding import NamedSharding
+
+    from deepfm_tpu.parallel.spmd import abstract_spmd_state
+
+    ctx = make_context(cfg, _mesh(1, devices=[device]))
+    state = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        abstract_spmd_state(ctx), ctx.state_shardings)
+    batch = {"feat_ids": jax.ShapeDtypeStruct(
+        (rows, cfg.model.field_size), jnp.int32,
+        sharding=NamedSharding(ctx.mesh, ctx.batch_specs["feat_ids"]))}
+    return make_spmd_train_step(ctx).lower(state, batch)
+
+
+# a sequence of 256 and heads of 64: the least the kernel's tiles take
+_KERNEL_SIZED = dict(field_size=256, embedding_size=128,
+                     num_attention_heads=2, num_key_value_heads=1)
+
+
 def test_the_step_built_for_a_chip_takes_the_attention_kernel_by_itself(
-        caplog):
+        caplog, chip):
     """The step builders as they stand, at a tiny size with a sequence of 256
     and heads of 64: lowered for a described v5e chip (no chip attached, the
     process's backend the CPU) the step holds the Pallas kernel's calls,
     forward and backward, and says so; lowered for this CPU, XLA's ops.  No
     option chooses: a rehearsal compile is the program the chip runs."""
-    from jax.experimental import topologies
-    from jax.sharding import NamedSharding
-
-    from deepfm_tpu.parallel.spmd import abstract_spmd_state
-
-    try:
-        chip = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices[0]
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    cfg = _config(field_size=256, embedding_size=128, num_attention_heads=2,
-                  num_key_value_heads=1)
+    cfg = _config(**_KERNEL_SIZED)
 
     def lowered(device):
-        ctx = make_context(cfg, _mesh(1, devices=[device]))
-        state = jax.tree_util.tree_map(
-            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
-            abstract_spmd_state(ctx), ctx.state_shardings)
-        batch = {"feat_ids": jax.ShapeDtypeStruct(
-            (2, 256), jnp.int32,
-            sharding=NamedSharding(ctx.mesh, ctx.batch_specs["feat_ids"]))}
         caplog.clear()
         with caplog.at_level(logging.INFO, logger="deepfm_tpu.ops.attention"):
-            text = make_spmd_train_step(ctx).lower(state, batch).as_text()
+            text = _lowered_for(cfg, device).as_text()
         return text, {r.getMessage() for r in caplog.records}
 
     text, said = lowered(chip)
@@ -288,7 +309,7 @@ def test_the_step_built_for_a_chip_takes_the_attention_kernel_by_itself(
         "attention: XLA's blocked ops (devices: cpu), positions=256"}
 
 
-_OP_NAME = __import__("re").compile(r'op_name="([^"]*)"')
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 def test_each_scope_of_the_family_is_in_the_compiled_steps_op_names():
@@ -313,3 +334,105 @@ def test_each_scope_of_the_family_is_in_the_compiled_steps_op_names():
     names = set(_OP_NAME.findall(hlo))
     assert any("/checkpoint/rematted_computation/experts/" in n for n in names)
     assert any("/checkpoint/experts/" in n for n in names)
+
+
+@pytest.mark.parametrize("device", ["cpu", "v5e"])
+def test_a_blocks_recomputation_holds_no_matmul_sort_top_k_or_kernel(
+        device, request):
+    """``KEEP``'s rule in the compiled step's text, for a conv + dense, an
+    attention + experts and a conv + experts block: on this CPU (XLA's
+    blocked attention, the grouped product a ``dot_general``) and for a
+    described v5e chip (the Pallas kernel, whose ``op_name`` ends in its own
+    name and ``pallas_call``; the grouped product a ``ragged_dot``).  What the
+    blocks still form again is element-wise: the expert layer's masks and
+    counts among it."""
+    if device == "cpu":
+        cfg = _config()
+        ctx = make_context(cfg, _mesh(1))
+        hlo = make_spmd_train_step(ctx, donate=False).lower(
+            create_spmd_state(ctx),
+            shard_batch(ctx, {"feat_ids": _ids(cfg, 4)})).compile().as_text()
+    else:
+        cfg = _config(**_KERNEL_SIZED).with_overrides(
+            model={"compute_dtype": "bfloat16"})
+        hlo = _lowered_for(
+            cfg, request.getfixturevalue("chip")).compile().as_text()
+        assert "splash_mha_fwd" in hlo and "splash_mha_dkv" in hlo
+        # XLA:TPU's grouped product keeps no ``op_name``: counted instead.
+        # An expert layer's forward runs 3 in each of its two branches, each
+        # backward branch its own 3 again and 6 more; a block that ran its
+        # layer's forward twice would add 6
+        products = set(re.findall(r"%(ragged-dot-none[.\d]*) = ", hlo))
+        assert len(products) == 2 * (3 + 9) * 2
+    assert cfg.model.layer_types == ("conv", "full_attention", "conv")
+    assert cfg.model.num_dense_layers == 1
+    again = {recomputed_part(n) for n in _OP_NAME.findall(hlo)} - {None}
+    for scope in ("conv_mixer", "attention", "dense_ffn", "router",
+                  "experts"):
+        assert any(n.startswith(scope + "/") for n in again), scope
+    twice = sorted(n for n in again
+                   if n.rsplit("/", 1)[-1] in NOT_ELEMENT_WISE
+                   or "splash" in n)
+    assert not twice, twice
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
+        compute_dtype):
+    """What a block keeps is what it would have computed again: the loss and
+    every leaf's gradient with the blocks checkpointed under ``KEEP`` equal
+    those with ``remat=False``."""
+    cfg = _config().with_overrides(
+        model={"compute_dtype": compute_dtype}).model
+    params, state = lfm2_moe.init_lfm2_moe(jax.random.PRNGKey(39), cfg)
+    ids = jnp.asarray(_ids(_config(), 3, seed=39), jnp.int32)
+
+    def loss(params, remat):
+        hidden, _ = lfm2_moe.hidden_states(params, state, ids, cfg=cfg,
+                                           remat=remat)
+        logits = lfm2_moe.logits_of(params, hidden, cfg)
+        return jnp.mean(lfm2_moe.sequence_losses(logits, ids))
+
+    grad = jax.jit(jax.value_and_grad(loss), static_argnums=1)
+    (kept, kept_grads), (plain, plain_grads) = grad(params, True), grad(
+        params, False)
+    assert float(kept) == float(plain)
+    got, want = c.flat_names(kept_grads), c.flat_names(plain_grads)
+    assert set(got) == set(want)
+    for name in want:
+        assert _rel(got[name], want[name]) <= 1e-6, name
+
+
+def test_the_blocks_say_once_a_trace_what_they_keep(caplog):
+    """``blocks keep: <names>, <MB> a step`` at INFO, the bytes summed from
+    the named arrays' shapes: of 4 sequences of 32 tokens at width 32 in
+    float32, each conv layer's ``in_proj`` [·, 96] and ``out_proj`` [·, 32];
+    the attention layer's q and o projections [·, 32] and k's [·, 16], its
+    operands q [·, 32], k and v [·, 16] and its output [·, 32]; the dense
+    layer's ``w1`` and ``w3`` [·, 48] and its operands [·, 32] and [·, 48];
+    each expert layer's logits [·, 16], choice and chosen scores [·, 2],
+    order [tokens · 2] and sizes [4].  Evaluation is no ``jax.checkpoint``,
+    keeps nothing and says nothing."""
+    cfg = _config().model
+    params, state = lfm2_moe.init_lfm2_moe(jax.random.PRNGKey(0), cfg)
+    ids = jnp.asarray(_ids(_config(), 4), jnp.int32)
+
+    def said_by(trace):
+        caplog.clear()
+        with caplog.at_level(logging.INFO,
+                             logger="deepfm_tpu.models.lfm2_moe"):
+            jax.eval_shape(trace, params)
+        return [r.getMessage() for r in caplog.records]
+
+    tokens = 4 * 32
+    projections = 4 * tokens * (2 * (96 + 32) + (32 + 32 + 16) + 2 * 48)
+    attention = 4 * tokens * (32 + 16 + 16 + 32)
+    swiglu = 4 * tokens * (32 + 48)
+    routing = 2 * 4 * (tokens * (16 + 2 + 2 + 2) + 4)
+    assert projections + attention + swiglu + routing == 333_856
+    assert said_by(jax.grad(lambda p: jnp.sum(lfm2_moe.hidden_states(
+        p, state, ids, cfg=cfg)[0]))) == [
+        "blocks keep: attention_residuals, projections, routing_residuals, "
+        "swiglu_operands, 0.334 MB a step"]
+    assert said_by(lambda p: lfm2_moe.hidden_states(
+        p, state, ids, cfg=cfg, remat=False)) == []

@@ -822,6 +822,20 @@ def test_scope_of_reads_through_the_transforms():
     assert len(set(STEP_SCOPES)) == len(STEP_SCOPES)
 
 
+@pytest.mark.parametrize("op_name, part", [
+    ("jit(local_step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+     "router/mul", "router/mul"),
+    ("jit(local_step)/transpose(jvp(jvp()))/checkpoint/experts/"
+     "jit(_either_buffer)/cond/branch_0_fun/checkpoint/rematted_computation/"
+     "ragged_dot", None),                  # the branch's own checkpoint
+    ("jit(local_step)/transpose(jvp(jvp()))/checkpoint/attention/mul", None),
+    ("jit(local_step)/jvp(attention)/dot_general", None),
+], ids=["recomputed", "nested", "backward", "forward"])
+def test_recomputed_part_reads_the_outermost_checkpoints_recomputation(
+        op_name, part):
+    assert obs_trace.recomputed_part(op_name) == part
+
+
 @pytest.mark.parametrize("model", [
     {"model_name": "deepfm"},
     {"model_name": "xdeepfm", "cin_layers": (5, 4)},
