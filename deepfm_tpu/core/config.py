@@ -187,7 +187,7 @@ class ModelConfig:
     batch_norm: bool = False          # ps:64-66
     batch_norm_decay: float = 0.9     # ps:67-69
     l2_reg: float = 0.0001            # ps:57; applied to FM_W/FM_V only (ps:275-279)
-    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe
+    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe | evabyte
     model_name: str = "deepfm"
     # xDeepFM CIN layer sizes / DCN-v2 cross depth (ignored by plain deepfm)
     cin_layers: tuple[int, ...] = (128, 128)
@@ -206,6 +206,7 @@ class ModelConfig:
     # others).  The hidden size rides ``embedding_size`` (a token row), the
     # sequence length ``field_size``, the vocabulary held ``feature_size``.
     # One entry a layer: "conv" (gated short convolution) | "full_attention"
+    # | "eva" (the byte family's)
     layer_types: tuple[str, ...] = ()
     num_dense_layers: int = 0         # leading layers with the dense SwiGLU
     intermediate_size: int = 0        # the dense SwiGLU's width
@@ -223,6 +224,15 @@ class ModelConfig:
     conv_L_cache: int = 3             # the short convolution's kernel
     norm_eps: float = 1e-5
     rope_theta: float = 1_000_000.0
+    # byte family (model_name="evabyte", models/evabyte.py; it also reads
+    # layer_types — one "eva" a layer —, intermediate_size,
+    # num_attention_heads, norm_eps and rope_theta above).  Attention heads
+    # held here, heads 0..held-1 of ``num_attention_heads`` (0 = all of
+    # them): what the absent ones would add to a layer's output is left out
+    heads_held: int = 0
+    window_size: int = 0              # EVA: tokens a window of exact keys
+    chunk_size: int = 0               # EVA: tokens a summarised chunk
+    num_pred_heads: int = 0           # output heads: head p scores byte t+1+p
     # compute dtype for the MLP/FM math (params stay f32; bf16 feeds the MXU)
     compute_dtype: str = "bfloat16"
     # "scatter" | "segsum": selects nothing since PR 27.  The chip decided

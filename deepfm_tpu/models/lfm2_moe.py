@@ -31,9 +31,10 @@ step leaves it as it is (the published config gives no update rule).
 
 Matmuls run in ``compute_dtype`` over float32 master weights; the residual
 stream, norms, router, softmaxes and the loss in float32.  Each block is a
-``jax.checkpoint`` that keeps its products (``KEEP``: every projection's
-output, what the attention reads and writes, the routing) and forms the
-element-wise work between them again in its backward.  Named scopes
+``jax.checkpoint`` that keeps its products (every projection's output, what
+the attention reads and writes, the routing: ``ops/kept.py``'s rule, as far
+as the bytes go) and forms the element-wise work between them again in its
+backward.  Named scopes
 (``obs/trace.STEP_SCOPES``): ``lookup``, ``conv_mixer``, ``attention``,
 ``dense_ffn``, ``router``, ``experts``, ``lm_head``, ``loss``.
 """
@@ -49,30 +50,24 @@ from jax import lax
 
 from ..core.config import DATA_AXIS, MODEL_AXIS, ModelConfig
 from ..ops.attention import (
-    ATTENTION_RESIDUALS,
     apply_rope,
     causal_attention,
     kernel_tile,
     rope_tables,
 )
+from ..ops.dense import dense_ffn, kept_mm, mm, rms_norm
 from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.experts import (
-    ROUTING_RESIDUALS,
     compact_rows,
     held_experts_sum,
     route,
 )
-from ..ops.kept import keep, tally
+from ..ops.kept import block_policy
 from .base import BatchField, ModelDef, register_model
 
 TABLE = "tok_embedding"
 INIT_STD = 0.02     # every matrix and the table (the family's initializer_range)
 BIAS_STD = 0.01     # the selection bias: choice and weight really differ
-# checkpoint names: a projection's product, in the dtype it was computed in;
-# and the dense SwiGLU's two operands in ``compute_dtype`` (its normalised
-# input and ``silu(a)·b``)
-PROJECTIONS = "projections"
-SWIGLU_OPERANDS = "swiglu_operands"
 
 
 def held(cfg: ModelConfig) -> int:
@@ -153,21 +148,6 @@ def init_lfm2_moe(key: jax.Array, cfg: ModelConfig) -> tuple[dict, dict]:
     return params, state
 
 
-def rms_norm(x, gain, eps: float):
-    x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps) * gain
-
-
-def _mm(x, w, dt):
-    return jnp.dot(x.astype(dt), w.astype(dt))
-
-
-def _kept_mm(x, w, dt):
-    """The product under its name, before any cast, reshape or norm: what
-    reads a product in the backward reads the value the name sits on."""
-    return keep(_mm(x, w, dt), PROJECTIONS)
-
-
 @jax.named_scope("conv_mixer")
 def conv_mixer(p: dict, x, cfg: ModelConfig):
     """The gated short convolution; no activation anywhere in it.  The gates
@@ -176,11 +156,11 @@ def conv_mixer(p: dict, x, cfg: ModelConfig):
     # split, then cast: each reader of a gate converts its own third of the
     # kept product, and no float32 [·, 3h] copy of it is written
     b, c, u = (part.astype(jnp.float32) for part in jnp.split(
-        _kept_mm(x, p["in_proj"], dt), 3, -1))
+        kept_mm(x, p["in_proj"], dt), 3, -1))
     taps, s = cfg.conv_L_cache, x.shape[1]
     bu = jnp.pad(b * u, ((0, 0), (taps - 1, 0), (0, 0)))
     v = sum(p["conv"][j] * bu[:, j:j + s] for j in range(taps))
-    return _kept_mm(c * v, p["out_proj"], dt)
+    return kept_mm(c * v, p["out_proj"], dt)
 
 
 @jax.named_scope("attention")
@@ -192,8 +172,8 @@ def attention(p: dict, x, rope, cfg: ModelConfig):
     def heads(w, gain=None):
         # v's product goes to the attention as it is, which names it
         if gain is None:
-            return _mm(x, w, dt).reshape(b, s, -1, d)
-        y = _kept_mm(x, w, dt).reshape(b, s, -1, d)
+            return mm(x, w, dt).reshape(b, s, -1, d)
+        y = kept_mm(x, w, dt).reshape(b, s, -1, d)
         return apply_rope(rms_norm(y, gain, cfg.norm_eps), *rope).astype(dt)
 
     tile = kernel_tile(s)
@@ -201,19 +181,7 @@ def attention(p: dict, x, rope, cfg: ModelConfig):
                            heads(p["k_proj"], p["k_norm"]),
                            heads(p["v_proj"]),
                            kernel=tile is not None, block=tile)
-    return _kept_mm(out.reshape(b, s, -1), p["o_proj"], dt)
-
-
-@jax.named_scope("dense_ffn")
-def dense_ffn(p: dict, x, cfg: ModelConfig):
-    dt = jnp.dtype(cfg.compute_dtype)
-    # the three weight gradients are the step's widest products; an operand
-    # formed again inside one slows it by more than the pass that forms it
-    # (PERF.md §6, PR 39), so both are kept as the products read them
-    x = keep(x.astype(dt), SWIGLU_OPERANDS)
-    a, b = _kept_mm(x, p["w1"], dt), _kept_mm(x, p["w3"], dt)
-    h = jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)
-    return _mm(keep(h.astype(dt), SWIGLU_OPERANDS), p["w2"], dt)
+    return kept_mm(out.reshape(b, s, -1), p["o_proj"], dt)
 
 
 def sparse_ffn(p: dict, bias, x, cfg: ModelConfig, axis_name):
@@ -246,24 +214,6 @@ def block(p: dict, state: dict, x, rope, *, cfg: ModelConfig, layer: int,
     return x + y, took
 
 
-# What a rematerialised block keeps of its forward.  The rule: a block's
-# recomputation holds no matmul, no sort, no top-k, no gather by index and no
-# kernel; it holds element-wise work only (the block norms, gates, casts, the
-# taps, masks and counts).  So every product is kept where it leaves the MXU:
-# the operators' projections in and out and the dense SwiGLU's two wide ones
-# (``w2``'s output is read by nothing a backward needs); what the attention
-# reads and writes (q, k and v as it takes them, its output and log-sum-exp);
-# the router's logits, choice and chosen scores, the grouping's order and
-# sizes.  Past the rule, where a traced run showed that it pays (``PERF.md``
-# §6, PR 39): the dense SwiGLU's operands.  2.6 GB a step at the token cell's
-# size (``PERF.md`` §4).  The expert layer's buffers are checkpoints of their
-# own under a ``cond`` and keep their inputs only (``ops/experts.py``).  Fixed
-# here, no option: a configuration that cannot hold its products is where a
-# rule on the bytes observed from the shapes would enter.
-KEEP = jax.checkpoint_policies.save_only_these_names(
-    ATTENTION_RESIDUALS, PROJECTIONS, ROUTING_RESIDUALS, SWIGLU_OPERANDS)
-
-
 def hidden_states(params: dict, model_state: dict, ids, *, cfg: ModelConfig,
                   lookup_fn=dense_lookup, axis_name=None, remat: bool = True):
     """ids [b, S] -> (n_out(x) [b, S, h] float32, for each expert layer the
@@ -271,20 +221,28 @@ def hidden_states(params: dict, model_state: dict, ids, *, cfg: ModelConfig,
     with jax.named_scope("lookup"):
         x = lookup_fn(params[TABLE], ids).astype(jnp.float32)
     rope = rope_tables(ids.shape[1], head_dim(cfg), cfg.rope_theta)
-    took = []
-    with tally() as kept:
+
+    def blocks(x, wrap=lambda run: run):
+        took = []
         for l in range(len(cfg.layer_types)):
             run = functools.partial(block, cfg=cfg, layer=l,
                                     axis_name=axis_name)
-            x, t = (jax.checkpoint(run, policy=KEEP) if remat else run)(
-                params[f"layer_{l}"], model_state.get(f"layer_{l}", {}), x,
-                rope)
+            x, t = wrap(run)(params[f"layer_{l}"],
+                             model_state.get(f"layer_{l}", {}), x, rope)
             if t is not None:
                 took.append(t)
+        return x, took
+
     if remat:
-        logging.getLogger(__name__).info(
-            "blocks keep: %s, %.3f MB a step", ", ".join(sorted(kept)),
-            sum(kept.values()) / 1e6)
+        # what a block keeps follows the bytes (``ops/kept.py``): at the
+        # token cell's size every name, 2.6 GB a step (``PERF.md`` §4).  The
+        # expert layer's buffers are checkpoints of their own under a
+        # ``cond`` and keep their inputs only (``ops/experts.py``)
+        policy = block_policy(blocks, x, params, len(cfg.layer_types),
+                              logging.getLogger(__name__))
+        x, took = blocks(x, lambda run: jax.checkpoint(run, policy=policy))
+    else:
+        x, took = blocks(x)
     return rms_norm(x, params["out_norm"], cfg.norm_eps), took
 
 

@@ -639,14 +639,18 @@ STEP_SCOPES = ("lookup", "fm", "cin", "cross", "mlp", "tower", "loss",
                "l2_penalty", "grad_sync", "optimizer", "metrics",
                # the token family's (models/lfm2_moe.py)
                "conv_mixer", "attention", "router", "experts", "dense_ffn",
-               "lm_head")
+               "lm_head",
+               # the byte family's chunk pooling (ops/attention.eva_pool),
+               # inside its ``attention``; the rest it shares with the above
+               "eva_pool")
 
 
 def scope_of(op_name: str) -> tuple[str | None, str | None]:
     """The named scope an HLO ``op_name`` lies under, bare and as written:
     ``jit(local_step)/transpose(jvp(lookup))/scatter-add`` ->
-    ``("lookup", "transpose(jvp(lookup))")``; ``(None, None)`` under none."""
-    for part in op_name.split("/"):
+    ``("lookup", "transpose(jvp(lookup))")``; ``(None, None)`` under none.
+    Of a scope inside a scope (``attention/eva_pool``) the inner one."""
+    for part in reversed(op_name.split("/")):
         inner = part
         while "(" in inner and inner.endswith(")"):
             inner = inner[inner.index("(") + 1:-1]
@@ -657,7 +661,7 @@ def scope_of(op_name: str) -> tuple[str | None, str | None]:
 
 # the last part of an ``op_name`` that is no element-wise work: what a
 # rematerialised block of the token family keeps instead of running it again
-# (``models/lfm2_moe.KEEP``'s rule)
+# (``ops/kept.py``'s rule, where the bytes allow it)
 NOT_ELEMENT_WISE = ("dot_general", "ragged_dot", "sort", "top_k", "gather",
                     "pallas_call")
 

@@ -1,17 +1,19 @@
-"""Causal grouped-query attention over long sequences.
+"""Attention over long sequences: causal grouped-query attention, and EVA
+(Zheng et al., ICLR 2023: exact softmax terms for a query's own window, every
+earlier window through one key and one value a chunk, in the same softmax).
 
 On a TPU the work is JAX's own Pallas kernel (``splash_attention``: a flash
-attention whose grid follows the causal mask's live blocks, key-value heads
-shared by their query heads inside the kernel, a fused backward).  Its
-instructions carry the kernel's name in the compiled step
-(``splash_mha_fwd_residuals``, ``splash_mha_dkv_no_residuals``: what the
-benchmark's ``splash_attention_roofline`` reads), and its operands (q with
-the scale on it, k and v, heads first, as it reads them), its output and its
-log-sum-exp carry ``ATTENTION_RESIDUALS`` for a ``jax.checkpoint`` policy:
-a rematerialised block that keeps them (``models/lfm2_moe.KEEP``) does not
-run the forward twice, nor the norms, RoPE and layout copies that make its
-operands (6.1 ms a layer at the cell's size, heads of 64 being half a lane
-tile: PERF.md §6, PR 39).
+attention whose grid follows the mask's live blocks, key-value heads shared
+by their query heads inside the kernel, a fused backward).  Its instructions
+carry the kernel's name in the compiled step (``splash_mha_fwd_residuals``,
+``splash_mha_dkv_no_residuals``: what the benchmark's
+``splash_attention_roofline`` and ``eva_attention_roofline`` read), and its
+operands (q with the scale on it, k and v, heads first, as it reads them),
+its output and its log-sum-exp carry ``ATTENTION_RESIDUALS`` for a
+``jax.checkpoint`` policy: a rematerialised block that keeps them
+(``ops/kept.py``) does not run the forward twice, nor the norms, RoPE and
+layout copies that make its operands (6.1 ms a layer at the token cell's
+size, heads of 64 being half a lane tile: PERF.md §6, PR 39).
 
 Elsewhere, and for a sequence none of the kernel's tiles divides, XLA's own
 ops (``kernel_tile`` decides, from the devices the step is traced for): a
@@ -26,6 +28,18 @@ blocks' forward once, and each block's own checkpoint forms its scores again
 inside its backward.  On the chip that path reads 1.15 s a step where the
 kernel and what surrounds it read 0.06 (PERF.md §6, PR 36): its fusions of a
 64-deep contraction run at 0.3 TFLOP/s.
+
+EVA (``eva_attention``) is the second shape of problem through the same
+seam: keys that outnumber the queries — the ``S`` tokens and the ``S/chunk``
+chunk summaries ``eva_pool`` makes of them, ``[k ; k̃]`` — under a mask that
+is no triangle (``eva_live``: the query's own window, causal inside it;
+every chunk of every earlier window).  The kernel takes the mask as an
+object that forms a block when its set-up asks for one and computes partial
+blocks inside the kernel (``_eva_mask``), so dead blocks are skipped and the
+backward, through the summaries to ``k``, ``v``, ``φ`` and ``μ``, is the
+kernel's and plain ``jnp``'s own.  XLA's path goes window by window: a
+``[heads, window, window + S/chunk]`` score block, never ``[heads, S, S +
+S/chunk]``.
 """
 
 from __future__ import annotations
@@ -35,9 +49,10 @@ import logging
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from .kept import count, keep
+from .kept import ATTENTION_RESIDUALS, count, keep
 
 # query rows a block: [heads, 512, S] float32 scores are 0.5 GB at 32 heads
 # of 8,192 keys, and 16 blocks unroll into the step
@@ -49,21 +64,22 @@ QUERY_BLOCK = 512
 # sequence than a multiple of 1,024; 512 and 256 read 1.25× and 1.7× its time
 KERNEL_TILES = (1024, 512, 256, 128)
 KERNEL_COMPUTE_BLOCK = 512
-# checkpoint name of what the kernel's backward needs of its forward
-ATTENTION_RESIDUALS = "attention_residuals"
 
 
-def kernel_tile(positions: int) -> int | None:
-    """The kernel's tile for a sequence of ``positions``, or None for XLA's
-    ops — observed, not set: the kernel where the trace runs under a mesh of
-    TPU devices (the ``shard_map`` of the step builders; a rehearsal compile
-    for a described chip sees that chip's, whatever backend the process
-    has) and one of its tiles divides the sequence, the largest that does.
-    Said once a trace: ``attention: Pallas kernel, tile=… | XLA's blocked
-    ops (…), positions=…``."""
+def kernel_tile(positions: int, keys: int | None = None) -> int | None:
+    """The kernel's tile for a sequence of ``positions`` (and, where they are
+    not as many, ``keys``), or None for XLA's ops — observed, not set: the
+    kernel where the trace runs under a mesh of TPU devices (the
+    ``shard_map`` of the step builders; a rehearsal compile for a described
+    chip sees that chip's, whatever backend the process has) and one of its
+    tiles divides the sequence, the largest that does.  Said once a trace:
+    ``attention: Pallas kernel, tile=… | XLA's blocked ops (…),
+    positions=…``."""
     device = jax.sharding.get_abstract_mesh().abstract_device
     kind = device.device_kind if device is not None else "no mesh"
-    tile = next((t for t in KERNEL_TILES if positions % t == 0), None)
+    lengths = (positions,) if keys is None else (positions, keys)
+    tile = next((t for t in KERNEL_TILES
+                 if all(n % t == 0 for n in lengths)), None)
     if not kind.startswith("TPU"):
         tile, how = None, f"XLA's blocked ops (devices: {kind})"
     elif tile is None:
@@ -71,7 +87,8 @@ def kernel_tile(positions: int) -> int | None:
     else:
         how = f"Pallas kernel, tile={tile}"
     logging.getLogger(__name__).info(
-        "attention: %s, positions=%d", how, positions)
+        "attention: %s, positions=%d%s", how, positions,
+        "" if keys is None else f", keys={keys}")
     return tile
 
 
@@ -106,33 +123,47 @@ def _block(q, k, v, start: int):
     return jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype), v)
 
 
-def _kernel_attention(q, k, v, *, block: int, interpret: bool):
+def _splash(mask, q, k, v, *, block: int, interpret: bool):
+    """The kernel under one ``mask`` for every head: q [B, H, S, d] with the
+    scale on it, k and v [B, Hkv, keys, d] -> [B, H, S, d]."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel,
         splash_attention_mask as masks,
     )
 
-    s, hq, d = q.shape[1:]
     inner = min(block, KERNEL_COMPUTE_BLOCK)
     sizes = kernel.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=inner,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=inner,
         use_fused_bwd_kernel=True)
     attend = kernel.make_splash_mha_single_device(
-        masks.MultiHeadMask([masks.CausalMask((s, s))] * hq),
+        masks.MultiHeadMask([mask] * q.shape[1]),
         block_sizes=sizes, residual_checkpoint_name=ATTENTION_RESIDUALS,
         interpret=interpret)
-    heads_first = lambda x: jnp.swapaxes(x, 1, 2)
-    # the kernel takes the scale on the queries; its operands carry the name
-    # as it reads them, heads first: its backward reads them again
-    q, k, v = (keep(heads_first(x), ATTENTION_RESIDUALS)
-               for x in (q * jnp.asarray(d ** -0.5, q.dtype), k, v))
     out = jax.vmap(attend)(q, k, v)
     # the kernel names its own residuals: the output and a float32
     # log-sum-exp a query row
     count(ATTENTION_RESIDUALS, out.shape, out.dtype)
     count(ATTENTION_RESIDUALS, out.shape[:-1], jnp.float32)
-    return heads_first(out)
+    return out
+
+
+def _heads_first(x):
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _kernel_attention(q, k, v, *, block: int, interpret: bool):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks,
+    )
+
+    s, d = q.shape[1], q.shape[3]
+    # the kernel takes the scale on the queries; its operands carry the name
+    # as it reads them, heads first: its backward reads them again
+    q, k, v = (keep(_heads_first(x), ATTENTION_RESIDUALS)
+               for x in (q * jnp.asarray(d ** -0.5, q.dtype), k, v))
+    return _heads_first(_splash(masks.CausalMask((s, s)), q, k, v,
+                                block=block, interpret=interpret))
 
 
 def causal_attention(q, k, v, *, kernel: bool = False,
@@ -160,3 +191,175 @@ def causal_attention(q, k, v, *, kernel: bool = False,
 
     return keep(lax.map(one, (q, k, v)).reshape(b, s, hq, d),
                 ATTENTION_RESIDUALS)
+
+
+# -- EVA ----------------------------------------------------------------------
+
+
+def eva_live(q_ids, kv_ids, *, positions: int, window: int, chunk: int):
+    """Whether query ``q_ids`` sees key ``kv_ids`` of ``[tokens ; chunk
+    summaries]`` (``positions`` tokens, then ``positions/chunk`` summaries):
+    a token of its own window, not ahead of it; the summary of a chunk of an
+    earlier window.  Integer arrays that broadcast, numpy's (the kernel's
+    set-up, the counts) or jax's (inside the kernel, XLA's blocks)."""
+    own = ((kv_ids < positions) & (q_ids // window == kv_ids // window)
+           & (q_ids >= kv_ids))
+    earlier = ((kv_ids >= positions)
+               & ((kv_ids - positions) // (window // chunk) < q_ids // window))
+    return own | earlier
+
+
+def _eva_block(rows, columns, *, positions: int, window: int, chunk: int):
+    """``eva_live`` over the 1-D ``rows`` × ``columns`` (numpy), formed only
+    in the columns some row can see: a token of one of the rows' windows, a
+    summary of a chunk before the last of them (every other column is dead
+    for each row, by ``eva_live``'s own two clauses).  Of the cell's 272
+    blocks of 1,024² that is 38 formed, not 272."""
+    row_windows = rows // window
+    seen = np.where(
+        columns < positions, np.isin(columns // window, row_windows),
+        (columns - positions) // (window // chunk) < row_windows.max())
+    block = np.zeros((rows.size, columns.size), bool)
+    if seen.any():
+        block[:, seen] = eva_live(
+            rows[:, None], columns[seen][None, :], positions=positions,
+            window=window, chunk=chunk)
+    return block
+
+
+@functools.lru_cache(maxsize=None)
+def eva_key_counts(positions: int, window: int, chunk: int) -> tuple:
+    """(token keys, summary keys) that the queries of one sequence attend,
+    summed over them, counted on ``eva_live`` itself window by window."""
+    window = min(window, positions)
+    columns = np.arange(positions + positions // chunk)
+    tokens = summaries = 0
+    for at in range(0, positions, window):
+        live = _eva_block(np.arange(at, at + window), columns,
+                          positions=positions, window=window, chunk=chunk)
+        tokens += int(live[:, :positions].sum())
+        summaries += int(live[:, positions:].sum())
+    return tokens, summaries
+
+
+@functools.lru_cache(maxsize=None)
+def _eva_mask(positions: int, window: int, chunk: int):
+    """``eva_live`` as the kernel's mask object: a block of it is formed when
+    the kernel's set-up asks for one (``mask[rows, columns]``: which blocks
+    are dead, whole or partial), and a partial block is computed inside the
+    kernel from the row and column numbers (``q_sequence``,
+    ``mask_function``); the [S, S + S/chunk] array is never held."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks,
+    )
+
+    named = dict(positions=positions, window=window, chunk=chunk)
+
+    class EvaMask(masks.Mask):
+        sizes = (positions, window, chunk)
+        q_sequence = np.arange(positions, dtype=np.int32)
+        mask_function = staticmethod(functools.partial(eva_live, **named))
+
+        @property
+        def shape(self):
+            return positions, positions + positions // chunk
+
+        def __getitem__(self, idx):
+            rows, columns = (np.arange(n)[i] for n, i in zip(self.shape, idx))
+            return _eva_block(rows, columns, **named)
+
+        def __eq__(self, other):
+            return getattr(other, "sizes", None) == self.sizes
+
+        def __hash__(self):
+            return hash(self.sizes)
+
+    return EvaMask()
+
+
+@jax.named_scope("eva_pool")
+def eva_pool(k, v, phi, mu, *, chunk: int):
+    """One key and one value a chunk, heads first: k and v [B, H, S, d], phi
+    and mu [H, d] -> (k̃, ṽ) [B, H, S/chunk, d] in the dtype they came in,
+    float32 inside.  a = softmax over a chunk's tokens of k·φ/√d;  k̃ = Σ a·k
+    + μ;  ṽ = Σ a·v.  Element-wise products and sums over 16 rows: no work
+    for the MXU."""
+    b, h, s, d = k.shape
+    f32 = jnp.float32
+    kc = k.astype(f32).reshape(b, h, s // chunk, chunk, d)
+    vc = v.astype(f32).reshape(b, h, s // chunk, chunk, d)
+    phi, mu = (x.astype(f32)[None, :, None, :] for x in (phi, mu))
+    a = jax.nn.softmax(
+        jnp.sum(kc * phi[..., None, :], axis=-1) * d ** -0.5, axis=-1)
+    pooled = lambda x: jnp.sum(a[..., None] * x, axis=3)
+    return (keep((pooled(kc) + mu).astype(k.dtype), ATTENTION_RESIDUALS),
+            keep(pooled(vc).astype(v.dtype), ATTENTION_RESIDUALS))
+
+
+def _eva_windows(q, k, v, kt, vt, *, window: int, chunk: int):
+    """XLA's ops, window by window: q (scaled), k and v [B, H, S, d], kt and
+    vt [B, H, S/chunk, d] -> [B, H, S, d].  Each window's queries against its
+    own tokens and every summary (the later windows' masked), softmax in
+    float32 over both together; a window a ``jax.checkpoint`` of its own,
+    windows and sequences one by one (``lax.map``): the peak is one
+    ``[H, window, window + S/chunk]`` score block."""
+    b, h, s, d = q.shape
+    n = s // window
+
+    @jax.checkpoint
+    def one_window(q, k, v, kt, vt, at):
+        keys = jnp.concatenate([k, kt], axis=1)
+        values = jnp.concatenate([v, vt], axis=1)
+        scores = jnp.einsum("hqd,hkd->hqk", q, keys,
+                            preferred_element_type=jnp.float32)
+        q_ids = at + jnp.arange(window)
+        kv_ids = jnp.concatenate([q_ids, s + jnp.arange(s // chunk)])
+        live = eva_live(q_ids[:, None], kv_ids[None, :], positions=s,
+                        window=window, chunk=chunk)
+        p = jax.nn.softmax(jnp.where(live, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("hqk,hkd->hqd", p.astype(values.dtype), values)
+
+    def one_sequence(args):
+        q, k, v, kt, vt = args
+        by_window = lambda x: jnp.swapaxes(x.reshape(h, n, window, d), 0, 1)
+        out = lax.map(
+            lambda a: one_window(a[0], a[1], a[2], kt, vt, a[3]),
+            (by_window(q), by_window(k), by_window(v),
+             jnp.arange(n) * window))
+        return jnp.swapaxes(out, 0, 1).reshape(h, s, d)
+
+    return lax.map(one_sequence, (q, k, v, kt, vt))
+
+
+def eva_attention(q, k, v, phi, mu, *, window: int, chunk: int,
+                  kernel: bool = False, block: int | None = None,
+                  interpret: bool = False):
+    """EVA: q, k and v [B, S, H, d] (one key-value head a query head), phi
+    and mu [H, d] -> [B, S, H, d] in v's dtype.  With ``k̃``, ``ṽ`` the chunk
+    summaries (``eva_pool``), query t's output is ONE softmax over the tokens
+    of its window up to itself and the summaries of every chunk of the
+    windows before its own (``eva_live``), times the values ``[v ; ṽ]``.  A
+    sequence of one window (``S <= window``) is plain causal attention.
+    ``kernel``: the Pallas kernel over the keys ``[k ; k̃]`` (``interpret``
+    for a CPU test of it), else XLA's ops by windows."""
+    b, s, h, d = q.shape
+    window = min(window, s)
+    if s % window or window % chunk:
+        raise ValueError(
+            f"EVA takes whole windows of whole chunks: a sequence of {s} "
+            f"with window_size {window} and chunk_size {chunk} is not")
+    # the scale on the queries, as the kernel takes it; q, k and v carry the
+    # name heads first, as the kernel and the pooling read them: a block that
+    # keeps them forms them once
+    q, k, v = (keep(_heads_first(x), ATTENTION_RESIDUALS)
+               for x in (q * jnp.asarray(d ** -0.5, q.dtype), k, v))
+    kt, vt = eva_pool(k, v, phi, mu, chunk=chunk)
+    if kernel:
+        out = _splash(_eva_mask(s, window, chunk), q,
+                      jnp.concatenate([k, kt], axis=2),
+                      jnp.concatenate([v, vt], axis=2),
+                      block=block or KERNEL_TILES[0], interpret=interpret)
+    else:
+        out = keep(_eva_windows(q, k, v, kt, vt, window=window, chunk=chunk),
+                   ATTENTION_RESIDUALS)
+    return _heads_first(out)

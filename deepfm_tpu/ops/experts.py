@@ -41,14 +41,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from .grouped import group_rows, grouped_swiglu
-from .kept import keep
+from .kept import ROUTING_RESIDUALS, keep
 
-# checkpoint name of what the layer's backward needs of its routing: the
-# router's logits, choice and chosen scores, the grouping's order and sizes.
-# A rematerialised caller that keeps them runs no product, top-k, sort or
-# gather of this layer's routing twice; the masks and counts around them are
-# element-wise and cheap to form again
-ROUTING_RESIDUALS = "routing_residuals"
+# ``ROUTING_RESIDUALS`` names what the layer's backward needs of its routing:
+# the router's logits, choice and chosen scores, the grouping's order and
+# sizes.  A rematerialised caller that keeps them runs no product, top-k, sort
+# or gather of this layer's routing twice; the masks and counts around them
+# are element-wise and cheap to form again
 
 
 def route(x, gate, bias, *, top_k: int, norm_topk_prob: bool = True,

@@ -14,7 +14,7 @@ and without the benchmark's ``p0`` copy of the parameters (4 B a parameter,
 ``jax.checkpoint``s (the token family's blocks) run again in their backward:
 the instructions under their recomputation by the last part of their
 ``op_name``, and those of them that are a product, a sort, a top-k, a gather
-or a kernel (``models/lfm2_moe.KEEP``'s rule: none).  Nothing runs: no time,
+or a kernel (``ops/kept.py``'s rule: none where everything fits).  Nothing runs: no time,
 no result.
 """
 

@@ -7,7 +7,8 @@ HLO text (``env.step.lower(state, batch).compile().as_text()``), traces a few
 seconds of steps, and joins each ``XLA Ops`` event of the TPU plane — the bare
 HLO instruction, which carries no ``op_name`` — to its ``op_name`` by
 instruction name, and that to the step's named scope
-(``deepfm_tpu/obs/trace.scope_of``).  A fusion spans scopes; the name XLA
+(``deepfm_tpu/obs/trace.scope_of``: of a scope inside a scope, the byte
+family's ``attention/eva_pool``, the inner one).  A fusion spans scopes; the name XLA
 keeps on it is its root's; a ``while`` is left out of the sums, since the ops of
 its body have events of their own.  Prints the 40 longest ops with their scope, the
 time per scope, and the share of the step's device time under no scope; the

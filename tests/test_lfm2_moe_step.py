@@ -339,7 +339,7 @@ def test_each_scope_of_the_family_is_in_the_compiled_steps_op_names():
 @pytest.mark.parametrize("device", ["cpu", "v5e"])
 def test_a_blocks_recomputation_holds_no_matmul_sort_top_k_or_kernel(
         device, request):
-    """``KEEP``'s rule in the compiled step's text, for a conv + dense, an
+    """``ops/kept.py``'s rule in the compiled step's text, for a conv + dense, an
     attention + experts and a conv + experts block: on this CPU (XLA's
     blocked attention, the grouped product a ``dot_general``) and for a
     described v5e chip (the Pallas kernel, whose ``op_name`` ends in its own
@@ -380,7 +380,7 @@ def test_a_blocks_recomputation_holds_no_matmul_sort_top_k_or_kernel(
 def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
         compute_dtype):
     """What a block keeps is what it would have computed again: the loss and
-    every leaf's gradient with the blocks checkpointed under ``KEEP`` equal
+    every leaf's gradient with the blocks checkpointed (``ops/kept.py``) equal
     those with ``remat=False``."""
     cfg = _config().with_overrides(
         model={"compute_dtype": compute_dtype}).model

@@ -244,6 +244,21 @@ def _lfm2_leaves() -> dict:
                for k, shape in layer.items()}}
 
 
+def _evabyte_leaves() -> dict:
+    """The contract between ``models/evabyte.py`` and
+    ``perf/reference/evabyte.py`` at the published widths: published layers
+    0–3 as one tree of stacked leaves, heads 0–7 of 32."""
+    layer = {"attn_norm": (4096,), "ffn_norm": (4096,),
+             "attention/q_proj": (4096, 1024), "attention/k_proj": (4096, 1024),
+             "attention/v_proj": (4096, 1024), "attention/o_proj": (1024, 4096),
+             "attention/phi": (8, 128), "attention/mu": (8, 128),
+             "dense_ffn/w1": (4096, 11008), "dense_ffn/w3": (4096, 11008),
+             "dense_ffn/w2": (11008, 4096)}
+    return {"byte_embedding": (320, 4096), "heads": (4096, 2560),
+            "out_norm": (4096,),
+            **{f"layers/{k}": (4, *shape) for k, shape in layer.items()}}
+
+
 # per family: the TRUE rows of its table share, its parameter leaves, the
 # non-trainable state beside them, its placed batch ((b, f) the traffic's
 # batch and the configuration's field_size) and its step's metrics
@@ -265,6 +280,14 @@ CONTRACTS = {
         "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
         "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
                     "experts_compact_share", "loss_per_shard"},
+    },
+    "evabyte": {
+        "true_feature_size": 320,
+        "leaves": _evabyte_leaves(),
+        "model_state": {},
+        "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
+        "metrics": {"loss", "ce", "heads_held_share", "eva_summary_key_share",
+                    "loss_per_shard"},
     },
 }
 
