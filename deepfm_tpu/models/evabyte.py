@@ -172,25 +172,27 @@ def _unstacked(tree, n: int) -> list:
 
 def hidden_states(params: dict, ids, *, cfg: ModelConfig,
                   lookup_fn=dense_lookup, axis_name=None, remat: bool = True):
-    """ids [b, S] -> n_out(x) [b, S, h] float32."""
+    """ids [b, S] -> (n_out(x) [b, S, h] float32, the share of the blocks
+    that keep every product they carry: 1 where none is checkpointed)."""
     with jax.named_scope("lookup"):
         x = lookup_fn(params[TABLE], ids).astype(jnp.float32)
     rope = rope_tables(ids.shape[1], head_dim(cfg), cfg.rope_theta)
     run = functools.partial(block, cfg=cfg, axis_name=axis_name)
     layers = _unstacked(params["layers"], len(cfg.layer_types))
 
-    def blocks(x, wrap=lambda run: run):
-        for p in layers:
-            x = wrap(run)(p, x, rope)
+    def blocks(x, wrap=lambda run, i: run):
+        for i, p in enumerate(layers):
+            x = wrap(run, i)(p, x, rope)
         return x
 
+    kept_share = 1.0
     if remat:
-        policy = block_policy(blocks, x, params, len(layers),
-                              logging.getLogger(__name__))
-        x = blocks(x, lambda run: jax.checkpoint(run, policy=policy))
+        policies, kept_share = block_policy(blocks, x, params,
+                                            logging.getLogger(__name__))
+        x = blocks(x, lambda run, i: jax.checkpoint(run, policy=policies[i]))
     else:
         x = blocks(x)
-    return rms_norm(x, 1.0 + params["out_norm"], cfg.norm_eps)
+    return rms_norm(x, 1.0 + params["out_norm"], cfg.norm_eps), kept_share
 
 
 def logits_of(params: dict, hidden, cfg: ModelConfig):
@@ -226,22 +228,24 @@ def _ids(batch: dict, cfg: ModelConfig):
 
 
 def _sequence_terms(params, ids, cfg, lookup_fn, remat):
-    hidden = hidden_states(params, ids, cfg=cfg,
-                           lookup_fn=lookup_fn or dense_lookup,
-                           axis_name=MODEL_AXIS, remat=remat)
+    hidden, kept_share = hidden_states(params, ids, cfg=cfg,
+                                       lookup_fn=lookup_fn or dense_lookup,
+                                       axis_name=MODEL_AXIS, remat=remat)
     logits = logits_of(params, hidden, cfg)
     with jax.named_scope("loss"):
-        return position_losses(jnp.swapaxes(logits, 0, 1), ids.T)
+        return position_losses(jnp.swapaxes(logits, 0, 1), ids.T), kept_share
 
 
 def evabyte_loss(params, model_state, batch, *, cfg, train=False, rng=None,
                  lookup_fn=None):
     """Mean over this shard's sequences (equal-sized shards: the step's pmean
-    of local means is the global mean).  ``outputs`` are the two counters
+    of local means is the global mean).  ``outputs`` are the three counters
     ``metrics`` hands on: ``heads_held_share``, from the leaves the step
-    holds, and ``eva_summary_key_share``, the share of a step's attended keys
+    holds, ``eva_summary_key_share``, the share of a step's attended keys
     that are chunk summaries, counted on the mask the attention ran under
-    (``ops/attention.eva_key_counts``)."""
+    (``ops/attention.eva_key_counts``), and ``blocks_products_kept_share``,
+    the share of the blocks whose backward runs no product again
+    (``ops/kept.block_policy``)."""
     if lax.axis_size(MODEL_AXIS) > 1:
         raise ValueError(
             "evabyte shares a layer's attention heads over the model axis, "
@@ -249,7 +253,8 @@ def evabyte_loss(params, model_state, batch, *, cfg, train=False, rng=None,
             "tables over it: each shard would hold the same heads; use "
             "model_parallel=1")
     ids = _ids(batch, cfg)
-    loss = jnp.mean(_sequence_terms(params, ids, cfg, lookup_fn, True))
+    terms, kept_share = _sequence_terms(params, ids, cfg, lookup_fn, True)
+    loss = jnp.mean(terms)
     held = params["layers"]["attention"]["phi"].shape[1]
     tokens, summaries = eva_key_counts(ids.shape[1], cfg.window_size,
                                        cfg.chunk_size)
@@ -257,12 +262,14 @@ def evabyte_loss(params, model_state, batch, *, cfg, train=False, rng=None,
         "heads_held_share": jnp.asarray(held / cfg.num_attention_heads),
         "eva_summary_key_share": jnp.asarray(
             summaries / (tokens + summaries)),
+        "blocks_products_kept_share": jnp.asarray(kept_share),
     }
 
 
 EVABYTE_METRICS = {
     k: (lambda outputs, batch, k=k: outputs[k])
-    for k in ("heads_held_share", "eva_summary_key_share")
+    for k in ("heads_held_share", "eva_summary_key_share",
+              "blocks_products_kept_share")
 }
 
 
@@ -271,7 +278,8 @@ def evabyte_evaluate(acc, params, model_state, batch, weight, *, cfg,
     """Weighted mean loss over whole sequences; a zero-weight (padded)
     sequence counts for nothing."""
     ids = _ids(batch, cfg)
-    ce = jnp.mean(_sequence_terms(params, ids, cfg, lookup_fn, False), axis=0)
+    terms, _ = _sequence_terms(params, ids, cfg, lookup_fn, False)
+    ce = jnp.mean(terms, axis=0)
     w = jnp.ones_like(ce) if weight is None else weight.astype(ce.dtype)
     count = lax.psum(jnp.sum(w), DATA_AXIS)
     loss = lax.psum(jnp.sum(w * ce), DATA_AXIS) / jnp.maximum(count, 1.0)

@@ -217,33 +217,36 @@ def block(p: dict, state: dict, x, rope, *, cfg: ModelConfig, layer: int,
 def hidden_states(params: dict, model_state: dict, ids, *, cfg: ModelConfig,
                   lookup_fn=dense_lookup, axis_name=None, remat: bool = True):
     """ids [b, S] -> (n_out(x) [b, S, h] float32, for each expert layer the
-    [held] rows each held expert took)."""
+    [held] rows each held expert took, the share of the blocks that keep
+    every product they carry: 1 where none is checkpointed)."""
     with jax.named_scope("lookup"):
         x = lookup_fn(params[TABLE], ids).astype(jnp.float32)
     rope = rope_tables(ids.shape[1], head_dim(cfg), cfg.rope_theta)
 
-    def blocks(x, wrap=lambda run: run):
+    def blocks(x, wrap=lambda run, l: run):
         took = []
         for l in range(len(cfg.layer_types)):
             run = functools.partial(block, cfg=cfg, layer=l,
                                     axis_name=axis_name)
-            x, t = wrap(run)(params[f"layer_{l}"],
-                             model_state.get(f"layer_{l}", {}), x, rope)
+            x, t = wrap(run, l)(params[f"layer_{l}"],
+                                model_state.get(f"layer_{l}", {}), x, rope)
             if t is not None:
                 took.append(t)
         return x, took
 
+    kept_share = 1.0
     if remat:
         # what a block keeps follows the bytes (``ops/kept.py``): at the
         # token cell's size every name, 2.6 GB a step (``PERF.md`` §4).  The
         # expert layer's buffers are checkpoints of their own under a
         # ``cond`` and keep their inputs only (``ops/experts.py``)
-        policy = block_policy(blocks, x, params, len(cfg.layer_types),
-                              logging.getLogger(__name__))
-        x, took = blocks(x, lambda run: jax.checkpoint(run, policy=policy))
+        policies, kept_share = block_policy(blocks, x, params,
+                                            logging.getLogger(__name__))
+        x, took = blocks(
+            x, lambda run, l: jax.checkpoint(run, policy=policies[l]))
     else:
         x, took = blocks(x)
-    return rms_norm(x, params["out_norm"], cfg.norm_eps), took
+    return rms_norm(x, params["out_norm"], cfg.norm_eps), took, kept_share
 
 
 def logits_of(params: dict, hidden, cfg: ModelConfig):
@@ -281,20 +284,28 @@ def lfm2_moe_loss(params, model_state, batch, *, cfg, train=False, rng=None,
     ``expert_load_max_share``, the fullest held expert's rows over the held
     experts' mean, the worst layer, and ``experts_compact_share``, the share
     of the expert layers whose held rows fit the compact buffer
-    (``ops/experts.compact_rows``: 1 where that buffer is every row)."""
+    (``ops/experts.compact_rows``: 1 where that buffer is every row); beside
+    them ``blocks_products_kept_share``, the share of the blocks whose
+    backward runs no product again (``ops/kept.block_policy``)."""
     ids = _ids(batch, cfg)
-    hidden, took = hidden_states(
+    hidden, took, kept_share = hidden_states(
         params, model_state, ids, cfg=cfg,
         lookup_fn=lookup_fn or dense_lookup, axis_name=MODEL_AXIS)
     logits = logits_of(params, hidden, cfg)
     with jax.named_scope("loss"):
         loss = jnp.mean(sequence_losses(logits, ids))
-    return loss, model_state, routing_counters(took, ids.size, cfg)
+    return loss, model_state, {
+        **routing_counters(took, ids.size, cfg),
+        "blocks_products_kept_share": jnp.asarray(kept_share)}
+
+
+ROUTING_COUNTERS = ("rows_held_share", "expert_load_max_share",
+                    "experts_compact_share")
 
 
 def routing_counters(took: list, tokens: int, cfg: ModelConfig) -> dict:
     if not took:
-        return {k: jnp.zeros(()) for k in LFM2_MOE_METRICS}
+        return {k: jnp.zeros(()) for k in ROUTING_COUNTERS}
     took = lax.stop_gradient(jnp.stack(took))
     mean, rows = jnp.mean(took, axis=1), jnp.sum(took, axis=1)
     assignments = tokens * cfg.num_experts_per_tok
@@ -310,8 +321,7 @@ def routing_counters(took: list, tokens: int, cfg: ModelConfig) -> dict:
 
 LFM2_MOE_METRICS = {
     k: (lambda outputs, batch, k=k: outputs[k])
-    for k in ("rows_held_share", "expert_load_max_share",
-              "experts_compact_share")
+    for k in (*ROUTING_COUNTERS, "blocks_products_kept_share")
 }
 
 
@@ -320,7 +330,7 @@ def lfm2_moe_evaluate(acc, params, model_state, batch, weight, *, cfg,
     """Weighted mean next-token loss over whole sequences; a zero-weight
     (padded) sequence counts for nothing."""
     ids = _ids(batch, cfg)
-    hidden, _ = hidden_states(
+    hidden, _, _ = hidden_states(
         params, model_state, ids, cfg=cfg,
         lookup_fn=lookup_fn or dense_lookup, axis_name=MODEL_AXIS, remat=False)
     ce = sequence_losses(logits_of(params, hidden, cfg), ids)

@@ -127,7 +127,7 @@ def test_loss_and_every_gradient_leaf_match_the_reference():
     ids = jnp.asarray(_ids(cfg, 3), jnp.int32)
 
     def program(params):
-        hidden = evabyte.hidden_states(params, ids, cfg=cfg.model)
+        hidden, _ = evabyte.hidden_states(params, ids, cfg=cfg.model)
         logits = evabyte.logits_of(params, hidden, cfg.model)
         return jnp.mean(evabyte.position_losses(
             jnp.swapaxes(logits, 0, 1), ids.T)), logits
@@ -337,6 +337,46 @@ def test_names_are_kept_in_order_as_far_as_half_of_what_is_left():
     assert kept.NAMES.index(kept.ROUTING_RESIDUALS) == 1
 
 
+_A, _R, _P, _S = kept.NAMES
+# one block of the byte cell: the attention's q, k, v and output with the
+# pooled keys and values and the log-sum-exp; W₁n, W₃n [16384, 11008] and
+# W_o's [16384, 4096] product in bfloat16; the normalised input and silu(a)·b
+_BYTE_BLOCK = {_A: 138_936_320, _P: 855_638_016, _S: 494_927_872}
+_BYTE_STATE = 16 * 620_015_616
+_SMALL = {_A: 10, _P: 30, _S: 20}
+
+
+@pytest.mark.parametrize("named, inputs, state, memory, want", [
+    pytest.param([_SMALL] * 3, 20, 100, 500, [(_A, _P, _S)] * 3,
+                 id="everything-fits"),
+    pytest.param([_BYTE_BLOCK] * 4, 4 * 16384 * 4096 * 4, _BYTE_STATE,
+                 16_909_336_064, [(_A,)] * 3 + [(_A, _P)], id="byte-cell"),
+    pytest.param([_SMALL] * 3, 60, 100, 219, [()] * 2 + [(_A, _P)],
+                 id="nothing-fits"),
+    pytest.param([_SMALL] * 3, 20, 100, None, [(_A, _P, _S)] * 3,
+                 id="unknown-memory"),
+    pytest.param([_SMALL], 20, 100, 219, [(_A, _P)], id="one-block"),
+    # a token stack's last block is an attention and experts: it carries no
+    # SwiGLU operand, and keeps of the products those it carries
+    pytest.param([{_P: 40, _S: 20}, {_A: 10, _R: 5, _P: 30}], 20, 100, 150,
+                 [(), (_A, _R, _P)], id="mixed-blocks"),
+])
+def test_the_last_block_also_keeps_its_products_whatever_the_bytes(
+        named, inputs, state, memory, want):
+    """``names_by_block`` on plain numbers: every block keeps what
+    ``names_that_fit`` gives the stack's sum (today's choice, the floor); the
+    last block, whose backward the step reaches first, also the products it
+    carries, and never the SwiGLU's operands past the floor."""
+    assert kept.PRODUCTS == (_A, _R, _P)
+    total = {k: sum(b.get(k, 0) for b in named) for k in kept.NAMES}
+    floor = kept.names_that_fit({k: v for k, v in total.items() if v},
+                                inputs, state, memory)
+    got = kept.names_by_block(named, inputs, state, memory)
+    assert got == want
+    assert all(names == floor for names in got[:-1])
+    assert set(floor) <= set(got[-1]) <= set(floor) | set(kept.PRODUCTS)
+
+
 @pytest.fixture(scope="module")
 def v5e_chip():
     """A described v5e chip (no chip attached): the TPU compiler is asked
@@ -358,9 +398,11 @@ def test_what_a_block_keeps_follows_the_bytes_on_a_described_chip(
     """Both sequence cells at their own size, traced from shapes under a mesh
     of one described v5e chip (no device: its memory is the table's): the
     token configuration keeps every name, what its fixed rule kept (2.6 GB of
-    9.4 left), this one the attention residuals alone — the projections'
-    3.4 GB would pass half of the 7 GB its state leaves — and says what a
-    block runs again.  On the CPU nothing says how much there is: every name."""
+    9.4 left) in every block, this one the attention residuals alone — the
+    projections' 3.4 GB would pass half of the 7 GB its state leaves — but
+    for its last block, which also keeps its products, and says what which
+    blocks run again.  On the CPU nothing says how much there is: every
+    name."""
     from deepfm_tpu.models import get_model
     from perf import manifest
     from perf.entries import train
@@ -374,10 +416,16 @@ def test_what_a_block_keeps_follows_the_bytes_on_a_described_chip(
     batch = cell.traffic["params"]["batch_size"]
     ids = jax.ShapeDtypeStruct((batch, cfg.field_size), jnp.int32)
 
+    shares = []
+
     def hidden(params, state, ids):
         if family is lfm2_moe:
-            return lfm2_moe.hidden_states(params, state, ids, cfg=cfg)[0]
-        return evabyte.hidden_states(params, ids, cfg=cfg)
+            hidden, _, share = lfm2_moe.hidden_states(params, state, ids,
+                                                      cfg=cfg)
+        else:
+            hidden, share = evabyte.hidden_states(params, ids, cfg=cfg)
+        shares.append(share)
+        return hidden
 
     def said_under(device):
         caplog.clear()
@@ -397,6 +445,7 @@ def test_what_a_block_keeps_follows_the_bytes_on_a_described_chip(
                 "blocks keep: attention_residuals, projections, "
                 "routing_residuals, swiglu_operands, 263")
             assert said.endswith(" MB a step")
+        assert shares == [1.0, 1.0]
     else:
         # q, k, v and the output of 8 heads of 128 over 16,384 positions in
         # bfloat16, 1,024 pooled keys and values, a float32 log-sum-exp a row
@@ -404,12 +453,14 @@ def test_what_a_block_keeps_follows_the_bytes_on_a_described_chip(
                          + 8 * 16384 * 4)
         assert residuals == 555_745_280
         assert chip == (
-            "blocks keep: attention_residuals, 555.745 MB a step; run again: "
-            "projections, swiglu_operands, 5402.264 MB (6989.086 MB left of "
-            "16909.336 once the state is made)")
+            "blocks keep: attention_residuals, 555.745 MB a step; the last "
+            "block also: projections, 855.638 MB; run again: projections in "
+            "3 blocks, swiglu_operands in 4 blocks, 4546.626 MB (6989.086 MB "
+            "left of 16909.336 once the state is made)")
         assert cpu.startswith("blocks keep: attention_residuals, "
                               "projections, swiglu_operands, 595")
         assert cpu.endswith(" MB a step")
+        assert shares == [0.25, 1.0]
 
 
 def test_the_work_functions_count_the_cell_by_hand():

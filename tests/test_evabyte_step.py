@@ -20,6 +20,7 @@ from test_evabyte import MANIFEST, TINY, _config, _ids, _mesh, _rel, c, ref
 
 from deepfm_tpu.models import evabyte
 from deepfm_tpu.obs.trace import STEP_SCOPES, recomputed_part, scope_of
+from deepfm_tpu.ops import kept
 from deepfm_tpu.parallel import (
     create_spmd_state,
     make_context,
@@ -85,7 +86,7 @@ def test_the_planted_fault_reaches_the_programs_loss_by_its_name():
         s = ref.sizes_from_config(TINY)
         params = ref.init(jax.random.PRNGKey(2), s)
         one = jnp.asarray(_ids(cfg, 1, seed=3), jnp.int32)
-        hidden = evabyte.hidden_states(params, one, cfg=cfg.model)
+        hidden, _ = evabyte.hidden_states(params, one, cfg=cfg.model)
         terms = evabyte.position_losses(jnp.swapaxes(
             evabyte.logits_of(params, hidden, cfg.model), 0, 1), one.T)
         want = ref.loss(params, one, s, c.Policy(half_batch=True))
@@ -112,8 +113,11 @@ def test_data_parallel_gives_the_same_loss_and_model_parallel_is_refused():
             state, m = step(state, batch)
         losses[dp] = float(m["loss"])
         assert set(m) == {"loss", "ce", "loss_per_shard", "heads_held_share",
-                          "eva_summary_key_share"}
+                          "eva_summary_key_share",
+                          "blocks_products_kept_share"}
         assert float(m["heads_held_share"]) == 0.5
+        # the CPU says nothing of its memory: every block keeps every name
+        assert float(m["blocks_products_kept_share"]) == 1.0
         # 4 windows of 16, chunks of 4: 64·(16+1)/2·… tokens against
         # 16·4·(0+1+2+3) summaries a sequence
         assert float(m["eva_summary_key_share"]) == pytest.approx(
@@ -186,7 +190,8 @@ def test_run_task_trains_and_evaluates_the_family_from_records(tmp_path,
     state = run_task(cfg)
     assert int(state.step) == 2          # 16 sequences / 8
     logged = capsys.readouterr()
-    for counter in ("heads_held_share", "eva_summary_key_share"):
+    for counter in ("heads_held_share", "eva_summary_key_share",
+                    "blocks_products_kept_share"):
         assert counter in logged.out + logged.err, counter
     result = run_task(cfg.with_overrides(run={"task_type": "eval"}))
     assert result["examples"] == 6 == result["sequences"]
@@ -288,7 +293,7 @@ def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
     ids = jnp.asarray(_ids(_config(), 3, seed=41), jnp.int32)
 
     def loss(params, remat):
-        hidden = evabyte.hidden_states(params, ids, cfg=cfg, remat=remat)
+        hidden, _ = evabyte.hidden_states(params, ids, cfg=cfg, remat=remat)
         return jnp.mean(evabyte.position_losses(jnp.swapaxes(
             evabyte.logits_of(params, hidden, cfg), 0, 1), ids.T))
 
@@ -297,6 +302,99 @@ def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
         params, False)
     assert float(kept) == float(plain)
     got, want = c.flat_names(kept_grads), c.flat_names(plain_grads)
+    assert set(got) == set(want)
+    for name in want:
+        assert _rel(got[name], want[name]) <= 1e-6, name
+
+
+def _memory_for_the_attention_residuals_alone(cfg, params, rows: int) -> int:
+    """A device's memory under which ``names_that_fit`` keeps the attention
+    residuals and nothing after them: the state, and twice the blocks'
+    inputs with those residuals, to the byte."""
+    ids = jnp.zeros((rows, cfg.field_size), jnp.int32)
+    with kept.tally() as named:
+        jax.eval_shape(lambda p: evabyte.hidden_states(
+            p, ids, cfg=cfg, remat=False), params)
+    assert set(named) == {kept.ATTENTION_RESIDUALS, kept.PROJECTIONS,
+                          kept.SWIGLU_OPERANDS}
+    state = 4 * sum(p.size * p.dtype.itemsize
+                    for p in jax.tree_util.tree_leaves(params))
+    inputs = (len(cfg.layer_types) * rows * cfg.field_size
+              * cfg.embedding_size * 4)
+    return state + 2 * (inputs + named[kept.ATTENTION_RESIDUALS])
+
+
+@pytest.mark.parametrize("layers", [1, 2, 3])
+def test_the_last_blocks_recomputation_holds_no_matmul(
+        layers, monkeypatch, caplog):
+    """The compiled step's text under a described memory that leaves room for
+    the attention residuals alone: every block but the last forms its
+    SwiGLU's two wide products and W_o's again in its backward, the last
+    block none (a stack of one block: none at all; what it still forms again
+    is element-wise), and the line says so."""
+    cfg = _config(layer_types=("eva",) * layers)
+    ctx = make_context(cfg, _mesh(1))
+    state = create_spmd_state(ctx)
+    monkeypatch.setattr(
+        kept, "device_memory",
+        lambda: _memory_for_the_attention_residuals_alone(
+            cfg.model, state.params, rows=4))
+    with caplog.at_level(logging.INFO, logger="deepfm_tpu.models.evabyte"):
+        hlo = make_spmd_train_step(ctx, donate=False).lower(
+            state, shard_batch(ctx, {"feat_ids": _ids(cfg, 4)})
+        ).compile().as_text()
+    said = {r.getMessage() for r in caplog.records}
+    assert len(said) == 1, said
+    said, = said
+    again = [recomputed_part(n) for n in _OP_NAME.findall(hlo)]
+    # XLA:CPU's products by their instruction (a bitcast of one carries its
+    # ``op_name`` too)
+    products = sorted(filter(None, map(recomputed_part, re.findall(
+        r' dot\(.*op_name="([^"]*)"', hlo))))
+    assert products == sorted(["attention/dot_general"] * (layers - 1)
+                              + ["dense_ffn/dot_general"] * 2 * (layers - 1))
+    assert any(n and n.startswith("dense_ffn/") for n in again)
+    head = "blocks keep: attention_residuals"
+    if layers == 1:
+        assert said.startswith(head + ", projections, ")
+        assert "the last block" not in said
+        assert "; run again: swiglu_operands in 1 block, " in said
+    else:
+        assert said.startswith(head + ", ")
+        assert "; the last block also: projections, " in said
+        assert (f"; run again: projections in {layers - 1} block"
+                f"{'s' * (layers > 2)}, swiglu_operands in {layers} blocks, "
+                in said)
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_a_last_block_that_keeps_more_gives_the_same_loss_and_gradients(
+        compute_dtype, monkeypatch):
+    """The per-block rule engaged (the attention residuals in every block,
+    the products in the last): the loss and every leaf's gradient equal those
+    with ``remat=False``."""
+    cfg = _config(layer_types=("eva",) * 3).with_overrides(
+        model={"compute_dtype": compute_dtype}).model
+    params, _ = evabyte.init_evabyte(jax.random.PRNGKey(42), cfg)
+    ids = jnp.asarray(_ids(_config(), 3, seed=42), jnp.int32)
+    monkeypatch.setattr(
+        kept, "device_memory",
+        lambda: _memory_for_the_attention_residuals_alone(cfg, params, 3))
+    shares = []
+
+    def loss(params, remat):
+        hidden, share = evabyte.hidden_states(params, ids, cfg=cfg,
+                                              remat=remat)
+        shares.append(share)
+        return jnp.mean(evabyte.position_losses(jnp.swapaxes(
+            evabyte.logits_of(params, hidden, cfg), 0, 1), ids.T))
+
+    grad = jax.jit(jax.value_and_grad(loss), static_argnums=1)
+    (some, some_grads), (plain, plain_grads) = grad(params, True), grad(
+        params, False)
+    assert shares == [pytest.approx(1 / 3), 1.0]
+    assert float(some) == float(plain)
+    got, want = c.flat_names(some_grads), c.flat_names(plain_grads)
     assert set(got) == set(want)
     for name in want:
         assert _rel(got[name], want[name]) <= 1e-6, name

@@ -119,7 +119,7 @@ def test_logits_loss_and_every_gradient_leaf_match_the_reference(routing):
     ids = jnp.asarray(_ids(cfg, 3), jnp.int32)
 
     def program(params):
-        hidden, took = lfm2_moe.hidden_states(
+        hidden, took, _ = lfm2_moe.hidden_states(
             params, state, ids, cfg=cfg.model)
         logits = lfm2_moe.logits_of(params, hidden, cfg.model)
         return jnp.mean(lfm2_moe.sequence_losses(logits, ids)), (logits, took)

@@ -27,6 +27,7 @@ from deepfm_tpu.obs.trace import (
     recomputed_part,
     scope_of,
 )
+from deepfm_tpu.ops import kept
 from deepfm_tpu.ops.experts import compact_rows
 from deepfm_tpu.parallel import (
     create_spmd_state,
@@ -148,7 +149,9 @@ def test_data_parallel_gives_the_same_loss_and_model_parallel_is_refused():
             state, m = step(state, batch)
         losses[dp] = float(m["loss"])
         assert set(m) == {"loss", "ce", "loss_per_shard", "rows_held_share",
-                          "expert_load_max_share", "experts_compact_share"}
+                          "expert_load_max_share", "experts_compact_share",
+                          "blocks_products_kept_share"}
+        assert float(m["blocks_products_kept_share"]) == 1.0
         assert 0 < float(m["rows_held_share"]) < 1
         assert float(m["expert_load_max_share"]) >= 1
         # 128 tokens: ≈ 64 of 256 assignments held, a compact buffer of 128;
@@ -174,7 +177,7 @@ def test_the_compact_share_counts_the_layers_whose_rows_fit_the_buffer(
     assert compact_rows(128 * 2, 4, 16) == 128
     took = [jnp.asarray([n - 3.0, 1.0, 2.0, 0.0]) for n in rows]
     got = lfm2_moe.routing_counters(took, 128, cfg)
-    assert set(got) == set(lfm2_moe.LFM2_MOE_METRICS)
+    assert set(got) == set(lfm2_moe.ROUTING_COUNTERS)
     assert float(got["experts_compact_share"]) == want
     assert float(got["rows_held_share"]) == pytest.approx(sum(rows) / 512)
 
@@ -225,7 +228,7 @@ def test_run_task_trains_and_evaluates_the_family_from_records(tmp_path,
     logged = capsys.readouterr()
     lines = logged.out + logged.err
     for counter in ("rows_held_share", "expert_load_max_share",
-                    "experts_compact_share"):
+                    "experts_compact_share", "blocks_products_kept_share"):
         assert counter in lines, counter
     result = run_task(cfg.with_overrides(run={"task_type": "eval"}))
     assert result["examples"] == 10 == result["sequences"]
@@ -388,7 +391,7 @@ def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
     ids = jnp.asarray(_ids(_config(), 3, seed=39), jnp.int32)
 
     def loss(params, remat):
-        hidden, _ = lfm2_moe.hidden_states(params, state, ids, cfg=cfg,
+        hidden, _, _ = lfm2_moe.hidden_states(params, state, ids, cfg=cfg,
                                            remat=remat)
         logits = lfm2_moe.logits_of(params, hidden, cfg)
         return jnp.mean(lfm2_moe.sequence_losses(logits, ids))
@@ -401,6 +404,36 @@ def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
     assert set(got) == set(want)
     for name in want:
         assert _rel(got[name], want[name]) <= 1e-6, name
+
+
+def test_a_policy_a_block_lowers_the_step_that_one_policy_for_all_did(
+        monkeypatch):
+    """Where every name fits every block, the last block's policy adds
+    nothing: the tiny cell's step, lowered with ``block_policy``'s policies
+    and with one ``save_only_these_names`` of every name in their place,
+    reads the same to the character (blocks that keep the same names share
+    one policy object: a policy of its own a block would lower the blocks'
+    inner functions once a block)."""
+    cfg = _config()
+
+    def lowered():
+        ctx = make_context(cfg, _mesh(1))
+        return make_spmd_train_step(ctx, donate=False).lower(
+            create_spmd_state(ctx),
+            shard_batch(ctx, {"feat_ids": _ids(cfg, 4)})).as_text()
+
+    handed = []
+
+    def one_policy_for_all(*args):
+        policies, share = kept.block_policy(*args)
+        handed.append((len(policies), share))
+        return [jax.checkpoint_policies.save_only_these_names(
+            *kept.NAMES)] * len(policies), share
+
+    by_block = lowered()
+    monkeypatch.setattr(lfm2_moe, "block_policy", one_policy_for_all)
+    assert lowered() == by_block
+    assert handed and set(handed) == {(3, 1.0)}
 
 
 def test_the_blocks_say_once_a_trace_what_they_keep(caplog):

@@ -279,7 +279,8 @@ CONTRACTS = {
         "model_state": {f"layer_{l}/expert_bias": (64,) for l in (1, 2, 3, 4)},
         "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
         "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
-                    "experts_compact_share", "loss_per_shard"},
+                    "experts_compact_share", "blocks_products_kept_share",
+                    "loss_per_shard"},
     },
     "evabyte": {
         "true_feature_size": 320,
@@ -287,7 +288,7 @@ CONTRACTS = {
         "model_state": {},
         "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
         "metrics": {"loss", "ce", "heads_held_share", "eva_summary_key_share",
-                    "loss_per_shard"},
+                    "blocks_products_kept_share", "loss_per_shard"},
     },
 }
 
