@@ -187,7 +187,7 @@ class ModelConfig:
     batch_norm: bool = False          # ps:64-66
     batch_norm_decay: float = 0.9     # ps:67-69
     l2_reg: float = 0.0001            # ps:57; applied to FM_W/FM_V only (ps:275-279)
-    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe | evabyte
+    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe | evabyte | keye_vl2
     model_name: str = "deepfm"
     # xDeepFM CIN layer sizes / DCN-v2 cross depth (ignored by plain deepfm)
     cin_layers: tuple[int, ...] = (128, 128)
@@ -233,6 +233,18 @@ class ModelConfig:
     window_size: int = 0              # EVA: tokens a window of exact keys
     chunk_size: int = 0               # EVA: tokens a summarised chunk
     num_pred_heads: int = 0           # output heads: head p scores byte t+1+p
+    # selected-keys family (model_name="keye_vl2", models/keye_vl2.py; it
+    # also reads layer_types — one "selected_attention" a layer —, the expert
+    # and attention sizes, norm_eps and rope_theta above).  A head's size
+    # where the architecture states it apart from the hidden size (0 =
+    # embedding_size // num_attention_heads: the token family reads it too)
+    head_dim: int = 0
+    index_n_heads: int = 0            # the indexer's query heads (one key head)
+    index_head_dim: int = 0           # the indexer's head size
+    index_topk: int = 0               # keys a query attends, by indexer score
+    # what the router makes of its logits before the top-k: "sigmoid" (the
+    # token family's) | "softmax" over all ``num_experts``
+    router_score: str = "sigmoid"
     # compute dtype for the MLP/FM math (params stay f32; bf16 feeds the MXU)
     compute_dtype: str = "bfloat16"
     # "scatter" | "segsum": selects nothing since PR 27.  The chip decided
@@ -296,6 +308,11 @@ class ModelConfig:
             raise ValueError(
                 f"fused_kernel must be 'off', 'auto' or 'on', "
                 f"got {self.fused_kernel!r}"
+            )
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"router_score must be 'sigmoid' or 'softmax', "
+                f"got {self.router_score!r}"
             )
         if self.table_grad not in ("scatter", "segsum"):
             raise ValueError(

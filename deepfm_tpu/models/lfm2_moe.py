@@ -75,7 +75,9 @@ def held(cfg: ModelConfig) -> int:
 
 
 def head_dim(cfg: ModelConfig) -> int:
-    return cfg.embedding_size // cfg.num_attention_heads
+    """A head's size: the architecture's own where it states one, else the
+    hidden size over the heads."""
+    return cfg.head_dim or cfg.embedding_size // cfg.num_attention_heads
 
 
 def is_dense(cfg: ModelConfig, layer: int) -> bool:
@@ -86,11 +88,36 @@ def _normal(key, shape):
     return INIT_STD * jax.random.normal(key, shape, jnp.float32)
 
 
+def init_attention(k, cfg: ModelConfig) -> dict:
+    """Grouped-query attention's four matrices from four keys, q, k, v, o,
+    and the per-head gains of q and k."""
+    h, d = cfg.embedding_size, head_dim(cfg)
+    q, kv = cfg.num_attention_heads * d, cfg.num_key_value_heads * d
+    return {
+        "q_proj": _normal(k[0], (h, q)),
+        "k_proj": _normal(k[1], (h, kv)),
+        "v_proj": _normal(k[2], (h, kv)),
+        "o_proj": _normal(k[3], (q, h)),
+        "q_norm": jnp.ones((d,), jnp.float32),
+        "k_norm": jnp.ones((d,), jnp.float32),
+    }
+
+
+def init_experts(k, cfg: ModelConfig) -> dict:
+    """The held experts' three stacks and the router over all of them, from
+    four keys: w1, w3, w2, the gate."""
+    h, m, e = cfg.embedding_size, cfg.moe_intermediate_size, held(cfg)
+    return {"experts": {"w1": _normal(k[0], (e, h, m)),
+                        "w3": _normal(k[1], (e, h, m)),
+                        "w2": _normal(k[2], (e, m, h))},
+            "router": {"gate": _normal(k[3], (h, cfg.num_experts))}}
+
+
 def init_layer(key, cfg: ModelConfig, layer: int) -> tuple[dict, dict]:
     """One block's parameters and state; nine keys a layer, in this order:
     the operator's four matrices, the feed-forward's three, the router, the
     selection bias (the reference restates it)."""
-    h, d = cfg.embedding_size, head_dim(cfg)
+    h = cfg.embedding_size
     k = jax.random.split(key, 9)
     ones = jnp.ones((h,), jnp.float32)
     p, state = {"op_norm": ones, "ffn_norm": ones}, {}
@@ -101,26 +128,14 @@ def init_layer(key, cfg: ModelConfig, layer: int) -> tuple[dict, dict]:
             "out_proj": _normal(k[3], (h, h)),
         }
     else:
-        kv = cfg.num_key_value_heads * d
-        p["attention"] = {
-            "q_proj": _normal(k[0], (h, h)),
-            "k_proj": _normal(k[1], (h, kv)),
-            "v_proj": _normal(k[2], (h, kv)),
-            "o_proj": _normal(k[3], (h, h)),
-            "q_norm": jnp.ones((d,), jnp.float32),
-            "k_norm": jnp.ones((d,), jnp.float32),
-        }
+        p["attention"] = init_attention(k[:4], cfg)
     if is_dense(cfg, layer):
         m = cfg.intermediate_size
         p["dense_ffn"] = {"w1": _normal(k[4], (h, m)),
                           "w3": _normal(k[5], (h, m)),
                           "w2": _normal(k[6], (m, h))}
     else:
-        m, e = cfg.moe_intermediate_size, held(cfg)
-        p["experts"] = {"w1": _normal(k[4], (e, h, m)),
-                        "w3": _normal(k[5], (e, h, m)),
-                        "w2": _normal(k[6], (e, m, h))}
-        p["router"] = {"gate": _normal(k[7], (h, cfg.num_experts))}
+        p.update(init_experts(k[4:8], cfg))
         if cfg.use_expert_bias:
             state["expert_bias"] = BIAS_STD * jax.random.normal(
                 k[8], (cfg.num_experts,), jnp.float32)
@@ -163,8 +178,9 @@ def conv_mixer(p: dict, x, cfg: ModelConfig):
     return kept_mm(c * v, p["out_proj"], dt)
 
 
-@jax.named_scope("attention")
-def attention(p: dict, x, rope, cfg: ModelConfig):
+def qkv_heads(p: dict, x, rope, cfg: ModelConfig):
+    """x [B, S, h] -> q [B, S, heads, d], k and v [B, S, kv_heads, d] in
+    ``compute_dtype``: q and k normed a head and turned by position."""
     dt = jnp.dtype(cfg.compute_dtype)
     b, s, _ = x.shape
     d = head_dim(cfg)
@@ -176,12 +192,18 @@ def attention(p: dict, x, rope, cfg: ModelConfig):
         y = kept_mm(x, w, dt).reshape(b, s, -1, d)
         return apply_rope(rms_norm(y, gain, cfg.norm_eps), *rope).astype(dt)
 
+    return (heads(p["q_proj"], p["q_norm"]), heads(p["k_proj"], p["k_norm"]),
+            heads(p["v_proj"]))
+
+
+@jax.named_scope("attention")
+def attention(p: dict, x, rope, cfg: ModelConfig):
+    b, s, _ = x.shape
     tile = kernel_tile(s)
-    out = causal_attention(heads(p["q_proj"], p["q_norm"]),
-                           heads(p["k_proj"], p["k_norm"]),
-                           heads(p["v_proj"]),
+    out = causal_attention(*qkv_heads(p, x, rope, cfg),
                            kernel=tile is not None, block=tile)
-    return kept_mm(out.reshape(b, s, -1), p["o_proj"], dt)
+    return kept_mm(out.reshape(b, s, -1), p["o_proj"],
+                   jnp.dtype(cfg.compute_dtype))
 
 
 def sparse_ffn(p: dict, bias, x, cfg: ModelConfig, axis_name):
@@ -191,7 +213,8 @@ def sparse_ffn(p: dict, bias, x, cfg: ModelConfig, axis_name):
     with jax.named_scope("router"):
         chosen, w = route(
             x, p["router"]["gate"], bias, top_k=cfg.num_experts_per_tok,
-            norm_topk_prob=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor)
+            norm_topk_prob=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+            score=cfg.router_score)
     with jax.named_scope("experts"):
         y, took = held_experts_sum(
             x, chosen, w, p["experts"]["w1"], p["experts"]["w3"],

@@ -642,7 +642,11 @@ STEP_SCOPES = ("lookup", "fm", "cin", "cross", "mlp", "tower", "loss",
                "lm_head",
                # the byte family's chunk pooling (ops/attention.eva_pool),
                # inside its ``attention``; the rest it shares with the above
-               "eva_pool")
+               "eva_pool",
+               # the selected-keys family's (ops/indexer.py, models/keye_vl2.py):
+               # the indexer's projections and score blocks, the top-k and the
+               # mask, the kernel under that mask, the indexer's own loss
+               "indexer", "index_select", "selected_attention", "index_loss")
 
 
 def scope_of(op_name: str) -> tuple[str | None, str | None]:

@@ -40,6 +40,17 @@ backward, through the summaries to ``k``, ``v``, ``φ`` and ``μ``, is the
 kernel's and plain ``jnp``'s own.  XLA's path goes window by window: a
 ``[heads, window, window + S/chunk]`` score block, never ``[heads, S, S +
 S/chunk]``.
+
+A selection of keys that the step computes (``selected_attention``: each
+query attends the keys a learned indexer chose for it, ``ops/indexer.py``) is
+the third: a mask that is an array, not an object.  The kernel takes one
+sequence's ``[1, S, S]`` booleans for all of its heads (its set-up becomes
+part of the step: which blocks are dead is counted on the device, and every
+live block carries its tile of the mask, which Mosaic reads as int32: 4 MB a
+1,024² tile for each head that passes it), sequences one by one.  The
+selection arrives as bits (``pack_selection``) and is unpacked where it is
+used, both ways of differentiation.  XLA's path is the causal one's, block by
+block, with the block's rows of the selection in place of the triangle.
 """
 
 from __future__ import annotations
@@ -110,22 +121,28 @@ def apply_rope(x, cos, sin):
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3,))
-def _block(q, k, v, start: int):
+def _block(q, k, v, start: int, live=None):
     """One sequence's query rows [start, start+bq) against the keys [0, len):
     q [bq, G, R, d], k and v [len, G, d] -> [bq, G, R, d]; softmax in
-    float32."""
+    float32.  ``live`` [bq, len]: the keys each row attends, where they are
+    not all those up to itself."""
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("qgrd,kgd->grqk", q, k,
                    preferred_element_type=jnp.float32) * scale
-    q_at = start + jnp.arange(q.shape[0])
-    seen = jnp.arange(k.shape[0])[None, :] <= q_at[:, None]
-    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    if live is None:
+        q_at = start + jnp.arange(q.shape[0])
+        live = jnp.arange(k.shape[0])[None, :] <= q_at[:, None]
+    p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1)
     return jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype), v)
 
 
 def _splash(mask, q, k, v, *, block: int, interpret: bool):
     """The kernel under one ``mask`` for every head: q [B, H, S, d] with the
-    scale on it, k and v [B, Hkv, keys, d] -> [B, H, S, d]."""
+    scale on it, k and v [B, Hkv, keys, d] -> [B, H, S, d].  ``mask`` is an
+    object known when the step is traced, the same for every sequence, or
+    the step's own bits [B, S, keys/8] (``pack_selection``), a sequence's
+    for its heads: the kernel's set-up is then part of the step, and the
+    sequences go one by one."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel,
         splash_attention_mask as masks,
@@ -136,11 +153,15 @@ def _splash(mask, q, k, v, *, block: int, interpret: bool):
         block_q=block, block_kv=block, block_kv_compute=inner,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=inner,
         use_fused_bwd_kernel=True)
-    attend = kernel.make_splash_mha_single_device(
-        masks.MultiHeadMask([mask] * q.shape[1]),
-        block_sizes=sizes, residual_checkpoint_name=ATTENTION_RESIDUALS,
-        interpret=interpret)
-    out = jax.vmap(attend)(q, k, v)
+    make = functools.partial(
+        kernel.make_splash_mha_single_device, block_sizes=sizes,
+        residual_checkpoint_name=ATTENTION_RESIDUALS, interpret=interpret)
+    if isinstance(mask, jax.Array):
+        out = lax.map(
+            lambda a: make(unpack_selection(a[0])[None])(*a[1:]),
+            (mask, q, k, v))
+    else:
+        out = jax.vmap(make(masks.MultiHeadMask([mask] * q.shape[1])))(q, k, v)
     # the kernel names its own residuals: the output and a float32
     # log-sum-exp a query row
     count(ATTENTION_RESIDUALS, out.shape, out.dtype)
@@ -152,18 +173,49 @@ def _heads_first(x):
     return jnp.swapaxes(x, 1, 2)
 
 
+def _kernel_operands(q, k, v):
+    """[B, S, heads, d] -> [B, heads, S, d] as the kernel reads them: the
+    scale on the queries, heads first, under the name (its backward reads
+    them again, and a block that keeps them forms them once)."""
+    scale = jnp.asarray(q.shape[-1] ** -0.5, q.dtype)
+    return tuple(keep(_heads_first(x), ATTENTION_RESIDUALS)
+                 for x in (q * scale, k, v))
+
+
 def _kernel_attention(q, k, v, *, block: int, interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as masks,
     )
 
-    s, d = q.shape[1], q.shape[3]
-    # the kernel takes the scale on the queries; its operands carry the name
-    # as it reads them, heads first: its backward reads them again
-    q, k, v = (keep(_heads_first(x), ATTENTION_RESIDUALS)
-               for x in (q * jnp.asarray(d ** -0.5, q.dtype), k, v))
-    return _heads_first(_splash(masks.CausalMask((s, s)), q, k, v,
-                                block=block, interpret=interpret))
+    s = q.shape[1]
+    return _heads_first(_splash(
+        masks.CausalMask((s, s)), *_kernel_operands(q, k, v),
+        block=block, interpret=interpret))
+
+
+def _blocked_attention(q, k, v, selection, block: int | None):
+    """XLA's ops, query block by query block against the keys up to the
+    block's end: q [B, S, Hq, d], k and v [B, S, Hkv, d]; ``selection`` None
+    (every key up to the query) or bits [B, S, S/8] (``pack_selection``)."""
+    block = block or QUERY_BLOCK
+    q, k, v = (keep(x, ATTENTION_RESIDUALS) for x in (q, k, v))
+    b, s, hq, d = q.shape
+    g = k.shape[2]
+    if s % block:
+        block = s
+    q = q.reshape(b, s, g, hq // g, d)
+
+    def one(args):
+        q1, k1, v1, *bits = args
+        live = [unpack_selection(x) for x in bits]
+        return jnp.concatenate([
+            _block(q1[at:at + block], k1[:at + block], v1[:at + block], at,
+                   *(x[at:at + block, :at + block] for x in live))
+            for at in range(0, s, block)], axis=0)
+
+    operands = (q, k, v) if selection is None else (q, k, v, selection)
+    return keep(lax.map(one, operands).reshape(b, s, hq, d),
+                ATTENTION_RESIDUALS)
 
 
 def causal_attention(q, k, v, *, kernel: bool = False,
@@ -175,22 +227,67 @@ def causal_attention(q, k, v, *, kernel: bool = False,
     if kernel:
         return _kernel_attention(q, k, v, block=block or KERNEL_TILES[0],
                                  interpret=interpret)
-    block = block or QUERY_BLOCK
-    q, k, v = (keep(x, ATTENTION_RESIDUALS) for x in (q, k, v))
-    b, s, hq, d = q.shape
-    g = k.shape[2]
-    if s % block:
-        block = s
-    q = q.reshape(b, s, g, hq // g, d)
+    return _blocked_attention(q, k, v, None, block)
 
-    def one(qkv):
-        q1, k1, v1 = qkv
-        return jnp.concatenate([
-            _block(q1[at:at + block], k1[:at + block], v1[:at + block], at)
-            for at in range(0, s, block)], axis=0)
 
-    return keep(lax.map(one, (q, k, v)).reshape(b, s, hq, d),
-                ATTENTION_RESIDUALS)
+# -- keys selected from data ---------------------------------------------------
+
+
+def pack_selection(live):
+    """bool [..., keys] -> uint8 [..., keys/8]: bit b of byte j is key
+    ``b·keys/8 + j``, so that packing and unpacking are shifts of eight
+    contiguous slabs and no array is read across its lanes."""
+    slabs = jnp.split(live.astype(jnp.uint8), 8, axis=-1)
+    return functools.reduce(
+        jnp.bitwise_or, (slab << b for b, slab in enumerate(slabs)))
+
+
+def unpack_selection(bits):
+    """uint8 [..., keys/8] -> bool [..., keys], ``pack_selection`` undone."""
+    return jnp.concatenate(
+        [(bits >> b) & 1 for b in range(8)], axis=-1).astype(bool)
+
+
+def row_softmax_parts(x):
+    """``(m, l)`` with ``softmax(x) = exp(x − m) / l`` along the last axis,
+    each a pass of its own over ``x`` (``optimization_barrier``): left to
+    fuse the three passes of a softmax over float32 rows of 6,144 to 8,192,
+    XLA:TPU takes 96 ms where the passes apart take 3 (PERF.md §6, PR 43:
+    rows of 4,096 and of 10,240 are not touched by it).  No gradient goes
+    through it."""
+    m = lax.optimization_barrier(jnp.max(x, axis=-1, keepdims=True))
+    return m, lax.optimization_barrier(
+        jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True))
+
+
+def selected_probabilities(q, k, live):
+    """The heads' mean probability on the keys a query attends: q [G, R, bq,
+    d] with the scale on it and k [G, len, d], heads first as the kernel
+    reads them, live [bq, len] -> [bq, len] float32, zero off ``live``.  One
+    product a key-value head, its ``R`` query heads' rows side by side
+    (``[R·bq, d]·[d, len]``)."""
+    g, r, bq, d = q.shape
+    s = jnp.einsum("gmd,gkd->gmk", q.reshape(g, r * bq, d), k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(live, s.reshape(g, r, bq, -1), -jnp.inf)
+    m, l = row_softmax_parts(s)
+    return jnp.sum(jnp.exp(s - m) / (l * (g * r)), axis=(0, 1))
+
+
+def selected_attention(q, k, v, selection, *, kernel: bool = False,
+                       block: int | None = None, interpret: bool = False):
+    """softmax over the SELECTED keys of q·kᵀ/√d, times v: q [B, S, Hq, d],
+    k and v [B, S, Hkv, d] as ``causal_attention`` takes them, ``selection``
+    [B, S, S/8] the bits of ``pack_selection`` over a [S, S] mask, one a
+    sequence, for all of its heads (every query attends a key at least) ->
+    [B, S, Hq, d] in v's dtype.  ``kernel``: the Pallas kernel with the
+    selection as its mask (``interpret`` for a CPU test of it), else XLA's
+    ops, query block by query block."""
+    if kernel:
+        return _heads_first(_splash(
+            selection, *_kernel_operands(q, k, v),
+            block=block or KERNEL_TILES[0], interpret=interpret))
+    return _blocked_attention(q, k, v, selection, block)
 
 
 # -- EVA ----------------------------------------------------------------------
@@ -348,11 +445,8 @@ def eva_attention(q, k, v, phi, mu, *, window: int, chunk: int,
         raise ValueError(
             f"EVA takes whole windows of whole chunks: a sequence of {s} "
             f"with window_size {window} and chunk_size {chunk} is not")
-    # the scale on the queries, as the kernel takes it; q, k and v carry the
-    # name heads first, as the kernel and the pooling read them: a block that
-    # keeps them forms them once
-    q, k, v = (keep(_heads_first(x), ATTENTION_RESIDUALS)
-               for x in (q * jnp.asarray(d ** -0.5, q.dtype), k, v))
+    # heads first, as the kernel and the pooling read them
+    q, k, v = _kernel_operands(q, k, v)
     kt, vt = eva_pool(k, v, phi, mu, chunk=chunk)
     if kernel:
         out = _splash(_eva_mask(s, window, chunk), q,

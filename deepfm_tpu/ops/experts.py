@@ -51,22 +51,28 @@ from .kept import ROUTING_RESIDUALS, keep
 
 
 def route(x, gate, bias, *, top_k: int, norm_topk_prob: bool = True,
-          scale: float = 1.0):
-    """Sigmoid router with a selection bias, in float32: x [T, h], gate [h,
-    E], bias [E] or None -> ``(chosen [T, k] int32, weights [T, k])``.  The
-    bias takes part in the choice only; the weights are the chosen experts'
-    own scores, renormalised over the k where ``norm_topk_prob``."""
-    # the name sits on the product itself: the sigmoid's backward reads it
-    r = jax.nn.sigmoid(keep(
+          scale: float = 1.0, score: str = "sigmoid"):
+    """The router, in float32: x [T, h], gate [h, E], bias [E] or None ->
+    ``(chosen [T, k] int32, weights [T, k])``.  ``score`` makes the experts'
+    scores of the logits: ``"sigmoid"``, each expert's own, or ``"softmax"``
+    over all E.  A selection bias takes part in the choice only; the weights
+    are the chosen experts' own scores, renormalised over the k where
+    ``norm_topk_prob`` (the sigmoid router's sum with 1e-6 on it, as its
+    family's code has it; a softmax's chosen scores sum to more than 1/E)."""
+    # the name sits on the product itself: the score's backward reads it
+    logits = keep(
         jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
-                precision=lax.Precision.HIGHEST), ROUTING_RESIDUALS))
+                precision=lax.Precision.HIGHEST), ROUTING_RESIDUALS)
+    sigmoid = score == "sigmoid"
+    r = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
     _, chosen = lax.top_k(r if bias is None else r + bias, top_k)
     chosen = keep(chosen, ROUTING_RESIDUALS)
     # a gather costs by the index, not by the byte (0.7 ms a layer at 65,536
     # assignments for 262 kB: PERF.md §6, PR 39)
     w = keep(jnp.take_along_axis(r, chosen, axis=-1), ROUTING_RESIDUALS)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / ((total + 1e-6) if sigmoid else total)
     return chosen, w * scale
 
 

@@ -57,7 +57,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 # checkpoint names, in the order a block keeps them: what the attention
-# kernel's backward needs of its forward (``ops/attention.py``); the router's
+# kernel's backward needs of its forward (``ops/attention.py``; where the keys
+# are selected from data, the selection, as bits, and the gradient of its own
+# loss, formed where the score blocks are: ``ops/indexer.py``); the router's
 # logits, choice and chosen scores, the grouping's order and sizes
 # (``ops/experts.py``); a projection's product, in the dtype it was computed
 # in, and the dense SwiGLU's two operands in ``compute_dtype``

@@ -259,6 +259,25 @@ def _evabyte_leaves() -> dict:
             **{f"layers/{k}": (4, *shape) for k, shape in layer.items()}}
 
 
+def _keye_vl2_leaves() -> dict:
+    """The contract between ``models/keye_vl2.py`` and
+    ``perf/reference/keye_vl2.py`` at the published widths: published layers
+    0–3, experts 0–15 of 128, a head's size (128) apart from the hidden
+    size, the indexer whole, the head untied."""
+    layer = {"op_norm": (2048,), "ffn_norm": (2048,),
+             "attention/q_proj": (2048, 4096), "attention/k_proj": (2048, 512),
+             "attention/v_proj": (2048, 512), "attention/o_proj": (4096, 2048),
+             "attention/q_norm": (128,), "attention/k_norm": (128,),
+             "indexer/q_proj": (2048, 1024), "indexer/k_proj": (2048, 64),
+             "indexer/w_proj": (2048, 16),
+             "experts/w1": (16, 2048, 768), "experts/w3": (16, 2048, 768),
+             "experts/w2": (16, 768, 2048), "router/gate": (2048, 128)}
+    return {"tok_embedding": (18992, 2048), "lm_head": (2048, 18992),
+            "out_norm": (2048,),
+            **{f"layer_{l}/{k}": shape for l in range(4)
+               for k, shape in layer.items()}}
+
+
 # per family: the TRUE rows of its table share, its parameter leaves, the
 # non-trainable state beside them, its placed batch ((b, f) the traffic's
 # batch and the configuration's field_size) and its step's metrics
@@ -289,6 +308,16 @@ CONTRACTS = {
         "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
         "metrics": {"loss", "ce", "heads_held_share", "eva_summary_key_share",
                     "blocks_products_kept_share", "loss_per_shard"},
+    },
+    "keye_vl2": {
+        "true_feature_size": 18992,
+        "leaves": _keye_vl2_leaves(),
+        "model_state": {},
+        "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
+        "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
+                    "experts_compact_share", "index_loss",
+                    "index_selected_share", "blocks_products_kept_share",
+                    "loss_per_shard"},
     },
 }
 
