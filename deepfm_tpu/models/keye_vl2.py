@@ -148,10 +148,10 @@ def attention(p: dict, index: dict, x, ropes, cfg: ModelConfig):
     rope, index_rope = ropes
     with jax.named_scope("attention"):
         q, k, v = qkv_heads(p, x, rope, cfg)
+    tile = kernel_tile(s)
     bits, index_loss, selected = index_select(
         q, k, *index_inputs(index, lax.stop_gradient(x), index_rope, cfg),
-        topk=cfg.index_topk)
-    tile = kernel_tile(s)
+        topk=cfg.index_topk, kernel=tile is not None)
     with jax.named_scope("selected_attention"):
         out = selected_attention(q, k, v, bits, kernel=tile is not None,
                                  block=tile)

@@ -50,7 +50,15 @@ live block carries its tile of the mask, which Mosaic reads as int32: 4 MB a
 1,024² tile for each head that passes it), sequences one by one.  The
 selection arrives as bits (``pack_selection``) and is unpacked where it is
 used, both ways of differentiation.  XLA's path is the causal one's, block by
-block, with the block's rows of the selection in place of the triangle.
+block, with the block's rows of the selection in place of the triangle.  The
+indexer's target, the heads' mean probability on the selected keys
+(``selected_probabilities``), is a kernel of this module's own where the
+attention's runs (``target_tiles``): two sweeps over the key tiles of a query
+tile, every head's score tile formed in VMEM both times, the ``live`` tile read
+once for all the heads as int8, and only the ``[chunk, ≤S]`` mean written.
+XLA's ops for it write the ``[heads, chunk, ≤S]`` float32 score block (1.07 GB
+a chunk at 32 heads of 16,384 keys) and pass over it three times more: the
+fallback, and the kernel's plain reference in the tests.
 """
 
 from __future__ import annotations
@@ -75,6 +83,9 @@ QUERY_BLOCK = 512
 # sequence than a multiple of 1,024; 512 and 256 read 1.25× and 1.7× its time
 KERNEL_TILES = (1024, 512, 256, 128)
 KERNEL_COMPUTE_BLOCK = 512
+# what the index target's kernel may hold of a v5e core's 128 MiB: a
+# [R·rows, keys] float32 score tile is 8 MB and its passes hold a few of them
+TARGET_VMEM_BYTES = 64 * 2**20
 
 
 def kernel_tile(positions: int, keys: int | None = None) -> int | None:
@@ -253,19 +264,181 @@ def row_softmax_parts(x):
     each a pass of its own over ``x`` (``optimization_barrier``): left to
     fuse the three passes of a softmax over float32 rows of 6,144 to 8,192,
     XLA:TPU takes 96 ms where the passes apart take 3 (PERF.md §6, PR 43:
-    rows of 4,096 and of 10,240 are not touched by it).  No gradient goes
-    through it."""
+    rows of 4,096 and of 10,240 are not touched by it).  On a TPU it serves
+    the ``[chunk, ≤S]`` softmax of the index scores (``ops/indexer._chunk``),
+    whose rows pass through those widths too, so the passes stay apart; the
+    ``[heads, chunk, ≤S]`` block of ``selected_probabilities`` reaches it
+    only where XLA's ops make the target.  No gradient goes through it."""
     m = lax.optimization_barrier(jnp.max(x, axis=-1, keepdims=True))
     return m, lax.optimization_barrier(
         jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True))
 
 
-def selected_probabilities(q, k, live):
+# the index target's kernel: the query rows a tile (of a chunk's QUERY_BLOCK)
+# and the keys a tile, the largest that divides the keys in hand.  Of the tiles
+# tried on the chip at the cell's size (PERF.md §6, PR 44) 128 × 2,048 is the
+# fastest: 256 rows are no faster, and a key tile half as wide pays the
+# running maxima's and sums' upkeep (one value a vector register's row)
+# twice as often: 1.15× the time at 1,024, ≈ 1.5× at 512
+TARGET_ROWS = 128
+TARGET_KEY_TILES = (2048, 1024, 512, 256, 128)
+
+
+def target_tiles(kernel: bool, chunk: int, keys: int):
+    """``(rows, keys)`` a tile of ``selected_probabilities``' kernel for
+    chunks of ``chunk`` queries against multiples of ``keys`` keys, or None
+    for XLA's ops — observed as ``kernel_tile`` observes, whose answer
+    ``kernel`` is (the attention runs its kernel): the kernel where the
+    attention's runs and a tile divides each.  Said once a trace: ``index
+    target: Pallas kernel, rows=…, keys tile=… | XLA's ops (…)``."""
+    tiles = (TARGET_ROWS if chunk % TARGET_ROWS == 0 else None,
+             next((t for t in TARGET_KEY_TILES if keys % t == 0), None))
+    if not kernel:
+        tiles, how = None, "XLA's ops (as the attention)"
+    elif None in tiles:
+        tiles, how = None, (f"XLA's ops (no tile divides chunks of {chunk} "
+                            f"or {keys} keys)")
+    else:
+        how = "Pallas kernel, rows=%d, keys tile=%d" % tiles
+    logging.getLogger(__name__).info("index target: %s", how)
+    return tiles
+
+
+def _target_kernel(start_ref, q_ref, k_ref, live_ref, p_ref, m_ref, l_ref):
+    """One step of the grid (query tile, sweep, key tile) of
+    ``selected_probabilities``: q_ref [G, R·rows, d], k_ref [G, keys, d],
+    live_ref [rows, keys] int8 -> p_ref [rows, keys] float32; m_ref and l_ref
+    [G, R, rows, 1] hold a query tile's running maxima and sums of
+    exponentials over sweep 0 and 1/(l·heads) over sweep 1."""
+    from jax.experimental import pallas as pl
+
+    tile, sweep, at = (pl.program_id(axis) for axis in range(3))
+    groups, r, rows, _ = m_ref.shape
+    keys = p_ref.shape[1]
+    # key tiles wholly past the tile's last row are dead for every row
+    reached = at * keys <= start_ref[0] + (tile + 1) * rows - 1
+
+    def scores(g, dead):
+        s = lax.dot_general(q_ref[g], k_ref[g], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        return s.reshape(r, rows, keys) + dead
+
+    def dead_keys():
+        return jnp.where(live_ref[...].astype(jnp.int32) != 0,
+                         0.0, -jnp.inf)[None]
+
+    @pl.when((sweep == 0) & (at == 0))
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    @pl.when((sweep == 0) & reached)
+    def _():
+        dead = dead_keys()
+
+        def fold(g, _):
+            s = scores(g, dead)
+            was = m_ref[g]
+            m = jnp.maximum(was, jnp.max(s, axis=-1, keepdims=True))
+            # a row with no key selected so far: exp(−inf − 0), not of nan
+            shift = jnp.where(m == -jnp.inf, 0.0, m)
+            l_ref[g] = l_ref[g] * jnp.exp(was - shift) + jnp.sum(
+                jnp.exp(s - shift), axis=-1, keepdims=True)
+            m_ref[g] = m
+
+        lax.fori_loop(0, groups, fold, None)
+
+    @pl.when((sweep == 1) & (at == 0))
+    def _():
+        l_ref[...] = 1.0 / (l_ref[...] * (groups * r))
+
+    @pl.when(sweep == 1)
+    def _():
+        p_ref[...] = jnp.zeros(p_ref.shape, jnp.float32)
+
+    @pl.when((sweep == 1) & reached)
+    def _():
+        dead = dead_keys()
+
+        def add(g, _):
+            p_ref[...] += jnp.sum(
+                jnp.exp(scores(g, dead) - m_ref[g]) * l_ref[g], axis=0)
+
+        lax.fori_loop(0, groups, add, None)
+
+
+def _target_by_kernel(q, k, live, start, tiles, interpret: bool):
+    """``selected_probabilities`` as one Pallas kernel that writes no head's
+    score: for each tile of ``rows`` queries, sweep 0 over the key tiles
+    folds every head's masked ``[R·rows, d]·[d, keys]`` score tile into a
+    running maximum and sum of exponentials a (head, row) — the flash
+    recurrence, nothing written —, sweep 1 forms the tile again and writes
+    the heads' mean of ``exp(s − m)/l`` once.  The tile of ``live`` is read
+    once for all the heads, as int8 (Mosaic has no boolean memref); key tiles
+    past the tile's last row (``start`` + its rows) are neither fetched nor
+    computed."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    g, r, bq, d = q.shape
+    n = k.shape[1]
+    rows, keys = tiles
+    start = jnp.full((1,), n if start is None else start, jnp.int32)
+
+    def last(tile, at, start):      # the last key tile the rows reach
+        return jnp.minimum(at, (start[0] + (tile + 1) * rows - 1) // keys)
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(bq // rows, 2, n // keys),
+        in_specs=[
+            pl.BlockSpec((g, r * rows, d), lambda i, s, j, start: (0, i, 0)),
+            pl.BlockSpec((g, keys, d),
+                         lambda i, s, j, start: (0, last(i, j, start), 0)),
+            pl.BlockSpec((rows, keys),
+                         lambda i, s, j, start: (i, last(i, j, start))),
+        ],
+        # sweep 0 writes nothing: it stays on the block sweep 1 starts with
+        out_specs=pl.BlockSpec((rows, keys),
+                               lambda i, s, j, start: (i, j * s)),
+        scratch_shapes=[pltpu.VMEM((g, r, rows, 1), jnp.float32)] * 2)
+    pairs = g * r * bq * n
+    return pl.pallas_call(
+        _target_kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((bq, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=TARGET_VMEM_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * 2 * pairs * d, transcendentals=2 * pairs,
+            bytes_accessed=(q.size * q.dtype.itemsize
+                            + 2 * (bq // rows) * k.size * k.dtype.itemsize
+                            + 2 * live.size + 4 * bq * n)),
+        name="selected_probabilities", interpret=interpret,
+    )(start,
+      # a query tile's rows of the R heads of a key-value head side by side
+      q.reshape(g, r, bq // rows, rows, d).swapaxes(1, 2).reshape(
+          g, r * bq, d),
+      k, live.astype(jnp.int8))
+
+
+def selected_probabilities(q, k, live, *, start=None, tiles=None,
+                           interpret: bool = False):
     """The heads' mean probability on the keys a query attends: q [G, R, bq,
     d] with the scale on it and k [G, len, d], heads first as the kernel
-    reads them, live [bq, len] -> [bq, len] float32, zero off ``live``.  One
-    product a key-value head, its ``R`` query heads' rows side by side
-    (``[R·bq, d]·[d, len]``)."""
+    reads them, live [bq, len] -> [bq, len] float32, zero off ``live``.
+    Scores from the operands as they come, accumulated in float32; maximum,
+    exponential, sum, division and the mean over the heads in float32.
+
+    ``tiles`` (``target_tiles``): one Pallas kernel, two sweeps over the key
+    tiles of a query tile, no head's score written (``interpret`` for a CPU
+    test of it); ``start``, the chunk's first position where no row attends
+    a key past itself, lets it skip the key tiles past a query tile's last
+    row.  Else XLA's ops: one product a key-value head, its ``R`` query
+    heads' rows side by side (``[R·bq, d]·[d, len]``), the ``[G·R, bq,
+    len]`` float32 block written and passed over three times more."""
+    if tiles is not None:
+        return _target_by_kernel(q, k, live, start, tiles, interpret)
     g, r, bq, d = q.shape
     s = jnp.einsum("gmd,gkd->gmk", q.reshape(g, r * bq, d), k,
                    preferred_element_type=jnp.float32)

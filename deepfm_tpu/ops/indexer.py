@@ -19,13 +19,16 @@ the gradient of what it projects them from.
 Nothing ``[S, S]`` in float32 is ever held.  The queries go chunk by chunk
 (``ops/attention.QUERY_BLOCK`` rows: the source's own ``q_chunk_size``), each
 chunk against the keys up to the end of its group of ``GROUP`` chunks (the
-later ones masked), and one pass over a chunk's blocks
-— ``[J, chunk, ≤S]`` index products, ``[heads, chunk, ≤S]`` attention scores
-— makes everything that needs them: the selection (``select_keys``), ``L_I``'s
-terms and, because ``∂L_I/∂I = softmax_{S_t}(I) − p`` is in hand there,
-``L_I``'s gradient to ``qᴵ``, ``kᴵ`` and ``w``.  ``index_select`` is a
-``custom_vjp`` whose forward hands that gradient on as its residual under
-``ATTENTION_RESIDUALS``; its backward scales it.  The selection leaves as bits
+later ones masked), and one pass over a chunk — its ``[J, chunk, ≤S]`` index
+products, and ``p`` ``[chunk, ≤S]`` from the attention's scores, which on a
+TPU one kernel forms tile by tile and never writes
+(``ops/attention.selected_probabilities``; XLA's ops elsewhere: a ``[heads,
+chunk, ≤S]`` float32 block) — makes everything that needs them: the selection
+(``select_keys``), ``L_I``'s terms and, because ``∂L_I/∂I = softmax_{S_t}(I) −
+p`` is in hand there, ``L_I``'s gradient to ``qᴵ``, ``kᴵ`` and ``w``.
+``index_select`` is a ``custom_vjp`` whose forward hands that gradient on as
+its residual under ``ATTENTION_RESIDUALS``; its backward scales it (so the
+kernel needs no backward of its own).  The selection leaves as bits
 (``ops/attention.pack_selection``: S²/8 bytes a sequence, 33.5 MB at 16,384
 positions, where the scores it was made from would be 1 GB), under the same
 name: a rematerialised block that keeps both runs no score block and no top-k
@@ -49,6 +52,7 @@ from .attention import (
     pack_selection,
     row_softmax_parts,
     selected_probabilities,
+    target_tiles,
 )
 from .kept import ATTENTION_RESIDUALS, count, keep
 
@@ -118,12 +122,14 @@ def select_keys(scores, start, topk: int):
 GROUP = 4
 
 
-def _chunk(q, k, qi, ki, w, start, topk: int, with_gradient: bool):
+def _chunk(q, k, qi, ki, w, start, topk: int, with_gradient: bool,
+           probabilities):
     """One chunk of queries ``start … start+c−1`` against the keys in hand,
     none of which may be missing up to the chunk's end -> (its selection [c,
     n], its terms of L_I summed, ∂L_I/∂(qi, ki, w) or None).  q [G, R, c,
     d] with the scale on it and k [G, n, d] the attention's; qi [c, J, e],
-    ki [n, e], w [c, J]."""
+    ki [n, e], w [c, J]; ``probabilities``: ``selected_probabilities`` with
+    the way it makes ``p`` bound."""
     with jax.named_scope("indexer"):
         if with_gradient:
             scores, pull = jax.vjp(index_scores, qi, ki, w)
@@ -132,7 +138,7 @@ def _chunk(q, k, qi, ki, w, start, topk: int, with_gradient: bool):
     with jax.named_scope("index_select"):
         live = select_keys(scores, start, topk)
     with jax.named_scope("index_loss"):
-        p = selected_probabilities(q, k, live)
+        p = probabilities(q, k, live, start=start)
         scores = jnp.where(live, scores, -jnp.inf)
         m, l = row_softmax_parts(scores)
         log_q = scores - m - jnp.log(l)
@@ -146,15 +152,21 @@ def _chunk(q, k, qi, ki, w, start, topk: int, with_gradient: bool):
     return live, loss, gradient
 
 
-def _chunks(q, k, qi, ki, w, topk: int, chunk: int, with_gradient: bool):
+def _chunks(q, k, qi, ki, w, topk: int, chunk: int, kernel: bool,
+            interpret: bool, with_gradient: bool):
     """One sequence, chunk by chunk: q [S, H, d], k [S, G, d], qi [S, J, e],
     ki [S, e], w [S, J] -> ((bits [S, S/8] uint8, L_I's sum over the
-    queries, the selected keys' count), ∂L_I/∂(qi, ki, w) or None)."""
+    queries, the selected keys' count), ∂L_I/∂(qi, ki, w) or None).
+    ``kernel`` and ``interpret``: ``index_select``'s."""
     s, h, d = q.shape
     g = k.shape[1]
     if s % chunk:
         chunk = s
     group = chunk * (GROUP if s % (chunk * GROUP) == 0 else 1)
+    # every chunk's keys in hand are whole groups
+    probabilities = functools.partial(
+        selected_probabilities, tiles=target_tiles(kernel, chunk, group),
+        interpret=interpret)
     by_chunk = lambda x, at: x[at:at + group].reshape(
         group // chunk, chunk, *x.shape[1:])
     # the attention's operands as its kernel reads them: heads first, the
@@ -169,7 +181,7 @@ def _chunks(q, k, qi, ki, w, topk: int, chunk: int, with_gradient: bool):
         def one(a, end=end):
             live, term, gradient = _chunk(
                 a[0], k[:, :end], a[1], ki[:end], a[2], a[3], topk,
-                with_gradient)
+                with_gradient, probabilities)
             return (pack_selection(jnp.pad(live, ((0, 0), (0, s - end)))),
                     term, jnp.sum(live, dtype=jnp.float32), gradient)
 
@@ -191,18 +203,19 @@ def _chunks(q, k, qi, ki, w, topk: int, chunk: int, with_gradient: bool):
                  jnp.concatenate(d_w, axis=0))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _sequence(q, k, qi, ki, w, topk, chunk):
-    return _chunks(q, k, qi, ki, w, topk, chunk, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _sequence(q, k, qi, ki, w, topk, chunk, kernel, interpret):
+    return _chunks(q, k, qi, ki, w, topk, chunk, kernel, interpret, False)[0]
 
 
-def _sequence_fwd(q, k, qi, ki, w, topk, chunk):
-    out, gradient = _chunks(q, k, qi, ki, w, topk, chunk, True)
+def _sequence_fwd(q, k, qi, ki, w, topk, chunk, kernel, interpret):
+    out, gradient = _chunks(q, k, qi, ki, w, topk, chunk, kernel, interpret,
+                            True)
     return out, tuple(checkpoint_name(x, ATTENTION_RESIDUALS)
                       for x in gradient)
 
 
-def _sequence_bwd(topk, chunk, gradient, cotangent):
+def _sequence_bwd(topk, chunk, kernel, interpret, gradient, cotangent):
     _, d_loss, _ = cotangent
     return (None, None, *(d_loss * x for x in gradient))
 
@@ -210,17 +223,22 @@ def _sequence_bwd(topk, chunk, gradient, cotangent):
 _sequence.defvjp(_sequence_fwd, _sequence_bwd)
 
 
-def index_select(q, k, qi, ki, w, *, topk: int, chunk: int = QUERY_BLOCK):
+def index_select(q, k, qi, ki, w, *, topk: int, chunk: int = QUERY_BLOCK,
+                 kernel: bool = False, interpret: bool = False):
     """The selection and its loss, sequences one by one (``lax.map``): q [B,
     S, H, d] and k [B, S, G, d] as the attention reads them (any dtype; no
     gradient goes back to them), qi [B, S, J, e], ki [B, S, e], w [B, S, J]
     float32 -> ``(bits [B, S, S/8] uint8, loss [B], selected [B])``: the
     selection packed for ``ops/attention.selected_attention``, each
     sequence's L_I summed over its queries, and the keys it selected,
-    counted."""
+    counted.  ``kernel``, as ``selected_attention`` takes it: the attention
+    runs its Pallas kernel, and ``p`` is made by one too where its tiles
+    divide the chunks (``ops/attention.target_tiles``; ``interpret`` for a
+    CPU test of it); else by XLA's ops."""
     q, k = lax.stop_gradient((q, k))
     bits, loss, selected = lax.map(
-        lambda a: _sequence(*a, topk, chunk), (q, k, qi, ki, w))
+        lambda a: _sequence(*a, topk, chunk, kernel, interpret),
+        (q, k, qi, ki, w))
     # the loss's gradient carries the name inside the map, a sequence at a
     # time: counted here at what a step holds of it
     for x in (qi, ki, w):

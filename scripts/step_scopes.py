@@ -11,7 +11,8 @@ instruction name, and that to the step's named scope
 family's ``attention/eva_pool``, the inner one).  A fusion spans scopes; the name XLA
 keeps on it is its root's; a ``while`` is left out of the sums, since the ops of
 its body have events of their own.  Prints the 40 longest ops with their scope, the
-time per scope, and the share of the step's device time under no scope; the
+time per scope and per Pallas kernel (a custom call, by its name), and the
+share of the step's device time under no scope; the
 same goes to ``chiprun_out/step_scopes/<cell>.json``.  What ``PERF.md`` §5's
 scope column is made with, until a reader under ``perf/`` can do it (§7).
 """
@@ -119,9 +120,12 @@ def main() -> int:
     if not steps:
         raise SystemExit("no step of a TPU plane in the trace")
     total = sum(by_op.values())
-    by_scope, rows = {}, []
+    by_scope, by_kernel, rows = {}, {}, []
     for text, ns in sorted(by_op.items(), key=lambda kv: -kv[1]):
         instr = text.split(" = ", 1)[0].strip().lstrip("%")
+        if " custom-call(" in text:     # a Pallas call: the kernel's name
+            kernel = instr.split(".")[0]
+            by_kernel[kernel] = by_kernel.get(kernel, 0) + ns
         op_name = names.get(instr, "")
         scope = scope_of(op_name)[1]
         written = scope or KERNEL_SCOPES.get(instr.split(".")[0], "(none)")
@@ -143,6 +147,8 @@ def main() -> int:
         "top_ops": rows[:40],
         "ms_per_step_by_scope": {k: v / 1e6 / steps for k, v in sorted(
             by_scope.items(), key=lambda kv: -kv[1])},
+        "ms_per_step_by_kernel": {k: v / 1e6 / steps
+                                  for k, v in by_kernel.items()},
         "unscoped_share_pct": 100.0 * by_scope.get("(none)", 0) / total,
         "unscoped_ops": [r for r in rows if r["scope"] == "(none)"][:10],
         "ops_with_no_op_name": sum(not r["op_name"] for r in rows),

@@ -31,6 +31,8 @@ from deepfm_tpu.ops.attention import (  # noqa: E402
     causal_attention,
     pack_selection,
     selected_attention,
+    selected_probabilities,
+    target_tiles,
     unpack_selection,
 )
 from deepfm_tpu.ops.experts import held_experts_sum, route  # noqa: E402
@@ -262,20 +264,28 @@ def _dense_index(q, k, qi, ki, w, topk):
     return live, jnp.sum(kl)
 
 
-def test_the_chunked_selection_and_its_loss_are_the_dense_ones_both_ways():
-    """Four chunks of 16 queries, each against the keys up to its end, 12
-    kept: the bits, the loss and — from the gradient the forward formed —
+@pytest.mark.parametrize("path, s, chunk, topk", [
+    ("XLA's ops", 64, 16, 12), ("kernel", 512, 128, 96)])
+def test_the_chunked_selection_and_its_loss_are_the_dense_ones_both_ways(
+        path, s, chunk, topk):
+    """Four chunks of queries, each against the keys up to the end of its
+    group: the bits, the loss and — from the gradient the forward formed —
     ∂L_I/∂(qᴵ, kᴵ, w) are those of the whole [S, S] scores under autodiff;
-    nothing goes back to q and k."""
-    q, k, qi, ki, w = _index_inputs(2, 64, seed=4)
+    nothing goes back to q and k.  With ``p`` made by XLA's ops, and by the
+    Pallas kernel in interpret mode (tiles of 128 rows and 512 keys: a
+    chunk's keys in hand run past its own end, and it skips those tiles)."""
+    q, k, qi, ki, w = _index_inputs(2, s, seed=4)
+    how = dict(kernel=True, interpret=True) if path == "kernel" else {}
+    assert (target_tiles(path == "kernel", chunk, 4 * chunk)
+            == ((128, 512) if path == "kernel" else None))
 
     def chunked(qi, ki, w, q, k):
-        bits, loss, selected = index_select(q, k, qi, ki, w, topk=12,
-                                            chunk=16)
+        bits, loss, selected = index_select(q, k, qi, ki, w, topk=topk,
+                                            chunk=chunk, **how)
         return jnp.sum(loss * jnp.asarray([1.0, 2.0])), (bits, selected)
 
     def dense(qi, ki, w):
-        out = [_dense_index(q[b], k[b], qi[b], ki[b], w[b], 12)
+        out = [_dense_index(q[b], k[b], qi[b], ki[b], w[b], topk)
                for b in range(2)]
         return out[0][1] + 2.0 * out[1][1], jnp.stack([o[0] for o in out])
 
@@ -285,14 +295,60 @@ def test_the_chunked_selection_and_its_loss_are_the_dense_ones_both_ways():
         (want, live), want_grads = jax.value_and_grad(
             dense, argnums=(0, 1, 2), has_aux=True)(qi, ki, w)
     np.testing.assert_array_equal(unpack_selection(bits), live)
-    assert selected.tolist() == [12 * 13 / 2 + 52 * 12] * 2
+    assert selected.tolist() == [topk * (topk + 1) / 2 + (s - topk) * topk] * 2
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
     for got, wanted in zip(grads[:3], want_grads):
         assert _rel(got, wanted) <= 1e-5
     assert not np.any(grads[3]) and not np.any(grads[4])
     # one chunk of the whole sequence is the same selection
-    whole = index_select(q, k, qi, ki, w, topk=12)[0]
+    whole = index_select(q, k, qi, ki, w, topk=topk, **how)[0]
     np.testing.assert_array_equal(whole, bits)
+
+
+def _hand_made_selection(case: str, rows: int, keys: int, start: int):
+    """live [rows, keys] of the queries ``start … start+rows−1``: random
+    under the triangle with the diagonal in it, then the case's own rows."""
+    at = np.arange(keys)[None, :]
+    t = start + np.arange(rows)[:, None]
+    live = ((np.random.default_rng(9).random((rows, keys)) < 0.3)
+            | (at == t)) & (at <= t)
+    if case == "a row with a single key":
+        live[5] = at[0] == start + 5
+        live[rows - 1] = at[0] == 0      # ... and that one in the first tile
+    elif case == "rows whose keys lie in one key tile":
+        live[7] = (at[0] >= 128) & (at[0] < 256) & (at[0] % 3 == 0)
+        live[rows - 2] = (at[0] >= start) & (at[0] < start + 40)
+    return live
+
+
+@pytest.mark.parametrize("case, g, r, rows, keys, start", [
+    ("a row with a single key", 2, 1, 128, 384, 256),
+    ("rows whose keys lie in one key tile", 1, 2, 128, 512, 384),
+    ("grouped heads", 2, 4, 256, 256, 0),
+    ("keys in hand past the chunk's end", 2, 2, 128, 768, 128),
+])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_index_targets_kernel_is_the_target_by_xlas_ops(
+        dtype, case, g, r, rows, keys, start):
+    """``selected_probabilities`` by the Pallas kernel (interpret mode, tiles
+    of 128 rows and 128 keys) against XLA's ops, the operands as they come
+    and the softmax in float32 under both: equal to rounding on the selected
+    keys, and exactly zero — no nan — off them, in the tiles where a row has
+    no key and in those past the chunk's last row, which the kernel skips."""
+    key = jax.random.split(jax.random.PRNGKey(13), 2)
+    d = 64
+    q = (jax.random.normal(key[0], (g, r, rows, d)) * d ** -0.5).astype(dtype)
+    k = jax.random.normal(key[1], (g, keys, d)).astype(dtype)
+    live = jnp.asarray(_hand_made_selection(case, rows, keys, start))
+    assert bool(jnp.all(jnp.any(live, axis=-1)))
+    want = selected_probabilities(q, k, live)
+    for first in (start, None):       # with the dead tiles skipped, and not
+        got = selected_probabilities(q, k, live, start=first,
+                                     tiles=(128, 128), interpret=True)
+        assert got.shape == (rows, keys) and got.dtype == jnp.float32
+        np.testing.assert_array_equal(got == 0, ~live)
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-9)
+    np.testing.assert_allclose(jnp.sum(want, axis=-1), 1.0, rtol=1e-5)
 
 
 def test_the_selections_gradient_is_kept_under_the_attentions_name():

@@ -244,13 +244,91 @@ def test_the_step_built_for_a_chip_takes_the_kernel_under_the_selection(
 
     text, said = lowered(chip)
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
-    assert said == {"attention: Pallas kernel, tile=256, positions=256"}
+    assert said == {"attention: Pallas kernel, tile=256, positions=256",
+                    "index target: Pallas kernel, rows=128, keys tile=256"}
     text, said = lowered(jax.devices()[0])
     assert "splash_mha" not in text
     assert said == {
-        "attention: XLA's blocked ops (devices: cpu), positions=256"}
-    assert not {"attention_kernel", "keep", "remat", "index_chunk"} & set(
+        "attention: XLA's blocked ops (devices: cpu), positions=256",
+        "index target: XLA's ops (as the attention)"}
+    assert not {"attention_kernel", "keep", "remat", "index_chunk",
+                "index_kernel", "target_kernel"} & set(
         cfg.model.__dataclass_fields__)
+
+
+_LOC = re.compile(r"loc\((#loc\d+)\)$")
+_LOC_NAME = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
+_FLOAT32 = re.compile(r"tensor<((?:\d+x)+)f32>")
+
+
+def _under(text: str, scope: str) -> list:
+    """The operations of a lowered step (``as_text(debug_info=True)``) whose
+    location's name has ``scope`` among its scopes."""
+    names = dict(_LOC_NAME.findall(text))
+    return [line for line in text.splitlines()
+            if (m := _LOC.search(line))
+            and scope in names.get(m.group(1), "").split("/")]
+
+
+def _float32_sizes(lines) -> set:
+    return {int(np.prod([int(n) for n in dims.split("x") if n]))
+            for line in lines for dims in _FLOAT32.findall(line)}
+
+
+def test_the_step_built_for_a_chip_makes_the_index_target_in_one_kernel(chip):
+    """Lowered for the described chip, ``index_loss`` holds the call of the
+    kernel that makes ``p`` (its name is its own: the benchmark's
+    ``dsa_attention_roofline`` reads the names that start with
+    ``splash_mha_fwd`` and ``splash_mha_dkv``) and no float32 array of heads
+    × chunk × keys elements; lowered for this CPU it holds that array —
+    XLA's score block — and no kernel."""
+    cfg = _config(field_size=256, head_dim=128, index_topk=64,
+                  num_attention_heads=8, index_n_heads=2)
+    block = cfg.model.num_attention_heads * 256 * 256
+
+    def index_loss(device):
+        return _under(_lowered_for(cfg, device).as_text(debug_info=True),
+                      "index_loss")
+
+    ops = index_loss(chip)
+    calls = [op for op in ops if "tpu_custom_call" in op]
+    assert len(calls) == 1 and 'kernel_name = "selected_probabilities"' in (
+        calls[0]), calls
+    assert "splash_mha" not in calls[0]
+    assert block not in _float32_sizes(ops)
+    assert 256 * 256 in _float32_sizes(calls)          # p itself
+    ops = index_loss(jax.devices()[0])
+    assert ops and not any("custom_call" in op for op in ops)
+    assert block in _float32_sizes(ops)
+
+
+@pytest.mark.parametrize("keys", [2048, 16384])
+def test_the_index_targets_kernel_compiles_for_the_chip_at_the_cells_widths(
+        chip, keys):
+    """Mosaic takes the kernel at the benchmark cell's sizes — 32 heads of
+    128 on 4 key-value heads, a chunk of 512 queries in bfloat16 against
+    the narrowest and the widest keys in hand — with the tiles
+    ``target_tiles`` gives there (a compile for the described chip: nothing
+    runs)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.ops.attention import (
+        QUERY_BLOCK,
+        selected_probabilities,
+        target_tiles,
+    )
+
+    tiles = target_tiles(True, QUERY_BLOCK, 2048)
+    assert tiles is not None
+    shape = functools.partial(jax.ShapeDtypeStruct,
+                              sharding=SingleDeviceSharding(chip))
+    compiled = jax.jit(functools.partial(
+        selected_probabilities, tiles=tiles)).lower(
+        shape((4, 8, QUERY_BLOCK, 128), jnp.bfloat16),
+        shape((4, keys, 128), jnp.bfloat16),
+        shape((QUERY_BLOCK, keys), jnp.bool_),
+        start=shape((), jnp.int32)).compile()
+    assert "selected_probabilities" in compiled.as_text()
 
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
