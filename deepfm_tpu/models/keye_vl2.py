@@ -143,13 +143,14 @@ def index_inputs(p: dict, x, rope, cfg: ModelConfig):
 
 def attention(p: dict, index: dict, x, ropes, cfg: ModelConfig):
     """-> (W_o·o [B, S, h], each sequence's L_I summed over its queries [B],
-    the keys each selected [B])."""
+    the keys each selected [B], whether a kernel made the gradient of the
+    index scores)."""
     b, s, _ = x.shape
     rope, index_rope = ropes
     with jax.named_scope("attention"):
         q, k, v = qkv_heads(p, x, rope, cfg)
     tile = kernel_tile(s)
-    bits, index_loss, selected = index_select(
+    bits, index_loss, selected, by_kernel = index_select(
         q, k, *index_inputs(index, lax.stop_gradient(x), index_rope, cfg),
         topk=cfg.index_topk, kernel=tile is not None)
     with jax.named_scope("selected_attention"):
@@ -158,19 +159,20 @@ def attention(p: dict, index: dict, x, ropes, cfg: ModelConfig):
     with jax.named_scope("attention"):
         y = kept_mm(out.reshape(b, s, -1), p["o_proj"],
                     jnp.dtype(cfg.compute_dtype))
-    return y, index_loss, selected
+    return y, index_loss, selected, by_kernel
 
 
 def block(p: dict, x, ropes, *, cfg: ModelConfig, axis_name):
     """-> (x, the rows each held expert took [held], L_I's sums [B], the
-    selected keys' counts [B])."""
+    selected keys' counts [B], 1.0 where a kernel made the gradient of the
+    index scores)."""
     xn = rms_norm(x, p["op_norm"], cfg.norm_eps)
-    y, index_loss, selected = attention(p["attention"], p["indexer"], xn,
-                                        ropes, cfg)
+    y, index_loss, selected, by_kernel = attention(
+        p["attention"], p["indexer"], xn, ropes, cfg)
     x = x + y.astype(jnp.float32)
     xn = rms_norm(x, p["ffn_norm"], cfg.norm_eps)
     y, took = sparse_ffn(p, None, xn, cfg, axis_name)
-    return x + y, took, index_loss, selected
+    return x + y, took, index_loss, selected, jnp.float32(by_kernel)
 
 
 def hidden_states(params: dict, ids, *, cfg: ModelConfig,
@@ -179,7 +181,8 @@ def hidden_states(params: dict, ids, *, cfg: ModelConfig,
     rows each held expert took, each sequence's L_I — the mean over layers
     and queries — [b], the selected keys' count over all layers and
     sequences, the share of the blocks that keep every product they
-    carry)."""
+    carry, the share of the layers whose chunk passes have the gradient of
+    their index scores from the kernel)."""
     with jax.named_scope("lookup"):
         x = lookup_fn(params[TABLE], ids).astype(jnp.float32)
     s, n = ids.shape[1], len(cfg.layer_types)
@@ -188,24 +191,26 @@ def hidden_states(params: dict, ids, *, cfg: ModelConfig,
     run = functools.partial(block, cfg=cfg, axis_name=axis_name)
 
     def blocks(x, wrap=lambda run, l: run):
-        took, index_loss, selected = [], 0.0, 0.0
+        took, index_loss, selected, by_kernel = [], 0.0, 0.0, 0.0
         for l in range(n):
-            x, t, loss, count = wrap(run, l)(params[f"layer_{l}"], x, ropes)
+            x, t, loss, count, kernel = wrap(run, l)(
+                params[f"layer_{l}"], x, ropes)
             took.append(t)
             index_loss = index_loss + loss
             selected = selected + jnp.sum(count)
-        return x, took, index_loss / (n * s), selected
+            by_kernel = by_kernel + kernel
+        return x, took, index_loss / (n * s), selected, by_kernel / n
 
     kept_share = 1.0
     if remat:
         policies, kept_share = block_policy(blocks, x, params,
                                             logging.getLogger(__name__))
         wrap = lambda run, l: jax.checkpoint(run, policy=policies[l])
-        x, took, index_loss, selected = blocks(x, wrap)
+        x, took, index_loss, selected, by_kernel = blocks(x, wrap)
     else:
-        x, took, index_loss, selected = blocks(x)
+        x, took, index_loss, selected, by_kernel = blocks(x)
     return (rms_norm(x, params["out_norm"], cfg.norm_eps), took, index_loss,
-            selected, kept_share)
+            selected, kept_share, by_kernel)
 
 
 def logits_of(params: dict, hidden, cfg: ModelConfig):
@@ -231,7 +236,10 @@ def keye_vl2_loss(params, model_state, batch, *, cfg, train=False, rng=None,
     sequence).  ``outputs`` are the counters ``metrics`` hands on: the
     routing's (``models/lfm2_moe.routing_counters``), ``index_loss`` (L_I),
     ``index_selected_share``, the selected keys over the causal pairs,
-    counted on the selections the attention ran under, and
+    counted on the selections the attention ran under,
+    ``index_kernel_share``, the share of the step's chunk passes whose index
+    scores' gradient came from the Pallas kernel (``ops/indexer.pull_tiles``:
+    1.0 on a TPU at the cell's sizes, 0.0 where XLA's ops make it), and
     ``blocks_products_kept_share``."""
     if lax.axis_size(MODEL_AXIS) > 1:
         raise ValueError(
@@ -240,7 +248,7 @@ def keye_vl2_loss(params, model_state, batch, *, cfg, train=False, rng=None,
             "it: each shard would hold the same experts under another's "
             "numbers; use model_parallel=1")
     ids = _ids(batch, cfg)
-    hidden, took, index_loss, selected, kept_share = hidden_states(
+    hidden, took, index_loss, selected, kept_share, by_kernel = hidden_states(
         params, ids, cfg=cfg, lookup_fn=lookup_fn or dense_lookup,
         axis_name=MODEL_AXIS)
     logits = logits_of(params, hidden, cfg)
@@ -254,13 +262,14 @@ def keye_vl2_loss(params, model_state, batch, *, cfg, train=False, rng=None,
         **routing_counters(took, ids.size, cfg),
         "index_loss": lax.stop_gradient(index_loss),
         "index_selected_share": lax.stop_gradient(selected) / pairs,
+        "index_kernel_share": lax.stop_gradient(by_kernel),
         "blocks_products_kept_share": jnp.asarray(kept_share)}
 
 
 KEYE_VL2_METRICS = {
     k: (lambda outputs, batch, k=k: outputs[k])
     for k in (*ROUTING_COUNTERS, "index_loss", "index_selected_share",
-              "blocks_products_kept_share")
+              "index_kernel_share", "blocks_products_kept_share")
 }
 
 
@@ -269,7 +278,7 @@ def keye_vl2_evaluate(acc, params, model_state, batch, weight, *, cfg,
     """Weighted mean loss, L_LM + L_I, over whole sequences; a zero-weight
     (padded) sequence counts for nothing."""
     ids = _ids(batch, cfg)
-    hidden, _, index_loss, _, _ = hidden_states(
+    hidden, _, index_loss, _, _, _ = hidden_states(
         params, ids, cfg=cfg, lookup_fn=lookup_fn or dense_lookup,
         axis_name=MODEL_AXIS, remat=False)
     ce = sequence_losses(logits_of(params, hidden, cfg), ids) + index_loss

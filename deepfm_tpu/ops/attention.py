@@ -58,7 +58,9 @@ tile, every head's score tile formed in VMEM both times, the ``live`` tile read
 once for all the heads as int8, and only the ``[chunk, ≤S]`` mean written.
 XLA's ops for it write the ``[heads, chunk, ≤S]`` float32 score block (1.07 GB
 a chunk at 32 heads of 16,384 keys) and pass over it three times more: the
-fallback, and the kernel's plain reference in the tests.
+fallback, and the kernel's plain reference in the tests.  The indexer's own
+gradient has a kernel beside its equations (``ops/indexer.index_scores_pull``,
+chosen by the same observation and this module's key tiles).
 """
 
 from __future__ import annotations

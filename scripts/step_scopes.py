@@ -12,7 +12,10 @@ family's ``attention/eva_pool``, the inner one).  A fusion spans scopes; the nam
 keeps on it is its root's; a ``while`` is left out of the sums, since the ops of
 its body have events of their own.  Prints the 40 longest ops with their scope, the
 time per scope and per Pallas kernel (a custom call, by its name), and the
-share of the step's device time under no scope; the
+share of the step's device time under no scope; with ``--scope NAME`` (as
+often as wanted) also EVERY op of that scope, whatever the transforms around
+it, with its time (``ops_by_scope``: what a cut inside one scope is planned
+and checked on); the
 same goes to ``chiprun_out/step_scopes/<cell>.json``.  What ``PERF.md`` §5's
 scope column is made with, until a reader under ``perf/`` can do it (§7).
 """
@@ -54,6 +57,8 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, default=3.0)
+    ap.add_argument("--scope", action="append", default=[],
+                    help="print every op of this scope (repeatable)")
     args = ap.parse_args()
 
     from perf import manifest
@@ -121,13 +126,14 @@ def main() -> int:
         raise SystemExit("no step of a TPU plane in the trace")
     total = sum(by_op.values())
     by_scope, by_kernel, rows = {}, {}, []
+    ops_by_scope = {name: [] for name in args.scope}
     for text, ns in sorted(by_op.items(), key=lambda kv: -kv[1]):
         instr = text.split(" = ", 1)[0].strip().lstrip("%")
         if " custom-call(" in text:     # a Pallas call: the kernel's name
             kernel = instr.split(".")[0]
             by_kernel[kernel] = by_kernel.get(kernel, 0) + ns
         op_name = names.get(instr, "")
-        scope = scope_of(op_name)[1]
+        bare, scope = scope_of(op_name)
         written = scope or KERNEL_SCOPES.get(instr.split(".")[0], "(none)")
         # inside a block's jax.checkpoint the transforms wrap the checkpoint,
         # not the scope: its recomputation and its backward read the bare
@@ -141,6 +147,8 @@ def main() -> int:
         by_scope[written] = by_scope.get(written, 0) + ns
         rows.append({"op": perf_trace.short_op(text), "scope": written,
                      "op_name": op_name, "ms_per_step": ns / 1e6 / steps})
+        if bare in ops_by_scope:
+            ops_by_scope[bare].append(rows[-1])
     out = {
         "workload": cell.name, "steps": steps,
         "step_device_ms_sum_of_ops": total / 1e6 / steps,
@@ -149,6 +157,7 @@ def main() -> int:
             by_scope.items(), key=lambda kv: -kv[1])},
         "ms_per_step_by_kernel": {k: v / 1e6 / steps
                                   for k, v in by_kernel.items()},
+        "ops_by_scope": ops_by_scope,
         "unscoped_share_pct": 100.0 * by_scope.get("(none)", 0) / total,
         "unscoped_ops": [r for r in rows if r["scope"] == "(none)"][:10],
         "ops_with_no_op_name": sum(not r["op_name"] for r in rows),

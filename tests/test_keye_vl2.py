@@ -37,8 +37,11 @@ from deepfm_tpu.ops.attention import (  # noqa: E402
 )
 from deepfm_tpu.ops.experts import held_experts_sum, route  # noqa: E402
 from deepfm_tpu.ops.indexer import (  # noqa: E402
+    index_products,
     index_scores,
+    index_scores_pull,
     index_select,
+    pull_tiles,
     select_keys,
 )
 from deepfm_tpu.parallel import MODEL_AXIS, build_mesh  # noqa: E402
@@ -109,7 +112,7 @@ def _moved(params, seed: int = 1):
 
 def _terms(params, ids, cfg):
     """(L_LM, L_I) of the program, without the step around it."""
-    hidden, _, index_loss, _, _ = keye_vl2.hidden_states(
+    hidden, _, index_loss, *_ = keye_vl2.hidden_states(
         params, ids, cfg=cfg.model)
     logits = keye_vl2.logits_of(params, hidden, cfg.model)
     lm = jnp.mean(keye_vl2.position_losses(
@@ -271,17 +274,21 @@ def test_the_chunked_selection_and_its_loss_are_the_dense_ones_both_ways(
     """Four chunks of queries, each against the keys up to the end of its
     group: the bits, the loss and — from the gradient the forward formed —
     ∂L_I/∂(qᴵ, kᴵ, w) are those of the whole [S, S] scores under autodiff;
-    nothing goes back to q and k.  With ``p`` made by XLA's ops, and by the
-    Pallas kernel in interpret mode (tiles of 128 rows and 512 keys: a
-    chunk's keys in hand run past its own end, and it skips those tiles)."""
+    nothing goes back to q and k.  With ``p`` and the gradient of the index
+    scores made by XLA's ops, and by the Pallas kernels in interpret mode
+    (tiles of 128 rows and 512 keys: a chunk's keys in hand run past its own
+    end, and both skip those tiles)."""
     q, k, qi, ki, w = _index_inputs(2, s, seed=4)
     how = dict(kernel=True, interpret=True) if path == "kernel" else {}
     assert (target_tiles(path == "kernel", chunk, 4 * chunk)
             == ((128, 512) if path == "kernel" else None))
+    assert (pull_tiles(path == "kernel", chunk, 4, 4 * chunk)
+            == ((128, 512) if path == "kernel" else None))
 
     def chunked(qi, ki, w, q, k):
-        bits, loss, selected = index_select(q, k, qi, ki, w, topk=topk,
-                                            chunk=chunk, **how)
+        bits, loss, selected, by_kernel = index_select(
+            q, k, qi, ki, w, topk=topk, chunk=chunk, **how)
+        assert by_kernel is (path == "kernel")
         return jnp.sum(loss * jnp.asarray([1.0, 2.0])), (bits, selected)
 
     def dense(qi, ki, w):
@@ -349,6 +356,51 @@ def test_the_index_targets_kernel_is_the_target_by_xlas_ops(
         np.testing.assert_array_equal(got == 0, ~live)
         np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-9)
     np.testing.assert_allclose(jnp.sum(want, axis=-1), 1.0, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case, n, start", [
+    ("a row with a single selected key", 256, 128),
+    ("zero off a causal top-k", 384, 256),
+    ("weights negative and zero", 256, 128),
+    ("products that are exactly zero", 256, 128),
+    ("keys in hand past the chunk's end", 512, 128),
+])
+def test_the_index_gradients_kernel_is_the_pull_by_xlas_ops(case, n, start):
+    """``index_scores_pull`` (interpret mode; two query tiles of 64 rows and
+    two to four key tiles of 128, so that the sums over both run) against
+    the pull of ``jax.vjp(index_scores, …)``, float32: each of ``d_qi``,
+    ``d_ki`` and ``d_w`` to the rounding of its sums, with the key tiles past
+    a query tile's last row skipped (``start``) and not."""
+    c, j, e = 128, 8, 16
+    key = jax.random.split(jax.random.PRNGKey(45), 5)
+    qi = jax.random.normal(key[0], (c, j, e))
+    ki = jax.random.normal(key[1], (n, e))
+    w = jax.random.normal(key[2], (c, j))
+    live = select_keys(jax.random.normal(key[3], (c, n)), start, 48)
+    if case == "a row with a single selected key":
+        at = jnp.arange(n)
+        live = live.at[5].set(at == start + 5).at[c - 1].set(at == 0)
+    elif case == "weights negative and zero":
+        w = -jnp.abs(w).at[::3].set(0.0).at[:, 2].set(0.0)
+    elif case == "products that are exactly zero":
+        # no gradient goes through relu at 0, nor a weight's through it
+        qi = qi.at[::4, 1].set(0.0).at[7].set(0.0)
+        ki = ki.at[::5].set(0.0)
+    d_scores = jnp.where(live, jax.random.normal(key[4], (c, n)), 0.0)
+    z = index_products(qi, ki)
+    if case == "products that are exactly zero":
+        assert int(jnp.sum(z == 0)) > c * j * n // 5
+    with jax.default_matmul_precision("highest"):
+        want = jax.vjp(index_scores, qi, ki, w)[1](d_scores)
+    for first in (start, None):       # with the dead tiles skipped, and not
+        got = index_scores_pull(z, qi, ki, w, d_scores, start=first,
+                                tiles=(64, 128), interpret=True)
+        for name, x, y in zip(("d_qi", "d_ki", "d_w"), got, want):
+            assert x.shape == y.shape and x.dtype == jnp.float32
+            assert float(jnp.max(jnp.abs(x - y))) <= 2e-6 * float(
+                jnp.max(jnp.abs(y))), (name, first)
+    if case == "weights negative and zero":     # a zero weight: no d_qi
+        assert not np.any(got[0][::3]) and np.any(got[2][::3])
 
 
 def test_the_selections_gradient_is_kept_under_the_attentions_name():

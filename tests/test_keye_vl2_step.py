@@ -35,7 +35,7 @@ from deepfm_tpu.parallel import (
 
 COUNTERS = {"rows_held_share", "expert_load_max_share",
             "experts_compact_share", "index_loss", "index_selected_share",
-            "blocks_products_kept_share"}
+            "index_kernel_share", "blocks_products_kept_share"}
 
 
 def _cell():
@@ -92,7 +92,7 @@ def test_the_planted_fault_reaches_the_programs_loss_by_its_name():
         s = ref.sizes_from_config(TINY)
         params = ref.init(jax.random.PRNGKey(2), s)
         one = jnp.asarray(_ids(cfg, 1, seed=3), jnp.int32)
-        hidden, _, index_loss, _, _ = keye_vl2.hidden_states(
+        hidden, _, index_loss, *_ = keye_vl2.hidden_states(
             params, one, cfg=cfg.model)
         loss = jnp.mean(keye_vl2.position_losses(jnp.swapaxes(
             keye_vl2.logits_of(params, hidden, cfg.model), 0, 1)[
@@ -128,6 +128,8 @@ def test_data_parallel_gives_the_same_loss_and_model_parallel_is_refused():
         assert 0 < float(m["rows_held_share"]) < 1
         # the CPU says nothing of its memory: every block keeps every name
         assert float(m["blocks_products_kept_share"]) == 1.0
+        # ... and XLA's ops make the index scores' gradient
+        assert float(m["index_kernel_share"]) == 0.0
     assert losses[1] == pytest.approx(losses[2], rel=1e-5)
     ctx = make_context(cfg, _mesh(1, 2))
     with pytest.raises(ValueError, match="keye_vl2 shares a layer's experts "
@@ -238,24 +240,28 @@ def test_the_step_built_for_a_chip_takes_the_kernel_under_the_selection(
 
     def lowered(device):
         caplog.clear()
-        with caplog.at_level(logging.INFO, logger="deepfm_tpu.ops.attention"):
+        with caplog.at_level(logging.INFO, logger="deepfm_tpu.ops"):
             text = _lowered_for(cfg, device).as_text()
-        return text, {r.getMessage() for r in caplog.records}
+        return text, {r.getMessage() for r in caplog.records
+                      if r.name.split(".")[-1] in ("attention", "indexer")}
 
     text, said = lowered(chip)
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
     assert said == {"attention: Pallas kernel, tile=256, positions=256",
-                    "index target: Pallas kernel, rows=128, keys tile=256"}
+                    "index target: Pallas kernel, rows=128, keys tile=256",
+                    "index gradient: Pallas kernel, rows=256, keys tile=256"}
     text, said = lowered(jax.devices()[0])
     assert "splash_mha" not in text
     assert said == {
         "attention: XLA's blocked ops (devices: cpu), positions=256",
-        "index target: XLA's ops (as the attention)"}
+        "index target: XLA's ops (as the attention)",
+        "index gradient: XLA's ops (as the attention)"}
     assert not {"attention_kernel", "keep", "remat", "index_chunk",
-                "index_kernel", "target_kernel"} & set(
-        cfg.model.__dataclass_fields__)
+                "index_kernel", "target_kernel", "pull_kernel",
+                "gradient_kernel"} & set(cfg.model.__dataclass_fields__)
 
 
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _LOC = re.compile(r"loc\((#loc\d+)\)$")
 _LOC_NAME = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
 _FLOAT32 = re.compile(r"tensor<((?:\d+x)+)f32>")
@@ -291,15 +297,89 @@ def test_the_step_built_for_a_chip_makes_the_index_target_in_one_kernel(chip):
                       "index_loss")
 
     ops = index_loss(chip)
-    calls = [op for op in ops if "tpu_custom_call" in op]
-    assert len(calls) == 1 and 'kernel_name = "selected_probabilities"' in (
-        calls[0]), calls
+    calls = [op for op in ops if "tpu_custom_call" in op
+             and 'kernel_name = "selected_probabilities"' in op]
+    assert len(calls) == 1, calls
     assert "splash_mha" not in calls[0]
     assert block not in _float32_sizes(ops)
     assert 256 * 256 in _float32_sizes(calls)          # p itself
     ops = index_loss(jax.devices()[0])
     assert ops and not any("custom_call" in op for op in ops)
     assert block in _float32_sizes(ops)
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) ")
+_RESULT = re.compile(r" = (\([^)]*\)|\S+) ([\w\-]+)\(")
+_F32 = re.compile(r"f32\[([\d,]+)\]")
+_BRACES = re.compile(r"\{[^{}]*\}")
+# instructions whose result is no new array in memory
+_NO_WRITE = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+             "call", "conditional", "opt-barrier"}
+
+
+def _written(hlo: str, scopes, elements: int) -> list:
+    """The instructions of a compiled step, outside its fused computations,
+    that write a float32 array of ``elements`` elements under one of
+    ``scopes`` (by their ``op_name``)."""
+    found, computation = [], ""
+    for line in hlo.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION.match(line)
+            computation = m.group(1) if m else computation
+            continue
+        # without the layouts: ``{2,1,0:T(8,128)S(1)}`` holds brackets too
+        m = _RESULT.search(_BRACES.sub("", line))
+        name = _OP_NAME.search(line)
+        if ("fused_computation" in computation or not m or not name
+                or m.group(2) in _NO_WRITE
+                or scope_of(name.group(1))[0] not in scopes):
+            continue
+        if elements in (int(np.prod([int(n) for n in dims.split(",")]))
+                        for dims in _F32.findall(m.group(1))):
+            found.append(line.strip())
+    return found
+
+
+def test_the_step_built_for_a_chip_makes_the_index_gradient_in_one_kernel(
+        chip, monkeypatch):
+    """Lowered for the described chip, ``index_loss`` holds the call of the
+    kernel ``index_scores_pull``, and in the step compiled for that chip (no
+    chip attached: Mosaic takes the kernel as the step calls it) the only
+    float32 array of heads × chunk × keys elements that an instruction under
+    ``indexer`` or ``index_loss`` writes to memory is z, once a chunk body —
+    one a layer here; XLA's ops (``jax.vjp``'s pull, which this CPU's step
+    holds, and no kernel) also write its cotangent and copy that.  What
+    ``index_kernel_share`` is made of reads true in the one trace and false
+    in the other."""
+    cfg = _config(field_size=256, head_dim=128, index_topk=64,
+                  index_n_heads=8)
+    block = cfg.model.index_n_heads * 256 * 256
+    layers = len(cfg.model.layer_types)
+    by_kernel = []
+    select = keye_vl2.index_select
+
+    def recorded(*args, **kw):
+        out = select(*args, **kw)
+        by_kernel.append(out[3])
+        return out
+
+    monkeypatch.setattr(keye_vl2, "index_select", recorded)
+    lowered = _lowered_for(cfg, chip)
+    assert by_kernel and all(x is True for x in by_kernel)
+    calls = [op for op in _under(lowered.as_text(debug_info=True),
+                                 "index_loss")
+             if 'kernel_name = "index_scores_pull"' in op]
+    assert len(calls) == 1, calls         # lowered once for both layers
+    written = _written(lowered.compile().as_text(),
+                       ("indexer", "index_loss"), block)
+    assert len(written) == layers, written
+    assert all("/indexer/" in w for w in written)       # the forward's
+    del by_kernel[:]
+    lowered = _lowered_for(cfg, jax.devices()[0])
+    assert by_kernel and not any(by_kernel)
+    assert "index_scores_pull" not in lowered.as_text()
+    assert len(_written(lowered.compile().as_text(),
+                        ("indexer", "index_loss"), block)) > layers
 
 
 @pytest.mark.parametrize("keys", [2048, 16384])
@@ -331,7 +411,29 @@ def test_the_index_targets_kernel_compiles_for_the_chip_at_the_cells_widths(
     assert "selected_probabilities" in compiled.as_text()
 
 
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
+@pytest.mark.parametrize("keys", [2048, 16384])
+def test_the_index_gradients_kernel_compiles_for_the_chip_at_the_cells_widths(
+        chip, keys):
+    """Mosaic takes the kernel at the benchmark cell's sizes — a chunk of 512
+    queries of 16 index heads of 64 against the narrowest and the widest
+    keys in hand, float32 — with the tiles ``pull_tiles`` gives there (a
+    compile for the described chip: nothing runs)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.ops.attention import QUERY_BLOCK
+    from deepfm_tpu.ops.indexer import index_scores_pull, pull_tiles
+
+    tiles = pull_tiles(True, QUERY_BLOCK, 16, 2048)
+    assert tiles == (256, 512)
+    shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                              sharding=SingleDeviceSharding(chip))
+    compiled = jax.jit(functools.partial(
+        index_scores_pull, tiles=tiles)).lower(
+        shape((16, QUERY_BLOCK, keys)), shape((QUERY_BLOCK, 16, 64)),
+        shape((keys, 64)), shape((QUERY_BLOCK, 16)),
+        shape((QUERY_BLOCK, keys)),
+        start=shape((), dtype=jnp.int32)).compile()
+    assert "index_scores_pull" in compiled.as_text()
 
 
 def _compiled_names(cfg) -> set:
@@ -374,7 +476,7 @@ def test_the_loss_and_every_gradient_are_the_same_with_and_without_remat(
     ids = jnp.asarray(_ids(_config(), 3, seed=41), jnp.int32)
 
     def loss(params, remat):
-        hidden, _, index_loss, _, _ = keye_vl2.hidden_states(
+        hidden, _, index_loss, *_ = keye_vl2.hidden_states(
             params, ids, cfg=cfg, remat=remat)
         logits = keye_vl2.logits_of(params, hidden, cfg)
         return jnp.mean(keye_vl2.position_losses(

@@ -316,8 +316,8 @@ CONTRACTS = {
         "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
         "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
                     "experts_compact_share", "index_loss",
-                    "index_selected_share", "blocks_products_kept_share",
-                    "loss_per_shard"},
+                    "index_selected_share", "index_kernel_share",
+                    "blocks_products_kept_share", "loss_per_shard"},
     },
 }
 
