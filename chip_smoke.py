@@ -7,8 +7,7 @@ full width of the reference model (DeepFM, V=117,581, F=39, K=32, deep
 field at its default: seeded synthetic records -> ``launch.cli`` train
 (input pipeline, checkpoints, eval, export) -> the same command again
 (resume) -> ``infer`` on the chip and on the CPU from the same checkpoint
--> ``serve.server`` answering ``:predict`` in every bucket.  One
-compile-and-compare step keeps the Pallas CTR kernel honest.  With four
+-> ``serve.server`` answering ``:predict`` in every bucket.  With four
 chips visible it goes on to the sharded meshes ([1,4], [2,2], default
 [4,1]), a [1,4] -> [2,2] resume, and the four-chip serving pool.
 
@@ -286,18 +285,6 @@ def phase_probe() -> dict:
                              f"sees {one}, not one chip")
         _report_phase("probe_one_chip", child, wall, None, {"runtime": one})
     return report
-
-
-def phase_kernel() -> None:
-    """Compile the Pallas CTR kernel for the chip (not interpreted) at the
-    reference widths; forward and gradients against the lax reference."""
-    child = Child("kernel", _py(os.path.abspath(__file__), "--child", "kernel"))
-    wall = child.wait(600)
-    _check_platform("kernel", child.runtime(), REQUIRED_PLATFORM)
-    result = child.events("kernel")
-    if not result or not result[-1][1].get("ok"):
-        raise PhaseError(f"kernel: no ok result\n{child.tail()}")
-    _report_phase("kernel", child, wall, None, {"result": result[-1][1]})
 
 
 def write_data() -> dict:
@@ -632,56 +619,11 @@ def _child_probe() -> None:
     print(json.dumps({"kind": "runtime", **runtime_report()}), flush=True)
 
 
-def _child_kernel() -> None:
-    from deepfm_tpu.core.platform import configure_runtime, runtime_report
-
-    configure_runtime()
-    import jax
-    import jax.numpy as jnp
-
-    from deepfm_tpu.ops.embedding import dense_lookup, scaled_embedding
-    from deepfm_tpu.ops.fm import fm_first_order, fm_second_order
-    from deepfm_tpu.ops.pallas_ctr import fused_ctr_interaction
-
-    print(json.dumps({"kind": "runtime", **runtime_report()}), flush=True)
-    rng = np.random.default_rng(0)
-    fm_w = jnp.asarray(rng.normal(size=(V,)) * 0.01, jnp.float32)
-    fm_v = jnp.asarray(rng.normal(size=(V, K)) * 0.01, jnp.float32)
-    ids = jnp.asarray(np.concatenate(
-        [rng.integers(1, 14, size=(BATCH, 13)),
-         14 + rng.zipf(1.3, size=(BATCH, F - 13)) % (V - 14)], 1), jnp.int32)
-    vals = jnp.asarray(rng.random((BATCH, F)), jnp.float32)
-
-    def fused(w, v, x):
-        return fused_ctr_interaction(w, v, ids, x)   # compiled, never interpret
-
-    def oracle(w, v, x):
-        emb = scaled_embedding(v, ids, x)
-        return emb, fm_first_order(dense_lookup(w, ids), x), fm_second_order(emb)
-
-    def loss(fn):
-        return lambda w, v, x: sum(jnp.sum(jnp.sin(o)) for o in fn(w, v, x))
-
-    got, want = jax.jit(fused)(fm_w, fm_v, vals), jax.jit(oracle)(fm_w, fm_v, vals)
-    for g, w_, name in zip(got, want, ("emb", "y_w", "y_v")):
-        np.testing.assert_allclose(g, w_, rtol=1e-6, atol=1e-6, err_msg=name)
-    grads = [jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2)))(fm_w, fm_v, vals)
-             for fn in (fused, oracle)]
-    for g, w_, name in zip(*grads, ("d_fm_w", "d_fm_v", "d_vals")):
-        np.testing.assert_allclose(
-            g, w_, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(w_))),
-            err_msg=name)
-    print(json.dumps({"kind": "kernel", "ok": True,
-                      "kernel": "ops/pallas_ctr.fused_ctr_interaction",
-                      "shape": {"V": V, "F": F, "K": K, "batch": BATCH}}),
-          flush=True)
-
-
 # ---------------------------------------------------------------------------
 
 def main() -> int:
     if sys.argv[1:2] == ["--child"]:
-        {"probe": _child_probe, "kernel": _child_kernel}[sys.argv[2]]()
+        {"probe": _child_probe}[sys.argv[2]]()
         return 0
     signal.signal(signal.SIGTERM, _on_signal)
     signal.signal(signal.SIGINT, _on_signal)
@@ -694,7 +636,6 @@ def main() -> int:
         count = int(device["device_count"])
         _cache_summary("before", device["compile_cache_dir"])
         dirs = write_data()
-        phase_kernel()
         one = ONE_CHIP_ENV if count > 1 else None
         phase_train(dirs, one)
         chip = run_infer("infer_chip", dirs["te_chip"], one, REQUIRED_PLATFORM)

@@ -1216,7 +1216,7 @@ def _funnel_audit_ctx(mesh, retrieval: str = "exact"):
         # a scan tile that collides with no corpus dim (capacity 96,
         # per-shard 48/24 on the audited meshes): the per-tile dequant
         # [tile, D] f32 must be distinguishable from a whole-corpus one
-        extra = dict(oversample=2, retrieval_tile=16, pallas="off")
+        extra = dict(oversample=2, retrieval_tile=16)
     return make_funnel_context(
         rank_cfg, query_cfg, mesh,
         capacity=_FUNNEL_CAPACITY, top_k=_FUNNEL_K, return_n=_FUNNEL_N,
@@ -1471,7 +1471,7 @@ def audit_funnel(cfg=None, retrieve_builder=None,
                     f"— the quantized scorer must stream int8 tiles and "
                     f"hold only tile-sized f32",
                     hint="dequantize per scan tile "
-                         "(ops/pallas_retrieval.score_topk_tiles); never "
+                         "(funnel/quant.score_topk_tiles); never "
                          "codes.astype(f32) over the whole shard",
                     where=where, slug=f"funnel-{tag}-corpus-f32",
                 ))

@@ -88,23 +88,7 @@ class Checkpointer:
             else x,
             target_state,
         )
-        try:
-            return self._mngr.restore(step, args=ocp.args.StandardRestore(abstract))
-        except Exception as e:
-            if "fm_v" in str(e) and (
-                "shape" in str(e).lower() or "Sizes" in str(e)
-            ):
-                raise RuntimeError(
-                    f"checkpoint restore failed on a shape mismatch involving "
-                    f"fm_v: {e}\nHint: checkpoints written with "
-                    f"model.fused_kernel != 'off' store a window-padded fm_v "
-                    f"(rows rounded up to a multiple of 128 // embedding_size "
-                    f"when feature_size doesn't divide it); restoring under a "
-                    f"different fused_kernel setting changes the expected "
-                    f"shape.  Restore with the same fused_kernel value the "
-                    f"checkpoint was trained with (docs/PARITY.md)."
-                ) from e
-            raise
+        return self._mngr.restore(step, args=ocp.args.StandardRestore(abstract))
 
     def all_steps(self) -> list[int]:
         self._mngr.wait_until_finished()

@@ -2,7 +2,7 @@
 
 A TrainState checkpoint records embedding tables at the PADDED vocabulary of
 the mesh it was trained on (``padded_vocab`` = next multiple of
-lcm(model_parallel, window_multiple), parallel/spmd.py) — so a run saved on
+model_parallel, parallel/spmd.py) — so a run saved on
 a [4, 2] mesh cannot restore byte-for-byte into a [2, 4] context whose
 padding differs.  The reference had no notion of this (one fixed topology
 per job, SURVEY §5); here reshaping the mesh between runs is routine

@@ -66,8 +66,7 @@ EXECUTABLE_SPEC_FIELDS = (
     "deep_layers", "cin_layers", "cross_layers", "batch_norm",
     "tower_layers", "tower_dim", "user_vocab_size", "item_vocab_size",
     "user_field_size", "item_field_size", "compute_dtype", "table_grad",
-    "fused_kernel", "shard_exchange", "shard_exchange_capacity",
-    "tiered_embeddings",
+    "shard_exchange", "shard_exchange_capacity", "tiered_embeddings",
 )
 
 # keys a fleet tenant entry may carry (core/config.py and fleet/registry.py
@@ -253,10 +252,6 @@ class ModelConfig:
     # (ops/embedding.py dense_lookup).  The field stays for the
     # configuration files that name it; ROADMAP D3 removes it
     table_grad: str = "scatter"
-    # Pallas fused gather+FM kernel (ops/pallas_ctr.py): "off" | "auto" | "on".
-    # "auto" uses it on TPU backends; "on" means the compiled kernel and
-    # raises where it cannot compile (never interpret mode).
-    fused_kernel: str = "off"
     # row-sharded lookup collective strategy (parallel/embedding.py):
     # "psum" = every shard contributes a mostly-zeros [B, F, K] dense tensor,
     # assembled by lax.psum over the model axis (the original path) |
@@ -304,11 +299,6 @@ class ModelConfig:
                 f"dropout_keep has {len(self.dropout_keep)} entries for "
                 f"{len(self.deep_layers)} deep layers"
             )
-        if self.fused_kernel not in ("off", "auto", "on"):
-            raise ValueError(
-                f"fused_kernel must be 'off', 'auto' or 'on', "
-                f"got {self.fused_kernel!r}"
-            )
         if self.router_score not in ("sigmoid", "softmax"):
             raise ValueError(
                 f"router_score must be 'sigmoid' or 'softmax', "
@@ -340,12 +330,6 @@ class ModelConfig:
                     f"{name} must be >= 0 (0 = auto), got "
                     f"{getattr(self, name)}"
                 )
-        if self.tiered_embeddings and self.fused_kernel != "off":
-            raise ValueError(
-                "tiered_embeddings pages rows through a slot-space cache; "
-                "the fused kernel gathers a RESIDENT table — use "
-                "fused_kernel='off' with tiered embeddings"
-            )
 
 
 @dataclass(frozen=True)
@@ -1010,11 +994,6 @@ class RunConfig:
     # publish-time recall gate (funnel/recall.py): an int8 publish whose
     # measured recall@top_k falls under this is refused
     funnel_min_recall: float = 0.95
-    # the fused Pallas score/top-k kernel (ops/pallas_retrieval.py):
-    # on | off | auto.  The TPU compiler refuses the kernel today (no
-    # Mosaic lowering for top_k), so auto = the lax composition and "on"
-    # raises with the compiler's message
-    funnel_pallas: str = "auto"
     # online continuous training (task_type=online-train, online/trainer.py):
     # publish a servable version every N optimizer steps (0 = only at
     # stream end); stop after N batches (0 = unbounded); stop after N
@@ -1177,11 +1156,6 @@ class Config:
             raise ValueError(
                 f"run.funnel_retrieval={r.funnel_retrieval!r} is not one "
                 f"of {retrieval_modes}"
-            )
-        if r.funnel_pallas not in ("on", "off", "auto"):
-            raise ValueError(
-                f"run.funnel_pallas={r.funnel_pallas!r} must be "
-                f"'on', 'off' or 'auto'"
             )
         if r.funnel_oversample < 1:
             raise ValueError(

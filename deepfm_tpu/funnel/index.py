@@ -35,7 +35,7 @@ republished index with the same capacity is a jit cache hit, never a
 recompile.  Pad rows [items, capacity) carry ``item_id = -1`` and score
 ``-inf``, so they are unreturnable whenever the corpus holds >= K items.
 
-``retrieval_mode="int8"`` (funnel/quant.py + ops/pallas_retrieval.py)
+``retrieval_mode="int8"`` (funnel/quant.py)
 swaps the per-shard scorer for the quantized tier — stream int8 code
 tiles through a running top-(K·oversample), exact-f32-rescore the
 shortlist, reduce to K — and leaves every other stage of the diagram
@@ -139,7 +139,6 @@ class FunnelContext(NamedTuple):
     retrieval_mode: str = "exact"   # resolved: "exact" | "int8"
     oversample: int = 1        # int8 shortlist width = top_k * oversample
     retrieval_tile: int = 0    # int8 scan tile rows (0 = library default)
-    pallas: str = "off"        # fused-kernel knob: "on" | "off" | "auto"
 
 
 def make_funnel_context(
@@ -154,7 +153,6 @@ def make_funnel_context(
     retrieval: str = "exact",
     oversample: int = 4,
     retrieval_tile: int = 0,
-    pallas: str = "auto",
 ) -> FunnelContext:
     """Derive the funnel geometry + payload shardings by shape inference
     only (nothing materializes — the spmd.make_context discipline).
@@ -211,10 +209,6 @@ def make_funnel_context(
             f"funnel retrieval_tile must be >= 0 (0 = default), got "
             f"{retrieval_tile}"
         )
-    if pallas not in ("on", "off", "auto"):
-        raise ValueError(
-            f"funnel pallas={pallas!r} is not one of ('on', 'off', 'auto')"
-        )
     f = rank_cfg.model.field_size
     item_field = f - 1 if item_field is None else int(item_field)
     if not 0 <= item_field < f:
@@ -246,7 +240,7 @@ def make_funnel_context(
         rank_fields=f,
         payload_specs=specs, payload_shardings=shardings,
         retrieval_mode=mode, oversample=oversample,
-        retrieval_tile=retrieval_tile, pallas=pallas,
+        retrieval_tile=retrieval_tile,
     )
 
 
@@ -306,8 +300,7 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
     the original full-precision matmul, unchanged (the same ids and order
     as :func:`brute_force_topk` up to f32 summation order).  ``"int8"``
     streams the quantized code tiles through a running top-(K·oversample)
-    (ops/pallas_retrieval.py — the lax scan, or the fused Pallas kernel
-    when ``ctx.pallas`` resolves on), then re-scores ONLY the shortlist
+    (funnel/quant.py's lax scan), then re-scores ONLY the shortlist
     rows against the exact f32 embeddings (a shortlist-sized gather —
     never the corpus) before the unchanged candidate-pack merge: the
     output ABI, tie order, and collective footprint are identical across
@@ -354,16 +347,10 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
         return merge_packs(s, grow, cid)
 
     if ctx.retrieval_mode == "int8":
-        from ..ops.pallas_retrieval import (
-            DEFAULT_SCAN_TILE,
-            resolve_retrieval_kernel,
-            retrieval_topk_kernel,
-            score_topk_tiles,
-        )
+        from .quant import DEFAULT_SCAN_TILE, score_topk_tiles
 
         kos = k * ctx.oversample
         tile = ctx.retrieval_tile or DEFAULT_SCAN_TILE
-        use_kernel = resolve_retrieval_kernel(ctx.pallas)
 
         def local_retrieve_int8(payload, user_ids, user_vals):
             u = encode_tower(
@@ -373,12 +360,9 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
             iid = payload["index"]["item_ids"]      # [rows_local]
             codes = payload["index"]["item_codes"]  # [rows_local, D] i8
             scl = payload["index"]["item_scales"]   # [rows_local]
-            if use_kernel:
-                s_a, li = retrieval_topk_kernel(u, codes, scl, iid, kos=kos)
-            else:
-                s_a, li = score_topk_tiles(
-                    u, codes, scl, iid, kos=kos, tile=tile
-                )                                   # [B_local, K*os]
+            s_a, li = score_topk_tiles(
+                u, codes, scl, iid, kos=kos, tile=tile
+            )                                       # [B_local, K*os]
             # slots whose approximate score is -inf never saw a real row
             # (pads, or a corpus smaller than the shortlist): their row
             # indices are meaningless — clamp to 0 for the gather and
@@ -416,11 +400,6 @@ def build_retrieve_with(ctx: FunnelContext) -> Callable:
     def retrieve_with(payload, user_ids, user_vals):
         return mapped(payload, user_ids, user_vals)
 
-    # observability: which int8 scorer this executable runs (the Pallas
-    # kernel or the lax scan).  funnel_snapshot and the bench read this.
-    retrieve_with.kernel_engaged = (
-        ctx.retrieval_mode == "int8" and use_kernel
-    )
     return retrieve_with
 
 

@@ -119,7 +119,6 @@ class FunnelScorer:
         return_n: int = 0,
         retrieval: str = "",
         oversample: int = 0,
-        pallas: str = "",
         buckets=DEFAULT_BUCKETS,
         max_wait_ms: float = 2.0,
         max_queue_rows: int | None = None,
@@ -149,7 +148,6 @@ class FunnelScorer:
             item_field=int(meta["item_field"]),
             retrieval=retrieval or str(rsec.get("mode", "exact")),
             oversample=int(oversample) or int(rsec.get("oversample", 4)),
-            pallas=pallas or "auto",
         )
         payload = stage_funnel_payload(
             self.ctx, art.rank_params, art.rank_state, art.query_params,
@@ -465,9 +463,6 @@ class FunnelScorer:
                     self._degraded_os if self._degraded_active
                     else self.ctx.oversample
                 ),
-                "kernel_engaged": bool(getattr(
-                    self._retrieve_with, "kernel_engaged", False
-                )),
                 "degraded_dispatch_total": self.degraded_dispatch_total,
                 "candidates_total": self.candidates_total,
                 "candidates_per_sec": (
@@ -733,7 +728,6 @@ def serve_funnel(
     return_n: int = 0,
     retrieval: str = "",
     oversample: int = 0,
-    pallas: str = "",
     data_parallel: int = 1,
     model_parallel: int = 0,
     trace_sample_rate: float | None = None,
@@ -756,7 +750,7 @@ def serve_funnel(
     mesh = build_serve_mesh(data_parallel, model_parallel)
     scorer = FunnelScorer(
         servable_dir, mesh, top_k=top_k, return_n=return_n,
-        retrieval=retrieval, oversample=oversample, pallas=pallas,
+        retrieval=retrieval, oversample=oversample,
         buckets=buckets, max_wait_ms=max_wait_ms,
         max_queue_rows=max_queue_rows,
     )

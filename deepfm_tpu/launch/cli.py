@@ -138,13 +138,6 @@ def build_parser() -> argparse.ArgumentParser:
              "(funnel/recall.py; in (0, 1])",
     )
     p.add_argument(
-        "--funnel_pallas", choices=("on", "off", "auto"),
-        help="the fused Pallas score/top-k retrieval kernel "
-             "(ops/pallas_retrieval.py): on (the compiled kernel; raises "
-             "where the compiler refuses it) | off | auto (the lax "
-             "composition today)",
-    )
-    p.add_argument(
         "--coordinator_url",
         help="multi-host elastic coordination service "
              "(deepfm_tpu/elastic/coord.py; run one with `python -m "
@@ -207,7 +200,6 @@ _FLAG_MAP = {
     "funnel_retrieval": ("run", "funnel_retrieval"),
     "funnel_oversample": ("run", "funnel_oversample"),
     "funnel_min_recall": ("run", "funnel_min_recall"),
-    "funnel_pallas": ("run", "funnel_pallas"),
     "serve_tenants": ("fleet", "tenants"),
     "coordinator_url": ("elastic", "coordinator_url"),
     "lease_ttl_secs": ("elastic", "lease_ttl_secs"),

@@ -47,8 +47,7 @@ def _row_loss(logits, batch):
 
 def _score(apply, params, model_state, batch, *, cfg, train, rng, lookup_fn):
     """The family's logits for the batch.  ``lookup_fn=None`` leaves ``apply``
-    its own default (the fused kernel recognises the dense lookup by
-    identity)."""
+    its own default (DCNv2 recognises the dense lookup by identity)."""
     kwargs = {} if lookup_fn is None else {"lookup_fn": lookup_fn}
     return apply(
         params,

@@ -28,7 +28,6 @@ from ..ops.batch_norm import batch_norm, bn_init
 from ..ops.embedding import dense_lookup, narrow_ids
 from ..ops.fm import fm_first_order, fm_second_order
 from ..ops.initializers import glorot_normal, glorot_uniform
-from ..ops.pallas_ctr import fused_ctr_interaction, resolve_fused
 from .click_through import register_click_through
 
 
@@ -99,22 +98,11 @@ def apply_mlp(
 
 def init_deepfm(key: jax.Array, cfg: ModelConfig) -> tuple[dict, dict]:
     k_w, k_v, k_mlp = jax.random.split(key, 3)
-    fm_v = glorot_normal(k_v, (cfg.feature_size, cfg.embedding_size))  # ps:192-198
-    if cfg.fused_kernel != "off" and 128 % cfg.embedding_size == 0:
-        # pre-pad to an aligned-window multiple with zero rows so the Pallas
-        # wrapper never re-pads the table inside the per-step forward; the
-        # rows are never gathered (ids clip to feature_size-1) and stay zero
-        # under training (zero grads -> zero Adam updates, zero L2).
-        # Deliberately keyed on the config value, NOT resolve_fused(): the
-        # checkpointed table shape must not depend on which backend happened
-        # to run init ("auto" on TPU vs a later CPU export/infer restore)
-        pad = (-cfg.feature_size) % (128 // cfg.embedding_size)
-        if pad:
-            fm_v = jnp.pad(fm_v, ((0, pad), (0, 0)))
     params = {
         "fm_b": jnp.zeros((1,), jnp.float32),                      # ps:186-188
         "fm_w": glorot_normal(k_w, (cfg.feature_size,)),           # ps:189-191
-        "fm_v": fm_v,
+        "fm_v": glorot_normal(k_v, (cfg.feature_size,                # ps:192-198
+                                    cfg.embedding_size)),
         "mlp": init_mlp(k_mlp, cfg.field_size * cfg.embedding_size, cfg),
     }
     state: dict = {}
@@ -142,31 +130,13 @@ def apply_deepfm(
                           cfg.feature_size)
     feat_vals = feat_vals.reshape(-1, cfg.field_size).astype(jnp.float32)
 
-    if cfg.fused_kernel == "on" and lookup_fn is not dense_lookup:
-        raise ValueError(
-            "fused_kernel='on' requires the dense single-table lookup path; "
-            "lazy_embedding_updates and sharded (SPMD) tables substitute "
-            "their own row lookup, which cannot be fused — use "
-            "fused_kernel='auto' (or 'off') with those configs"
-        )
-    use_fused = lookup_fn is dense_lookup and resolve_fused(
-        cfg.fused_kernel, cfg.embedding_size
-    )
-    if use_fused:
-        # one HBM pass: both gathers + scaling + FM sums (ops/pallas_ctr.py)
-        with jax.named_scope("lookup"):
-            emb, y_w, y_v = fused_ctr_interaction(
-                params["fm_w"], params["fm_v"], feat_ids, feat_vals
-            )
-    else:
-        # one lookup for the two tables the ids index: [B, F], [B, F, K]
-        with jax.named_scope("lookup"):
-            feat_w, rows_v = lookup_fn(
-                (params["fm_w"], params["fm_v"]), feat_ids)
-            emb = rows_v * feat_vals[..., None]     # e = V[ids] * vals
-        with jax.named_scope("fm"):
-            y_w = fm_first_order(feat_w, feat_vals)     # ps:206-209
-            y_v = fm_second_order(emb)                  # ps:211-217
+    # one lookup for the two tables the ids index: [B, F], [B, F, K]
+    with jax.named_scope("lookup"):
+        feat_w, rows_v = lookup_fn((params["fm_w"], params["fm_v"]), feat_ids)
+        emb = rows_v * feat_vals[..., None]         # e = V[ids] * vals
+    with jax.named_scope("fm"):
+        y_w = fm_first_order(feat_w, feat_vals)     # ps:206-209
+        y_v = fm_second_order(emb)                  # ps:211-217
 
     # deep tower (ps:228-255)
     deep_in = emb.reshape(emb.shape[0], cfg.field_size * cfg.embedding_size)

@@ -85,8 +85,7 @@ def resolve_shard_exchange(cfg, backend: str | None = None) -> str:
     pod.  On the CPU backend (the virtual shared-memory mesh) "auto" stays
     on psum: there the dense assembly is a memcpy while the exchange's
     sort/index work is compute-bound (a CPU-backend timing, never a device
-    number: docs/ARCHITECTURE.md "Sharded embeddings"), the same
-    backend-conditional resolution ``fused_kernel="auto"`` uses.  Takes the
+    number: docs/ARCHITECTURE.md "Sharded embeddings").  Takes the
     full :class:`~..core.config.Config` (the mesh section must carry the
     RESOLVED axis sizes, as ``make_context`` writes them); ``backend``
     overrides ``jax.default_backend()`` for tests."""

@@ -80,26 +80,12 @@ class SPMDContext(NamedTuple):
     table_rows: Mapping[str, int] = {}
 
 
-def padded_vocab(
-    feature_size: int, model_parallel: int, window_multiple: int = 1
-) -> int:
-    """Next vocab size divisible by the row-shard factor AND the Pallas
-    aligned-window multiple.  Using the lcm keeps init_deepfm's own window
-    padding at zero, so table shapes equal the padded vocab and the
-    path-based sharding rules (shape[0] == vocab) always match."""
-    import math
-
-    m = math.lcm(max(1, model_parallel), max(1, window_multiple))
+def padded_vocab(feature_size: int, model_parallel: int) -> int:
+    """Next vocab size divisible by the row-shard factor, so table shapes
+    equal the padded vocab and the path-based sharding rules
+    (shape[0] == vocab) always match."""
+    m = max(1, model_parallel)
     return -(-feature_size // m) * m
-
-
-def _window_multiple(cfg: Config) -> int:
-    """init_deepfm pads fm_v to a 128-lane window multiple when the fused
-    kernel is enabled (models/deepfm.py) — mirror that here."""
-    k = cfg.model.embedding_size
-    if cfg.model.fused_kernel != "off" and 128 % k == 0:
-        return 128 // k
-    return 1
 
 
 def _spec_for_leaf(
@@ -219,9 +205,8 @@ def _make_context(cfg: Config, mesh: Mesh,
             f"model_parallel=1"
         )
     true_feature_size = cfg.model.feature_size
-    window = _window_multiple(cfg)
     cfg = cfg.with_overrides(
-        model={field: padded_vocab(true_rows[k], mp, window)
+        model={field: padded_vocab(true_rows[k], mp)
                for k, field in model.tables.items()},
         mesh={"data_parallel": dp, "model_parallel": mp},
     )

@@ -94,8 +94,6 @@ def _member_argv(args, group: str, index: int, port: int) -> list[str]:
         argv += ["--funnel-retrieval", args.funnel_retrieval]
     if args.funnel_oversample:
         argv += ["--funnel-oversample", str(args.funnel_oversample)]
-    if args.funnel_pallas:
-        argv += ["--funnel-pallas", args.funnel_pallas]
     if args.flight_dump:
         # one timeline file per process: members suffix their group name
         argv += ["--flight-dump", f"{args.flight_dump}.{group}"]
@@ -170,7 +168,6 @@ def _run_member(args) -> int:
         funnel_return_n=args.funnel_return_n,
         funnel_retrieval=args.funnel_retrieval,
         funnel_oversample=args.funnel_oversample,
-        funnel_pallas=args.funnel_pallas,
         tenants=_load_tenants(args.tenants) or None,
         slo=_parse_slo(args.slo),
     )
@@ -343,10 +340,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--funnel-oversample", type=int, default=0,
                     help="int8 shortlist width multiplier "
                          "(0 = the servable's published value)")
-    ap.add_argument("--funnel-pallas", default="",
-                    choices=("", "on", "off", "auto"),
-                    help="the fused Pallas score/top-k retrieval kernel: "
-                         "on | off | auto; '' = auto")
     ap.add_argument(
         "--slo", default="",
         help="SLO control plane (serve/control/): inline JSON or @file "

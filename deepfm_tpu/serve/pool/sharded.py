@@ -107,11 +107,11 @@ def make_serve_context(
 
     from ...models.base import get_model, table_rows
     from ...parallel.mesh import mesh_shape
-    from ...parallel.spmd import _spec_for_leaf, _window_multiple, padded_vocab
+    from ...parallel.spmd import _spec_for_leaf, padded_vocab
 
     dp, mp = mesh_shape(mesh)
     true_vocab = cfg.model.feature_size
-    pv = padded_vocab(true_vocab, mp, _window_multiple(cfg))
+    pv = padded_vocab(true_vocab, mp)
     cfg = cfg.with_overrides(
         model={"feature_size": pv},
         mesh={"data_parallel": dp, "model_parallel": mp},

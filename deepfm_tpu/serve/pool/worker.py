@@ -177,7 +177,6 @@ class GroupMember:
         funnel_return_n: int = 0,
         funnel_retrieval: str = "",
         funnel_oversample: int = 0,
-        funnel_pallas: str = "",
         precompile: bool = True,
         registry: MetricsRegistry | None = None,
         tenants=None,
@@ -231,8 +230,7 @@ class GroupMember:
             self._scorer = FunnelScorer(
                 servable_dir, mesh, top_k=funnel_top_k,
                 return_n=funnel_return_n, retrieval=funnel_retrieval,
-                oversample=funnel_oversample, pallas=funnel_pallas,
-                buckets=buckets,
+                oversample=funnel_oversample, buckets=buckets,
                 max_wait_ms=max_wait_ms, max_queue_rows=max_queue_rows,
                 admission=self.admission,
                 precompile=False, name=f"recommend[{group}/{member}]",

@@ -704,7 +704,6 @@ def serve_pool(
     reload_url: str | None = None, reload_interval_secs: float = 2.0,
     funnel_top_k: int = 0, funnel_return_n: int = 0,
     funnel_retrieval: str = "", funnel_oversample: int = 0,
-    funnel_pallas: str = "",
     funnel_data_parallel: int = 1, funnel_model_parallel: int = 0,
     max_restarts: int = 10,
     ready: threading.Event | None = None,
@@ -766,7 +765,6 @@ def serve_pool(
                     funnel_return_n=funnel_return_n,
                     funnel_retrieval=funnel_retrieval,
                     funnel_oversample=funnel_oversample,
-                    funnel_pallas=funnel_pallas,
                     funnel_data_parallel=funnel_data_parallel,
                     funnel_model_parallel=funnel_model_parallel,
                 )
@@ -855,7 +853,6 @@ def serve_forever(
     reload_url: str | None = None, reload_interval_secs: float = 2.0,
     funnel_top_k: int = 0, funnel_return_n: int = 0,
     funnel_retrieval: str = "", funnel_oversample: int = 0,
-    funnel_pallas: str = "",
     funnel_data_parallel: int = 1, funnel_model_parallel: int = 0,
     trace_sample_rate: float = DEFAULT_SAMPLE_RATE,
     trace_export: str | None = None,
@@ -901,7 +898,6 @@ def serve_forever(
             reload_interval_secs=reload_interval_secs,
             top_k=funnel_top_k, return_n=funnel_return_n,
             retrieval=funnel_retrieval, oversample=funnel_oversample,
-            pallas=funnel_pallas,
             data_parallel=funnel_data_parallel,
             model_parallel=funnel_model_parallel,
             trace_sample_rate=trace_sample_rate,
@@ -1152,12 +1148,6 @@ def main(argv: list[str] | None = None) -> int:
              "0 = the servable's published value)",
     )
     ap.add_argument(
-        "--funnel-pallas", default="", choices=("", "on", "off", "auto"),
-        help="the fused Pallas score/top-k retrieval kernel: on (the "
-             "compiled kernel; raises where the compiler refuses it) | off "
-             "| auto (the lax scan today); '' = auto",
-    )
-    ap.add_argument(
         "--funnel-dp", type=int, default=1,
         help="funnel mesh: request-batch shard factor (buckets must "
              "divide by it)",
@@ -1208,8 +1198,7 @@ def main(argv: list[str] | None = None) -> int:
             funnel_return_n=args.funnel_return_n,
             funnel_retrieval=args.funnel_retrieval,
             funnel_oversample=args.funnel_oversample,
-            funnel_pallas=args.funnel_pallas,
-            funnel_data_parallel=args.funnel_dp,
+                funnel_data_parallel=args.funnel_dp,
             funnel_model_parallel=args.funnel_mp,
         )
         return 0
@@ -1224,7 +1213,6 @@ def main(argv: list[str] | None = None) -> int:
         funnel_return_n=args.funnel_return_n,
         funnel_retrieval=args.funnel_retrieval,
         funnel_oversample=args.funnel_oversample,
-        funnel_pallas=args.funnel_pallas,
         funnel_data_parallel=args.funnel_dp,
         funnel_model_parallel=args.funnel_mp,
         trace_sample_rate=args.trace_sample,

@@ -69,11 +69,6 @@ def resolve_tiered(cfg: Config) -> dict:
 
 
 def _check_cfg(cfg: Config) -> None:
-    if cfg.model.fused_kernel != "off":
-        raise ValueError(
-            "tiered embeddings require fused_kernel='off' (the fused "
-            "kernel gathers a resident table)"
-        )
     if cfg.optimizer.name.lower() != "adam":
         raise ValueError(
             "tiered embeddings co-evict lazy-Adam moments; optimizer "

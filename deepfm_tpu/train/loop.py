@@ -873,8 +873,6 @@ def run_task(cfg: Config):
             if cfg.run.funnel_oversample != 4:
                 argv += ["--funnel-oversample",
                          str(cfg.run.funnel_oversample)]
-            if cfg.run.funnel_pallas != "auto":
-                argv += ["--funnel-pallas", cfg.run.funnel_pallas]
             if cfg.flywheel.enabled:
                 # data flywheel (deepfm_tpu/flywheel): the router logs
                 # a hash-stable sample of scored impressions for the
@@ -909,8 +907,6 @@ def run_task(cfg: Config):
                                   else cfg.run.funnel_retrieval),
                 funnel_oversample=(0 if cfg.run.funnel_oversample == 4
                                    else cfg.run.funnel_oversample),
-                funnel_pallas=("" if cfg.run.funnel_pallas == "auto"
-                               else cfg.run.funnel_pallas),
             )
             return None
         serve_forever(
@@ -931,8 +927,6 @@ def run_task(cfg: Config):
                               else cfg.run.funnel_retrieval),
             funnel_oversample=(0 if cfg.run.funnel_oversample == 4
                                else cfg.run.funnel_oversample),
-            funnel_pallas=("" if cfg.run.funnel_pallas == "auto"
-                           else cfg.run.funnel_pallas),
         )
         return None
     if task == "train":

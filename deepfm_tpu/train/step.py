@@ -219,8 +219,7 @@ def _make_lazy_train_step(cfg: Config, model, tx) -> Callable:
             new_rest = optax.apply_updates(rest, updates)
 
             # one sort shared by the tables (identical ids); clip to the
-            # smallest table (fm_v may carry aligned-window padding rows
-            # beyond fm_w)
+            # smallest table
             min_rows = min(tables[k].shape[0] for k in keys)
             flat_ids = jnp.clip(ids.reshape(-1), 0, min_rows - 1)
             segs = shared_segments(flat_ids, min_rows)

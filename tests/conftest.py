@@ -8,7 +8,7 @@ multi-chip sharding is exercised in CI without a pod.
 import os
 
 # Tests run on the CPU: set DEEPFM_TEST_TPU=1 to run them on the chip
-# instead (tests/test_pallas_ctr.py then compiles the kernel for real).
+# instead.
 if not os.environ.get("DEEPFM_TEST_TPU"):
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")

@@ -2137,7 +2137,7 @@ class TestFunnelContract:
 
         from deepfm_tpu.analysis.trace_audit import audit_funnel
         from deepfm_tpu.models.two_tower import encode_tower
-        from deepfm_tpu.ops.pallas_retrieval import score_topk_tiles
+        from deepfm_tpu.funnel.quant import score_topk_tiles
         from deepfm_tpu.parallel.mesh import DATA_AXIS
 
         def gathering_builder(ctx):

@@ -87,51 +87,6 @@ def test_more_than_one_jax_process_per_tpu_host_is_refused(monkeypatch):
     refuse_shared_chip(2, "serve --workers")
 
 
-def test_funnel_pallas_on_raises_instead_of_degrading():
-    """``pallas="on"`` means the compiled kernel: where the compiler
-    refuses it (here: the CPU backend has no Mosaic) building the
-    executable raises with the compiler's words — it does not fall back to
-    the lax scan or to interpret mode.  ``"auto"`` and ``"off"`` resolve to
-    the lax scan."""
-    import jax
-    import numpy as np
-
-    from deepfm_tpu.core.config import Config
-    from deepfm_tpu.funnel.index import (
-        abstract_funnel_payload, build_retrieve_with, make_funnel_context,
-    )
-    from deepfm_tpu.serve.pool.sharded import build_serve_mesh
-
-    rank_cfg = Config.from_dict({"model": {
-        "feature_size": 64, "field_size": 5, "embedding_size": 4,
-        "deep_layers": (8,), "dropout_keep": (1.0,),
-        "compute_dtype": "float32"}})
-    query_cfg = Config.from_dict({"model": {
-        "model_name": "two_tower", "user_vocab_size": 50,
-        "item_vocab_size": 40, "user_field_size": 2, "item_field_size": 2,
-        "tower_layers": (16,), "tower_dim": 8, "embedding_size": 4,
-        "compute_dtype": "float32"}})
-    mesh = build_serve_mesh(1, 2)
-
-    def lowered(pallas):
-        ctx = make_funnel_context(
-            rank_cfg, query_cfg, mesh, capacity=48, top_k=6,
-            retrieval="int8", oversample=2, pallas=pallas)
-        fn = build_retrieve_with(ctx)
-        queries = (jax.ShapeDtypeStruct((4, 2), np.int32),
-                   jax.ShapeDtypeStruct((4, 2), np.float32))
-        return fn, lambda: fn.lower(abstract_funnel_payload(ctx), *queries)
-
-    fn, lower = lowered("on")
-    assert fn.kernel_engaged
-    with pytest.raises(ValueError, match="interpret mode"):
-        lower()
-    for setting in ("auto", "off"):
-        fn, lower = lowered(setting)
-        assert not fn.kernel_engaged
-        lower()
-
-
 def test_resume_across_the_data_parallel_boundary(tmp_path):
     """A checkpoint written under [1,4] (dp=1: replicated optimizer state)
     resumes under [2,2] (dp=2: the dp-sharded layout) through the trainer's

@@ -141,7 +141,6 @@ def test_serve_task_dispatch(monkeypatch):
         # defaults are not operator overrides
         "funnel_retrieval": "",
         "funnel_oversample": 0,
-        "funnel_pallas": "",
     }
 
 

@@ -1,5 +1,7 @@
 """Config schema tests."""
 
+import pytest
+
 from deepfm_tpu.core.config import Config
 
 
@@ -19,6 +21,40 @@ def test_from_dict_ignores_unknown_fields(caplog):
     assert cfg.model.feature_size == 99
     assert any("unknown field" in r.message for r in caplog.records)
 
+
+
+@pytest.mark.parametrize("value", ["off", "auto", "on"])
+@pytest.mark.parametrize("section,key", [("model", "fused_kernel"),
+                                         ("run", "funnel_pallas")])
+def test_retired_kernel_knobs_still_load(caplog, section, key, value):
+    """The two kernel knobs retired in PR 46: a saved config naming either,
+    at any value it could hold, loads to the config without it and names
+    the key it dropped (before, "on" made the SPMD DeepFM forward raise)."""
+    import logging
+
+    base = {"model": {"feature_size": 99}}
+    saved = {"model": dict(base["model"])}
+    saved.setdefault(section, {})[key] = value
+    with caplog.at_level(logging.WARNING):
+        cfg = Config.from_dict(saved)
+    assert cfg == Config.from_dict(base)
+    assert any(f"{section}.{key}" in r.getMessage() for r in caplog.records)
+
+
+def test_a_saved_config_loads_with_no_unknown_field(tmp_path, caplog):
+    """A config.json as export_servable writes it (serve/export.py) loads
+    back through the servable's loader equal and without a warning: no
+    field the code writes is one it would drop."""
+    import json
+    import logging
+
+    from deepfm_tpu.serve.export import _load_config
+
+    cfg = Config.from_dict({"model": {"feature_size": 300, "field_size": 6}})
+    (tmp_path / "config.json").write_text(json.dumps(cfg.to_dict()))
+    with caplog.at_level(logging.WARNING):
+        assert _load_config(str(tmp_path)) == cfg
+    assert not [r for r in caplog.records if "unknown field" in r.getMessage()]
 
 # -- cross-section validation (exchange capacity / sort bound / tiers) ------
 
@@ -112,9 +148,6 @@ def test_tiered_geometry_validation():
         })
     with pytest.raises(ValueError, match="tiered_page_rows"):
         Config.from_dict({"model": {"tiered_page_rows": 0}})
-    with pytest.raises(ValueError, match="fused_kernel"):
-        Config.from_dict({"model": {"tiered_embeddings": True,
-                                    "fused_kernel": "on"}})
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         Config.from_dict({

@@ -1,6 +1,6 @@
-"""Quantized int8 retrieval tier: the codec (funnel/quant.py), the
-recall harness (funnel/recall.py), the screened scan + Pallas kernel
-(ops/pallas_retrieval.py), the int8 branch of build_retrieve_with on
+"""Quantized int8 retrieval tier: the codec and the screened scan
+(funnel/quant.py), the recall harness (funnel/recall.py), the int8
+branch of build_retrieve_with on
 both mesh orientations, the publish-time recall gate, mode-skew staging
 refusal, the degraded-oversample shed path, and the config/CLI knobs."""
 
@@ -146,7 +146,7 @@ class TestQuantCodec:
 
 
 # ---------------------------------------------------------------------------
-# the screened scan and the kernel
+# the screened scan
 
 
 def _topk_ref(emb, codes, scales, ids, u, kos):
@@ -176,14 +176,17 @@ class TestScoreTopkTiles:
         u = rng.normal(size=(3, d)).astype(np.float32)
         return emb, codes, scales, ids, u
 
-    @pytest.mark.parametrize("tile,group", [(1024, 16),   # screened
-                                            (16, 128)])   # plain path
-    def test_selection_is_exact_with_ties_and_pads(self, tile, group):
+    @pytest.mark.parametrize("rows,tile,group", [
+        (4096, 1024, 16),   # screened
+        (4096, 16, 128),    # plain path
+        (512, 128, 128),    # plain path, four tiles of a small shard
+    ])
+    def test_selection_is_exact_with_ties_and_pads(self, rows, tile, group):
         import jax
 
-        from deepfm_tpu.ops.pallas_retrieval import score_topk_tiles
+        from deepfm_tpu.funnel.quant import score_topk_tiles
 
-        emb, codes, scales, ids, u = self._data()
+        emb, codes, scales, ids, u = self._data(r=rows)
         kos = 16
         s, r = jax.jit(lambda u, c, sc, i: score_topk_tiles(
             u, c, sc, i, kos=kos, tile=tile, screen_group=group,
@@ -192,21 +195,6 @@ class TestScoreTopkTiles:
         np.testing.assert_array_equal(np.asarray(r), ref_r)
         np.testing.assert_allclose(np.asarray(s), ref_s,
                                    rtol=1e-5, atol=1e-6)
-
-    def test_kernel_interpret_parity(self):
-        from deepfm_tpu.ops.pallas_retrieval import (
-            retrieval_topk_kernel, score_topk_tiles,
-        )
-
-        _, codes, scales, ids, u = self._data(r=512)
-        kos = 16
-        s1, r1 = score_topk_tiles(u, codes, scales, ids, kos=kos,
-                                  tile=128)
-        s2, r2 = retrieval_topk_kernel(u, codes, scales, ids, kos=kos,
-                                       tile=128, interpret=True)
-        np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
-        np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                                   rtol=1e-4, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -454,7 +442,6 @@ class TestServeInt8:
         assert snap["retrieval_mode"] == "int8"
         assert snap["oversample"] == OS
         assert snap["oversample_effective"] == OS
-        assert snap["kernel_engaged"] is False      # CPU host
         # saved_bytes is honest: at this toy capacity the rescore gather
         # outweighs the code savings, so it clamps to 0 (corpus-scale
         # saved > 0 is pinned by test_score_bytes_estimate_is_mode_aware)
@@ -560,10 +547,6 @@ class TestQuantConfigAndCLI:
         with pytest.raises(ValueError, match="funnel_retrieval"):
             Config.from_dict({"run": {"funnel_retrieval": "int4"}})
 
-    def test_pallas_value_raises(self):
-        with pytest.raises(ValueError, match="funnel_pallas"):
-            Config.from_dict({"run": {"funnel_pallas": "maybe"}})
-
     def test_oversample_floor_raises(self):
         with pytest.raises(ValueError, match="funnel_oversample"):
             Config.from_dict({"run": {"funnel_oversample": 0}})
@@ -590,10 +573,8 @@ class TestQuantConfigAndCLI:
             "--funnel_retrieval", "int8",
             "--funnel_oversample", "2",
             "--funnel_min_recall", "0.9",
-            "--funnel_pallas", "off",
             "--no_env",
         ])
         assert cfg.run.funnel_retrieval == "int8"
         assert cfg.run.funnel_oversample == 2
         assert cfg.run.funnel_min_recall == 0.9
-        assert cfg.run.funnel_pallas == "off"

@@ -32,7 +32,7 @@ def _cfg(**model_over) -> Config:
         "model": {
             "feature_size": V, "field_size": F, "embedding_size": K,
             "deep_layers": (16, 8), "dropout_keep": (0.5, 0.5),
-            "fused_kernel": "off", "tiered_embeddings": True,
+            "tiered_embeddings": True,
             "tiered_page_rows": 64, **model_over,
         },
         "optimizer": {"lazy_embedding_updates": True,
