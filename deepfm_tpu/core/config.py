@@ -186,7 +186,8 @@ class ModelConfig:
     batch_norm: bool = False          # ps:64-66
     batch_norm_decay: float = 0.9     # ps:67-69
     l2_reg: float = 0.0001            # ps:57; applied to FM_W/FM_V only (ps:275-279)
-    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe | evabyte | keye_vl2
+    # deepfm | xdeepfm | dcnv2 | two_tower | lfm2_moe | evabyte | keye_vl2 |
+    # nemotron_h
     model_name: str = "deepfm"
     # xDeepFM CIN layer sizes / DCN-v2 cross depth (ignored by plain deepfm)
     cin_layers: tuple[int, ...] = (128, 128)
@@ -220,7 +221,8 @@ class ModelConfig:
     routed_scaling_factor: float = 1.0
     num_attention_heads: int = 0
     num_key_value_heads: int = 0
-    conv_L_cache: int = 3             # the short convolution's kernel
+    # the short convolution's kernel (nemotron_h: its mixer's ``conv_kernel``)
+    conv_L_cache: int = 3
     norm_eps: float = 1e-5
     rope_theta: float = 1_000_000.0
     # byte family (model_name="evabyte", models/evabyte.py; it also reads
@@ -230,7 +232,8 @@ class ModelConfig:
     # them): what the absent ones would add to a layer's output is left out
     heads_held: int = 0
     window_size: int = 0              # EVA: tokens a window of exact keys
-    chunk_size: int = 0               # EVA: tokens a summarised chunk
+    # EVA: tokens a summarised chunk; nemotron_h: positions a chunk of the scan
+    chunk_size: int = 0
     num_pred_heads: int = 0           # output heads: head p scores byte t+1+p
     # selected-keys family (model_name="keye_vl2", models/keye_vl2.py; it
     # also reads layer_types — one "selected_attention" a layer —, the expert
@@ -244,6 +247,18 @@ class ModelConfig:
     # what the router makes of its logits before the top-k: "sigmoid" (the
     # token family's) | "softmax" over all ``num_experts``
     router_score: str = "sigmoid"
+    # hybrid family (model_name="nemotron_h", models/nemotron_h.py; it also
+    # reads the expert and attention sizes, head_dim, norm_eps, conv_L_cache
+    # — the mixer's convolution — and chunk_size — the scan's chunk — above).
+    # One character a layer: "M" a Mamba-2 mixer, "E" a latent expert layer,
+    # "*" attention
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0          # the mixer's heads held here
+    mamba_head_dim: int = 0
+    n_groups: int = 0                 # groups of B and C held here
+    ssm_state_size: int = 0
+    moe_latent_size: int = 0          # the space the routed experts work in
+    moe_shared_expert_intermediate_size: int = 0
     # compute dtype for the MLP/FM math (params stay f32; bf16 feeds the MXU)
     compute_dtype: str = "bfloat16"
     # "scatter" | "segsum": selects nothing since PR 27.  The chip decided

@@ -646,7 +646,12 @@ STEP_SCOPES = ("lookup", "fm", "cin", "cross", "mlp", "tower", "loss",
                # the selected-keys family's (ops/indexer.py, models/keye_vl2.py):
                # the indexer's projections and score blocks, the top-k and the
                # mask, the kernel under that mask, the indexer's own loss
-               "indexer", "index_select", "selected_attention", "index_loss")
+               "indexer", "index_select", "selected_attention", "index_loss",
+               # the hybrid family's (ops/ssd.py, models/nemotron_h.py): the
+               # Mamba-2 mixer, its convolution and its scan inside it, the
+               # latent experts' two projections, the shared expert
+               "ssm_mixer", "ssm_conv", "ssd_scan", "latent_proj",
+               "shared_expert")
 
 
 def scope_of(op_name: str) -> tuple[str | None, str | None]:

@@ -1,4 +1,5 @@
-"""Rows grouped by expert, and the grouped products over the experts held.
+"""Rows grouped by expert, and the grouped products over the experts held:
+the gated SwiGLU expert and the relu² expert without a gate.
 
 No capacity and no dropped row: the assignments are sorted so that each held
 expert's rows are contiguous, the held ones first, and the caller's buffer is
@@ -34,6 +35,14 @@ def group_rows(expert_of, held: int):
     return order, sizes, jnp.arange(a) < jnp.sum(sizes)
 
 
+def _grouped_product(x, w, sizes, keep):
+    """Each live row of ``x`` times its group's ``w`` [held, in, out]."""
+    # the grouped product leaves the rows past the live prefix unwritten:
+    # zeroed before anything nonlinear reads them, so that neither way of
+    # differentiation multiplies by what lies there
+    return jnp.where(keep, lax.ragged_dot(x, w.astype(x.dtype), sizes), 0)
+
+
 def grouped_swiglu(rows, w1, w3, w2, sizes, live):
     """``W₂ᵉ(silu(W₁ᵉx) ⊙ W₃ᵉx)`` of every live row ``x`` with its group's
     expert ``e``: rows [R, h] grouped as ``sizes`` says (R any prefix of the
@@ -42,13 +51,19 @@ def grouped_swiglu(rows, w1, w3, w2, sizes, live):
     in float32, the products in the rows' dtype."""
     keep = live[:, None]
     rows = jnp.where(keep, rows, 0)
-
-    def product(x, w):
-        # the grouped product leaves the rows past the live prefix unwritten:
-        # zeroed before anything nonlinear reads them, so that neither way of
-        # differentiation multiplies by what lies there
-        return jnp.where(keep, lax.ragged_dot(x, w.astype(x.dtype), sizes), 0)
-
-    a, b = product(rows, w1), product(rows, w3)
+    a = _grouped_product(rows, w1, sizes, keep)
+    b = _grouped_product(rows, w3, sizes, keep)
     h = jax.nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)
-    return product(h.astype(rows.dtype), w2)
+    return _grouped_product(h.astype(rows.dtype), w2, sizes, keep)
+
+
+def grouped_relu2(rows, w1, w2, sizes, live):
+    """``W₂ᵉ relu(W₁ᵉx)²`` — the expert without a gate — of every live row
+    ``x`` with its group's expert ``e``, grouped as ``grouped_swiglu``'s rows
+    are: w1 [held, h, m], w2 [held, m, h] -> [R, h]; zero past the live
+    prefix.  The square in float32, the products in the rows' dtype."""
+    keep = live[:, None]
+    rows = jnp.where(keep, rows, 0)
+    a = _grouped_product(rows, w1, sizes, keep)
+    h = jnp.square(jax.nn.relu(a.astype(jnp.float32)))
+    return _grouped_product(h.astype(rows.dtype), w2, sizes, keep)

@@ -39,7 +39,9 @@ _CONTAINER = re.compile(r" = .*? (?:while|conditional|call)\(")
 # a Pallas call's instruction carries the kernel's name and no ``op_name``
 KERNEL_SCOPES = {"splash_mha_fwd_residuals": "attention kernel",
                  "splash_mha_fwd_no_residuals": "attention kernel",
-                 "splash_mha_dkv_no_residuals": "attention kernel (backward)"}
+                 "splash_mha_dkv_no_residuals": "attention kernel (backward)",
+                 "ssd_scan_fwd": "ssd scan kernel",
+                 "ssd_scan_bwd": "ssd scan kernel (backward)"}
 
 
 def op_names(hlo_text: str) -> dict:

@@ -278,6 +278,34 @@ def _keye_vl2_leaves() -> dict:
                for k, shape in layer.items()}}
 
 
+def _nemotron_h_leaves() -> dict:
+    """The contract between ``models/nemotron_h.py`` and
+    ``perf/reference/nemotron_h.py`` at the published widths: published
+    layers 26–36 (``EMEMEMEMEM*``), Mamba heads 0–15 of 128 (group 0 of 8),
+    experts 0–7 of 512 in the 1,024-wide latent, query heads 0–3 and
+    key-value head 0, the head untied."""
+    kinds = {
+        "M": {"mixer/in_proj": (4096, 2320), "mixer/conv": (4, 1280),
+              "mixer/conv_bias": (1280,), "mixer/A_log": (16,),
+              "mixer/dt_bias": (16,), "mixer/D": (16,),
+              "mixer/norm": (1024,), "mixer/out_proj": (1024, 4096)},
+        "E": {"router/gate": (4096, 512), "latent/down": (4096, 1024),
+              "latent/up": (1024, 4096), "experts/w1": (8, 1024, 2688),
+              "experts/w2": (8, 2688, 1024),
+              "shared_expert/w1": (4096, 5376),
+              "shared_expert/w2": (5376, 4096)},
+        "*": {"attention/q_proj": (4096, 512),
+              "attention/k_proj": (4096, 128),
+              "attention/v_proj": (4096, 128),
+              "attention/o_proj": (512, 4096)},
+    }
+    return {"tok_embedding": (16384, 4096), "lm_head": (4096, 16384),
+            "out_norm": (4096,),
+            **{f"layer_{l}/{k}": shape
+               for l, kind in enumerate("EMEMEMEMEM*")
+               for k, shape in {"norm": (4096,), **kinds[kind]}.items()}}
+
+
 # per family: the TRUE rows of its table share, its parameter leaves, the
 # non-trainable state beside them, its placed batch ((b, f) the traffic's
 # batch and the configuration's field_size) and its step's metrics
@@ -318,6 +346,17 @@ CONTRACTS = {
                     "experts_compact_share", "index_loss",
                     "index_selected_share", "index_kernel_share",
                     "blocks_products_kept_share", "loss_per_shard"},
+    },
+    "nemotron_h": {
+        "true_feature_size": 16384,
+        "leaves": _nemotron_h_leaves(),
+        # the routers' selection biases: drawn from the seed, never stepped
+        "model_state": {f"layer_{l}/expert_bias": (512,)
+                        for l in (0, 2, 4, 6, 8)},
+        "batch": lambda b, f: {"feat_ids": ((b, f), "int32")},
+        "metrics": {"loss", "ce", "rows_held_share", "expert_load_max_share",
+                    "experts_compact_share", "blocks_products_kept_share",
+                    "loss_per_shard"},
     },
 }
 
