@@ -4,9 +4,11 @@ process got.
 ``configure_runtime()`` is called once by each entry point (the launcher
 CLI, ``serve/server.py main``, the pool member, ``perf/entries/train.py``,
 ``__graft_entry__``) BEFORE the first jax backend initialisation: it places
-the persistent compile cache and, on the CPU platform only, relaxes XLA:CPU's
-collective watchdogs.  It never initialises a backend itself, so a
-supervisor that only forks workers stays off the chip.
+the persistent compile cache, on the CPU platform only relaxes XLA:CPU's
+collective watchdogs, and elsewhere starts importing Pallas on a thread of
+its own, beside the chip's opening (``await_pallas``).  It never initialises
+a backend itself, so a supervisor that only forks workers stays off the
+chip.
 
 ``runtime_report()`` is the one shape in which trainer, server and pool
 member say where they ran: platform, device kind and count, mesh, jax
@@ -20,6 +22,7 @@ from __future__ import annotations
 
 import glob
 import os
+import threading
 
 # <checkout>/.jax_cache — derived from the package's own location so every
 # process of one checkout agrees on it whatever its working directory
@@ -111,6 +114,44 @@ def _relax_cpu_collective_timeouts(
         os.environ["XLA_FLAGS"] = " ".join([flags] + add).strip()
 
 
+# the Pallas import started by ``configure_runtime``: (thread, its error)
+_pallas_import: tuple[threading.Thread, list] | None = None
+
+
+def _start_pallas_import() -> None:
+    """Import ``jax.experimental.pallas`` and its TPU module on a daemon
+    thread, once: about a second of the host's (jax's Mosaic-GPU interpreter
+    among it, whatever the platform) that would otherwise land in front of
+    the first step that traces a kernel."""
+    global _pallas_import
+    if _pallas_import is not None:
+        return
+    error: list = []
+
+    def run():
+        try:
+            from jax.experimental import pallas  # noqa: F401
+            from jax.experimental.pallas import tpu  # noqa: F401
+        except BaseException as e:  # raised again where it is awaited
+            error.append(e)
+
+    thread = threading.Thread(target=run, name="pallas-import", daemon=True)
+    _pallas_import = (thread, error)
+    thread.start()
+
+
+def await_pallas() -> None:
+    """Join the Pallas import ``configure_runtime`` started, if it did, and
+    raise what it raised.  A kernel's first trace calls this before its own
+    import of Pallas."""
+    if _pallas_import is None:
+        return
+    thread, error = _pallas_import
+    thread.join()
+    if error:
+        raise error[0]
+
+
 def configure_runtime() -> str:
     """Process-wide jax set-up; call before the first backend init.
 
@@ -128,6 +169,8 @@ def configure_runtime() -> str:
     * jax's trace, lowering, compile and cache-load events go to the
       training path's span recorder from here on
       (``obs/trace.install_compile_listener``).
+    * Off the CPU, Pallas is imported on a thread of its own while the
+      caller opens the chip (``await_pallas`` joins it).
 
     Returns the cache directory in use."""
     import jax
@@ -137,6 +180,8 @@ def configure_runtime() -> str:
     install_compile_listener()
     if _requested_platform() == "cpu":
         _relax_cpu_collective_timeouts()
+    else:
+        _start_pallas_import()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -392,6 +392,11 @@ class DistinctRows:
             lambda s, p: parts_of(_apart(s, self.tails), _apart(p, self.tails)),
             targets)
 
+    def parts(self, combined) -> dict:
+        """{key: the table's columns of ``combined``}, each ``[n_pad,
+        ...row]``."""
+        return dict(zip(self.keys, _apart(combined, self.tails)))
+
 
 def distinct_rows_gather(named: dict, sinks):
     """``(gather, taken)``: a local row gather (``dense_lookup``'s signature,

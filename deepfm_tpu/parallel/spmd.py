@@ -23,6 +23,8 @@ the only (infinitesimal) effect.
 
 from __future__ import annotations
 
+import collections
+import functools
 import logging
 from typing import Any, Callable, Mapping, NamedTuple
 
@@ -259,13 +261,16 @@ def create_spmd_state(ctx: SPMDContext, key: jax.Array | None = None) -> TrainSt
 
 
 @jax.named_scope("l2_penalty")
-def _sharded_penalty(model: ModelDef, params: dict, l2_reg: float) -> jnp.ndarray:
+def _sharded_penalty(model: ModelDef, params: dict, l2_reg: float,
+                     skip=()) -> jnp.ndarray:
     """Reference loss regularizer (ps:275-279) over row-sharded tables:
     ½·psum_model(Σ local²) per declared table — ``ModelDef.l2_penalty`` for
-    the sharded case."""
+    the sharded case.  The tables of ``skip`` are left out (their Adam
+    kernel sums their squares, ``_adam_by_kernel``)."""
     total = jnp.zeros(())
     for k in model.tables:
-        total = total + sharded_l2(params[k])
+        if k not in skip:
+            total = total + sharded_l2(params[k])
     return l2_reg * total
 
 
@@ -304,9 +309,10 @@ def _pmean_grads(model: ModelDef, grads: dict) -> dict:
 
 
 def _local_loss(cfg: Config, model: ModelDef, params, model_state, batch,
-                rng, train, gather=None):
-    """One data shard's loss: the family's data loss plus the table L2
-    (``gather``: the lookup's shard-local gather, ``sharded_lookup``)."""
+                rng, train, gather=None, skip_penalty=()):
+    """One data shard's loss: the family's data loss plus the table L2 but
+    that of ``skip_penalty`` (``gather``: the lookup's shard-local gather,
+    ``sharded_lookup``)."""
     data_loss, new_state, outputs = model.loss(
         params,
         model_state,
@@ -316,7 +322,8 @@ def _local_loss(cfg: Config, model: ModelDef, params, model_state, batch,
         rng=rng,
         lookup_fn=lookup_fn_from_config(cfg, gather=gather),
     )
-    loss = data_loss + _sharded_penalty(model, params, cfg.model.l2_reg)
+    loss = data_loss + _sharded_penalty(model, params, cfg.model.l2_reg,
+                                        skip_penalty)
     return loss, (data_loss, outputs, new_state)
 
 
@@ -364,7 +371,26 @@ def _is_adam(x) -> bool:
     return isinstance(x, optax.ScaleByAdamState)
 
 
-def _pre_add_rows(cfg: Config, opt_state, params, grads, taken, row_grads):
+def _adam_of(opt_state) -> optax.ScaleByAdamState:
+    (adam,) = [x for x in jax.tree_util.tree_leaves(opt_state, is_leaf=_is_adam)
+               if _is_adam(x)]
+    return adam
+
+
+def _with_adam(opt_state, adam):
+    return jax.tree_util.tree_map(
+        lambda x: adam if _is_adam(x) else x, opt_state, is_leaf=_is_adam)
+
+
+def _penalty_coefficient(cfg: Config):
+    """``c`` of the L2 penalty's gradient ``d = c·p`` of a table, the step's
+    own penalty differentiated, whatever the mesh does to a psum's
+    transpose."""
+    return jax.grad(lambda x: cfg.model.l2_reg * sharded_l2(x))(jnp.ones(()))
+
+
+def _pre_add_rows(cfg: Config, opt_state, params, grads, taken, row_grads,
+                  by_kernel=()):
     """Dense Adam without a dense gradient.  A table's gradient is ``g = d +
     s``: ``d``, the L2 penalty's, elementwise in the table (``c·p``: XLA forms
     it inside Adam's pass), and ``s``, non-zero on the step's distinct rows
@@ -391,24 +417,25 @@ def _pre_add_rows(cfg: Config, opt_state, params, grads, taken, row_grads):
     the same trip: a table of rows adds into its ``mu`` and ``nu``; a table
     of scalars (FM_W) keeps a table-shaped gradient, written in the same
     trips — two scalar adds a distinct row in place of one would cost more
-    than its 50 MB pass saves (``_chunks``' rule once more).  Returns the
-    optimizer state and the gradients to hand the chain."""
+    than its 50 MB pass saves (``_chunks``' rule once more).  The tables of
+    ``by_kernel`` take none of it: their rows go to Adam's kernel
+    (``_adam_by_kernel``).  Returns the optimizer state and the gradients to
+    hand the chain."""
     b1, b2 = cfg.optimizer.adam_b1, cfg.optimizer.adam_b2
     # nu's addend is g² − d², at least −d²: where s cancels d to the last
     # bits (g under 4e-3 of d: no gradient to speak of) the pass's own
     # (1−b2)·d² must still land on the positive side of a dozen roundings,
     # or Adam takes the root of a negative number
     nu_floor = -(1 - b2) / b2 * (1 - 2.0 ** -16)
-    # d = c·p with the step's own penalty differentiated, whatever the mesh
-    # does to a psum's transpose
-    c = jax.grad(lambda x: cfg.model.l2_reg * sharded_l2(x))(jnp.ones(()))
-    (adam,) = [x for x in jax.tree_util.tree_leaves(opt_state, is_leaf=_is_adam)
-               if _is_adam(x)]
+    c = _penalty_coefficient(cfg)
+    adam = _adam_of(opt_state)
     mu, nu, grads = dict(adam.mu), dict(adam.nu), dict(grads)
 
-    def parts_of(s_parts, p_parts):
+    def parts_of(keys, s_parts, p_parts):
         parts = []
-        for s, p in zip(s_parts, p_parts):
+        for k, s, p in zip(keys, s_parts, p_parts):
+            if k in by_kernel:
+                continue
             if s.ndim == 1:
                 parts.append(s)
                 continue
@@ -418,19 +445,90 @@ def _pre_add_rows(cfg: Config, opt_state, params, grads, taken, row_grads):
         return parts
 
     for rows, combined in zip(taken, row_grads):
-        added = iter(rows.add(combined, parts_of, [
-            t for k, tail in zip(rows.keys, rows.tails)
-            for t in ((mu[k], nu[k]) if tail else (jnp.zeros_like(params[k]),))
-        ]))
-        for k, tail in zip(rows.keys, rows.tails):
+        keys = [(k, tail) for k, tail in zip(rows.keys, rows.tails)
+                if k not in by_kernel]
+        if not keys:
+            continue
+        added = iter(rows.add(
+            combined, functools.partial(parts_of, rows.keys), [
+                t for k, tail in keys
+                for t in ((mu[k], nu[k]) if tail
+                          else (jnp.zeros_like(params[k]),))]))
+        for k, tail in keys:
             if tail:
                 mu[k], nu[k] = next(added), next(added)
             else:
                 grads[k] = grads[k] + next(added)
-    adam = adam._replace(mu=mu, nu=nu)
-    return jax.tree_util.tree_map(
-        lambda x: adam if _is_adam(x) else x, opt_state, is_leaf=_is_adam
-    ), grads
+    return _with_adam(opt_state, adam._replace(mu=mu, nu=nu)), grads
+
+
+def _by_kernel(params, taken) -> list:
+    """The tables of rows whose Adam step is ``ops/adam_rows.py``'s kernel:
+    the pre-add path's tables (``taken``) read by one lookup call whose row
+    is narrower than one 128-lane tile (``adam_rows.engages``)."""
+    from ..ops import adam_rows
+
+    calls = collections.Counter(k for rows in taken for k in rows.keys)
+    return sorted(k for k, n in calls.items()
+                  if n == 1 and adam_rows.engages(params[k]))
+
+
+def _adam_by_kernel(cfg: Config, params, opt_state, new_params,
+                    new_opt_state, taken, row_grads, by_kernel, after):
+    """Adam's step of each table of ``by_kernel`` by ``ops/adam_rows.py``:
+    its rows and their gradients from the lookup call that took them, the
+    table and its two moments as the step found them, the chain's own
+    scalars at the state's count (``adam_step_scalars``).  What the chain
+    made of those leaves is replaced, and XLA drops it unmade.  ``after``
+    (the loss) orders the kernel behind every other reader of the table,
+    which it overwrites in place.  The kernel also sums the squares of the
+    table it reads: the loss's L2 term of those tables, which the loss
+    left out (``_sharded_penalty``'s ``skip``), comes from there rather
+    than from a pass of its own over the table.  Returns the parameters, the
+    optimizer state and that term."""
+    from ..ops.adam_rows import adam_rows
+    from ..train.optimizer import adam_step_scalars
+
+    opt = cfg.optimizer
+    c = _penalty_coefficient(cfg)
+    adam, new_adam = _adam_of(opt_state), _adam_of(new_opt_state)
+    new_params, mu, nu = dict(new_params), dict(new_adam.mu), dict(new_adam.nu)
+    squares = jnp.zeros(())
+    for rows, combined in zip(taken, row_grads):
+        for k, part in rows.parts(combined).items():
+            if k not in by_kernel:
+                continue
+            bc1, bc2, step, mult = adam_step_scalars(
+                opt, adam.count, k, data_parallel_size=cfg.mesh.data_parallel)
+            p, m, v, _ = lax.optimization_barrier(
+                (params[k], adam.mu[k], adam.nu[k], after))
+            new_params[k], mu[k], nu[k], local = adam_rows(
+                p, m, v, rows.row_of, part, jnp.stack([c, bc1, bc2, step]),
+                b1=opt.adam_b1, b2=opt.adam_b2, eps=opt.adam_eps, mult=mult)
+            squares = squares + local
+    with jax.named_scope("l2_penalty"):
+        penalty = cfg.model.l2_reg * 0.5 * lax.psum(squares, MODEL_AXIS)
+    return (new_params, _with_adam(new_opt_state,
+                                   new_adam._replace(mu=mu, nu=nu)), penalty)
+
+
+def _say_table_update(model: ModelDef, params, by_row, by_kernel) -> None:
+    """The step's table-update paths, once at trace time."""
+    from ..ops.adam_rows import tile_columns
+
+    log = logging.getLogger(__name__)
+    tiles = collections.defaultdict(list)
+    for k in by_kernel:
+        tiles[tile_columns(*params[k].shape)].append(k)
+    for tile, keys in sorted(tiles.items()):
+        log.info("table update: Adam kernel over distinct rows, tables=%s, "
+                 "tile=%d", keys, tile)
+    for how, keys in (
+            ("moments pre-added by distinct rows",
+             [k for k in by_row if k not in by_kernel]),
+            ("dense gradient", sorted(set(model.tables) - set(by_row)))):
+        if keys:
+            log.info("table update: %s, tables=%s", how, keys)
 
 
 def _build_local_train_step(ctx: SPMDContext) -> Callable:
@@ -445,8 +543,12 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
     on the distinct-rows plan has no table-shaped gradient — its distinct
     rows are pre-added into Adam's moments (``_pre_add_rows``); everything
     else keeps the materialised gradient, a table the loss also reads whole
-    (``ModelDef.read_whole``) among it.  Said once at trace time: ``table
-    update: moments pre-added by distinct rows | dense gradient, tables=``."""
+    (``ModelDef.read_whole``) among it.  Of the tables of rows, one whose row
+    is narrower than a 128-lane tile takes its whole Adam step in one Pallas
+    kernel over its distinct rows instead (``_adam_by_kernel``,
+    ``ops/adam_rows.py``).  Said once at trace time: ``table update: Adam
+    kernel over distinct rows, tables=…, tile=… | moments pre-added by
+    distinct rows | dense gradient, tables=``."""
     cfg = ctx.cfg
     model = get_model(cfg.model)
     tx = _build_tx(cfg, ctx.zero_layout)
@@ -461,35 +563,32 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
         step_rng = jax.random.fold_in(state.rng, state.step)
         step_rng = jax.random.fold_in(step_rng, lax.axis_index(DATA_AXIS))
 
-        def loss_fn(params, sinks):
+        def loss_fn(params, sinks, by_kernel=()):
             gather, taken = distinct_rows_gather(
                 {k: params[k] for k in model.tables
                  if k not in model.read_whole}, sinks
             ) if by_rows else (None, [])
             loss, aux = _local_loss(
                 cfg, model, params, state.model_state, batch, step_rng, True,
-                gather=gather,
+                gather=gather, skip_penalty=by_kernel,
             )
             return loss, (aux, taken)
 
         # a sink a call that takes rows, in the shape of its compact buffer
-        sinks = [
-            jnp.zeros(rows.compact.shape, rows.compact.dtype)
-            for rows in jax.eval_shape(
-                lambda params: loss_fn(params, None)[1][1], state.params)
-        ] if by_rows else []
+        shapes = jax.eval_shape(
+            lambda params: loss_fn(params, None)[1][1], state.params
+        ) if by_rows else []
+        sinks = [jnp.zeros(rows.compact.shape, rows.compact.dtype)
+                 for rows in shapes]
+        by_kernel = _by_kernel(state.params, shapes)
         (loss, ((ce, outputs, new_model_state), taken)), (
             grads, row_grads) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True
+            functools.partial(loss_fn, by_kernel=by_kernel), argnums=(0, 1),
+            has_aux=True
         )(state.params, sinks)
         by_row = sorted(k for rows in taken for k in rows.keys
                         if state.params[k].ndim > 1)
-        for how, keys in (
-                ("moments pre-added by distinct rows", by_row),
-                ("dense gradient", sorted(set(model.tables) - set(by_row)))):
-            if keys:
-                logging.getLogger(__name__).info(
-                    "table update: %s, tables=%s", how, keys)
+        _say_table_update(model, state.params, by_row, by_kernel)
         new_model_state = _sync_model_state(new_model_state)
         if ctx.zero_layout:
             # RAW local grads go in — the wrapper reduce-scatters each
@@ -505,11 +604,17 @@ def _build_local_train_step(ctx: SPMDContext) -> Callable:
                 opt_state = state.opt_state
                 if taken:
                     opt_state, grads = _pre_add_rows(
-                        cfg, opt_state, state.params, grads, taken, row_grads)
+                        cfg, opt_state, state.params, grads, taken, row_grads,
+                        by_kernel)
                 updates, new_opt_state = tx.update(
                     grads, opt_state, state.params
                 )
                 new_params = optax.apply_updates(state.params, updates)
+                if by_kernel:
+                    new_params, new_opt_state, penalty = _adam_by_kernel(
+                        cfg, state.params, state.opt_state, new_params,
+                        new_opt_state, taken, row_grads, by_kernel, loss)
+                    loss = loss + penalty
         metrics = _train_metrics(model, loss, ce, outputs, batch)
         new_state = TrainState(
             step=state.step + 1,

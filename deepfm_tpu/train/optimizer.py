@@ -408,6 +408,30 @@ def zero_sharded(
     return ZeroShardedOptimizer(init_fn, update_and_apply)
 
 
+def adam_step_scalars(cfg: OptimizerConfig, count, key: str, *,
+                      data_parallel_size: int = 1):
+    """What ``build_optimizer``'s Adam chain does to one leaf ``key`` at its
+    update ``count`` (the state's count before the step), as scalars:
+    ``(bc1, bc2, step, mult)`` with
+
+        u = step · (mu'/bc1) / (√(nu'/bc2) + eps) · mult
+
+    optax's bias corrections at the incremented count, the schedule's
+    ``−lr`` at ``count`` (float32, as ``scale_by_learning_rate`` forms it)
+    and the embedding lr split (a python float, 1.0 where it does not apply
+    to ``key``).  For a kernel that makes a leaf's Adam step itself
+    (``ops/adam_rows.py``)."""
+    count_inc = optax.safe_increment(count)
+    bc1 = 1 - cfg.adam_b1 ** count_inc
+    bc2 = 1 - cfg.adam_b2 ** count_inc
+    lr = build_lr_schedule(cfg, data_parallel_size=data_parallel_size)
+    step = jnp.asarray(-1 * schedule_value(lr, count), jnp.float32)
+    mult = (cfg.embedding_lr_multiplier
+            if key in EMBEDDING_PARAM_KEYS else 1.0)
+    return (bc1.astype(jnp.float32), bc2.astype(jnp.float32), step,
+            float(mult))
+
+
 def build_optimizer(
     cfg: OptimizerConfig, *, data_parallel_size: int = 1
 ) -> optax.GradientTransformation:

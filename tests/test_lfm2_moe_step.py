@@ -27,7 +27,7 @@ from deepfm_tpu.obs.trace import (
     recomputed_part,
     scope_of,
 )
-from deepfm_tpu.ops import kept
+from deepfm_tpu.ops import adam_rows, kept
 from deepfm_tpu.ops.experts import compact_rows
 from deepfm_tpu.parallel import (
     create_spmd_state,
@@ -73,13 +73,16 @@ def _adam_leaves(state):
     return adam.mu[lfm2_moe.TABLE], adam.nu[lfm2_moe.TABLE]
 
 
-def test_the_tied_table_keeps_the_materialised_gradient_under_adam(caplog):
-    """Adam on [1, 1]: the step would pre-add a table's distinct rows into
-    its moments, and the head reads the same leaf.  The family declares the
+def test_the_tied_table_keeps_the_materialised_gradient_under_adam(
+        caplog, monkeypatch):
+    """Adam on [1, 1]: the step would take a table's distinct rows into its
+    moments, and the head reads the same leaf.  The family declares the
     table read whole, the step says ``dense gradient`` for it, and ``mu``,
     ``nu`` and the table after 3 steps are the materialised gradient's to
     1e-6; the same family without the declaration loses ``nu``'s cross
-    term."""
+    term where the rows are pre-added (a table a lane tile wide), and the
+    head's part of both moments where Adam's kernel takes the rows (this
+    table's 32 floats)."""
     cfg = _config()
     ctx = make_context(cfg, _mesh(1))
     ids = _ids(cfg, 4, seed=2)
@@ -123,17 +126,25 @@ def test_the_tied_table_keeps_the_materialised_gradient_under_adam(caplog):
     register_model(model._replace(name="lfm2_moe_undeclared",
                                   read_whole=frozenset()))
     ctx2 = make_context(_config(model_name="lfm2_moe_undeclared"), _mesh(1))
-    with caplog.at_level(logging.INFO, logger="deepfm_tpu.parallel.spmd"):
-        caplog.clear()
-        wrong, _ = make_spmd_train_step(ctx2, donate=False)(
-            create_spmd_state(ctx2), batch)
-    assert any("moments pre-added" in r.getMessage() for r in caplog.records)
     one, _ = step(first, batch)
-    np.testing.assert_allclose(_adam_leaves(wrong)[0], _adam_leaves(one)[0],
-                               atol=1e-7, rtol=1e-4)       # mu is linear
     touched = np.unique(ids)
-    assert _rel(np.asarray(_adam_leaves(wrong)[1])[touched],
-                np.asarray(_adam_leaves(one)[1])[touched]) > 0.05
+
+    def undeclared(path):
+        with caplog.at_level(logging.INFO, logger="deepfm_tpu.parallel.spmd"):
+            caplog.clear()
+            wrong, _ = make_spmd_train_step(ctx2, donate=False)(
+                create_spmd_state(ctx2), batch)
+        assert any(path in r.getMessage() for r in caplog.records)
+        return [np.asarray(leaf) for leaf in _adam_leaves(wrong)]
+
+    mu, nu = undeclared("Adam kernel over distinct rows")
+    for got, want in zip((mu, nu), _adam_leaves(one)):
+        assert _rel(got[touched], np.asarray(want)[touched]) > 0.05
+    monkeypatch.setattr(adam_rows, "engages", lambda table: False)
+    mu, nu = undeclared("moments pre-added")
+    np.testing.assert_allclose(mu, _adam_leaves(one)[0],
+                               atol=1e-7, rtol=1e-4)       # mu is linear
+    assert _rel(nu[touched], np.asarray(_adam_leaves(one)[1])[touched]) > 0.05
 
 
 def test_data_parallel_gives_the_same_loss_and_model_parallel_is_refused():

@@ -1,18 +1,22 @@
-"""Dense Adam without a dense gradient (``parallel/spmd.py _pre_add_rows``).
+"""Dense Adam without a dense gradient (``parallel/spmd.py _pre_add_rows``,
+``_adam_by_kernel``).
 
 Under Adam on a singleton data axis the dense step hands a table of rows to
-the optimizer as the step's distinct rows, pre-added into Adam's moments,
-instead of as a table-shaped gradient (``ops/embedding.py
-distinct_rows_gather``).  These tests pin: the new step against the
+the optimizer as the step's distinct rows instead of as a table-shaped
+gradient (``ops/embedding.py distinct_rows_gather``): a table whose row is
+narrower than a 128-lane tile takes its whole Adam step in one Pallas kernel
+(``ops/adam_rows.py``, interpret mode here), a wider one has its rows
+pre-added into Adam's moments.  These tests pin: the new step against the
 materialised-gradient step (the same builder with ``_rows_into_moments``
 answering no) over three steps — every parameter and both of Adam's moments of
-every leaf — on the benchmark's tiny configurations and on the other
+every leaf, and each step's loss, whose L2 term of a kernel's table the kernel
+sums — on the benchmark's tiny configurations and on the other
 families, for ids that repeat heavily, that are all distinct (several chunks,
 a ragged last one), that are one row, that lie outside the table, on [1, 1]
-and [1, 2], and through the scanned loop; where the choice engages and where
-the gradient stays table-shaped, by the trace-time log line and by what is
-lowered; and that the engaged step holds no table-shaped zero fill and no
-table-sized temporary.
+and [1, 2], at a width that keeps the pre-add, and through the scanned loop;
+where each path engages and where the gradient stays table-shaped, by the
+trace-time log line and by what is lowered; and that the engaged step holds
+no table-shaped zero fill and no table-sized temporary.
 """
 
 import json
@@ -25,7 +29,7 @@ import numpy as np
 import pytest
 
 from deepfm_tpu.core.config import Config
-from deepfm_tpu.ops import embedding
+from deepfm_tpu.ops import adam_rows, embedding
 from deepfm_tpu.parallel import (
     build_mesh, create_spmd_state, make_context, make_spmd_train_loop,
     make_spmd_train_step, spmd,
@@ -105,8 +109,10 @@ def _batches(cfg: Config, case: str, steps: int = 3) -> list:
     return out
 
 
-def _run(cfg: Config, batches, *, by_rows: bool, loop: int = 0):
-    """The state after the batches, and the log lines of the trace."""
+def _run(cfg: Config, batches, *, by_rows: bool, loop: int = 0,
+         losses: list | None = None):
+    """The state after the batches, and the log lines of the trace;
+    ``losses`` takes each step's ``loss``."""
     lines = []
     handler = logging.Handler()
     handler.emit = lambda record: lines.append(record.getMessage())
@@ -134,8 +140,10 @@ def _run(cfg: Config, batches, *, by_rows: bool, loop: int = 0):
         else:
             step = make_spmd_train_step(ctx)
             for batch in batches:
-                state, _ = step(state, jax.device_put(
+                state, metrics = step(state, jax.device_put(
                     batch, {k: ctx.batch_shardings[k] for k in batch}))
+                if losses is not None:
+                    losses.append(float(metrics["loss"]))
         return jax.device_get(state), lines
     finally:
         patch.undo()
@@ -184,37 +192,56 @@ CASES = ["heavy_repeats", "all_distinct", "one_distinct", "out_of_range"]
 def test_step_by_rows_is_the_materialised_step(name, case):
     cfg = _cfg(name)
     batches = _batches(cfg, case)
-    new, lines = _run(cfg, batches, by_rows=True)
-    ref, ref_lines = _run(cfg, batches, by_rows=False)
-    assert any("moments pre-added by distinct rows, tables=['fm_v']" in line
+    losses, ref_losses = [], []
+    new, lines = _run(cfg, batches, by_rows=True, losses=losses)
+    ref, ref_lines = _run(cfg, batches, by_rows=False, losses=ref_losses)
+    assert any("Adam kernel over distinct rows, tables=['fm_v']" in line
                for line in lines)
-    assert not any("moments pre-added" in line for line in ref_lines)
+    # the loss's L2 term of FM_V comes from the kernel's sum of squares
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-6)
+    assert not any("distinct rows, tables=" in line for line in ref_lines)
     _assert_states_agree(new, ref, cfg.optimizer.learning_rate)
     # the step moved what it should have: a touched row's moments, and not
     # the untouched rows' first moment beyond the penalty's
     assert np.any(np.asarray(ref.opt_state[0].nu["fm_v"]) > 0)
 
 
-@pytest.mark.parametrize("name,case,mesh,extra", [
-    ("deepfm", "heavy_repeats", (1, 2), {}),
-    ("xdeepfm", "out_of_range", (1, 2), {}),
-    ("deepfm", "all_distinct", (1, 4), {}),
-    ("dcnv2", "heavy_repeats", (1, 1), {}),
-    ("two_tower", "heavy_repeats", (1, 1), {}),
-    ("two_tower", "all_distinct", (1, 2), {}),
+KERNEL = "Adam kernel over distinct rows"
+PRE_ADD = "moments pre-added by distinct rows"
+
+
+@pytest.mark.parametrize("name,case,mesh,extra,path", [
+    ("deepfm", "heavy_repeats", (1, 2), {}, KERNEL),
+    ("xdeepfm", "out_of_range", (1, 2), {}, KERNEL),
+    ("deepfm", "all_distinct", (1, 4), {}, KERNEL),
+    ("dcnv2", "heavy_repeats", (1, 1), {}, KERNEL),
+    ("two_tower", "heavy_repeats", (1, 1), {}, KERNEL),
+    ("two_tower", "all_distinct", (1, 2), {}, KERNEL),
     ("deepfm", "heavy_repeats", (1, 1), {"embedding_lr_multiplier": 0.5,
                                         "lr_schedule": "cosine",
                                         "warmup_steps": 2,
-                                        "decay_steps": 10}),
+                                        "decay_steps": 10}, KERNEL),
+    ("deepfm", "heavy_repeats", (1, 2), {"embedding_size": 128}, PRE_ADD),
+    ("deepfm", "out_of_range", (1, 1), {"embedding_size": 128,
+                                       "embedding_lr_multiplier": 0.5},
+     PRE_ADD),
 ], ids=["deepfm_1x2", "xdeepfm_1x2_out_of_range", "deepfm_1x4_all_distinct",
-        "dcnv2", "two_tower", "two_tower_1x2", "lr_split_and_schedule"])
+        "dcnv2", "two_tower", "two_tower_1x2", "lr_split_and_schedule",
+        "wide_rows_1x2", "wide_rows_lr_split_out_of_range"])
 def test_step_by_rows_on_other_meshes_families_and_chains(
-        name, case, mesh, extra):
+        name, case, mesh, extra, path):
+    """The kernel where a table's row is narrower than a lane tile, the
+    pre-added moments where it is a lane tile wide."""
+    width = extra.pop("embedding_size", None)
     cfg = _cfg(name, *mesh, **extra)
+    if width:
+        cfg = cfg.with_overrides(model={"embedding_size": width})
     batches = _batches(cfg, case)
     new, lines = _run(cfg, batches, by_rows=True)
     ref, _ = _run(cfg, batches, by_rows=False)
-    assert any("moments pre-added" in line for line in lines)
+    assert any(path in line for line in lines)
+    assert not any(other in line for line in lines
+                   for other in {KERNEL, PRE_ADD} - {path})
     _assert_states_agree(new, ref, cfg.optimizer.learning_rate)
 
 
@@ -227,7 +254,7 @@ def test_scanned_loop_by_rows_is_the_materialised_steps(name):
     batches = _batches(cfg, "heavy_repeats", steps=4)
     new, lines = _run(cfg, batches, by_rows=True, loop=2)
     ref, _ = _run(cfg, batches, by_rows=False)
-    assert any("moments pre-added" in line for line in lines)
+    assert any(KERNEL in line for line in lines)
     assert int(new.step) == int(ref.step) == 4
     _assert_states_agree(new, ref, cfg.optimizer.learning_rate)
 
@@ -296,34 +323,68 @@ def test_the_all_to_all_owner_side_stays_dense(caplog):
 
 @pytest.mark.parametrize("mp", [1, 2], ids=["mesh_1x1", "mesh_1x2"])
 def test_engaged_step_holds_no_table_shaped_gradient(mp, caplog, monkeypatch):
-    """Mesh [1, 1] and [1, 2] with Adam: the line names the path and the
-    tables; the lowered step is the materialised one less its FM_V-shaped
-    zero fill, and every loop that carries an FM_V-shaped array starts it
-    from the step's own state (the table in the forward's read loop, its two
-    moments in the optimizer's), never from a fill: there is no table-shaped
-    gradient for Adam's pass to read.  (Compiled for the chip the step's
+    """Mesh [1, 1] and [1, 2] with Adam: the line names the path, the
+    tables and the kernel's tile; the lowered step holds no FM_V-shaped zero
+    fill (the materialised one holds two), and the one loop that carries an
+    FM_V-shaped array starts it from the step's own state (the table in the
+    forward's read loop), never from a fill: there is no table-shaped
+    gradient, and Adam's moments take no rows outside the kernel.  (Compiled for the chip the step's
     temporaries fall from 1.66 GB to 4.9 MB: ``perf/rehearse_compile.py``,
     PERF.md §6, PR 35.)"""
     cfg = _cfg("deepfm", 1, mp)
     lowered, said = _lowered_and_said(cfg, caplog)
+    rows, k = cfg.model.feature_size // mp, cfg.model.embedding_size
     assert said == [
-        "table update: moments pre-added by distinct rows, tables=['fm_v']",
+        "table update: Adam kernel over distinct rows, tables=['fm_v'], "
+        f"tile={adam_rows.tile_columns(rows, k)}",
         "table update: dense gradient, tables=['fm_w']"]
-    table = (f"tensor<{cfg.model.feature_size // mp}"
-             f"x{cfg.model.embedding_size}xf32>")
+    table = f"tensor<{rows}x{k}xf32>"
     text = lowered.as_text()
+    # the forward's read loop, and no loop that adds into Adam's moments
     carried = _carried_from(text, table)
-    assert len(carried) == 3 and all(c.startswith("%arg") for c in carried)
+    assert len(carried) == 1 and carried[0].startswith("%arg")
 
-    # the materialised step: one zero fill more (the gradient's; what both
-    # keep is the zero optax adds under Adam's root, ``eps_root``, which XLA
-    # folds), carried through the backward's write loop
+    # no FM_V-shaped fill at all; the materialised step has two (the
+    # gradient's, carried through the backward's write loop, and the zero
+    # optax adds under Adam's root, ``eps_root``, which XLA folds)
+    assert _zero_fills(text, table) == 0
     monkeypatch.setattr(spmd, "_rows_into_moments", lambda ctx: False)
     dense = _lower(cfg).as_text()
-    assert _zero_fills(dense, table) == _zero_fills(text, table) + 1
+    assert _zero_fills(dense, table) == 2
     carried = _carried_from(dense, table)
     assert len(carried) == 2
     assert sum(c.startswith("%arg") for c in carried) == 1
+
+
+@pytest.mark.parametrize("name,width,path", [
+    ("deepfm", None, KERNEL),
+    ("xdeepfm", None, KERNEL),
+    ("evabyte", 128, PRE_ADD),
+    ("keye-vl2", 128, PRE_ADD),
+    ("nemotron-h", 128, PRE_ADD),
+], ids=["deepfm", "xdeepfm", "evabyte", "keye-vl2", "nemotron-h"])
+def test_which_tables_take_the_kernel(name, width, path, caplog,
+                                      monkeypatch):
+    """The benchmark's tiny configurations: FM_V (K = 8) takes the kernel;
+    a token table a lane tile wide (the cells' are 2,048 floats and more)
+    keeps the pre-added moments, and its step lowers, character for
+    character, as with the kernel's rule answering no."""
+    over = _tiny(name)
+    if width:
+        over["model"]["embedding_size"] = width
+    over["data"] = {"batch_size": B}
+    cfg = Config().with_overrides(**over)
+    lowered, said = _lowered_and_said(cfg, caplog)
+    table = "fm_v" if path == KERNEL else spmd.get_model(cfg.model).tables
+    assert [line for line in said if path in line]
+    assert not [line for line in said for other in {KERNEL, PRE_ADD} - {path}
+                if other in line]
+    if path == PRE_ADD:
+        assert all(k in said[0] for k in table)
+        monkeypatch.setattr(adam_rows, "engages", lambda table: False)
+        assert lowered.as_text() == _lower(cfg).as_text()
+    else:
+        assert f"tables=['{table}']" in said[0]
 
 
 def _carried_from(text: str, tensor: str) -> list:
